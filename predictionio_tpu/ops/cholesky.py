@@ -143,6 +143,25 @@ def _chol_solve(A, b):
     return x[..., :k]
 
 
+def resolve_solve_mode(platform=None, prefer_pallas: bool = False) -> str:
+    """``"pallas"`` or ``"xla"`` for a solve traced for ``platform`` —
+    the platform and ``PIO_PALLAS_SOLVE``, nothing else: unset is the
+    XLA recursion (``auto`` when ``prefer_pallas``), ``1`` and ``auto``
+    are the VMEM kernel on a TPU. Nothing is tried and caught: on a
+    TPU a selected kernel compiles or the caller fails with the
+    compiler's message."""
+    import os
+
+    from predictionio_tpu import ops
+
+    flag = os.environ.get("PIO_PALLAS_SOLVE", "")
+    if flag == "" and prefer_pallas:
+        flag = "auto"
+    if flag in ("1", "auto") and ops.use_pallas(platform):
+        return "pallas"
+    return "xla"
+
+
 def chol_solve_batched(A, b, platform=None, prefer_pallas=False):
     """Solve the batched SPD systems ``A x = b``.
 
@@ -151,16 +170,10 @@ def chol_solve_batched(A, b, platform=None, prefer_pallas=False):
 
     The default is the XLA block-recursive path (internally padded to
     a power of two with an identity block, which factors to itself and
-    leaves the k×k solve untouched). ``PIO_PALLAS_SOLVE=1`` opts into
-    the Pallas VMEM-resident kernel (:func:`chol_solve_pallas`) on TPU;
-    ``PIO_PALLAS_SOLVE=auto`` restores the r4 behavior (kernel on TPU
-    behind a one-time on-device preflight with automatic XLA fallback).
-
-    Why XLA is the default (r5 A/B on the v5e, `profile_als.py --ab`):
-    the full ML-20M train measured warm 4.92 s with the XLA recursion
-    vs 9.78 s with the Pallas kernel — the VMEM solve halves the cold
-    compile (24.5 s vs 113 s) but loses 2× on execution on real
-    hardware, so it stays opt-in for compile-latency-sensitive runs.
+    leaves the k×k solve untouched). ``PIO_PALLAS_SOLVE=1`` (or
+    ``auto``) selects the Pallas VMEM-resident kernel
+    (:func:`chol_solve_pallas`) on TPU for (N ≥ 256, k, k) batches —
+    see :func:`resolve_solve_mode`.
 
     ``prefer_pallas=True`` flips the UNSET-flag default to ``auto``:
     callers already committed to the fat-dispatch regime (the fused
@@ -173,15 +186,11 @@ def chol_solve_batched(A, b, platform=None, prefer_pallas=False):
     b = jnp.asarray(b, jnp.float32)
     import os
 
-    from predictionio_tpu import ops
-
-    flag = os.environ.get("PIO_PALLAS_SOLVE", "")
-    if flag == "" and prefer_pallas:
-        flag = "auto"
-    if A.ndim == 3 and A.shape[0] >= 256 and ops.use_pallas(platform):
-        if flag == "1" or (flag == "auto" and _pallas_solve_preflight()):
-            return chol_solve_pallas(A, b)
-    elif flag == "1":
+    kernel_shape = A.ndim == 3 and A.shape[0] >= 256
+    if kernel_shape and resolve_solve_mode(platform,
+                                           prefer_pallas) == "pallas":
+        return chol_solve_pallas(A, b)
+    if os.environ.get("PIO_PALLAS_SOLVE", "") == "1":
         # The flag promises "force the kernel" — an A/B run that
         # silently measured the XLA path instead would be dishonest.
         import warnings
@@ -194,27 +203,6 @@ def chol_solve_batched(A, b, platform=None, prefer_pallas=False):
             f"dispatch ({reason}); falling back to the XLA path",
             RuntimeWarning, stacklevel=2)
     return _chol_solve(A, b)
-
-
-_PALLAS_PREFLIGHT: dict = {}
-
-
-def _pallas_solve_preflight() -> bool:
-    """Compile + run the kernel once on a tiny batch (cached)."""
-    if "ok" not in _PALLAS_PREFLIGHT:
-        try:
-            import numpy as _np
-
-            A = _np.broadcast_to(_np.eye(8, dtype=_np.float32),
-                                 (256, 8, 8)).copy()
-            b = _np.ones((256, 8), _np.float32)
-            x = _np.asarray(chol_solve_pallas(jnp.asarray(A),
-                                              jnp.asarray(b)))
-            _PALLAS_PREFLIGHT["ok"] = bool(
-                _np.allclose(x, b, rtol=1e-5, atol=1e-6))
-        except Exception:
-            _PALLAS_PREFLIGHT["ok"] = False
-    return _PALLAS_PREFLIGHT["ok"]
 
 
 # -- Pallas VMEM-resident blocked solve ---------------------------------------

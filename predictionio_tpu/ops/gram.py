@@ -115,48 +115,62 @@ def rows_gram(F_g, w_outer, w_b, *, block_rows: int = 8,
 #
 # The XLA path above still pays for the gather as a SEPARATE HLO: the
 # (R, C, k) gathered block round-trips through HBM between the gather
-# and the Gram einsum, and the gather itself is pinned at XLA's ~140
-# GB/s row-gather ceiling (r5 trace). This kernel moves the gather
-# inside: the index block is DMA'd into SMEM up front (the scalar core
-# needs the row ids to program the data DMAs), factor rows stream
-# HBM→VMEM in T-row tiles with per-row async copies, and the weighted
-# normal equations accumulate in a (k, k) VMEM block — so per row block
-# only (C·4B indices + C·k·F-bytes factor reads + k·(k+1)·4B results)
-# touch HBM, the roofline minimum.
+# and the Gram einsum. This kernel moves the gather inside: the index
+# block is DMA'd into SMEM up front (the scalar core needs the row ids
+# to program the data DMAs), factor rows stream HBM→VMEM in T-row tiles
+# with per-row async copies, and the weighted normal equations
+# accumulate in VMEM.
 #
-# VMEM sizing (per program): 2·RB·C·4 (weights) + T·k·F_bytes (factor
-# tile) + (k+1)·k·4 (accumulators) + RB·k·(k+1)·4 (output block),
-# with T = min(C, 256) and RB = 8 (Mosaic block mappings want the row
-# block divisible by 8; rows are padded up in the wrapper) — worst
-# case (C = 8192, k = 128) ≈ 0.8 MB, ~1.6 MB with the runtime's double
-# buffering of the blocked operands: far under the ~16 MB/core budget.
+# What the chip's compiler accepts (Mosaic, v5e): a DMA slice must be a
+# whole number of (1, 128) f32 lane tiles, so a ``1 × k`` copy with
+# k < 128 is refused ("Slice shape along dimension 1 must be aligned to
+# tiling (128)"), and bf16 rows are packed two to a sublane, so a
+# single bf16 row cannot be sliced at all. The kernel therefore gathers
+# LINES: F_other is viewed as (N/G, 128) f32 with G = 128 // k rows to
+# a line (a free reshape of the row-major array), row ``i`` is fetched
+# as line ``i // G``, and the lanes of the other G-1 rows are masked to
+# zero. The Gram of the masked tile is block-diagonal — each row lands
+# in the diagonal block of its slot ``i % G`` — and the G diagonal
+# blocks are summed once per output row. bf16 factors are upcast
+# before the call: a line is 512 B whatever the dtype, so bf16 saves
+# no gather traffic here.
+#
+# VMEM sizing (per program): 3·RB·C·4 (weights + index block) +
+# T·L·4 (line tile) + (L+1)·L·4 (accumulators) + RB·kp·(kp+1)·4
+# (output block), with L = max(128, kp), T = min(C, 256), RB = 8 —
+# worst case (C = 8192) ≈ 1 MB, ~2 MB with the runtime's double
+# buffering of the blocked operands.
 
 _GATHER_TILE = 256  # factor rows per DMA burst (T)
+_LANES = 128
 
 
-def _gather_gram_kernel(idx_hbm, wo_ref, wb_ref, F_hbm, A_ref, b_ref,
-                        idx_smem, f_tile, accA, accB, sem_idx, sem_row,
-                        *, RB: int, C: int, T: int, k: int):
+def _gather_gram_kernel(idx_hbm, idx_ref, wo_ref, wb_ref, F_hbm, A_ref,
+                        b_ref, idx_smem, f_tile, accA, accB, sem_idx,
+                        sem_row, *, RB: int, C: int, T: int, kp: int,
+                        G: int):
     i = pl.program_id(0)
+    L = f_tile.shape[1]
     # index block HBM→SMEM first: row ids live on the scalar core, which
-    # issues the factor-row DMAs below
+    # issues the factor-line DMAs below
     cp = pltpu.make_async_copy(
         idx_hbm.at[pl.ds(i * RB, RB), :], idx_smem, sem_idx)
     cp.start()
     cp.wait()
     nT = C // T
+    lane_slot = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1) // kp
     for r in range(RB):  # static unroll: RB is small (≤ 8)
-        accA[...] = jnp.zeros((k, k), jnp.float32)
-        accB[...] = jnp.zeros((1, k), jnp.float32)
+        accA[...] = jnp.zeros((L, L), jnp.float32)
+        accB[...] = jnp.zeros((1, L), jnp.float32)
 
         def tile_body(t, _):
-            # burst-issue T row copies, then drain the semaphore T
+            # burst-issue T line copies, then drain the semaphore T
             # times — each wait retires one completed copy (all copies
-            # share sem_row and the same (1, k) shape)
+            # share sem_row and the same (1, L) shape)
             def issue(j, _):
                 row = idx_smem[r, t * T + j]
                 pltpu.make_async_copy(
-                    F_hbm.at[pl.ds(row, 1), :],
+                    F_hbm.at[pl.ds(row // G, 1), :],
                     f_tile.at[pl.ds(j, 1), :],
                     sem_row).start()
                 return 0
@@ -171,7 +185,10 @@ def _gather_gram_kernel(idx_hbm, wo_ref, wb_ref, F_hbm, A_ref, b_ref,
                 return 0
 
             jax.lax.fori_loop(0, T, drain, 0)
-            F = f_tile[...].astype(jnp.float32)
+            F = f_tile[...]
+            if G > 1:
+                slot = idx_ref[r, pl.ds(t * T, T)] % G
+                F = jnp.where(lane_slot == slot[:, None], F, 0.0)
             wo = wo_ref[r, pl.ds(t * T, T)]
             wb = wb_ref[r, pl.ds(t * T, T)]
             # f32 normal equations (see rows_gram: bf16 Gram error ~3e-1
@@ -184,8 +201,13 @@ def _gather_gram_kernel(idx_hbm, wo_ref, wb_ref, F_hbm, A_ref, b_ref,
             return 0
 
         jax.lax.fori_loop(0, nT, tile_body, 0)
-        A_ref[r] = accA[...]
-        b_ref[r] = accB[0]
+        A = accA[0:kp, 0:kp]
+        b = accB[:, 0:kp]
+        for g in range(1, G):  # fold the slots' diagonal blocks
+            A = A + accA[g * kp:(g + 1) * kp, g * kp:(g + 1) * kp]
+            b = b + accB[:, g * kp:(g + 1) * kp]
+        A_ref[r] = A
+        b_ref[r] = b[0]
 
 
 def gather_gram_xla(F_other, idx, wo, wb):
@@ -200,6 +222,26 @@ def gather_gram_xla(F_other, idx, wo, wb):
     return A, b
 
 
+def kernel_takes_width(C: int) -> bool:
+    """The kernel takes a bucket whose width is a whole number of
+    128-lane tiles. The ALS ladder's two narrow widths (8, 32) stay on
+    the XLA gather + einsum: their (RB, C) index block cannot be sliced
+    into SMEM ("Slice shape along dimension 1 must be aligned to tiling
+    (128)", the v5e's compiler)."""
+    return C % _LANES == 0
+
+
+def _line_width(k: int):
+    """(kp, G): the padded row width and rows per 128-lane line — kp is
+    the smallest divisor of 128 that holds k (or a multiple of 128)."""
+    if k >= _LANES:
+        return -(-k // _LANES) * _LANES, 1
+    kp = 8
+    while kp < k:
+        kp *= 2
+    return kp, _LANES // kp
+
+
 def gather_gram(F_other, idx, wo, wb, *, interpret: bool = False):
     """Fused gather→weighted-Gram: ONE Pallas kernel computing
 
@@ -207,9 +249,9 @@ def gather_gram(F_other, idx, wo, wb, *, interpret: bool = False):
         b[r] = Σ_c wb[r,c] · F[idx[r,c]]
 
     without ever materializing the gathered (R, C, k) block in HBM.
-    ``F_other`` may be f32 or bf16 (bf16 halves the dominant factor-row
-    HBM traffic; rows are cast to f32 in VMEM before accumulation).
-    ``interpret=True`` runs the Mosaic interpreter (CPU tests).
+    ``F_other`` may be f32 or bf16 (bf16 rows are upcast before the
+    call — see the block comment above). ``interpret=True`` runs the
+    Mosaic interpreter (CPU tests).
     """
     R, C = idx.shape
     N, k = F_other.shape
@@ -219,6 +261,15 @@ def gather_gram(F_other, idx, wo, wb, *, interpret: bool = False):
     T = min(C, _GATHER_TILE)
     while C % T:  # ladder widths always divide; guard odd test shapes
         T -= 1
+    # lines of G rows × kp lanes (a pure reshape when k divides 128 and
+    # N divides G — rank 64 on an even catalog)
+    kp, G = _line_width(k)
+    L = kp * G
+    F = F_other.astype(jnp.float32)
+    Np = -(-N // G) * G
+    if kp != k or Np != N:
+        F = jnp.pad(F, [(0, Np - N), (0, kp - k)])
+    F = F.reshape(Np // G, L)
     # Mosaic block mappings need the row-block dim divisible by 8 (or
     # equal to R): pad the row count up and slice the results back —
     # pad rows gather row 0 with zero weight, contributing nothing
@@ -229,44 +280,47 @@ def gather_gram(F_other, idx, wo, wb, *, interpret: bool = False):
         idx = jnp.pad(idx, pad)
         wo = jnp.pad(wo, pad)
         wb = jnp.pad(wb, pad)
+    row_block = pl.BlockSpec((RB, C), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM)
     A, b = pl.pallas_call(
-        functools.partial(_gather_gram_kernel, RB=RB, C=C, T=T, k=k),
+        functools.partial(_gather_gram_kernel, RB=RB, C=C, T=T, kp=kp,
+                          G=G),
         grid=(Rp // RB,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),   # idx: stays in HBM
-            pl.BlockSpec((RB, C), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((RB, C), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # F_other: HBM source
+            pl.BlockSpec(memory_space=pl.ANY),   # idx: stays in HBM
+            row_block,      # idx again, as a vector operand (slot mask)
+            row_block,
+            row_block,
+            pl.BlockSpec(memory_space=pl.ANY),   # F lines: HBM source
         ],
         out_specs=(
-            pl.BlockSpec((RB, k, k), lambda i: (i, 0, 0),
+            pl.BlockSpec((RB, kp, kp), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((RB, k), lambda i: (i, 0),
+            pl.BlockSpec((RB, kp), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((Rp, k, k), jnp.float32),
-            jax.ShapeDtypeStruct((Rp, k), jnp.float32),
+            jax.ShapeDtypeStruct((Rp, kp, kp), jnp.float32),
+            jax.ShapeDtypeStruct((Rp, kp), jnp.float32),
         ),
         scratch_shapes=[
             pltpu.SMEM((RB, C), jnp.int32),
-            pltpu.VMEM((T, k), F_other.dtype),
-            pltpu.VMEM((k, k), jnp.float32),
-            pltpu.VMEM((1, k), jnp.float32),
+            pltpu.VMEM((T, L), jnp.float32),
+            pltpu.VMEM((L, L), jnp.float32),
+            pltpu.VMEM((1, L), jnp.float32),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
         cost_estimate=pl.CostEstimate(
-            flops=2 * R * C * k * (k + 1),
-            bytes_accessed=(R * C * (4 + F_other.dtype.itemsize * k)
-                            + 8 * R * C + 4 * R * k * (k + 1)),
+            flops=2 * R * C * L * (L + 1),
+            bytes_accessed=(R * C * (8 + 4 * L)
+                            + 8 * R * C + 4 * R * kp * (kp + 1)),
             transcendentals=0,
         ),
+        name="gather_gram",
         interpret=interpret,
-    )(idx, wo, wb, F_other)
-    return (A, b) if Rp == R else (A[:R], b[:R])
+    )(idx, idx, wo, wb, F)
+    return A[:R, :k, :k], b[:R, :k]
 
 
 def resolve_gram_mode(platform: Optional[str] = None) -> str:
@@ -276,12 +330,16 @@ def resolve_gram_mode(platform: Optional[str] = None) -> str:
     - ``"pallas"`` — the fused kernel (:func:`gather_gram`);
     - ``"interpret"`` — the same kernel under the Mosaic interpreter
       (chip-free CPU parity testing of the TRAIN-level program);
-    - ``"off"`` — today's XLA gather + packed einsum path.
+    - ``"off"`` — the XLA gather + packed einsum path.
 
-    Flag values: ``auto`` (default — kernel on TPU behind a one-time
-    on-device preflight, XLA elsewhere), ``0`` (force XLA everywhere,
-    byte-identical to the pre-kernel program), ``1`` (force the kernel;
-    warns and falls back off-TPU), ``interpret`` (test escape hatch).
+    The rule is the platform and the flag, nothing else: ``auto``
+    (default) is the kernel on a TPU and XLA elsewhere; ``0`` forces
+    XLA everywhere; ``1`` forces the kernel (off-TPU it cannot
+    dispatch: warns and resolves to XLA); ``interpret`` is the tests'
+    escape hatch. Nothing is tried and caught here — on a TPU a
+    selected kernel compiles, or the train fails with the compiler's
+    message (tests/test_chip_compile.py holds the kernel to the chip's
+    compiler at every ladder width).
     """
     flag = os.environ.get("PIO_PALLAS_GRAM", "auto").strip().lower()
     if flag in ("0", "off"):
@@ -290,9 +348,9 @@ def resolve_gram_mode(platform: Optional[str] = None) -> str:
         return "interpret"
     from predictionio_tpu import ops
 
+    if ops.use_pallas(platform):
+        return "pallas"
     if flag == "1":
-        if ops.use_pallas(platform):
-            return "pallas"
         import warnings
 
         warnings.warn(
@@ -300,36 +358,4 @@ def resolve_gram_mode(platform: Optional[str] = None) -> str:
             f"cannot dispatch (platform {platform or 'default'} is not "
             f"TPU); falling back to the XLA path",
             RuntimeWarning, stacklevel=2)
-        return "off"
-    if not ops.use_pallas(platform):
-        return "off"
-    return "pallas" if _gather_gram_preflight() else "off"
-
-
-_GATHER_PREFLIGHT: dict = {}
-
-
-def _gather_gram_preflight() -> bool:
-    """Compile + run the kernel once on a tiny block and check it
-    against the XLA fallback (cached) — same contract as
-    ``cholesky._pallas_solve_preflight``."""
-    if "ok" not in _GATHER_PREFLIGHT:
-        try:
-            import numpy as _np
-
-            rng = _np.random.default_rng(0)
-            F = rng.standard_normal((64, 8)).astype(_np.float32)
-            idx = rng.integers(0, 64, (8, 32)).astype(_np.int32)
-            wo = rng.standard_normal((8, 32)).astype(_np.float32)
-            wb = rng.standard_normal((8, 32)).astype(_np.float32)
-            A, b = gather_gram(jnp.asarray(F), jnp.asarray(idx),
-                               jnp.asarray(wo), jnp.asarray(wb))
-            A_ref, b_ref = gather_gram_xla(F, idx, wo, wb)
-            _GATHER_PREFLIGHT["ok"] = bool(
-                _np.allclose(_np.asarray(A), _np.asarray(A_ref),
-                             rtol=1e-4, atol=1e-4)
-                and _np.allclose(_np.asarray(b), _np.asarray(b_ref),
-                                 rtol=1e-4, atol=1e-4))
-        except Exception:
-            _GATHER_PREFLIGHT["ok"] = False
-    return _GATHER_PREFLIGHT["ok"]
+    return "off"

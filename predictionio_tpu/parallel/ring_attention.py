@@ -88,11 +88,8 @@ def _ring_attention_sharded(q, k, v, k_mask, *, mesh, axis: str,
                             causal: bool):
     from jax.sharding import PartitionSpec as P
 
-    from predictionio_tpu.parallel.mesh import (get_shard_map, has_vma,
-                                                pvary,
-                                                shard_map_unchecked)
+    from predictionio_tpu.parallel.mesh import pvary
 
-    shard_map = get_shard_map()
     n_dev = mesh.shape[axis]
     scale = 1.0 / np.sqrt(q.shape[-1])
 
@@ -133,15 +130,9 @@ def _ring_attention_sharded(q, k, v, k_mask, *, mesh, axis: str,
 
     spec = P(None, axis, None, None)
     mspec = P(None, axis)
-    if has_vma():
-        fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                        in_specs=(spec, spec, spec, mspec),
                        out_specs=spec)
-    else:
-        # pre-vma jax: the pvary annotations above are no-ops and the
-        # set-based checker rejects the scan carry — run unchecked
-        fn = shard_map_unchecked(local, mesh,
-                                 (spec, spec, spec, mspec), spec)
     if k_mask is None:
         k_mask = jnp.ones(k.shape[:2], bool)
     return fn(q, k, v, k_mask)

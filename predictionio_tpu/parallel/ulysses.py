@@ -28,9 +28,6 @@ def _ulysses_sharded(q, k, v, k_mask, *, mesh, axis: str, causal: bool):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from predictionio_tpu.parallel.mesh import get_shard_map
-
-    shard_map = get_shard_map()
     n_dev = mesh.shape[axis]
 
     def local(q_l, k_l, v_l, mask_l):
@@ -51,8 +48,8 @@ def _ulysses_sharded(q, k, v, k_mask, *, mesh, axis: str, causal: bool):
 
     spec = P(None, axis, None, None)
     mspec = P(None, axis)
-    fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec, mspec),
-                   out_specs=spec)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec, spec, spec, mspec), out_specs=spec)
     if k_mask is None:
         k_mask = jnp.ones(k.shape[:2], bool)
     return fn(q, k, v, k_mask)

@@ -19,8 +19,8 @@ end-to-end concurrent p50 6.45 → 5.75 ms and 1,103 → 1,349 q/s on a
 the full 2 ms returns only where the dispatch itself is sub-ms, i.e.
 on-chip). ``max_wait_ms > 0`` remains
 as an opt-in batch-formation floor for sparse traffic where trading
-latency for bigger batches is worth it (e.g. remote-tunneled devices
-with a large fixed per-dispatch cost).
+latency for bigger batches is worth it (a large fixed per-dispatch
+cost).
 
 Latency math: a lone query pays ~0 extra; under load per-query cost
 approaches dispatch/B. Enable with ``pio deploy --batching`` (or

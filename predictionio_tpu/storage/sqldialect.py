@@ -16,7 +16,7 @@ interface, which absorbs the real engine differences:
 - **upsert** — INSERT OR REPLACE / ON CONFLICT DO UPDATE / REPLACE INTO.
 - **generated keys** — lastrowid vs RETURNING.
 - **index creation** — MySQL has no CREATE INDEX IF NOT EXISTS.
-- **error taxonomy** — which exceptions mean "table missing", and
+- **error classes** — which exceptions mean "table missing", and
   whether the failed transaction must be rolled back first (PostgreSQL).
 
 The SQLITE dialect is the CI-tested reference implementation; PGSQL /
@@ -99,7 +99,7 @@ class SQLDialect(ABC):
         engines with true server-side cursors override."""
         return conn.cursor()
 
-    # -- error taxonomy --------------------------------------------------------
+    # -- error classes --------------------------------------------------------
 
     @abstractmethod
     def is_missing_table(self, exc: BaseException) -> bool:

@@ -35,7 +35,7 @@ _CALL_PRIMS = ("pjit", "closed_call", "core_call", "xla_call", "remat",
 
 def _inner_jaxprs(eqn):
     """Every ClosedJaxpr/Jaxpr hiding in an eqn's params."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     out = []
     for v in eqn.params.values():
@@ -49,7 +49,7 @@ def _inner_jaxprs(eqn):
 
 def count_jaxpr_ops(jaxpr) -> int:
     """Device-op estimate for a (Closed)Jaxpr — see module docstring."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
@@ -110,15 +110,9 @@ def als_iteration_ops(prep, params, gram_mode: str = "off",
     """Device ops for ONE ALS iteration (two half-steps) at ``prep``'s
     geometry under ``gram_mode`` — traced abstractly for ``platform``
     (default "tpu": count what the CHIP would dispatch, even from a
-    chip-free host).
-
-    The Pallas solve preflight is bypassed by tracing with
-    ``PIO_PALLAS_SOLVE=1`` when the fused mode would prefer the kernel
-    (the preflight EXECUTES on the default backend — meaningless and
-    Mosaic-unsupported during an abstract CPU trace of a TPU program).
+    chip-free host). The fused modes prefer the Pallas solve by rule
+    (``cholesky.resolve_solve_mode``), so the trace holds it too.
     """
-    import os
-
     import jax
     import jax.numpy as jnp
 
@@ -144,16 +138,7 @@ def als_iteration_ops(prep, params, gram_mode: str = "off",
     V = jax.ShapeDtypeStruct((prep.n_items, p.rank), jnp.float32)
     s = jax.ShapeDtypeStruct((), jnp.float32)
 
-    force_solve = (gram_mode in ("pallas", "interpret")
-                   and platform == "tpu"
-                   and not os.environ.get("PIO_PALLAS_SOLVE"))
-    if force_solve:
-        os.environ["PIO_PALLAS_SOLVE"] = "1"
-    try:
-        return count_fn_ops(step, u_bufs, i_bufs, U, V, s, s)
-    finally:
-        if force_solve:
-            del os.environ["PIO_PALLAS_SOLVE"]
+    return count_fn_ops(step, u_bufs, i_bufs, U, V, s, s)
 
 
 def als_dispatch_report(prep, params, platform: Optional[str] = "tpu"

@@ -172,6 +172,30 @@ class TestCLIVerbs:
         assert "ran:a,b" in r.stdout
 
 
+class TestStatusVerb:
+    """`pio status` answers for the devices too: "all systems go" is
+    only printed when jax has them."""
+
+    def _status(self, tmp_path, platforms):
+        return subprocess.run(
+            [sys.executable, "-m", "predictionio_tpu.tools.cli", "status"],
+            capture_output=True, text=True, cwd=REPO,
+            env={**os.environ, "PYTHONPATH": REPO,
+                 "PIO_HOME": str(tmp_path / "home"),
+                 "JAX_PLATFORMS": platforms})
+
+    def test_ok_with_devices(self, tmp_path):
+        r = self._status(tmp_path, "cpu")
+        assert r.returncode == 0, r.stderr
+        assert "jax devices" in r.stdout and "all systems go" in r.stdout
+
+    def test_fails_without_devices(self, tmp_path):
+        r = self._status(tmp_path, "no_such_platform")
+        assert r.returncode != 0
+        assert "all systems go" not in r.stdout
+        assert "jax devices unavailable" in r.stderr
+
+
 class TestBinScripts:
     def test_present_and_executable(self):
         for name in ("pio", "pio-daemon", "pio-start-all", "pio-stop-all",

@@ -1,0 +1,210 @@
+"""The main path's kernels and programs, held to the CHIP's compiler.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``): what it
+refuses here it would refuse on the v5e, at no chip time. Interpret
+mode and ``jax.export`` lowering (tests/test_ops.py TestTPULowering)
+cannot show this — the fused gather→Gram kernel passed both and was
+refused by Mosaic for its ``1 × k`` row slices until it gathered
+128-lane lines.
+
+Everything that touches the topology lives in the module-scoped
+fixtures below — never at import, in a ``skipif`` or in
+``parametrize`` — and in this ONE file: only one process may load the
+TPU library, and pytest-xdist hands a file to one worker.
+
+A compile that passes is not a chip run.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device compile is written to the persistent cache but
+    # cannot be read back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _sds_tree(tree, sharding_of):
+    """Host arrays → ShapeDtypeStructs (lowering needs only avals)."""
+    def one(a):
+        a = np.asarray(a)
+        return _sds(a.shape, a.dtype, sharding_of(a))
+
+    return jax.tree.map(one, tree)
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# -- the four kernels at the widths of ML-20M, rank 64 ------------------------
+
+
+def test_chol_solve_pallas(one_chip):
+    from predictionio_tpu.ops.cholesky import chol_solve_pallas
+
+    c = jax.jit(chol_solve_pallas).lower(
+        _sds((4096, 64, 64), jnp.float32, one_chip),
+        _sds((4096, 64), jnp.float32, one_chip)).compile()
+    assert _has_kernel(c)
+
+
+def test_rows_gram(one_chip):
+    from predictionio_tpu.ops.gram import rows_gram
+
+    c = rows_gram.lower(
+        _sds((64, 128, 64), jnp.float32, one_chip),
+        _sds((64, 128), jnp.float32, one_chip),
+        _sds((64, 128), jnp.float32, one_chip)).compile()
+    assert _has_kernel(c)
+
+
+def test_score_topk(one_chip):
+    import functools
+
+    from predictionio_tpu.ops.topk import score_topk
+
+    c = jax.jit(functools.partial(score_topk, k=16, tile=2048,
+                                  n_valid=26_744)).lower(
+        _sds((64, 64), jnp.float32, one_chip),
+        _sds((27_136, 64), jnp.float32, one_chip)).compile()
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("C", [128, 512, 2048, 8192])
+def test_gather_gram(one_chip, C, dtype):
+    """Rank 64 at every gathered ladder width, against both factor
+    sides (26,744 items; 138,493 users padded to the solve chunk)."""
+    from predictionio_tpu.ops.gram import gather_gram
+
+    for n_other in (26_744, 138_496):
+        c = jax.jit(gather_gram).lower(
+            _sds((n_other, 64), dtype, one_chip),
+            _sds((304, C), jnp.int32, one_chip),
+            _sds((304, C), jnp.float32, one_chip),
+            _sds((304, C), jnp.float32, one_chip)).compile()
+        assert _has_kernel(c)
+
+
+# -- the programs around them -------------------------------------------------
+
+
+@pytest.mark.parametrize("B", [1, 2, 4, 8, 16, 32, 64])
+def test_serving_program_every_default_bucket(one_chip, B):
+    """gather → score → top-k at each bucket of the default AOT ladder
+    (the XLA scorer: ``ResidentScorer._pallas_for`` keeps the streaming
+    kernel for B·n_items > 64M)."""
+    from predictionio_tpu.models import als
+    from predictionio_tpu.server.aot import BucketLadder
+
+    assert B in list(BucketLadder.parse("auto", 64))
+    als._gather_score_topk_jit().lower(
+        _sds((138_493, 64), jnp.float32, one_chip),
+        _sds((27_136, 64), jnp.float32, one_chip),
+        _sds((B,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        k=16, n_valid=26_744, pallas=False, tile=2048).compile()
+
+
+@pytest.fixture(scope="module")
+def ml20m_shape_coo():
+    """The ML-20M catalog (138,493 × 26,744) at a reduced nnz."""
+    from predictionio_tpu.models.als import RatingsCOO
+
+    rng = np.random.default_rng(7)
+    nnz = 200_000
+    users = (rng.zipf(1.35, size=nnz) % 138_493).astype(np.int32)
+    items = (rng.zipf(1.25, size=nnz) % 26_744).astype(np.int32)
+    ratings = (rng.integers(1, 11, size=nnz) * 0.5).astype(np.float32)
+    return RatingsCOO(users, items, ratings, 138_493, 26_744)
+
+
+@pytest.mark.parametrize("gram_mode", ["pallas", "off"])
+def test_als_step_single_device(one_chip, ml20m_shape_coo, gram_mode):
+    """One ALS iteration at rank 64 as `pio train` builds it. Code that
+    asks ``jax.default_backend()`` sees the CPU here, so the platform
+    and the Gram mode a TPU resolves to are passed in."""
+    from predictionio_tpu.models import als
+    from predictionio_tpu.utils.opcount import _host_side_bufs
+
+    prep = als.als_prepare(ml20m_shape_coo)
+    train = als._compiled_bucketed(
+        prep.u_side.geometry, prep.i_side.geometry, prep.n_users,
+        prep.n_items, 64, 1, False, True, "tpu", False, "high", gram_mode)
+    args = _sds_tree(
+        (_host_side_bufs(prep.u_side), _host_side_bufs(prep.i_side),
+         np.zeros((prep.n_items, 64), np.float32),
+         np.float32(0.05), np.float32(1.0)),
+        lambda a: one_chip)
+    c = train.lower(*args).compile()
+    assert _has_kernel(c) == (gram_mode == "pallas")
+
+
+def test_als_step_sharded_four_chips(topo, ml20m_shape_coo):
+    """The program `pio train` takes on a four-chip host (default
+    meshConf → als_train_sharded), fused mode, on a mesh of the
+    described devices; per-device memory must fit the chip."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from predictionio_tpu.models import als_sharded
+
+    n_dev = len(topo.devices)
+    assert n_dev == 4
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    sprep = als_sharded.als_prepare_sharded(ml20m_shape_coo, n_dev)
+    train = als_sharded._compiled_sharded(
+        mesh, sprep.geom_u, sprep.geom_i, 64, 1, False, True,
+        gram_mode="pallas")
+
+    def sharding_of(a):
+        # stacked layouts lead with the device axis
+        return NamedSharding(mesh, P("data") if a.ndim >= 1
+                             and a.shape[0] == n_dev else P())
+
+    args = _sds_tree(
+        (sprep._stacked(sprep.u_sides), sprep._stacked(sprep.i_sides)),
+        sharding_of) + (
+        _sds((sprep.block_i * n_dev, 64), jnp.float32,
+             NamedSharding(mesh, P("data", None))),
+        _sds((), jnp.float32, NamedSharding(mesh, P())),
+        _sds((), jnp.float32, NamedSharding(mesh, P())))
+    c = train.lower(*args).compile()
+    assert _has_kernel(c) and "all-gather" in c.as_text()
+    mem = c.memory_analysis()
+    per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                  + mem.temp_size_in_bytes)
+    assert per_device < 16e9

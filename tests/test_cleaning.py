@@ -17,13 +17,14 @@ UTC = dt.timezone.utc
 NOW = dt.datetime(2026, 7, 29, 12, 0, 0, tzinfo=UTC)
 
 
-def ev(name, eid, t_days_ago, props=None, etype="user", target=None):
+def ev(name, eid, t_days_ago, props=None, etype="user", target=None,
+       now=NOW):
     return Event(
         event=name, entity_type=etype, entity_id=eid,
         target_entity_type="item" if target else None,
         target_entity_id=target,
         properties=props or {},
-        event_time=NOW - dt.timedelta(days=t_days_ago),
+        event_time=now - dt.timedelta(days=t_days_ago),
     )
 
 
@@ -140,10 +141,15 @@ class TestMixin:
         from predictionio_tpu.templates.recommendation.engine import (
             DataSourceParams, RecDataSource)
 
+        # read_training cleans against REAL wall-clock now (see above):
+        # event times are relative to it, not to the fixed NOW
+        real_now = dt.datetime.now(UTC)
         storage.events.insert(
-            ev("rate", "u1", 100, {"rating": 5.0}, target="i1"), app.id)
+            ev("rate", "u1", 100, {"rating": 5.0}, target="i1",
+               now=real_now), app.id)
         storage.events.insert(
-            ev("rate", "u1", 1, {"rating": 3.0}, target="i2"), app.id)
+            ev("rate", "u1", 1, {"rating": 3.0}, target="i2",
+               now=real_now), app.id)
         ds = RecDataSource(DataSourceParams(
             app_name="cleanapp",
             event_window={"duration": "30 days"}))

@@ -86,14 +86,13 @@ COLLECTIVES = textwrap.dedent("""
 
     # a cross-process collective: psum over the 4-device global mesh
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from predictionio_tpu.parallel.mesh import get_shard_map
 
     mesh = Mesh(np.array(jax.devices()).reshape(4), ("data",))
     sharding = NamedSharding(mesh, P("data"))
     x = jax.make_array_from_callback(
         (8,), sharding,
         lambda idx: np.arange(8, dtype=np.float32)[idx])
-    sm = get_shard_map()
+    sm = jax.shard_map
 
     def f(x):
         return jax.lax.psum(x.sum(), "data")

@@ -10,7 +10,7 @@ Four tiers here:
 - The SAME store suites run through the REAL ``PostgresDialect`` /
   ``MySQLDialect`` classes bound to wire-behavior driver doubles
   (``tests/fake_sql_drivers.py``): the dialects' own upsert SQL,
-  RETURNING path, error taxonomy, streaming cursors and
+  RETURNING path, error classes, streaming cursors and
   aborted-transaction recovery all execute, against emulated server
   semantics (this image has neither servers nor drivers — see the
   doubles' module docstring for exactly what is and is not proven).
