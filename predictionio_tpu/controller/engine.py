@@ -97,25 +97,28 @@ class Engine:
 
     def train(self, ctx: WorkflowContext, engine_params: EngineParams) -> List[Any]:
         """readTraining → prepare → per-algorithm train (reference:
-        Engine.train, SURVEY.md §3.1). Returns models in algorithms order;
-        per-phase wall-clock lands in ``ctx.timings``."""
-        import time
-
+        Engine.train, SURVEY.md §3.1). Returns models in algorithms order.
+        Each phase is one span (``train.read`` / ``train.prepare`` /
+        ``train.fit``); under a verb (``run_train``'s ``train.run``) the
+        spans are recorded and their lengths land in ``ctx.timings`` —
+        one timing per phase, the span's own."""
         from predictionio_tpu.utils import tracing
 
-        t0 = time.perf_counter()
-        with tracing.span("train.read"):
+        def timed(key: str, sp) -> None:
+            if sp.seconds is not None:      # None: no verb, no record
+                ctx.timings[key] = sp.seconds
+
+        with tracing.span("train.read") as sp:
             ds = self.data_source_cls(engine_params.data_source_params)
             td = ds.read_training(ctx)
-        ctx.timings["read_training"] = time.perf_counter() - t0
+        timed("read_training", sp)
         ctx.log("read_training done")
         if ctx.stop_after_read:
             return []
-        t0 = time.perf_counter()
-        with tracing.span("train.prepare"):
+        with tracing.span("train.prepare") as sp:
             prep = self.preparator_cls(engine_params.preparator_params)
             pd = prep.prepare(ctx, td)
-        ctx.timings["prepare"] = time.perf_counter() - t0
+        timed("prepare", sp)
         ctx.log("prepare done")
         if ctx.stop_after_prepare:
             return []
@@ -124,10 +127,9 @@ class Engine:
             if not ctx.skip_sanity_check:
                 algo.sanity_check(pd)
             ctx.log(f"training algorithm {name!r}")
-            t0 = time.perf_counter()
-            with tracing.span("train.fit", algorithm=name):
+            with tracing.span("train.fit", algorithm=name) as sp:
                 models.append(algo.train(ctx, pd))
-            ctx.timings[f"train:{name}"] = time.perf_counter() - t0
+            timed(f"train:{name}", sp)
             ctx.log(f"algorithm {name!r} trained")
         return models
 

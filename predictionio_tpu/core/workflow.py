@@ -15,6 +15,7 @@ COMPLETED (or FAILED). Deploy loads the latest COMPLETED instance for
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import pickle
@@ -67,6 +68,33 @@ def _ckpt_root(storage: Storage, engine_factory: str, variant_id: str) -> str:
     return os.path.join(storage.config.home, "train_ckpt", safe)
 
 
+@contextlib.contextmanager
+def _train_run(engine_factory: str, verbose: int):
+    """The ``train.run`` root span of one train verb — and, with
+    ``PIO_PROFILE_DIR=<dir>``, a JAX profiler trace around it (xplane →
+    Perfetto/TensorBoard; SURVEY.md §5). The trace starts BEFORE the
+    root: an annotation made while no session runs is not recorded, and
+    every verb span is one (``pio:train.run`` … in the host plane,
+    utils/tracing.py). ``verbose`` prints the finished tree."""
+    from predictionio_tpu.utils import tracing
+
+    try:
+        with contextlib.ExitStack() as stack:
+            profile_dir = os.environ.get("PIO_PROFILE_DIR")
+            if profile_dir:
+                import jax
+
+                stack.enter_context(jax.profiler.trace(profile_dir))
+            yield stack.enter_context(
+                tracing.verb("train.run", engine_factory=engine_factory))
+    finally:
+        tree = tracing.last_verb("train.run")
+        if verbose and tree:
+            iid = (tree[0].get("attrs") or {}).get("instance_id") or "-"
+            print(f"[workflow {iid}] train spans:\n"
+                  + tracing.render_trace_tree(tree), flush=True)
+
+
 def run_train(
     engine_factory: str,
     variant: Optional[Dict[str, Any]] = None,
@@ -97,70 +125,67 @@ def run_train(
     from predictionio_tpu.parallel import distributed
     from predictionio_tpu.utils import compilecache, tracing
 
-    compilecache.enable()
-
     # Multi-host (SURVEY.md §2d P5): when the PIO_* rendezvous vars are
     # set (or a Cloud-TPU slice announces itself), every host runs this
-    # same function in lockstep — jax.distributed rendezvous here, the
-    # coordinator mints the instance id and owns all meta/model writes,
-    # barriers keep hosts aligned around training.
+    # same function in lockstep — jax.distributed rendezvous here (before
+    # the profiler of ``_train_run`` touches a backend), the coordinator
+    # mints the instance id and owns all meta/model writes, barriers keep
+    # hosts aligned around training.
     multi = distributed.initialize()
-    coord = distributed.is_coordinator()
+    # every stretch below is inside a named child span of ``train.run``,
+    # so that the verb's wall time is accounted for by name
+    # (docs/observability.md "Train verb")
+    with _train_run(engine_factory, verbose) as root:
+        with tracing.span("train.init"):
+            compilecache.enable()
+            coord = distributed.is_coordinator()
 
-    storage = storage or get_storage()
-    engine = EngineFactory.create(engine_factory)
-    if variant_path is not None:
-        variant = load_variant(variant_path)
-    variant = variant or {}
-    if engine_params is None:
-        engine_params = engine.params_from_variant(variant)
+            storage = storage or get_storage()
+            engine = EngineFactory.create(engine_factory)
+            if variant_path is not None:
+                variant = load_variant(variant_path)
+            variant = variant or {}
+            if engine_params is None:
+                engine_params = engine.params_from_variant(variant)
 
-    instance_id = storage.meta.new_instance_id() if coord else ""
-    if multi:
-        instance_id = distributed.broadcast_string(instance_id)
-    mesh_conf = variant.get("meshConf") or variant.get("sparkConf") or {}
-    ei = EngineInstance(
-        id=instance_id,
-        status="INIT",
-        start_time=utcnow(),
-        end_time=None,
-        engine_factory=engine_factory,
-        engine_variant=str(variant.get("id", "")),
-        batch=batch or str(variant.get("description", "")),
-        env={},
-        mesh_conf=mesh_conf,
-        data_source_params=json.dumps(params_to_json(engine_params.data_source_params)),
-        preparator_params=json.dumps(params_to_json(engine_params.preparator_params)),
-        algorithms_params=_algorithms_params_json(engine_params),
-        serving_params=json.dumps(params_to_json(engine_params.serving_params)),
-    )
-    if coord:
-        storage.meta.insert_engine_instance(ei)
-    ckpt_root = _ckpt_root(storage, engine_factory, ei.engine_variant)
-    if coord and not resume:
-        shutil.rmtree(ckpt_root, ignore_errors=True)
-    if multi:
-        distributed.barrier("pio_ckpt_ready")
-    ctx = _build_context(storage, mesh_conf, verbose, instance_id, use_mesh,
-                         checkpoint_dir=ckpt_root)
-    _prev_scan_cache = (set_scan_cache(scan_cache)
-                        if scan_cache is not None else None)
-    try:
-        with tracing.root_span("train.run", engine_factory=engine_factory,
-                               instance_id=instance_id):
+            instance_id = storage.meta.new_instance_id() if coord else ""
+            if multi:
+                instance_id = distributed.broadcast_string(instance_id)
+            root.set_attr("instance_id", instance_id)
+            mesh_conf = (variant.get("meshConf") or variant.get("sparkConf")
+                         or {})
+            ei = EngineInstance(
+                id=instance_id,
+                status="INIT",
+                start_time=utcnow(),
+                end_time=None,
+                engine_factory=engine_factory,
+                engine_variant=str(variant.get("id", "")),
+                batch=batch or str(variant.get("description", "")),
+                env={},
+                mesh_conf=mesh_conf,
+                data_source_params=json.dumps(params_to_json(engine_params.data_source_params)),
+                preparator_params=json.dumps(params_to_json(engine_params.preparator_params)),
+                algorithms_params=_algorithms_params_json(engine_params),
+                serving_params=json.dumps(params_to_json(engine_params.serving_params)),
+            )
+            if coord:
+                storage.meta.insert_engine_instance(ei)
+            ckpt_root = _ckpt_root(storage, engine_factory, ei.engine_variant)
+            if coord and not resume:
+                shutil.rmtree(ckpt_root, ignore_errors=True)
+            if multi:
+                distributed.barrier("pio_ckpt_ready")
+            ctx = _build_context(storage, mesh_conf, verbose, instance_id,
+                                 use_mesh, checkpoint_dir=ckpt_root)
+        _prev_scan_cache = (set_scan_cache(scan_cache)
+                            if scan_cache is not None else None)
+        try:
             ei.status = "TRAINING"
             if coord:
-                storage.meta.update_engine_instance(ei)
-            # tracing hook (SURVEY.md §5): PIO_PROFILE_DIR=<dir> wraps the
-            # train in a JAX profiler trace (xplane → Perfetto/TensorBoard)
-            profile_dir = os.environ.get("PIO_PROFILE_DIR")
-            if profile_dir:
-                import jax
-
-                with jax.profiler.trace(profile_dir):
-                    models = engine.train(ctx, engine_params)
-            else:
-                models = engine.train(ctx, engine_params)
+                with tracing.span("train.init"):
+                    storage.meta.update_engine_instance(ei)
+            models = engine.train(ctx, engine_params)
             if ctx.timings:
                 phases = ", ".join(f"{k}={v:.3f}s"
                                    for k, v in ctx.timings.items())
@@ -175,33 +200,41 @@ def run_train(
                                   algorithms=len(models)):
                     instance_dir = storage.models.model_dir(instance_id)
                     blobs: List[Optional[bytes]] = []
-                    for (name, algo), model in zip(
-                            engine.make_algorithms(engine_params), models):
-                        algo_dir = None
-                        if instance_dir is not None:
-                            algo_dir = os.path.join(instance_dir, name)
-                            os.makedirs(algo_dir, exist_ok=True)
-                        blobs.append(algo.save_model(model, algo_dir))
-                    storage.models.put(instance_id, pickle.dumps(blobs))
+                    with tracing.span("model.serialize") as sp:
+                        for (name, algo), model in zip(
+                                engine.make_algorithms(engine_params), models):
+                            algo_dir = None
+                            if instance_dir is not None:
+                                algo_dir = os.path.join(instance_dir, name)
+                                os.makedirs(algo_dir, exist_ok=True)
+                            blobs.append(algo.save_model(model, algo_dir))
+                        sp.set_attr("bytes", sum(len(b) for b in blobs if b))
+                    with tracing.span("model.put") as sp:
+                        payload = pickle.dumps(blobs)
+                        storage.models.put(instance_id, payload)
+                        sp.set_attr("bytes", len(payload))
 
-                ei.status = "COMPLETED"
-                ei.end_time = utcnow()
-                storage.meta.update_engine_instance(ei)
-                # the run completed: its mid-train checkpoints are consumed
-                shutil.rmtree(ckpt_root, ignore_errors=True)
+                with tracing.span("train.finish"):
+                    ei.status = "COMPLETED"
+                    ei.end_time = utcnow()
+                    storage.meta.update_engine_instance(ei)
+                    # the run completed: its mid-train checkpoints are consumed
+                    shutil.rmtree(ckpt_root, ignore_errors=True)
             if multi:
                 distributed.barrier("pio_persist_done")
+            root.set_attr("status", ei.status)
             return instance_id
-    except Exception:
-        ei.status = "FAILED"
-        ei.end_time = utcnow()
-        if coord:
-            storage.meta.update_engine_instance(ei)
-        traceback.print_exc()
-        raise
-    finally:
-        if scan_cache is not None:
-            set_scan_cache(_prev_scan_cache)
+        except Exception:
+            ei.status = "FAILED"
+            ei.end_time = utcnow()
+            root.set_attr("status", ei.status)
+            if coord:
+                storage.meta.update_engine_instance(ei)
+            traceback.print_exc()
+            raise
+        finally:
+            if scan_cache is not None:
+                set_scan_cache(_prev_scan_cache)
 
 
 @dataclass

@@ -377,9 +377,14 @@ def read_training_interactions(
             scan, st, app_id, channel_id, start_time, until_time,
             entity_type, target_entity_type, event_names, value_key)
         if cols is not None:
-            return interactions_from_columnar(cols, value_spec,
-                                              default_spec,
-                                              chunk_size=chunk_size)
+            with _tracing.span("train.read.index") as sp:
+                data = interactions_from_columnar(cols, value_spec,
+                                                  default_spec,
+                                                  chunk_size=chunk_size)
+                sp.set_attr("kept", int(data.n_events))
+                sp.set_attr("n_entities", len(data.user_ids))
+                sp.set_attr("n_targets", len(data.item_ids))
+            return data
 
     def value_fn(e):
         spec = (value_spec or {}).get(e.event, default_spec)

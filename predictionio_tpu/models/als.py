@@ -54,6 +54,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from predictionio_tpu.utils import tracing
+
 log = logging.getLogger(__name__)
 
 
@@ -446,10 +448,34 @@ class ALSPrepared:
     u_side: _BucketSide
     i_side: _BucketSide
     _device_bufs: Optional[dict] = None
+    #: bytes :meth:`device_buffers` has sent to a device so far (a
+    #: cached call sends none) — what the ``als.upload`` span reports
+    uploaded_bytes: int = 0
 
     @property
     def geometry(self):
         return (self.u_side.geometry, self.i_side.geometry)
+
+    def kernel_rows(self) -> dict:
+        """What the fused gather→Gram kernel is handed per iteration
+        when the Gram mode is fused, counted over the buckets that
+        ``ops.gram.kernel_takes_width`` — the predicate ``_make_half``
+        routes by — sends to it: real (unpadded) interactions, padded
+        slots, and bucket rows. ``real ÷ padded`` is the share of the
+        kernel's gathers that fetch a row somebody rated."""
+        from predictionio_tpu.ops.gram import kernel_takes_width
+
+        real = padded = rows = 0
+        for side in (self.u_side, self.i_side):
+            for b in side.buckets:
+                if kernel_takes_width(b.C):
+                    # one mask slot per interaction = the entities'
+                    # counts (exact in f64; no pass over the mask)
+                    real += int(b.counts.sum(dtype=np.float64))
+                    rows += b.n_slabs * b.slab
+                    padded += b.n_slabs * b.slab * b.C
+        return {"kernel_real_rows": real, "kernel_padded_rows": padded,
+                "kernel_bucket_rows": rows}
 
     def device_buffers(self, device=None):
         """Bucket arrays as device arrays (cached per device across
@@ -462,6 +488,7 @@ class ALSPrepared:
             self._device_bufs = {}
         if device not in self._device_bufs:
             def put(a):
+                self.uploaded_bytes += a.nbytes
                 return (jnp.asarray(a) if device is None
                         else jax.device_put(a, device))
 
@@ -523,7 +550,10 @@ def als_train(
     # a 1-device mesh still pins the platform: run the single-device path
     # on THAT device, not wherever the default backend happens to live
     device = mesh.devices.flat[0] if mesh is not None else None
-    return als_train_prepared(als_prepare(coo), params, device=device,
+    with tracing.span("als.prepare", nnz=int(coo.nnz)):
+        prep = als_prepare(coo)
+        tracing.add_attrs(**prep.kernel_rows())
+    return als_train_prepared(prep, params, device=device,
                               checkpointer=checkpointer,
                               checkpoint_every=checkpoint_every)
 
@@ -627,11 +657,14 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
     from predictionio_tpu.ops import gram as ops_gram
     from predictionio_tpu.ops.cholesky import chol_solve_batched as _csb
 
-    chol_solve_batched = functools.partial(
+    # ``jax.named_scope`` here and on the stages below: names for the
+    # profiler's trace only (the op_name of every operation of a stage
+    # starts with its scope); the programs compute what they computed
+    chol_solve_batched = jax.named_scope("als.solve")(functools.partial(
         _csb, platform=platform,
         # fat-dispatch regime: the ~50-op XLA solve recursion would
         # re-create the dispatch wall the Gram fusion removes
-        prefer_pallas=(gram_mode == "pallas"))
+        prefer_pallas=(gram_mode == "pallas")))
 
     # reg/alpha are bound per trace by ``half`` (traced scalars shared
     # by every helper below via this cell — threading them through five
@@ -644,6 +677,7 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
             return (alpha * v_s) * m_s, (1.0 + alpha * v_s) * m_s
         return m_s, v_s * m_s
 
+    @jax.named_scope("als.gram_xla")
     def row_grams(F_other, oi_s, v_s, m_s):
         """One slab's per-row normal-equation partials on the MXU.
 
@@ -740,6 +774,7 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
         Ab_e, _ = jax.lax.scan(seg_body, init, (oi, vv, mm, seg, seg_off))
         return ridge(Ab_e[:nb, :, :k], cnt, G), Ab_e[:nb, :, k]
 
+    @jax.named_scope("als.dense_head")
     def dense_equations(F_other, dbuf, G):
         """Dense head: normal equations for the heaviest entities as
         two GEMMs over the FULL other side — A rows against the factor
@@ -853,9 +888,10 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
         F_g = (F_other.astype(jnp.bfloat16) if bf16_gather else F_other)
         G = None
         if implicit:
-            G = jnp.einsum("nk,nl->kl", F_other, F_other,
-                           precision=prec,
-                           preferred_element_type=jnp.float32)
+            with jax.named_scope("als.yty"):
+                G = jnp.einsum("nk,nl->kl", F_other, F_other,
+                               precision=prec,
+                               preferred_element_type=jnp.float32)
         # spans in the solve buffer: the dense head and seg buckets
         # emit nb exact rows once, regular buckets their padded slabs
         spans = ([dense_geom[0]] if dense_geom is not None else []) + \
@@ -912,16 +948,18 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
     return half
 
 
-def log_train_modes(platform: str, gram_mode: str, n_devices: int) -> None:
+def log_train_modes(platform: str, gram_mode: str, n_devices: int) -> str:
     """Say which Gram and which solve implementation this train runs —
     the selection is by rule (``ops.resolve_gram_mode`` /
-    ``cholesky.resolve_solve_mode``), so it can be stated up front."""
+    ``cholesky.resolve_solve_mode``), so it can be stated up front.
+    Returns the solve mode."""
     from predictionio_tpu.ops.cholesky import resolve_solve_mode
 
     solve = resolve_solve_mode(platform,
                                prefer_pallas=(gram_mode == "pallas"))
     log.info("ALS train: platform=%s devices=%d gram=%s solve=%s",
              platform, n_devices, gram_mode, solve)
+    return solve
 
 
 def _gram_precision() -> str:
@@ -979,6 +1017,7 @@ def _unpermute_pack():
     import jax
     import jax.numpy as jnp
 
+    @jax.named_scope("als.unpermute_pack")
     def f(U, V, inv_u, inv_v):
         return jnp.concatenate([jnp.take(U, inv_u, axis=0),
                                 jnp.take(V, inv_v, axis=0)], axis=0)
@@ -1007,8 +1046,6 @@ def als_train_prepared(prep: ALSPrepared, p: ALSParams, device=None,
     def put(a):
         return jnp.asarray(a) if device is None else jax.device_put(a, device)
 
-    u_bufs, i_bufs = prep.device_buffers(device)
-
     platform = (device.platform if device is not None
                 else jax.default_backend())
     # resolved HERE (not inside the lru_cached builder) so an env flip
@@ -1017,7 +1054,7 @@ def als_train_prepared(prep: ALSPrepared, p: ALSParams, device=None,
     from predictionio_tpu import ops
 
     gram_mode = ops.resolve_gram_mode(platform)
-    log_train_modes(platform, gram_mode, n_devices=1)
+    solve_mode = log_train_modes(platform, gram_mode, n_devices=1)
 
     def compiled(n_iters: int):
         return _compiled_bucketed(
@@ -1027,62 +1064,97 @@ def als_train_prepared(prep: ALSPrepared, p: ALSParams, device=None,
             bool(p.weighted_reg), platform,
             bool(p.bf16_gather), _gram_precision(), gram_mode)
 
+    def iterate(n_iters: int, V, then=None):
+        """One block of iterations, timed up to the point where its
+        factors are ready. ``then(U, V)`` is dispatched behind the block
+        BEFORE the host waits, so the wait adds no synchronisation that
+        was not there: the host would block on these values in its next
+        fetch anyway."""
+        with tracing.span("als.iterate", iterations=n_iters,
+                          gram=gram_mode, solve=solve_mode):
+            U, V = compiled(n_iters)(u_bufs, i_bufs, V, reg_a, alpha_a)
+            queued = then(U, V) if then is not None else None
+            jax.block_until_ready((U, V))
+        return U, V, queued
+
     reg_a = np.float32(p.reg)
     alpha_a = np.float32(p.alpha)
 
     start = 0
-    V0 = init_factors(prep.n_items, p.rank, p.seed)[prep.i_side.perm]
     U0 = None  # restored U (only consumed when start == iterations)
-    if checkpointer is not None and checkpointer.latest_step() is not None:
-        from predictionio_tpu.utils.checkpoint import CheckpointGeometryError
+    with tracing.span("als.init"):
+        V0 = init_factors(prep.n_items, p.rank, p.seed)[prep.i_side.perm]
+        if (checkpointer is not None
+                and checkpointer.latest_step() is not None):
+            from predictionio_tpu.utils.checkpoint import (
+                CheckpointGeometryError)
 
-        template = {"U": np.zeros((prep.n_users, p.rank), np.float32),
-                    "V": np.zeros_like(V0)}
-        try:
-            state, step = checkpointer.restore_latest_compatible(template)
-            V0 = np.asarray(state["V"])
-            U0 = np.asarray(state["U"])
-            start = min(int(step), p.iterations)
-        except CheckpointGeometryError:
-            # CONFIRMED stale (different geometry/rank): fresh start,
-            # and the dir must be WIPED, else the fresh run's lower
-            # step numbers stay shadowed by the stale latest_step and
-            # every future resume restores the bad checkpoint again.
-            # Transient read errors propagate instead — wiping on those
-            # would destroy valid checkpoints (ADVICE r3).
-            import warnings
+            template = {"U": np.zeros((prep.n_users, p.rank), np.float32),
+                        "V": np.zeros_like(V0)}
+            try:
+                state, step = checkpointer.restore_latest_compatible(
+                    template)
+                V0 = np.asarray(state["V"])
+                U0 = np.asarray(state["U"])
+                start = min(int(step), p.iterations)
+            except CheckpointGeometryError:
+                # CONFIRMED stale (different geometry/rank): fresh start,
+                # and the dir must be WIPED, else the fresh run's lower
+                # step numbers stay shadowed by the stale latest_step and
+                # every future resume restores the bad checkpoint again.
+                # Transient read errors propagate instead — wiping on
+                # those would destroy valid checkpoints (ADVICE r3).
+                import warnings
 
-            warnings.warn(
-                "ALS checkpoints are stale (geometry/format change) — wiped; training restarts from scratch", RuntimeWarning)
-            checkpointer.clear()
+                warnings.warn(
+                    "ALS checkpoints are stale (geometry/format change) — wiped; training restarts from scratch", RuntimeWarning)
+                checkpointer.clear()
 
-    if start >= p.iterations and U0 is not None:
-        # died between the final checkpoint and model persistence: the
-        # train is already done, nothing to recompute
-        U, V = U0, V0
-    elif (checkpointer is None or checkpoint_every <= 0
-          or p.iterations == 0):  # its U-recovery program has no
-        # blocks to checkpoint; without this, the block loop below
-        # never runs and the not-None assert fires (r5 review)
-        U, V = compiled(p.iterations - start)(u_bufs, i_bufs, put(V0),
-                                              reg_a, alpha_a)
-    else:
-        V = put(V0)
+    # died between the final checkpoint and model persistence: the
+    # train is already done, nothing to recompute
+    done = start >= p.iterations and U0 is not None
+    with tracing.span("als.upload") as sp:
+        sent = prep.uploaded_bytes
+        u_bufs, i_bufs = prep.device_buffers(device)
+        small = [V0, prep.u_side.inv_perm, prep.i_side.inv_perm]
+        V, inv_u, inv_v = (put(a) for a in small)
         U = None
+        if done:
+            small.append(U0)
+            U = put(U0)
+        sp.set_attr("bytes", int(prep.uploaded_bytes - sent
+                                 + sum(a.nbytes for a in small)))
+
+    def unpermute(U, V):
+        # un-permute to original entity order ON DEVICE, U and V as ONE
+        # packed array: each device→host fetch is a full round trip,
+        # and the device does the fancy-index copy faster than the host
+        # would
+        return _unpermute_pack()(U, V, inv_u, inv_v)
+
+    packed = None
+    if not done and (checkpointer is None or checkpoint_every <= 0
+                     or p.iterations == 0):  # its U-recovery program has
+        # no blocks to checkpoint; without this, the block loop below
+        # never runs and the not-None assert fires (r5 review)
+        U, V, packed = iterate(p.iterations - start, V, then=unpermute)
+    elif not done:
         it = start
         while it < p.iterations:
             n = min(checkpoint_every, p.iterations - it)
-            U, V = compiled(n)(u_bufs, i_bufs, V, reg_a, alpha_a)
+            U, V, _ = iterate(n, V)
             it += n
-            checkpointer.save(it, {"U": np.asarray(U), "V": np.asarray(V)})
+            with tracing.span("als.checkpoint", step=it) as sp:
+                state = {"U": np.asarray(U), "V": np.asarray(V)}
+                checkpointer.save(it, state)
+                sp.set_attr("bytes",
+                            int(state["U"].nbytes + state["V"].nbytes))
         assert U is not None  # start < iterations here, loop ran
-    # un-permute to original entity order ON DEVICE and fetch U and V as
-    # ONE packed array: each device→host fetch is a full round trip,
-    # and the device does the fancy-index copy faster than the host
-    # would
-    packed = np.asarray(_unpermute_pack()(
-        put(U), put(V), put(prep.u_side.inv_perm),
-        put(prep.i_side.inv_perm)))
+    with tracing.span("als.fetch") as sp:
+        if packed is None:
+            packed = unpermute(U, V)
+        packed = np.asarray(packed)
+        sp.set_attr("bytes", int(packed.nbytes))
     return packed[:prep.n_users], packed[prep.n_users:]
 
 

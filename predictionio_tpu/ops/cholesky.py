@@ -343,6 +343,7 @@ def chol_solve_pallas(A, b, interpret: bool = False):
         out_specs=pl.BlockSpec((kp, _BT), lambda i: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((kp, Np), jnp.float32),
+        name="chol_solve",
         cost_estimate=pl.CostEstimate(
             flops=int(Np * (2 * kp**3 / 3 + 4 * kp**2)),
             bytes_accessed=4 * (Np * kp * kp + 3 * Np * kp),

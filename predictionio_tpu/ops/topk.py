@@ -313,6 +313,7 @@ def score_topk(Q, V, k: int, *, tile: int = 512, n_valid: int = 0,
             pltpu.VMEM((B, k), jnp.float32),
             pltpu.VMEM((B, k), jnp.int32),
         ],
+        name="score_topk",
         cost_estimate=pl.CostEstimate(
             flops=2 * B * d * V.shape[0] + 2 * B * k * V.shape[0],
             bytes_accessed=4 * (B * d + V.shape[0] * d + 2 * B * k),

@@ -40,6 +40,7 @@ from predictionio_tpu.models.als import (
     als_train,
     recommend,
 )
+from predictionio_tpu.utils import tracing
 from predictionio_tpu.utils.bimap import BiMap
 
 
@@ -144,7 +145,9 @@ class RecDataSource(SelfCleaningDataSource, DataSource):
             default_spec=p.buy_rating,
             storage=ctx.storage,
         )
-        uu, ii, rr = data.arrays()
+        with tracing.span("train.read.arrays") as sp:
+            uu, ii, rr = data.arrays()
+            sp.set_attr("bytes", int(uu.nbytes + ii.nbytes + rr.nbytes))
         return TrainingData(uu, ii, rr, data.user_ids, data.item_ids)
 
     def read_training(self, ctx: WorkflowContext) -> TrainingData:
@@ -293,7 +296,9 @@ class ALSAlgorithm(Algorithm):
 
     def train(self, ctx: WorkflowContext, pd: TrainingData) -> ALSModel:
         p: ALSAlgorithmParams = self.params
-        coo, user_ids, item_ids = self._to_coo(pd)
+        with tracing.span("als.index") as sp:
+            coo, user_ids, item_ids = self._to_coo(pd)
+            sp.set_attr("nnz", int(coo.nnz))
         U, V = als_train(
             coo,
             self._als_params(p),

@@ -33,6 +33,19 @@ exporter (drill it with the ``trace.export`` fault site) increments
 ``pio_trace_export_failures_total`` and nothing else — a trace is never
 worth failing the request it describes.
 
+**Verbs** are the exception to "disabled by default": a span opened
+under a :func:`verb` root (``pio train`` opens ``train.run``) is
+recorded whether or not tracing is enabled — a verb runs for seconds
+to minutes and opens a few dozen spans, so the record costs nothing
+that matters and is what every timing of the verb is read from. The
+finished tree of the newest verb of each root name stays in memory
+(:func:`last_verb`), and every verb span enters a
+``jax.profiler.TraceAnnotation("pio:<name>")``: under a profiler
+session (``PIO_PROFILE_DIR``) the span lands in the trace's host plane,
+on the clock of the device operations; with no session that is a flag
+test. JAX is imported when a verb opens, never by importing this module
+(the event and router servers run without it).
+
 Interop: inbound W3C ``traceparent`` headers are honoured
 (``00-<trace>-<span>-<flags>``), as is the simpler ``X-PIO-Trace-Id``;
 responses are tagged with ``X-PIO-Trace-Id`` so a client can quote the
@@ -92,21 +105,30 @@ class Span:
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "attrs",
                  "start_us", "duration_us", "status", "error", "sampled",
-                 "_t0")
+                 "verb", "duration_ns", "_t0")
 
     def __init__(self, name: str, trace_id: str, parent_id: Optional[str],
-                 sampled: bool, attrs: Optional[Dict[str, Any]] = None) -> None:
+                 sampled: bool, attrs: Optional[Dict[str, Any]] = None,
+                 verb: Optional["_Verb"] = None) -> None:
         self.name = name
         self.trace_id = trace_id
         self.span_id = new_span_id()
         self.parent_id = parent_id
         self.sampled = sampled
+        #: the verb record this span belongs to (None: a request span)
+        self.verb = verb
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
         self.status = "ok"
         self.error: Optional[str] = None
         self.start_us = time.time_ns() // 1000
         self.duration_us = 0
+        self.duration_ns = 0
         self._t0 = time.perf_counter_ns()
+
+    @property
+    def seconds(self) -> float:
+        """Length of the finished span (0.0 while it is open)."""
+        return self.duration_ns / 1e9
 
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
@@ -141,18 +163,30 @@ class _SpanHandle:
     and finishes/exports it on exit. Exceptions mark the span ``error``
     and propagate."""
 
-    __slots__ = ("span", "_tracer", "_token")
+    __slots__ = ("span", "_tracer", "_token", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: Span) -> None:
         self.span = span
         self._tracer = tracer
         self._token: Optional[contextvars.Token] = None
+        self._annotation: Optional[Any] = None
 
     def __enter__(self) -> Span:
         self._token = _CURRENT.set(self.span)
+        verb = self.span.verb
+        if verb is not None:
+            if self.span.parent_id is None:
+                self._tracer._count_open_verbs(+1)  # finish() takes it off
+            # the profiler's own span of the same stretch: with no
+            # profiler session this is a flag test
+            self._annotation = verb.annotation(f"pio:{self.span.name}")
+            self._annotation.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if self._token is not None:
             _CURRENT.reset(self._token)
             self._token = None
@@ -177,6 +211,9 @@ class _NoopSpan:
     parent_id = None
     sampled = False
     status = "ok"
+    verb = None
+    #: no span, no timing (``Engine.train`` outside a verb)
+    seconds = None
 
     def set_attr(self, key: str, value: Any) -> None:
         pass
@@ -337,7 +374,17 @@ class Tracer:
     reach it."""
 
     def __init__(self) -> None:
+        #: per-request tracing (``--tracing``); changed by
+        #: :meth:`configure` only, which keeps :attr:`active` in step
         self.enabled = False
+        #: what ``span()`` reads: enabled, or a verb is open somewhere
+        #: in the process (its spans are recorded either way)
+        self.active = False
+        self._open_verbs = 0
+        self._verb_lock = threading.Lock()
+        #: root name → the finished tree of the newest verb of that
+        #: name (:func:`last_verb`)
+        self.last_verbs: Dict[str, List[Dict[str, Any]]] = {}
         #: probability a NEW trace is file-exported (errors and slow
         #: spans always are — tail sampling)
         self.sample_rate = 1.0
@@ -374,7 +421,13 @@ class Tracer:
                               if not isinstance(e, JSONLExporter)]
             self.exporters.append(JSONLExporter(jsonl_path))
         self.enabled = enabled
+        self.active = enabled or self._open_verbs > 0
         return self
+
+    def _count_open_verbs(self, delta: int) -> None:
+        with self._verb_lock:
+            self._open_verbs = max(0, self._open_verbs + delta)
+            self.active = self.enabled or self._open_verbs > 0
 
     def reset(self) -> None:
         """Back to the disabled defaults (tests)."""
@@ -398,7 +451,14 @@ class Tracer:
         in-flight exception, export (fail-open), maybe log slowness."""
         if exc is not None and span.status != "error":
             span.set_error(f"{getattr(exc_type, '__name__', 'Exception')}: {exc}")
-        span.duration_us = (time.perf_counter_ns() - span._t0) // 1000
+        span.duration_ns = time.perf_counter_ns() - span._t0
+        span.duration_us = span.duration_ns // 1000
+        if span.verb is not None:
+            span.verb.record(span)
+            if span.parent_id is None:
+                self._count_open_verbs(-1)
+        if not self.enabled:
+            return
         _M_SPANS.inc((span.status,))
         d = span.to_dict()
         try:
@@ -432,21 +492,78 @@ class Tracer:
 TRACER = Tracer()
 
 
+# -- verbs ---------------------------------------------------------------------
+
+
+class _Verb:
+    """The spans of one running verb, finished ones only, as dicts:
+    ``Span.to_dict()`` plus ``startNs`` / ``endNs`` on
+    ``time.perf_counter_ns`` (what lengths and the order of siblings
+    are read from; ``startUs`` is wall-clock and may step)."""
+
+    __slots__ = ("name", "spans", "annotation")
+
+    def __init__(self, name: str) -> None:
+        # the train path has imported JAX long before it opens its
+        # verb; the servers that import this module never get here
+        from jax.profiler import TraceAnnotation
+
+        self.name = name
+        self.spans: List[Dict[str, Any]] = []
+        self.annotation = TraceAnnotation
+
+    def record(self, span: Span) -> None:
+        d = span.to_dict()
+        d["startNs"] = span._t0
+        d["endNs"] = span._t0 + span.duration_ns
+        self.spans.append(d)     # list.append: safe from any thread
+        if span.parent_id is None:
+            self.spans.sort(key=lambda s: s["startNs"])
+            TRACER.last_verbs[self.name] = self.spans
+
+
+def verb(name: str, **attrs: Any):
+    """Open the root of a verb (``train.run``): a new trace, whatever
+    the context holds, whose spans are recorded whether or not tracing
+    is enabled and written into the profiler's trace as ``pio:<name>``
+    (module docstring). When tracing IS enabled the spans also go to
+    the ring and the exporters, like any other."""
+    tr = TRACER
+    s = Span(name, new_trace_id(), None,
+             tr.enabled and tr._decide_sampled(), attrs, _Verb(name))
+    return _SpanHandle(tr, s)
+
+
+def last_verb(name: str) -> Optional[List[Dict[str, Any]]]:
+    """The span dicts of the newest FINISHED verb rooted at ``name``,
+    root first, in start order; None when no such verb has run in this
+    process. One tree is kept per root name; the next verb replaces
+    it."""
+    spans = TRACER.last_verbs.get(name)
+    return None if spans is None else list(spans)
+
+
 # -- span entry points ---------------------------------------------------------
 
 
 def span(name: str, **attrs: Any):
     """Open a child span of the context's current span (or a new root
     if there is none). Usable as ``with`` and ``async with``. On the
-    disabled path this returns the shared no-op handle."""
+    disabled path this returns the shared no-op handle; under a verb
+    (:func:`verb`) the span is recorded either way."""
     tr = TRACER
-    if not tr.enabled:
+    if not tr.active:
         return NOOP_SPAN
     parent = _CURRENT.get()
     if parent is not None:
-        s = Span(name, parent.trace_id, parent.span_id, parent.sampled, attrs)
-    else:
+        if parent.verb is None and not tr.enabled:
+            return NOOP_SPAN
+        s = Span(name, parent.trace_id, parent.span_id, parent.sampled,
+                 attrs, parent.verb)
+    elif tr.enabled:
         s = Span(name, new_trace_id(), None, tr._decide_sampled(), attrs)
+    else:       # a verb is open elsewhere in the process, not here
+        return NOOP_SPAN
     return _SpanHandle(tr, s)
 
 
@@ -565,7 +682,9 @@ def render_trace_tree(spans: List[Dict[str, Any]]) -> str:
         key = pid if pid in by_id else None
         children.setdefault(key, []).append(d)
     for kids in children.values():
-        kids.sort(key=lambda d: d.get("startUs", 0))
+        # a verb's spans carry the monotonic start too: wall-clock
+        # microseconds tie (and may step) between siblings
+        kids.sort(key=lambda d: d.get("startNs", d.get("startUs", 0)))
     lines: List[str] = []
 
     def emit(d: Dict[str, Any], depth: int) -> None:

@@ -265,6 +265,129 @@ class TestHistogramExemplars:
             REGISTRY.histogram("test_tracing_hist", "t", labelnames=("other",))
 
 
+# -- unit: verbs (always recorded, fetched in-process) --------------------------
+
+
+class TestVerb:
+    def test_recorded_while_tracing_is_disabled(self):
+        assert not tracing.TRACER.enabled
+        with tracing.verb("unit.verb", who="test") as root:
+            assert tracing.TRACER.active
+            with tracing.span("unit.child", n=1) as child:
+                tracing.add_attrs(deep=True)
+                with tracing.span("unit.grandchild"):
+                    pass
+            root.set_attr("status", "done")
+        assert not tracing.TRACER.active
+        tree = tracing.last_verb("unit.verb")
+        assert [s["name"] for s in tree] == [
+            "unit.verb", "unit.child", "unit.grandchild"]
+        r, c, g = tree
+        assert r["parentId"] is None and c["parentId"] == r["spanId"]
+        assert g["parentId"] == c["spanId"]
+        assert r["attrs"] == {"who": "test", "status": "done"}
+        assert c["attrs"] == {"n": 1, "deep": True}
+        assert child.seconds == (c["endNs"] - c["startNs"]) / 1e9 > 0
+        assert r["startNs"] <= c["startNs"] <= g["startNs"] <= g["endNs"] \
+            <= c["endNs"] <= r["endNs"]
+        # the request tracer saw none of it
+        assert len(tracing.TRACER.ring) == 0
+
+    def test_span_outside_a_verb_stays_the_noop(self):
+        assert tracing.span("x") is tracing.NOOP_SPAN
+        assert tracing.NOOP_SPAN.seconds is None
+        with tracing.verb("unit.verb"):
+            seen = []
+            # another thread, no context carried: not under the verb
+            t = threading.Thread(
+                target=lambda: seen.append(tracing.span("elsewhere")))
+            t.start()
+            t.join()
+            assert seen == [tracing.NOOP_SPAN]
+        assert tracing.span("x") is tracing.NOOP_SPAN
+        assert [s["name"] for s in tracing.last_verb("unit.verb")] == [
+            "unit.verb"]
+
+    def test_bound_thread_records_under_the_verb(self):
+        with tracing.verb("unit.verb") as root:
+            def work():
+                with tracing.span("unit.pooled"):
+                    pass
+            with ThreadPoolExecutor(max_workers=1) as pool:
+                pool.submit(tracing.bind_current(work)).result()
+        tree = tracing.last_verb("unit.verb")
+        assert [s["name"] for s in tree] == ["unit.verb", "unit.pooled"]
+        assert tree[1]["parentId"] == root.span_id
+
+    def test_newest_finished_verb_replaces_the_tree(self):
+        assert tracing.last_verb("unit.never") is None
+        with tracing.verb("unit.verb", run=1):
+            pass
+        first = tracing.last_verb("unit.verb")
+        with tracing.verb("unit.verb", run=2):
+            # an open verb has not displaced the finished one
+            assert tracing.last_verb("unit.verb") == first
+        second = tracing.last_verb("unit.verb")
+        assert second[0]["attrs"] == {"run": 2} and len(second) == 1
+        second.clear()     # a copy: the record is not the caller's
+        assert len(tracing.last_verb("unit.verb")) == 1
+        # roots are kept apart by name
+        with tracing.verb("unit.other"):
+            pass
+        assert tracing.last_verb("unit.verb")[0]["attrs"] == {"run": 2}
+
+    def test_error_closes_the_verb_and_is_recorded(self):
+        with pytest.raises(RuntimeError):
+            with tracing.verb("unit.verb"):
+                with tracing.span("unit.child"):
+                    raise RuntimeError("boom")
+        tree = tracing.last_verb("unit.verb")
+        assert [s["status"] for s in tree] == ["error", "error"]
+        assert "boom" in tree[1]["error"]
+        assert not tracing.TRACER.active
+
+    def test_enabled_tracer_gets_the_verb_spans_too(self):
+        tracing.TRACER.configure(enabled=True)
+        before = sum(tracing._M_SPANS._values.values())
+        with tracing.verb("unit.verb") as root:
+            with tracing.span("unit.child"):
+                pass
+        assert [d["name"] for d in tracing.TRACER.ring.trace(
+            root.trace_id)] == ["unit.verb", "unit.child"]
+        assert sum(tracing._M_SPANS._values.values()) == before + 2
+        assert len(tracing.last_verb("unit.verb")) == 2
+        # and disabling again leaves a later verb recorded all the same
+        tracing.TRACER.configure(enabled=False)
+        with tracing.verb("unit.verb"):
+            pass
+        assert len(tracing.TRACER.ring) == 2
+        assert len(tracing.last_verb("unit.verb")) == 1
+
+    def test_tree_renders_in_start_order(self):
+        with tracing.verb("unit.verb"):
+            for name in ("b.first", "a.second", "c.third"):
+                with tracing.span(name):
+                    pass
+        text = tracing.render_trace_tree(tracing.last_verb("unit.verb"))
+        assert [ln.split()[0] for ln in text.splitlines()] == [
+            "unit.verb", "b.first", "a.second", "c.third"]
+
+    def test_importing_tracing_does_not_import_jax(self):
+        """The event and router servers import this module and must
+        stay off JAX; only opening a verb reaches for it."""
+        import subprocess
+        import sys
+
+        code = ("import sys; import predictionio_tpu.utils.tracing as t; "
+                "assert 'jax' not in sys.modules, 'import'; "
+                "t.TRACER.configure(enabled=True); "
+                "s = t.span('x'); s.__enter__(); s.__exit__(None,None,None); "
+                "assert 'jax' not in sys.modules, 'span'; print('ok')")
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
 # -- e2e: one trace id through the servers ------------------------------------
 
 

@@ -134,3 +134,337 @@ class TestEvalWorkflow:
         assert vi.status == "EVALCOMPLETED"
         assert len(result.candidates) == 2
         assert result.best_score == min(s for _, s, _ in result.candidates)
+
+
+# -- the train verb's span tree (utils/tracing.verb, PERF.md §3) ---------------
+
+import contextlib
+import importlib.util
+import io
+import os
+import re
+import signal
+
+from predictionio_tpu.utils import tracing
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: span → its parent, for every span of a checkpointed ALS train off a
+#: store with a columnar scan (the contract of ISSUE 24's table)
+SPAN_PARENTS = {
+    "train.init": "train.run",
+    "train.read": "train.run",
+    "storage.scan": "train.read",
+    "train.read.index": "train.read",
+    "train.read.arrays": "train.read",
+    "train.prepare": "train.run",
+    "train.fit": "train.run",
+    "als.index": "train.fit",
+    "als.prepare": "train.fit",
+    "als.init": "train.fit",
+    "als.upload": "train.fit",
+    "als.iterate": "train.fit",
+    "als.checkpoint": "train.fit",
+    "als.fetch": "train.fit",
+    "train.save": "train.run",
+    "model.serialize": "train.save",
+    "model.put": "train.save",
+    "train.finish": "train.run",
+}
+
+SPAN_ATTRS = {
+    "train.run": ("engine_factory", "instance_id", "status"),
+    "storage.scan": ("scan_cache", "records"),
+    "train.read.index": ("kept", "n_entities", "n_targets"),
+    "als.index": ("nnz",),
+    "als.prepare": ("nnz", "kernel_real_rows", "kernel_padded_rows",
+                    "kernel_bucket_rows"),
+    "als.upload": ("bytes",),
+    "als.iterate": ("iterations", "gram", "solve"),
+    "als.checkpoint": ("step", "bytes"),
+    "als.fetch": ("bytes",),
+    "model.serialize": ("bytes",),
+    "model.put": ("bytes",),
+}
+
+SPAN_VARIANT = dict(VARIANT, algorithms=[{"name": "als", "params": {
+    "rank": 8, "numIterations": 4, "lambda": 0.05, "checkpointEvery": 2}}])
+
+
+@contextlib.contextmanager
+def scan_storage(home):
+    """Meta and models in memory, events in SQLite: a store with a
+    columnar scan (so ``storage.scan`` runs) that needs no compiler."""
+    from predictionio_tpu.storage.registry import (Storage, StorageConfig,
+                                                   set_storage)
+
+    st = Storage(StorageConfig(metadata_type="MEMORY",
+                               modeldata_type="MEMORY",
+                               eventdata_type="SQLITE", home=str(home)))
+    set_storage(st)
+    try:
+        yield st
+    finally:
+        st.events.close()
+        set_storage(None)
+
+
+def _generator_phase_regex():
+    """The benchmark generator's own regex for the phase line, read out
+    of its source (importing it would import the whole benchmark)."""
+    with open(os.path.join(REPO, "benchmark", "generators",
+                           "train_jobs.py")) as f:
+        m = re.search(r'^_PHASES = re\.compile\(r"(.*)"\)$', f.read(), re.M)
+    assert m, "benchmark/generators/train_jobs.py lost its _PHASES regex"
+    return re.compile(m.group(1))
+
+
+@pytest.fixture(scope="class")
+def traced_train(tmp_path_factory):
+    """ONE verbose train with tracing disabled: its tree, its context
+    and what it printed."""
+    import predictionio_tpu.core.workflow as wf
+
+    tracing.TRACER.reset()
+    seen = {}
+    build = wf._build_context
+
+    def keeping(*a, **kw):
+        seen["ctx"] = build(*a, **kw)
+        return seen["ctx"]
+
+    out = io.StringIO()
+    with scan_storage(tmp_path_factory.mktemp("spans")) as st:
+        seed_ratings(st)
+        wf._build_context = keeping
+        try:
+            with contextlib.redirect_stdout(out):
+                iid = run_train(FACTORY, variant=SPAN_VARIANT, storage=st,
+                                use_mesh=False, verbose=1)
+        finally:
+            wf._build_context = build
+        seen.update(iid=iid, tree=tracing.last_verb("train.run"),
+                    lines=out.getvalue().splitlines(), storage=st)
+        yield seen
+
+
+def _by_name(tree, name):
+    return [s for s in tree if s["name"] == name]
+
+
+class TestTrainSpans:
+    @pytest.mark.parametrize("name", sorted(SPAN_PARENTS))
+    def test_span_is_recorded_under_its_parent(self, traced_train, name):
+        tree = traced_train["tree"]
+        ids = {s["spanId"]: s for s in tree}
+        got = _by_name(tree, name)
+        assert got, f"no {name} span in {[s['name'] for s in tree]}"
+        for s in got:
+            parent = ids[s["parentId"]]
+            assert parent["name"] == SPAN_PARENTS[name]
+            assert parent["startNs"] <= s["startNs"] <= s["endNs"] \
+                <= parent["endNs"]
+
+    @pytest.mark.parametrize("name", sorted(SPAN_ATTRS))
+    def test_span_carries_its_counts(self, traced_train, name):
+        for s in _by_name(traced_train["tree"], name):
+            assert set(SPAN_ATTRS[name]) <= set(s.get("attrs") or {}), s
+
+    def test_root_is_first_and_names_the_instance(self, traced_train):
+        root = traced_train["tree"][0]
+        assert root["name"] == "train.run" and root["parentId"] is None
+        assert root["attrs"]["instance_id"] == traced_train["iid"]
+        assert root["attrs"]["status"] == "COMPLETED"
+        assert root["attrs"]["engine_factory"] == FACTORY
+
+    def test_no_unknown_span(self, traced_train):
+        names = {s["name"] for s in traced_train["tree"]}
+        assert names == set(SPAN_PARENTS) | {"train.run"}
+
+    def test_siblings_do_not_overlap(self, traced_train):
+        kids = {}
+        for s in traced_train["tree"]:
+            kids.setdefault(s["parentId"], []).append(s)
+        for group in kids.values():
+            group.sort(key=lambda s: s["startNs"])
+            for a, b in zip(group, group[1:]):
+                assert a["endNs"] <= b["startNs"], (a["name"], b["name"])
+
+    def test_one_iterate_and_checkpoint_per_block(self, traced_train):
+        tree = traced_train["tree"]
+        assert [s["attrs"]["iterations"]
+                for s in _by_name(tree, "als.iterate")] == [2, 2]
+        assert [s["attrs"]["step"]
+                for s in _by_name(tree, "als.checkpoint")] == [2, 4]
+
+    def test_timings_are_the_spans_durations(self, traced_train):
+        tree, ctx = traced_train["tree"], traced_train["ctx"]
+        assert list(ctx.timings) == ["read_training", "prepare", "train:als"]
+        for key, name in (("read_training", "train.read"),
+                          ("prepare", "train.prepare"),
+                          ("train:als", "train.fit")):
+            (s,) = _by_name(tree, name)
+            assert ctx.timings[key] == (s["endNs"] - s["startNs"]) / 1e9
+
+    def test_phase_line_still_parses_as_the_benchmark_parses_it(
+            self, traced_train):
+        regex = _generator_phase_regex()
+        hits = [m for m in map(regex.search, traced_train["lines"]) if m]
+        assert len(hits) == 1
+        phases = {k: float(v.rstrip("s")) for k, v in
+                  (kv.split("=") for kv in hits[0].group(1).split(", "))}
+        assert list(phases) == ["read_training", "prepare", "train:als"]
+        ctx = traced_train["ctx"]
+        assert phases == {k: float(f"{v:.3f}")
+                          for k, v in ctx.timings.items()}
+
+    def test_phase_text_is_on_no_other_line(self, traced_train):
+        holding = [ln for ln in traced_train["lines"]
+                   if "train phases:" in ln]
+        assert len(holding) == 1
+        assert holding[0].startswith(f"[workflow {traced_train['iid']}] "
+                                     "train phases: read_training=")
+
+    def test_tree_is_printed_under_its_own_heading(self, traced_train):
+        lines = traced_train["lines"]
+        at = lines.index(f"[workflow {traced_train['iid']}] train spans:")
+        assert lines[at + 1].startswith("train.run ")
+        printed = [ln.split()[0] for ln in lines[at + 1:]]
+        assert printed == [s["name"] for s in _ordered(traced_train["tree"])]
+
+    def test_tracer_stayed_disabled_and_its_ring_empty(self, traced_train):
+        assert not tracing.TRACER.enabled and not tracing.TRACER.active
+        assert len(tracing.TRACER.ring) == 0
+        assert tracing.span("outside.any.verb") is tracing.NOOP_SPAN
+
+    def test_second_train_replaces_the_tree(self, traced_train):
+        first = traced_train["tree"]
+        second_id = run_train(FACTORY, variant=SPAN_VARIANT,
+                              storage=traced_train["storage"],
+                              use_mesh=False)
+        tree = tracing.last_verb("train.run")
+        assert tree[0]["attrs"]["instance_id"] == second_id != \
+            first[0]["attrs"]["instance_id"]
+        assert not {s["spanId"] for s in tree} & {s["spanId"] for s in first}
+        (scan,) = _by_name(tree, "storage.scan")
+        assert scan["attrs"]["scan_cache"] == "hit"
+
+    def test_bare_als_train_leaves_the_tree_alone(self, traced_train):
+        from predictionio_tpu.models.als import (ALSParams, RatingsCOO,
+                                                 als_train)
+
+        before = tracing.last_verb("train.run")
+        rng = np.random.default_rng(0)
+        coo = RatingsCOO(rng.integers(0, 20, 300).astype(np.int32),
+                         rng.integers(0, 15, 300).astype(np.int32),
+                         rng.random(300).astype(np.float32), 20, 15)
+        als_train(coo, ALSParams(rank=4, iterations=1))
+        assert tracing.last_verb("train.run") == before
+
+    def test_failed_train_records_its_status(self, traced_train):
+        st = traced_train["storage"]
+        st.meta.create_app("Empty")
+        variant = dict(SPAN_VARIANT,
+                       datasource={"params": {"appName": "Empty"}})
+        with pytest.raises(ValueError):
+            run_train(FACTORY, variant=variant, storage=st, use_mesh=False)
+        tree = tracing.last_verb("train.run")
+        assert tree[0]["attrs"]["status"] == "FAILED"
+        assert tree[0]["status"] == "error"
+        assert not tracing.TRACER.active
+
+
+def _ordered(tree):
+    """Depth-first, siblings in start order: the order the tree prints."""
+    kids = {}
+    for s in tree:
+        kids.setdefault(s["parentId"], []).append(s)
+    out = []
+
+    def walk(s):
+        out.append(s)
+        for k in sorted(kids.get(s["spanId"], []),
+                        key=lambda s: s["startNs"]):
+            walk(k)
+
+    walk(tree[0])
+    return out
+
+
+@contextlib.contextmanager
+def _time_limit(seconds: int):
+    def expired(signum, frame):
+        raise TimeoutError(f"no end within {seconds} s")
+
+    old = signal.signal(signal.SIGALRM, expired)
+    signal.alarm(seconds)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
+
+
+def test_verb_spans_land_in_the_profilers_trace(tmp_path):
+    """Under a profiler session every verb span is an event of the
+    trace's host plane, named ``pio:<span>`` — on the clock of the
+    device operations."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with _time_limit(240):
+        with scan_storage(tmp_path / "home") as st:
+            seed_ratings(st)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path / "trace"),
+                                     profiler_options=opts)
+            try:
+                run_train(FACTORY, variant=SPAN_VARIANT, storage=st,
+                          use_mesh=False)
+            finally:
+                jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "trace" / "plugins" / "profile"
+                                / "*" / "*.xplane.pb"))
+        events = {ev.name: ev
+                  for plane in ProfileData.from_file(path).planes
+                  for line in plane.lines for ev in line.events
+                  if ev.name.startswith("pio:")}
+    tree = tracing.last_verb("train.run")
+    assert {"pio:" + s["name"] for s in tree} == set(events)
+    for name in ("train.run", "als.prepare"):
+        (s,) = _by_name(tree, name)
+        ev = events["pio:" + name]
+        assert abs(ev.duration_ns - (s["endNs"] - s["startNs"])) < 1e6
+
+
+def test_program_kernel_rows_equal_the_benchmarks_roofline_mirror():
+    """``benchmark/roofline.py`` mirrors the rule by which ``_make_half``
+    hands a bucket to ``gather_gram``; the program counts by the rule
+    itself (``ALSPrepared.kernel_rows``). Held equal on a layout with
+    kernel-width buckets on both sides."""
+    from predictionio_tpu.models.als import RatingsCOO, als_prepare
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_roofline", os.path.join(REPO, "benchmark", "roofline.py"))
+    roofline = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(roofline)
+
+    rng = np.random.default_rng(5)
+    n_users, n_items = 400, 900
+    deg = np.minimum(rng.zipf(1.3, n_users) + 3, n_items)
+    users = np.repeat(np.arange(n_users), deg)
+    items = np.concatenate([rng.choice(n_items, d, replace=False)
+                            for d in deg])
+    coo = RatingsCOO(users.astype(np.int32), items.astype(np.int32),
+                     rng.random(users.size).astype(np.float32),
+                     n_users, n_items)
+    prep = als_prepare(coo)
+    got = prep.kernel_rows()
+    need = roofline.gather_gram_need(prep, rank=8, iterations=1)
+    assert got["kernel_padded_rows"] > 0, "no kernel-width bucket: no test"
+    assert got == {"kernel_real_rows": need["real_rows"],
+                   "kernel_padded_rows": need["padded_rows"],
+                   "kernel_bucket_rows": need["bucket_rows"]}
