@@ -310,6 +310,13 @@ def _bucket_side(idx_self, idx_other_pos, vals, n_self, counts,
     count ≤ every entity before it, and merged boundaries only ever
     move p into the dense head or a bucket at least as wide as its
     natural one — so capacity C ≥ count always holds.
+
+    Invariant the fused kernel rests on: in every bucket row — regular
+    or segmented, natural or forced boundaries — the real slots are a
+    PREFIX (``col = within`` resp. ``within % C``; only an entity's
+    last segment row is short), so ``mask.sum(1)`` is the row's real
+    length and ``ops.gather_gram`` fetches just that many lines
+    (tests/test_als.py holds it).
     """
     if n_other is None:
         n_other = (int(idx_other_pos.max()) + 1 if idx_other_pos.size
@@ -461,8 +468,11 @@ class ALSPrepared:
         when the Gram mode is fused, counted over the buckets that
         ``ops.gram.kernel_takes_width`` — the predicate ``_make_half``
         routes by — sends to it: real (unpadded) interactions, padded
-        slots, and bucket rows. ``real ÷ padded`` is the share of the
-        kernel's gathers that fetch a row somebody rated."""
+        slots (the layout's padding, still streamed as index and
+        weights), bucket rows, and the factor-line copies the kernel
+        starts. ``real ÷ padded`` is the share of the slots that hold
+        an interaction, ``real ÷ dma`` the share of the copies that
+        fetch a row somebody rated."""
         from predictionio_tpu.ops.gram import kernel_takes_width
 
         real = padded = rows = 0
@@ -474,8 +484,12 @@ class ALSPrepared:
                     real += int(b.counts.sum(dtype=np.float64))
                     rows += b.n_slabs * b.slab
                     padded += b.n_slabs * b.slab * b.C
+        # the kernel is given each row's real length and starts exactly
+        # that many copies (``_gather_gram_kernel``: no rounding of a
+        # length) — a kernel that rounded lengths up would count the
+        # rounded ones here
         return {"kernel_real_rows": real, "kernel_padded_rows": padded,
-                "kernel_bucket_rows": rows}
+                "kernel_bucket_rows": rows, "kernel_dma_rows": real}
 
     def device_buffers(self, device=None):
         """Bucket arrays as device arrays (cached per device across
@@ -727,7 +741,12 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
         normal-equation blocks come back — the gathered (R, C, k)
         factor block never exists in HBM."""
         wo, wb = weights(v2, m2)
-        return ops_gram.gather_gram(F_g, oi2, wo, wb, interpret=interp)
+        # the mask is a prefix of every row (``_bucket_side``), so its
+        # row sum is the row's real length: the kernel fetches that
+        # many lines and no padding (exact in f32: a row has ≤ 8192)
+        lengths = m2.sum(axis=1).astype(jnp.int32)
+        return ops_gram.gather_gram(F_g, oi2, wo, wb, lengths,
+                                    interpret=interp)
 
     def seg_equations(F_g, buf, nb, slab, G):
         """Heavy bucket: entities span rows; each slab aggregates its

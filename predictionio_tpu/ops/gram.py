@@ -12,10 +12,13 @@ Two kernels live here:
   equations accumulate in a VMEM register block, and only the
   ``(R, k, k)`` / ``(R, k)`` results are written back. The gathered
   ``(R, C, k)`` block never materializes in HBM and the weighting never
-  round-trips — this is the kernel the r5 VERDICT prescribed to break
-  the ~140 GB/s XLA row-gather ceiling and the 1.0%-MFU device latency
-  wall (~8.8k dispatches/iteration). ``models/als.py _make_half``
-  selects it via ``PIO_PALLAS_GRAM`` (see :func:`resolve_gram_mode`).
+  round-trips. What bounds it (PERF_LEDGER.jsonl, PR 24: nine bucket
+  shapes, widths 128 to 8192, two configurations) is neither the MXU
+  nor HBM but the scalar core starting and retiring one 512-byte line
+  copy at a time, a constant ~32 ns each — so the kernel is given
+  every row's REAL length and starts no copy for a padded slot (PR 25).
+  ``models/als.py _make_half`` selects it via ``PIO_PALLAS_GRAM`` (see
+  :func:`resolve_gram_mode`).
 
 Per padded rating row r:
 
@@ -139,14 +142,15 @@ def rows_gram(F_g, w_outer, w_b, *, block_rows: int = 8,
 # T·L·4 (line tile) + (L+1)·L·4 (accumulators) + RB·kp·(kp+1)·4
 # (output block), with L = max(128, kp), T = min(C, 256), RB = 8 —
 # worst case (C = 8192) ≈ 1 MB, ~2 MB with the runtime's double
-# buffering of the blocked operands.
+# buffering of the blocked operands. SMEM: the (RB, C) index block
+# (256 KB at C = 8192) and two (8, 128) blocks of row lengths (8 KB).
 
 _GATHER_TILE = 256  # factor rows per DMA burst (T)
 _LANES = 128
 
 
-def _gather_gram_kernel(idx_hbm, idx_ref, wo_ref, wb_ref, F_hbm, A_ref,
-                        b_ref, idx_smem, f_tile, accA, accB, sem_idx,
+def _gather_gram_kernel(idx_hbm, len_ref, idx_ref, wo_ref, wb_ref, F_hbm,
+                        A_ref, b_ref, idx_smem, f_tile, accA, accB, sem_idx,
                         sem_row, *, RB: int, C: int, T: int, kp: int,
                         G: int):
     i = pl.program_id(0)
@@ -157,16 +161,24 @@ def _gather_gram_kernel(idx_hbm, idx_ref, wo_ref, wb_ref, F_hbm, A_ref,
         idx_hbm.at[pl.ds(i * RB, RB), :], idx_smem, sem_idx)
     cp.start()
     cp.wait()
-    nT = C // T
+    tile_row = jax.lax.broadcasted_iota(jnp.int32, (T, L), 0)
     lane_slot = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1) // kp
+    # this program's RB lengths within the (8, 128) block of lengths
+    # (RB consecutive lanes of one line: RB divides 128)
+    len_at = (i * RB) % (8 * _LANES)
+    len_line, len_lane = len_at // _LANES, len_at % _LANES
     for r in range(RB):  # static unroll: RB is small (≤ 8)
         accA[...] = jnp.zeros((L, L), jnp.float32)
         accB[...] = jnp.zeros((1, L), jnp.float32)
+        n = len_ref[len_line, len_lane + r]
 
         def tile_body(t, _):
-            # burst-issue T line copies, then drain the semaphore T
-            # times — each wait retires one completed copy (all copies
-            # share sem_row and the same (1, L) shape)
+            # only the row's real slots are fetched: burst-issue `live`
+            # line copies, then drain the semaphore as many times —
+            # each wait retires one completed copy (all copies share
+            # sem_row and the same (1, L) shape)
+            live = jnp.minimum(n - t * T, T)
+
             def issue(j, _):
                 row = idx_smem[r, t * T + j]
                 pltpu.make_async_copy(
@@ -175,7 +187,7 @@ def _gather_gram_kernel(idx_hbm, idx_ref, wo_ref, wb_ref, F_hbm, A_ref,
                     sem_row).start()
                 return 0
 
-            jax.lax.fori_loop(0, T, issue, 0)
+            jax.lax.fori_loop(0, live, issue, 0)
 
             def drain(j, _):
                 pltpu.make_async_copy(
@@ -184,11 +196,15 @@ def _gather_gram_kernel(idx_hbm, idx_ref, wo_ref, wb_ref, F_hbm, A_ref,
                     sem_row).wait()
                 return 0
 
-            jax.lax.fori_loop(0, T, drain, 0)
-            F = f_tile[...]
+            jax.lax.fori_loop(0, live, drain, 0)
+            # tile rows past `live` still hold what an earlier tile or
+            # row fetched (or nothing yet): masked by ROW, because a
+            # zero weight does not make a stale inf or NaN a zero
+            keep = tile_row < live
             if G > 1:
                 slot = idx_ref[r, pl.ds(t * T, T)] % G
-                F = jnp.where(lane_slot == slot[:, None], F, 0.0)
+                keep &= lane_slot == slot[:, None]
+            F = jnp.where(keep, f_tile[...], 0.0)
             wo = wo_ref[r, pl.ds(t * T, T)]
             wb = wb_ref[r, pl.ds(t * T, T)]
             # f32 normal equations (see rows_gram: bf16 Gram error ~3e-1
@@ -200,7 +216,9 @@ def _gather_gram_kernel(idx_hbm, idx_ref, wo_ref, wb_ref, F_hbm, A_ref,
             accB[...] += jnp.sum(F * wb[:, None], axis=0, keepdims=True)
             return 0
 
-        jax.lax.fori_loop(0, nT, tile_body, 0)
+        # tiles past the row's last real slot are not visited; a row of
+        # length 0 (slab padding) copies nothing and writes zeros
+        jax.lax.fori_loop(0, (n + T - 1) // T, tile_body, 0)
         A = accA[0:kp, 0:kp]
         b = accB[:, 0:kp]
         for g in range(1, G):  # fold the slots' diagonal blocks
@@ -242,13 +260,18 @@ def _line_width(k: int):
     return kp, _LANES // kp
 
 
-def gather_gram(F_other, idx, wo, wb, *, interpret: bool = False):
+def gather_gram(F_other, idx, wo, wb, lengths=None, *,
+                interpret: bool = False):
     """Fused gather→weighted-Gram: ONE Pallas kernel computing
 
         A[r] = Σ_c wo[r,c] · F[idx[r,c]] ⊗ F[idx[r,c]]
         b[r] = Σ_c wb[r,c] · F[idx[r,c]]
 
     without ever materializing the gathered (R, C, k) block in HBM.
+    ``lengths`` (R,) int32 says how many LEADING slots of each row hold
+    an interaction: only those are fetched and summed (the rest must
+    carry zero weight — ``models/als.py _bucket_side`` pads rows at
+    their end). Without it every slot of every row is fetched.
     ``F_other`` may be f32 or bf16 (bf16 rows are upcast before the
     call — see the block comment above). ``interpret=True`` runs the
     Mosaic interpreter (CPU tests).
@@ -258,6 +281,8 @@ def gather_gram(F_other, idx, wo, wb, *, interpret: bool = False):
     if R == 0:
         return (jnp.zeros((0, k, k), jnp.float32),
                 jnp.zeros((0, k), jnp.float32))
+    if lengths is None:
+        lengths = jnp.full((R,), C, jnp.int32)
     T = min(C, _GATHER_TILE)
     while C % T:  # ladder widths always divide; guard odd test shapes
         T -= 1
@@ -272,7 +297,7 @@ def gather_gram(F_other, idx, wo, wb, *, interpret: bool = False):
     F = F.reshape(Np // G, L)
     # Mosaic block mappings need the row-block dim divisible by 8 (or
     # equal to R): pad the row count up and slice the results back —
-    # pad rows gather row 0 with zero weight, contributing nothing
+    # pad rows have length 0: they copy nothing and come back zero
     RB = 8
     Rp = -(-R // RB) * RB
     if Rp != R:
@@ -280,6 +305,12 @@ def gather_gram(F_other, idx, wo, wb, *, interpret: bool = False):
         idx = jnp.pad(idx, pad)
         wo = jnp.pad(wo, pad)
         wb = jnp.pad(wb, pad)
+    # the lengths reach SMEM as a blocked operand, which Mosaic wants
+    # in whole (8, 128) blocks: one block holds the lengths of 1024
+    # rows and stays put for the 1024 // RB programs that share it
+    Rl = -(-R // (8 * _LANES)) * (8 * _LANES)
+    lengths = jnp.pad(jnp.clip(lengths.astype(jnp.int32), 0, C),
+                      (0, Rl - R)).reshape(Rl // _LANES, _LANES)
     row_block = pl.BlockSpec((RB, C), lambda i: (i, 0),
                              memory_space=pltpu.VMEM)
     A, b = pl.pallas_call(
@@ -288,6 +319,8 @@ def gather_gram(F_other, idx, wo, wb, *, interpret: bool = False):
         grid=(Rp // RB,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),   # idx: stays in HBM
+            pl.BlockSpec((8, _LANES), lambda i: (i // (8 * _LANES // RB), 0),
+                         memory_space=pltpu.SMEM),
             row_block,      # idx again, as a vector operand (slot mask)
             row_block,
             row_block,
@@ -319,7 +352,7 @@ def gather_gram(F_other, idx, wo, wb, *, interpret: bool = False):
         ),
         name="gather_gram",
         interpret=interpret,
-    )(idx, idx, wo, wb, F)
+    )(idx, lengths, idx, wo, wb, F)
     return A[:R, :k, :k], b[:R, :k]
 
 

@@ -30,11 +30,16 @@ from predictionio_tpu.storage.registry import Storage, StorageConfig, set_storag
 
 
 @pytest.fixture()
-def storage():
-    """A fresh, fully in-memory Storage installed as process default."""
+def storage(tmp_path):
+    """A fresh, fully in-memory Storage installed as process default.
+    Its home (train checkpoints, scan cache, model registry) is the
+    test's own directory: under xdist two workers training the same
+    engine id in one shared ``~/.pio_store/train_ckpt`` read each
+    other's half-written checkpoint steps."""
     st = Storage(StorageConfig(metadata_type="MEMORY",
                                eventdata_type="MEMORY",
-                               modeldata_type="MEMORY"))
+                               modeldata_type="MEMORY",
+                               home=str(tmp_path / "pio_home")))
     # force instantiation so the fixtures are shared instances
     st._meta = MetaStore(":memory:")
     st._events = MemoryEventStore()

@@ -374,6 +374,96 @@ class TestFusedGram:
         Ur, Vr = _ref_als(coo, p)
         np.testing.assert_allclose(Uf, Ur, rtol=2e-3, atol=2e-3)
 
+    @staticmethod
+    def _wide_layout(monkeypatch):
+        """A layout with a dense head, a segmented bucket and regular
+        buckets the kernel takes (width 128), on both sides."""
+        import predictionio_tpu.models.als as als_mod
+
+        monkeypatch.setattr(als_mod, "_LADDER", (8, 128))
+        monkeypatch.setattr(als_mod, "_C_MAX", 128)
+        monkeypatch.setattr(als_mod, "_DENSE_MIN_COUNT", 300)
+        monkeypatch.setattr(als_mod, "_DENSE_RATIO", 0.75)
+        rng = np.random.default_rng(31)
+        n_u, n_i = 90, 400
+        deg = np.minimum(rng.zipf(1.25, n_u) + 2, n_i)
+        deg[:3] = (390, 350, 330)          # the dense head
+        deg[3:9] = (290, 260, 200, 170, 140, 129)   # segmented rows
+        uu = np.repeat(np.arange(n_u), deg).astype(np.int32)
+        ii = np.concatenate([rng.choice(n_i, d, replace=False)
+                             for d in deg]).astype(np.int32)
+        rr = rng.uniform(1, 5, len(uu)).astype(np.float32)
+        return RatingsCOO(uu, ii, rr, n_u, n_i)
+
+    @staticmethod
+    def _kernel_buckets(sides):
+        from predictionio_tpu.ops.gram import kernel_takes_width
+
+        return [b for s in sides for b in s.buckets
+                if kernel_takes_width(b.C)]
+
+    @pytest.mark.parametrize("sharded", [False, True],
+                             ids=["single", "sharded_forced_bounds"])
+    def test_real_slots_are_a_prefix_of_every_bucket_row(self, monkeypatch,
+                                                         sharded):
+        """What the kernel's lengths rest on (``_bucket_side``): padding
+        only at a row's end, so ``mask.sum(1)`` is the real length —
+        under natural bounds and under the sharded path's forced ones."""
+        import predictionio_tpu.models.als as als_mod
+        from predictionio_tpu.models.als_sharded import als_prepare_sharded
+
+        coo = self._wide_layout(monkeypatch)
+        if sharded:
+            prep = als_prepare_sharded(coo, 4)
+            sides = prep.u_sides + prep.i_sides
+        else:
+            prep = als_mod.als_prepare(coo)
+            sides = [prep.u_side, prep.i_side]
+            assert prep.u_side.dense is not None
+        buckets = self._kernel_buckets(sides)
+        assert any(b.seg is not None for b in buckets)
+        assert any(b.seg is None for b in buckets)
+        for b in buckets:
+            m = b.mask.reshape(-1, b.C)
+            assert (m[:, :-1] >= m[:, 1:]).all()
+            # every interaction of the bucket sits in some row's prefix
+            assert m.sum() == b.counts.sum()
+
+    def test_kernel_is_given_the_mask_row_sums(self, monkeypatch):
+        """Each half-step hands ``gather_gram`` one length per bucket
+        row, equal to that row's mask sum, and ``kernel_dma_rows`` is
+        their total: the kernel starts one copy per real slot."""
+        import jax.numpy as jnp
+        from predictionio_tpu.ops import gram as gram_mod
+        import predictionio_tpu.models.als as als_mod
+
+        coo = self._wide_layout(monkeypatch)
+        prep = als_mod.als_prepare(coo)
+        given = []
+        orig = gram_mod.gather_gram
+
+        def spy(F, idx, wo, wb, lengths=None, **kw):
+            given.append(np.asarray(lengths))
+            return orig(F, idx, wo, wb, lengths, **kw)
+
+        monkeypatch.setattr(gram_mod, "gather_gram", spy)
+        half = als_mod._make_half(4, False, True, gram_mode="interpret")
+        u_bufs, i_bufs = prep.device_buffers()
+        rng = np.random.default_rng(0)
+        for side, bufs, n_other in ((prep.u_side, u_bufs, coo.n_items),
+                                    (prep.i_side, i_bufs, coo.n_users)):
+            F = jnp.asarray(rng.standard_normal((n_other, 4)), jnp.float32)
+            half(F, bufs, side.geometry, 0.1, 1.0)   # eager: no jit
+        buckets = self._kernel_buckets([prep.u_side, prep.i_side])
+        assert len(given) == len(buckets) > 2
+        for lengths, b in zip(given, buckets):
+            np.testing.assert_array_equal(
+                lengths, b.mask.reshape(-1, b.C).sum(1).astype(np.int32))
+        rows = prep.kernel_rows()
+        assert rows["kernel_dma_rows"] == sum(int(g.sum()) for g in given)
+        # no slot that holds an interaction is skipped
+        assert rows["kernel_dma_rows"] >= rows["kernel_real_rows"] > 0
+
     def test_kernel_actually_traced(self, monkeypatch):
         """Guard against the silent-skip failure mode: a geometry where
         everything lands in the dense head never calls the kernel and

@@ -115,7 +115,8 @@ def test_gather_gram(one_chip, C, dtype):
             _sds((n_other, 64), dtype, one_chip),
             _sds((304, C), jnp.int32, one_chip),
             _sds((304, C), jnp.float32, one_chip),
-            _sds((304, C), jnp.float32, one_chip)).compile()
+            _sds((304, C), jnp.float32, one_chip),
+            _sds((304,), jnp.int32, one_chip)).compile()
         assert _has_kernel(c)
 
 

@@ -285,7 +285,8 @@ class TestTPULowering:
             jax.ShapeDtypeStruct((26744, 64), jnp.float32),
             jax.ShapeDtypeStruct((300, 512), jnp.int32),
             jax.ShapeDtypeStruct((300, 512), jnp.float32),
-            jax.ShapeDtypeStruct((300, 512), jnp.float32)).mlir_module()
+            jax.ShapeDtypeStruct((300, 512), jnp.float32),
+            jax.ShapeDtypeStruct((300,), jnp.int32)).mlir_module()
         assert "tpu_custom_call" in txt, txt[:300]
 
 
@@ -354,6 +355,77 @@ class TestGatherGram:
                                    rtol=5e-2, atol=1e-1)
         np.testing.assert_allclose(np.asarray(b16), np.asarray(b32),
                                    rtol=5e-2, atol=1e-1)
+
+    def _ragged(self, C, lengths, k=13, seed=0):
+        """Rows whose slots past ``lengths`` carry zero weight and
+        point at factor row 0, which is poisoned with inf: a kernel
+        that fetched one of them would return NaN (0 × inf)."""
+        F, idx, wo, wb = self._data(len(lengths), C, k, seed=seed)
+        F[0] = np.inf
+        idx = np.maximum(idx, 1)
+        pad = np.arange(C)[None, :] >= np.asarray(lengths)[:, None]
+        idx[pad] = 0
+        wo[pad] = 0.0
+        wb[pad] = 0.0
+        return F, idx, wo, wb
+
+    def _ref_real(self, F, idx, wo, wb):
+        # the reference sums the real slots only (F[0] is never one)
+        F = F.copy()
+        F[0] = 0.0
+        return self._ref(F, idx, wo, wb)
+
+    @pytest.mark.parametrize("C", [128, 512, 2048, 8192])
+    def test_ragged_lengths(self, C):
+        """Every row length around the tile edges (T = min(C, 256))
+        and the row's ends, against the float64 reference."""
+        from predictionio_tpu.ops.gram import gather_gram
+
+        T = min(C, 256)
+        lengths = sorted({0, 1, T - 1, T, min(T + 1, C), C - 1, C})
+        F, idx, wo, wb = self._ragged(C, lengths)
+        A, b = gather_gram(jnp.asarray(F), jnp.asarray(idx),
+                           jnp.asarray(wo), jnp.asarray(wb),
+                           jnp.asarray(lengths, jnp.int32), interpret=True)
+        An, bn = self._ref_real(F, idx, wo, wb)
+        tol = dict(rtol=1e-4, atol=2e-5 * np.sqrt(C))
+        np.testing.assert_allclose(np.asarray(A), An, **tol)
+        np.testing.assert_allclose(np.asarray(b), bn, **tol)
+        # the length-0 row copied nothing and is exactly zero
+        assert not np.asarray(A[0]).any() and not np.asarray(b[0]).any()
+
+    def test_stale_tile_rows_are_masked(self):
+        """Row 0 fills the line tile with inf; row 1 of the same block
+        fetches 3 lines over it and must not see what row 0 left."""
+        from predictionio_tpu.ops.gram import gather_gram
+
+        C = 128
+        F, idx, wo, wb = self._ragged(C, [C, 3])
+        idx[0] = 0      # row 0 really gathers the inf row, every slot
+        wo[0] = wb[0] = 1.0
+        A, b = gather_gram(jnp.asarray(F), jnp.asarray(idx),
+                           jnp.asarray(wo), jnp.asarray(wb),
+                           jnp.asarray([C, 3], jnp.int32), interpret=True)
+        assert not np.isfinite(np.asarray(A[0])).any()
+        An, bn = self._ref_real(F, idx[1:], wo[1:], wb[1:])
+        np.testing.assert_allclose(np.asarray(A[1]), An[0], rtol=1e-4,
+                                   atol=1e-4)
+        np.testing.assert_allclose(np.asarray(b[1]), bn[0], rtol=1e-4,
+                                   atol=1e-4)
+
+    @pytest.mark.parametrize("C", [128, 512])
+    def test_no_lengths_is_every_slot(self, C):
+        """Lengths are an input, not a mode: omitted, every slot is
+        fetched — bit for bit what lengths all C give, zero weights
+        anywhere in the row."""
+        from predictionio_tpu.ops.gram import gather_gram
+
+        args = [jnp.asarray(a) for a in self._data(11, C, 13, seed=4)]
+        A0, b0 = gather_gram(*args, interpret=True)
+        A1, b1 = gather_gram(*args, jnp.full((11,), C, jnp.int32),
+                             interpret=True)
+        np.testing.assert_array_equal(np.asarray(A0), np.asarray(A1))
+        np.testing.assert_array_equal(np.asarray(b0), np.asarray(b1))
 
     def test_empty_rows(self):
         from predictionio_tpu.ops.gram import gather_gram

@@ -205,8 +205,14 @@ class TestRouting:
         # stopped stub's sockets stay half-open (the loop just stops),
         # so the per-try timeout is what surfaces the failure — the
         # worst case of a kill: a peer that neither answers nor resets.
+        # The survivor answers in 50 ms, so the dark replica's last
+        # EWMA (or its fresh floor) stays the lower P2C score and live
+        # requests keep reaching it: on an idle host two equally fast
+        # stubs let the survivor's EWMA sink under the dead one's and
+        # no request ever failed (the breaker stayed closed).
         with fleet(2, {"hedge": False, "health_interval": 30.0,
-                       "per_try_timeout_ms": 300.0}) as (
+                       "per_try_timeout_ms": 300.0},
+                   stub_latency=[0.0, 0.05]) as (
                 router, stubs, threads):
             base = f"http://127.0.0.1:{router.http.port}"
             assert http("POST", f"{base}/queries.json", {})[0] == 200
