@@ -240,6 +240,11 @@ class _BucketSide:
     inv_perm: np.ndarray   # original entity id → position
     buckets: list
     dense: Optional[_DenseHead] = None
+    #: which path the data took through :func:`_bucket_side`: 16-bit
+    #: radix passes of the order (1 | 2), and how the dense head was
+    #: filled ("assign" | "bincount" | "none" without a head)
+    radix_passes: int = 0
+    dense_fill: str = "none"
 
     @property
     def geometry(self):
@@ -296,13 +301,46 @@ def _merge_bounds(counts_sorted_list, n_other: int) -> tuple:
     return (nb_dense, (nb_seg, rows_cap), regs)
 
 
-def _bucket_side(idx_self, idx_other_pos, vals, n_self, counts,
+def _stable_order(inv_perm: np.ndarray, idx_self: np.ndarray):
+    """``np.argsort(inv_perm[idx_self], kind="stable")`` — the
+    interactions ordered by their entity's permuted position — as an
+    LSD radix sort over 16-bit digits: numpy's stable argsort of a
+    ``uint16`` array IS its radix sort (O(n)), where that of the
+    ``int32`` positions is a merge sort. One digit where the positions
+    fit it, else two (``o1[o2]``; each pass being stable, so is their
+    composition). A digit is gathered from a table of ``len(inv_perm)``
+    entries, so the positions themselves are never laid out.
+    ``(order, passes)``."""
+    # (the cast to uint16 keeps a position's low 16 bits)
+    o1 = np.argsort(inv_perm.astype(np.uint16)[idx_self], kind="stable")
+    if len(inv_perm) <= 1 << 16:
+        return o1, 1
+    hi = (inv_perm >> 16).astype(np.uint16)[idx_self][o1]
+    return o1[np.argsort(hi, kind="stable")], 2
+
+
+def _fill_rows(rowlen: np.ndarray, C: int, o: np.ndarray, v: np.ndarray):
+    """``(other_idx, vals, mask)`` of ``len(rowlen)`` rows of width
+    ``C``: row r holds the next ``rowlen[r]`` interactions of ``o``,
+    ``v`` as a prefix, zeros behind. Boolean assignment walks the
+    prefix mask row-major, which IS the sorted order of ``o``, ``v`` —
+    no slot's (row, column) is ever computed."""
+    m = np.arange(C, dtype=np.int32) < rowlen[:, None]
+    oi = np.zeros(m.shape, np.int32)
+    vv = np.zeros(m.shape, np.float32)
+    oi[m] = o
+    vv[m] = v
+    return oi, vv, m.astype(np.float32)
+
+
+def _bucket_side(idx_self, idx_other, other_pos, vals, n_self, counts,
                  perm, inv_perm, n_other=None, bounds=None) -> _BucketSide:
-    """Bucket one orientation. ``idx_other_pos`` must already be mapped
-    to the other side's factor-row positions; ``counts/perm/inv_perm``
-    come from :func:`_perm_by_count_desc` on this side's counts;
-    ``n_other`` is the other side's factor-row count (the width of
-    dense-head weight rows — the gathered factor matrix height).
+    """Bucket one orientation. ``other_pos`` maps an other-side id (an
+    entry of ``idx_other``) to its factor-row position;
+    ``counts/perm/inv_perm`` come from :func:`_perm_by_count_desc` on
+    this side's counts; ``n_other`` is the other side's factor-row count
+    (the width of dense-head weight rows — the gathered factor matrix
+    height).
 
     ``bounds`` forces common bucket boundaries (sharded path: the
     max-merge over all devices, so every device traces one program).
@@ -311,24 +349,42 @@ def _bucket_side(idx_self, idx_other_pos, vals, n_self, counts,
     move p into the dense head or a bucket at least as wide as its
     natural one — so capacity C ≥ count always holds.
 
+    The algorithm is O(nnz) passes over 4-byte data (the arrays are
+    ``np.array_equal`` to those of the comparison-sort builder kept as
+    ``tests/als_layout_oracle.py``):
+
+    1. the interactions are ordered by their entity's permuted position
+       with a STABLE radix sort (:func:`_stable_order`; one or two
+       16-bit passes, read from the entity count) — stable, because a
+       row's slot order is the COO's order of appearance and the
+       Gram's float sums depend on it — and
+       ``other_pos[idx_other[order]]``, ``vals[order]`` are the only
+       nnz-long gathers of 4-byte data;
+    2. the dense head is filled by assignment where no (entity, other)
+       pair repeats — seen on the data: as many non-zero cells as
+       interactions — and by two ``bincount``s where one does;
+    3. every bucket is filled through its prefix mask
+       (:func:`_fill_rows`) from the rows' lengths alone.
+
+    ``radix_passes`` and ``dense_fill`` of the result say which path
+    the data took.
+
     Invariant the fused kernel rests on: in every bucket row — regular
     or segmented, natural or forced boundaries — the real slots are a
-    PREFIX (``col = within`` resp. ``within % C``; only an entity's
-    last segment row is short), so ``mask.sum(1)`` is the row's real
-    length and ``ops.gather_gram`` fetches just that many lines
+    PREFIX (a row is filled from its start; only an entity's last
+    segment row is short), so ``mask.sum(1)`` is the row's real length
+    and ``ops.gather_gram`` fetches just that many lines
     (tests/test_als.py holds it).
     """
-    if n_other is None:
-        n_other = (int(idx_other_pos.max()) + 1 if idx_other_pos.size
-                   else 1)
-    nnz = idx_self.shape[0]
-    pos = inv_perm[idx_self]
-    order = np.argsort(pos, kind="stable")
-    ps, o, v = pos[order], idx_other_pos[order], vals[order]
-    counts_perm = counts[perm].astype(np.int64)
-    starts = np.zeros(n_self + 1, np.int64)
-    np.cumsum(counts_perm, out=starts[1:])
-    within = (np.arange(nnz, dtype=np.int64) - starts[ps]).astype(np.int64)
+    with tracing.span("als.prepare.order"):
+        order, passes = _stable_order(inv_perm, idx_self)
+        o, v = other_pos[idx_other[order]], vals[order]
+        del order
+        if n_other is None:
+            n_other = int(o.max()) + 1 if o.size else 1
+        counts_perm = counts[perm].astype(np.int64)
+        starts = np.zeros(n_self + 1, np.int64)
+        np.cumsum(counts_perm, out=starts[1:])
 
     if bounds is None:
         bounds = _merge_bounds([counts_perm], n_other)
@@ -336,26 +392,42 @@ def _bucket_side(idx_self, idx_other_pos, vals, n_self, counts,
 
     # dense head: heaviest entities (permuted positions [0, nb_dense))
     # as dense weight rows — see _DENSE_RATIO
-    dense = None
+    dense, dense_fill = None, "none"
     if nb_dense:
-        hi = int(starts[min(nb_dense, n_self)])
-        # bincount over linearized (entity, other) indices: np.add.at
-        # is an unbuffered scalar scatter, ~50-100× slower over the
-        # millions of nnz the dense head holds
-        lin = ps[:hi].astype(np.int64) * n_other + o[:hi]
-        size = nb_dense * n_other
-        w_cnt = np.bincount(lin, minlength=size).astype(
-            np.float32).reshape(nb_dense, n_other)
-        w_val = np.bincount(lin, weights=v[:hi], minlength=size).astype(
-            np.float32).reshape(nb_dense, n_other)
-        cnts = np.zeros(nb_dense, np.float32)
-        real = min(nb_dense, n_self)
-        cnts[:real] = counts_perm[:real]
-        dense = _DenseHead(nb_dense, n_other, w_cnt, w_val, cnts)
+        with tracing.span("als.prepare.dense"):
+            real = min(nb_dense, n_self)
+            hi = int(starts[real])
+            # linearized (entity, other) cell of each interaction
+            lin = np.repeat(np.arange(real, dtype=np.int64) * n_other,
+                            counts_perm[:real])
+            lin += o[:hi]
+            # where every pair is distinct a cell receives ONE
+            # interaction, so assigning it is exactly what summing it
+            # gives (``+ 0.0``: a sum never returns -0.0)
+            w_cnt = np.zeros((nb_dense, n_other), np.float32)
+            w_cnt.reshape(-1)[lin] = 1.0
+            if np.count_nonzero(w_cnt) == hi:
+                dense_fill = "assign"
+                w_val = np.zeros((nb_dense, n_other), np.float32)
+                w_val.reshape(-1)[lin] = v[:hi] + np.float32(0.0)
+            else:
+                # a pair repeats (a user who rated an item twice): sum.
+                # bincount, because np.add.at is an unbuffered scalar
+                # scatter, ~50-100× slower over the millions of
+                # interactions the dense head holds
+                dense_fill = "bincount"
+                size = nb_dense * n_other
+                w_cnt = np.bincount(lin, minlength=size).astype(
+                    np.float32).reshape(nb_dense, n_other)
+                w_val = np.bincount(lin, weights=v[:hi],
+                                    minlength=size).astype(
+                    np.float32).reshape(nb_dense, n_other)
+            cnts = np.zeros(nb_dense, np.float32)
+            cnts[:real] = counts_perm[:real]
+            dense = _DenseHead(nb_dense, n_other, w_cnt, w_val, cnts)
         # rebase the remainder so the seg/ladder code below sees a
         # self-contained problem over positions [nb_dense, n_self)
-        ps = ps[hi:] - nb_dense
-        o, v, within = o[hi:], v[hi:], within[hi:]
+        o, v = o[hi:], v[hi:]
         counts_perm = counts_perm[nb_dense:]
         starts = starts[nb_dense:] - hi
         n_self_rest = max(n_self - nb_dense, 0)
@@ -363,83 +435,78 @@ def _bucket_side(idx_self, idx_other_pos, vals, n_self, counts,
         n_self_rest = n_self
     buckets = []
 
-    # heavy entities (count > _C_MAX): one SEGMENTED bucket — each
-    # entity spans ceil(count/C) rows of width C; the one-hot ``seg``
-    # matrix aggregates row partials per entity inside the compiled
-    # program. Entities are count-descending, so these are the first
-    # positions after the dense head and the output concatenation order
-    # is preserved.
-    if nb_seg:
-        C = _C_MAX
-        cnts = counts_perm[:nb_seg]
-        rows_per = (cnts + C - 1) // C  # forced-in light entities: 1 row
-        row_starts = np.zeros(nb_seg + 1, np.int64)
-        np.cumsum(rows_per, out=row_starts[1:])
-        n_rows = int(row_starts[-1])
-        # slab capped at the (merged) row count: padding a small bucket
-        # to a full 64MB slab made every tiny block solve tens of
-        # thousands of identity systems
-        slab = max(1, min(_SLAB_ELEMS // C, rows_cap))
-        n_slabs = -(-rows_cap // slab)
-        assert n_rows <= n_slabs * slab
-        R = n_slabs * slab
-        oi = np.zeros((R, C), np.int32)
-        vv = np.zeros((R, C), np.float32)
-        mm = np.zeros((R, C), np.float32)
-        hi = int(starts[nb_seg])
-        row = row_starts[ps[:hi]] + within[:hi] // C
-        col = within[:hi] % C
-        oi[row, col] = o[:hi]
-        vv[row, col] = v[:hi]
-        mm[row, col] = 1.0
-        row_ent = np.repeat(np.arange(nb_seg), rows_per)
-        # slab-local one-hot: entity index relative to the slab's first
-        # entity (rows are entity-sorted → ≤ slab consecutive entities)
-        if n_rows:
-            seg_off = row_ent[np.minimum(np.arange(n_slabs) * slab,
-                                         n_rows - 1)].astype(np.int32)
-            local = row_ent - seg_off[np.arange(n_rows) // slab]
-            seg = np.zeros((R, slab), np.float32)
-            seg[np.arange(n_rows), local] = 1.0  # pad rows stay all-zero
-        else:  # a device with no ratings in the (forced) seg range
-            seg_off = np.zeros(n_slabs, np.int32)
-            seg = np.zeros((R, slab), np.float32)
-        buckets.append(_Bucket(
-            C, nb_seg, slab, n_slabs,
-            oi.reshape(n_slabs, slab, C),
-            vv.reshape(n_slabs, slab, C),
-            mm.reshape(n_slabs, slab, C),
-            cnts.astype(np.float32),
-            seg=seg.reshape(n_slabs, slab, slab),
-            seg_off=seg_off))
+    with tracing.span("als.prepare.fill"):
+        # heavy entities (count > _C_MAX): one SEGMENTED bucket — each
+        # entity spans ceil(count/C) rows of width C; the one-hot
+        # ``seg`` matrix aggregates row partials per entity inside the
+        # compiled program. Entities are count-descending, so these are
+        # the first positions after the dense head and the output
+        # concatenation order is preserved.
+        if nb_seg:
+            C = _C_MAX
+            cnts = counts_perm[:nb_seg]
+            rows_per = (cnts + C - 1) // C  # forced-in light entities: 1 row
+            row_starts = np.zeros(nb_seg + 1, np.int64)
+            np.cumsum(rows_per, out=row_starts[1:])
+            n_rows = int(row_starts[-1])
+            # slab capped at the (merged) row count: padding a small
+            # bucket to a full 64MB slab made every tiny block solve
+            # tens of thousands of identity systems
+            slab = max(1, min(_SLAB_ELEMS // C, rows_cap))
+            n_slabs = -(-rows_cap // slab)
+            assert n_rows <= n_slabs * slab
+            R = n_slabs * slab
+            # a row is full but an entity's last (and the slab's
+            # padding rows, which hold nothing)
+            rowlen = np.zeros(R, np.int32)
+            rowlen[:n_rows] = C
+            has = rows_per > 0
+            rowlen[row_starts[1:][has] - 1] = (cnts - (rows_per - 1) * C)[has]
+            hi = int(starts[nb_seg])
+            oi, vv, mm = _fill_rows(rowlen, C, o[:hi], v[:hi])
+            row_ent = np.repeat(np.arange(nb_seg), rows_per)
+            # slab-local one-hot: entity index relative to the slab's
+            # first entity (rows are entity-sorted → ≤ slab consecutive
+            # entities)
+            if n_rows:
+                seg_off = row_ent[np.minimum(np.arange(n_slabs) * slab,
+                                             n_rows - 1)].astype(np.int32)
+                local = row_ent - seg_off[np.arange(n_rows) // slab]
+                seg = np.zeros((R, slab), np.float32)
+                seg[np.arange(n_rows), local] = 1.0  # pad rows stay all-zero
+            else:  # a device with no ratings in the (forced) seg range
+                seg_off = np.zeros(n_slabs, np.int32)
+                seg = np.zeros((R, slab), np.float32)
+            buckets.append(_Bucket(
+                C, nb_seg, slab, n_slabs,
+                oi.reshape(n_slabs, slab, C),
+                vv.reshape(n_slabs, slab, C),
+                mm.reshape(n_slabs, slab, C),
+                cnts.astype(np.float32),
+                seg=seg.reshape(n_slabs, slab, slab),
+                seg_off=seg_off))
 
-    # the rest: one row per entity, padded to the bucket width
-    e = nb_seg
-    for C, nb in regs:
-        slab = max(1, min(_SLAB_ELEMS // C, nb))
-        n_slabs = -(-nb // slab)
-        nb_pad = n_slabs * slab
-        oi = np.zeros((nb_pad, C), np.int32)
-        vv = np.zeros((nb_pad, C), np.float32)
-        mm = np.zeros((nb_pad, C), np.float32)
-        # forced boundaries may extend past this device's entities
-        e_end = min(e + nb, n_self_rest)
-        lo, hi = int(starts[min(e, n_self_rest)]), int(starts[e_end])
-        row = (ps[lo:hi] - e).astype(np.int64)
-        col = within[lo:hi]
-        oi[row, col] = o[lo:hi]
-        vv[row, col] = v[lo:hi]
-        mm[row, col] = 1.0
-        cnt = np.zeros(nb_pad, np.float32)
-        cnt[: max(e_end - e, 0)] = counts_perm[e:e_end]
-        buckets.append(_Bucket(
-            C, nb, slab, n_slabs,
-            oi.reshape(n_slabs, slab, C),
-            vv.reshape(n_slabs, slab, C),
-            mm.reshape(n_slabs, slab, C),
-            cnt.reshape(n_slabs, slab)))
-        e += nb
-    return _BucketSide(n_self, perm, inv_perm, buckets, dense=dense)
+        # the rest: one row per entity, padded to the bucket width
+        e = nb_seg
+        for C, nb in regs:
+            slab = max(1, min(_SLAB_ELEMS // C, nb))
+            n_slabs = -(-nb // slab)
+            nb_pad = n_slabs * slab
+            # forced boundaries may extend past this device's entities
+            e_end = min(e + nb, n_self_rest)
+            lo, hi = int(starts[min(e, n_self_rest)]), int(starts[e_end])
+            rowlen = np.zeros(nb_pad, np.int32)
+            rowlen[: max(e_end - e, 0)] = counts_perm[e:e_end]
+            oi, vv, mm = _fill_rows(rowlen, C, o[lo:hi], v[lo:hi])
+            buckets.append(_Bucket(
+                C, nb, slab, n_slabs,
+                oi.reshape(n_slabs, slab, C),
+                vv.reshape(n_slabs, slab, C),
+                mm.reshape(n_slabs, slab, C),
+                rowlen.astype(np.float32).reshape(n_slabs, slab)))
+            e += nb
+    return _BucketSide(n_self, perm, inv_perm, buckets, dense=dense,
+                       radix_passes=passes, dense_fill=dense_fill)
 
 
 @dataclass
@@ -491,6 +558,14 @@ class ALSPrepared:
         return {"kernel_real_rows": real, "kernel_padded_rows": padded,
                 "kernel_bucket_rows": rows, "kernel_dma_rows": real}
 
+    def layout_paths(self) -> dict:
+        """Which data-dependent path :func:`_bucket_side` took on each
+        side (attributes of the ``als.prepare`` span)."""
+        return {"radix_passes_u": self.u_side.radix_passes,
+                "radix_passes_i": self.i_side.radix_passes,
+                "dense_fill_u": self.u_side.dense_fill,
+                "dense_fill_i": self.i_side.dense_fill}
+
     def device_buffers(self, device=None):
         """Bucket arrays as device arrays (cached per device across
         train calls — a reused prep may be trained on different pinned
@@ -524,14 +599,15 @@ class ALSPrepared:
 
 def als_prepare(coo: RatingsCOO) -> ALSPrepared:
     """Build the bucketed layout for single-device training."""
-    cnt_u = np.bincount(coo.user_idx, minlength=coo.n_users)
-    cnt_i = np.bincount(coo.item_idx, minlength=coo.n_items)
-    perm_u, inv_u = _perm_by_count_desc(cnt_u)
-    perm_i, inv_i = _perm_by_count_desc(cnt_i)
-    u_side = _bucket_side(coo.user_idx, inv_i[coo.item_idx], coo.rating,
+    with tracing.span("als.prepare.order"):
+        cnt_u = np.bincount(coo.user_idx, minlength=coo.n_users)
+        cnt_i = np.bincount(coo.item_idx, minlength=coo.n_items)
+        perm_u, inv_u = _perm_by_count_desc(cnt_u)
+        perm_i, inv_i = _perm_by_count_desc(cnt_i)
+    u_side = _bucket_side(coo.user_idx, coo.item_idx, inv_i, coo.rating,
                           coo.n_users, cnt_u, perm_u, inv_u,
                           n_other=coo.n_items)
-    i_side = _bucket_side(coo.item_idx, inv_u[coo.user_idx], coo.rating,
+    i_side = _bucket_side(coo.item_idx, coo.user_idx, inv_u, coo.rating,
                           coo.n_items, cnt_i, perm_i, inv_i,
                           n_other=coo.n_users)
     return ALSPrepared(coo.n_users, coo.n_items, coo.nnz, u_side, i_side)
@@ -566,7 +642,7 @@ def als_train(
     device = mesh.devices.flat[0] if mesh is not None else None
     with tracing.span("als.prepare", nnz=int(coo.nnz)):
         prep = als_prepare(coo)
-        tracing.add_attrs(**prep.kernel_rows())
+        tracing.add_attrs(**prep.kernel_rows(), **prep.layout_paths())
     return als_train_prepared(prep, params, device=device,
                               checkpointer=checkpointer,
                               checkpoint_every=checkpoint_every)

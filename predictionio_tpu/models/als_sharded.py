@@ -174,7 +174,7 @@ def _side_prepared(idx_self, idx_other, vals, block, n_dev,
         sel = owner == d
         sides.append(_bucket_side(
             (idx_self[sel] - d * block).astype(np.int32),
-            other_pos[idx_other[sel]].astype(np.int32),
+            idx_other[sel], other_pos,
             vals[sel].astype(np.float32),
             block, locs[d].astype(np.float32), perms[d], invs[d],
             n_other=n_other, bounds=bounds))

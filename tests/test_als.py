@@ -104,6 +104,118 @@ def _ref_als(coo, p):
     return U, V
 
 
+def _layout_cases():
+    """name → (knobs to monkeypatch on models/als.py, COO builder
+    (given the monkeypatch), devices of the sharded layout or 0, what
+    the new builder must report about the paths it took)."""
+
+    def coo_of(uu, ii, rr, n_u, n_i, dtype=np.int32):
+        return RatingsCOO(np.asarray(uu).astype(dtype),
+                          np.asarray(ii).astype(dtype),
+                          np.asarray(rr, np.float32), n_u, n_i)
+
+    def power_law(seed=41, n_u=2000, n_i=400, nnz=60000, dedupe=True,
+                  dtype=np.int32):
+        rng = np.random.default_rng(seed)
+        uu = rng.zipf(1.3, nnz) % n_u
+        ii = rng.zipf(1.2, nnz) % n_i
+        if dedupe:
+            keep = np.sort(np.unique(uu * n_i + ii, return_index=True)[1])
+            uu, ii = uu[keep], ii[keep]
+        return coo_of(uu, ii, rng.uniform(1, 5, len(uu)), n_u, n_i, dtype)
+
+    def many_entities():
+        # every one of 70,000 users rated: permuted positions pass
+        # 65,536, so the order needs its second 16-bit digit
+        rng = np.random.default_rng(42)
+        n_u, n_i = 70_000, 300
+        uu = np.concatenate([rng.permutation(n_u),
+                             rng.zipf(1.5, 20_000) % n_u])
+        ii = rng.integers(0, n_i, len(uu))
+        return coo_of(uu, ii, rng.uniform(1, 5, len(uu)), n_u, n_i)
+
+    def short_last_row():
+        # counts 8·r + {1, 7, 0}: an entity's last segment row is
+        # short, or (a multiple of the width) full
+        deg = [8 * 5 + 1, 8 * 3 + 7, 8 * 4, 8 + 3, 7, 2, 1, 0, 3]
+        rng = np.random.default_rng(43)
+        uu = np.repeat(np.arange(len(deg)), deg)
+        ii = np.concatenate([rng.choice(50, d, replace=False) for d in deg])
+        mix = rng.permutation(len(uu))
+        return coo_of(uu[mix], ii[mix], rng.uniform(1, 5, len(uu)),
+                      len(deg), 50)
+
+    def dense(repeat):
+        rng = np.random.default_rng(44)
+        n_u, n_i = 40, 25
+        deg = np.minimum(rng.zipf(1.3, n_u) + 1, n_i)
+        deg[:4] = (25, 20, 11, 6)
+        uu = np.repeat(np.arange(n_u), deg)
+        ii = np.concatenate([rng.choice(n_i, d, replace=False)
+                             for d in deg])
+        rr = rng.uniform(1, 5, len(uu))
+        rr[3] = -0.0            # a sum gives +0.0: so must the assignment
+        if repeat:              # user 1 rated item ii[30] twice
+            uu, ii, rr = (np.append(uu, 1), np.append(ii, ii[30]),
+                          np.append(rr, 2.5))
+        mix = rng.permutation(len(uu))
+        return coo_of(uu[mix], ii[mix], rr[mix], n_u, n_i)
+
+    def no_interactions():
+        # ids 50..119 and items 30..39 never appear
+        rng = np.random.default_rng(45)
+        return coo_of(rng.integers(0, 50, 600), rng.integers(0, 30, 600),
+                      rng.uniform(1, 5, 600), 120, 40)
+
+    def skewed_devices():
+        # user 0 owns the segmented range: seven of eight devices have
+        # nothing in the forced seg bucket
+        rng = np.random.default_rng(11)
+        n_u, n_i = 33, 17
+        uu = np.concatenate([np.zeros(16, np.int64),
+                             rng.integers(1, n_u, 120)])
+        ii = np.concatenate([np.arange(16) % n_i,
+                             rng.integers(0, n_i, 120)])
+        keep = np.sort(np.unique(uu * n_i + ii, return_index=True)[1])
+        return coo_of(uu[keep], ii[keep], rng.uniform(1, 5, len(keep)),
+                      n_u, n_i)
+
+    small = dict(_LADDER=(2, 8), _C_MAX=8)
+    head = dict(_DENSE_MIN_COUNT=6)
+    empty = np.zeros(0, np.int32)
+    return {
+        "default_ladder_power_law": (
+            {}, lambda mp: power_law(), 0, dict(radix_passes_u=1, dense_fill_u="assign",
+                                   dense_fill_i="assign")),
+        "two_radix_passes": (
+            {}, lambda mp: many_entities(), 0, dict(radix_passes_u=2, radix_passes_i=1)),
+        "segmented_short_last_row": (
+            small, lambda mp: short_last_row(), 0, dict(dense_fill_u="none")),
+        "dense_head_distinct_pairs": (
+            head, lambda mp: dense(False), 0, dict(dense_fill_u="assign")),
+        "dense_head_repeated_pair": (
+            head, lambda mp: dense(True), 0, dict(dense_fill_u="bincount")),
+        "dense_head_power_law_repeats": (
+            {}, lambda mp: power_law(dedupe=False), 0,
+            dict(dense_fill_u="bincount")),
+        "entities_without_interactions": (
+            {}, lambda mp: no_interactions(), 0, {}),
+        "empty_coo": (
+            {}, lambda mp: coo_of(empty, empty, empty, 5, 6), 0,
+            dict(dense_fill_u="none", dense_fill_i="none")),
+        "int64_indices": (
+            small, lambda mp: power_law(46, 60, 40, 900, dtype=np.int64),
+            0, {}),
+        "sharded_skewed_devices": (
+            small, lambda mp: skewed_devices(), 8, {}),
+        "sharded_dense_seg_regular": (
+            {}, lambda mp: TestFusedGram._wide_layout(mp), 4, {}),
+        "sharded_uneven_empty_device": (
+            head, lambda mp: power_law(47, 9, 21, 300, dedupe=False), 8,
+            {}),
+    }
+
+
 class TestBucketedLayout:
     def test_segmented_heavy_bucket_matches_dense_reference(self, monkeypatch):
         """Shrink the width ladder so the heavy (segmented, one-hot
@@ -313,6 +425,107 @@ class TestBucketedLayout:
         Ur, Vr = _ref_als(coo, p)
         np.testing.assert_allclose(U, Ur, rtol=2e-3, atol=2e-3)
         np.testing.assert_allclose(V, Vr, rtol=2e-3, atol=2e-3)
+
+    @staticmethod
+    def _same_array(what, new, old):
+        assert (new is None) == (old is None), what
+        if new is not None:
+            assert new.dtype == old.dtype and new.shape == old.shape, what
+            # bit for bit: array_equal would let -0.0 pass for 0.0
+            assert new.tobytes() == old.tobytes(), what
+
+    def _same_side(self, name, new, old):
+        assert new.geometry == old.geometry, name
+        self._same_array(f"{name}.perm", new.perm, old.perm)
+        self._same_array(f"{name}.inv_perm", new.inv_perm, old.inv_perm)
+        assert (new.dense is None) == (old.dense is None), name
+        if new.dense is not None:
+            for f in ("w_cnt", "w_val", "counts"):
+                self._same_array(f"{name}.dense.{f}", getattr(new.dense, f),
+                                 getattr(old.dense, f))
+        for j, (b, ob) in enumerate(zip(new.buckets, old.buckets)):
+            for f in ("other_idx", "vals", "mask", "counts", "seg",
+                      "seg_off"):
+                self._same_array(f"{name}.buckets[{j}] (C={b.C}).{f}",
+                                 getattr(b, f), getattr(ob, f))
+
+    @pytest.mark.parametrize("case", list(_layout_cases()))
+    def test_layout_equals_oracle(self, monkeypatch, case):
+        """ISSUE 28: the O(nnz) builder (radix order, prefix-mask fill,
+        dense head by assignment) against the builder it replaced
+        (``tests/als_layout_oracle.py``, the parent's body): every
+        array of both sides equal bit for bit."""
+        import predictionio_tpu.models.als as als_mod
+        import predictionio_tpu.models.als_sharded as sh_mod
+        from tests.als_layout_oracle import bucket_side_oracle
+
+        knobs, build, n_dev, paths = _layout_cases()[case]
+        for k, val in knobs.items():
+            monkeypatch.setattr(als_mod, k, val)
+        coo = build(monkeypatch)
+
+        def prepare():
+            if n_dev:
+                p = sh_mod.als_prepare_sharded(coo, n_dev)
+                return p, p.u_sides + p.i_sides
+            p = als_mod.als_prepare(coo)
+            return p, [p.u_side, p.i_side]
+
+        prep, new = prepare()
+
+        def oracle(idx_self, idx_other, other_pos, vals, *rest, **kw):
+            return bucket_side_oracle(idx_self, other_pos[idx_other], vals,
+                                      *rest, **kw)
+
+        monkeypatch.setattr(als_mod, "_bucket_side", oracle)
+        monkeypatch.setattr(sh_mod, "_bucket_side", oracle)
+        _, old = prepare()
+        assert len(new) == len(old) == 2 * max(n_dev, 1)
+        for j, (a, b) in enumerate(zip(new, old)):
+            self._same_side(f"side {j}", a, b)
+        if case == "segmented_short_last_row":
+            assert new[0].buckets[0].seg is not None
+        if case == "sharded_skewed_devices":
+            seg = [s.buckets[0] for s in prep.u_sides]
+            assert all(b.seg is not None for b in seg)
+            assert sum(1 for b in seg if not b.mask.any()) >= 1
+        if not n_dev:
+            got = prep.layout_paths()
+            assert {k: got[k] for k in paths} == paths
+
+    def test_prepare_spans_only_inside_a_verb(self, monkeypatch):
+        """``als.prepare`` has children since ISSUE 28 — ``order``,
+        ``dense`` (a side with a head), ``fill`` — and carries the
+        paths the data took; a direct ``als_prepare`` outside a verb
+        (the benchmark's) records nothing."""
+        import predictionio_tpu.models.als as als_mod
+        from predictionio_tpu.utils import tracing
+
+        monkeypatch.setattr(als_mod, "_DENSE_MIN_COUNT", 6)
+        _, build, _, _ = _layout_cases()["dense_head_repeated_pair"]
+        coo = build(monkeypatch)
+        before = tracing.last_verb("layout.test")
+        prep = als_mod.als_prepare(coo)
+        assert tracing.last_verb("layout.test") is before
+        assert prep.u_side.dense_fill == "bincount"
+        with tracing.verb("layout.test"):
+            als_mod.als_train(coo, ALSParams(rank=4, iterations=1))
+        tree = tracing.last_verb("layout.test")
+        (parent,) = [s for s in tree if s["name"] == "als.prepare"]
+        kids = [s for s in tree if s["parentId"] == parent["spanId"]]
+        # the perms, then per side its order, its head if it has one,
+        # its buckets
+        want = ["order"] + [
+            step for side in (prep.u_side, prep.i_side)
+            for step in ("order", "dense", "fill")
+            if step != "dense" or side.dense is not None]
+        assert [s["name"] for s in kids] == [
+            "als.prepare." + step for step in want]
+        for a, b in zip(kids, kids[1:]):
+            assert parent["startNs"] <= a["startNs"] <= a["endNs"] \
+                <= b["startNs"] <= b["endNs"] <= parent["endNs"]
+        paths = prep.layout_paths()
+        assert {k: parent["attrs"][k] for k in paths} == paths
 
 
 def _zipf_coo(seed, n_u, n_i, nnz):

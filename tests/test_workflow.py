@@ -161,6 +161,8 @@ SPAN_PARENTS = {
     "train.fit": "train.run",
     "als.index": "train.fit",
     "als.prepare": "train.fit",
+    "als.prepare.order": "als.prepare",
+    "als.prepare.fill": "als.prepare",
     "als.init": "train.fit",
     "als.upload": "train.fit",
     "als.iterate": "train.fit",
@@ -178,7 +180,9 @@ SPAN_ATTRS = {
     "train.read.index": ("kept", "n_entities", "n_targets"),
     "als.index": ("nnz",),
     "als.prepare": ("nnz", "kernel_real_rows", "kernel_padded_rows",
-                    "kernel_bucket_rows", "kernel_dma_rows"),
+                    "kernel_bucket_rows", "kernel_dma_rows",
+                    "radix_passes_u", "radix_passes_i", "dense_fill_u",
+                    "dense_fill_i"),
     "als.upload": ("bytes",),
     "als.iterate": ("iterations", "gram", "solve"),
     "als.checkpoint": ("step", "bytes"),
