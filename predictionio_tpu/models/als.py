@@ -91,9 +91,6 @@ class ALSParams:
     bf16_gather: bool = False
 
 
-
-
-
 def init_factors(n: int, rank: int, seed: int) -> np.ndarray:
     """Deterministic host-side factor init shared by the single-device and
     sharded paths (so their iterates are bitwise-comparable)."""
@@ -114,16 +111,15 @@ def init_factors(n: int, rank: int, seed: int) -> np.ndarray:
 # during training (so same-width entities are contiguous); factors are
 # un-permuted once at the end.
 
-_SLAB_ELEMS = int(os.environ.get("PIO_ALS_SLAB_ELEMS", str(1 << 20)))
-                        # slab_entities × width bound per scan step. The r5
+_SLAB_ELEMS = 1 << 20   # slab_entities × width bound per scan step. The r5
                         # trace showed the warm train latency-bound (~8.8k
                         # device ops/iteration, HBM at 49 of 819 GB/s), so
                         # bigger slabs = fewer, larger dispatches: 2^20
                         # (~256 MB gather at k=64) measured 2.16 s vs 2.71 s
                         # device-side for the ML-20M train against the r2-r4
-                        # 2^18 default (profile_als.py --tune on the v5e).
-                        # Env-tunable; layout parity across slab sizes is
-                        # tested (test_als.py::test_slab_size_parity).
+                        # 2^18 default (an A/B on the v5e, r5). Layout
+                        # parity across slab sizes is tested
+                        # (test_als.py::test_slab_size_parity).
 
 # Allowed padded widths. Round 2 used every power of two up to the
 # heaviest entity's count (8.4M!): 38 buckets across both sides, each
@@ -145,8 +141,8 @@ _C_MAX = _LADDER[-1]
 # ML-20M, k=64); catalogs where it would exceed the cap below fall back
 # to in-body solves (memory flat, compile slower, persistent cache
 # amortizes).
-_SOLVE_CHUNK = int(os.environ.get("PIO_ALS_SOLVE_CHUNK", "4096"))
-_SOLVE_BUF_MB = int(os.environ.get("PIO_ALS_SOLVE_BUF_MB", "4096"))
+_SOLVE_CHUNK = 4096
+_SOLVE_BUF_MB = 4096
 
 # Dense-head crossover. The heaviest entities dominate padded slots
 # under a power law (ML-20M shape: the >8K-rating "seg" entities are
@@ -252,6 +248,15 @@ class _BucketSide:
                 self.dense.geometry if self.dense is not None else None,
                 tuple(b.geometry for b in self.buckets))
 
+    def arrays(self):
+        """The side's host arrays in the ``(dense, buckets)`` structure
+        ``_make_half``'s ``half`` takes as ``bufs_side``."""
+        d = self.dense
+        return (() if d is None else (d.w_cnt, d.w_val, d.counts), tuple(
+            (b.other_idx, b.vals, b.mask, b.counts)
+            + ((b.seg, b.seg_off) if b.seg is not None else ())
+            for b in self.buckets))
+
 
 def _perm_by_count_desc(counts: np.ndarray):
     perm = np.argsort(-counts, kind="stable").astype(np.int32)
@@ -273,12 +278,10 @@ def _merge_bounds(counts_sorted_list, n_other: int) -> tuple:
     """
     thresh = max(_DENSE_MIN_COUNT, int(_DENSE_RATIO * n_other))
     nb_dense = max(int((c >= thresh).sum()) for c in counts_sorted_list)
-    # byte-cap the head (PIO_ALS_DENSE_HEAD_MB, see _DENSE_HEAD_MB):
-    # counts are sorted descending, so truncating keeps the heaviest —
-    # highest-payoff — entities and spills the rest to the buckets below
-    head_mb = int(os.environ.get("PIO_ALS_DENSE_HEAD_MB",
-                                 str(_DENSE_HEAD_MB)))
-    nb_dense = min(nb_dense, (head_mb << 20) // max(1, 8 * n_other))
+    # byte-cap the head (see _DENSE_HEAD_MB): counts are sorted
+    # descending, so truncating keeps the heaviest — highest-payoff —
+    # entities and spills the rest to the buckets below
+    nb_dense = min(nb_dense, (_DENSE_HEAD_MB << 20) // max(1, 8 * n_other))
     nb_seg = max(int((c[nb_dense:] > _C_MAX).sum())
                  for c in counts_sorted_list)
     rows_cap = 0
@@ -533,8 +536,9 @@ class ALSPrepared:
     def kernel_rows(self) -> dict:
         """What the fused gather→Gram kernel is handed per iteration
         when the Gram mode is fused, counted over the buckets that
-        ``ops.gram.kernel_takes_width`` — the predicate ``_make_half``
-        routes by — sends to it: real (unpadded) interactions, padded
+        ``ops.gram.kernel_takes_width`` sends to it — the predicate
+        ``bucket_systems`` in ``_make_half`` routes by, the one place
+        that does: real (unpadded) interactions, padded
         slots (the layout's padding, still streamed as index and
         weights), bucket rows, and the factor-line copies the kernel
         starts. ``real ÷ padded`` is the share of the slots that hold
@@ -581,19 +585,8 @@ class ALSPrepared:
                 return (jnp.asarray(a) if device is None
                         else jax.device_put(a, device))
 
-            def side_bufs(side):
-                dense = (() if side.dense is None else
-                         (put(side.dense.w_cnt), put(side.dense.w_val),
-                          put(side.dense.counts)))
-                return (dense, tuple(
-                    tuple((put(b.other_idx), put(b.vals), put(b.mask),
-                           put(b.counts))
-                          + ((put(b.seg), put(b.seg_off))
-                             if b.seg is not None else ())
-                          for b in side.buckets)))
-
-            self._device_bufs[device] = (side_bufs(self.u_side),
-                                         side_bufs(self.i_side))
+            self._device_bufs[device] = jax.tree.map(
+                put, (self.u_side.arrays(), self.i_side.arrays()))
         return self._device_bufs[device]
 
 
@@ -686,7 +679,7 @@ def als_train_many(
 
 
 def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
-               platform=None, bf16_gather: bool = False,
+               bf16_gather: bool = False,
                precision: str = "high", gram_mode: str = "off"):
     """Build the half-step program shared by the single-device and
     sharded (shard_map) paths:
@@ -706,34 +699,34 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
     exists to let an on-device run A/B the two modes when triaging a
     numerical regression (ADVICE r3).
 
-    Per bucket, per slab (a ``lax.scan`` step): gather the (slab, C, k)
-    factor block, one batched weighted-Gram einsum (MXU), add ridge +
-    implicit term; all buckets emit their k×k systems into one solve
-    buffer and a single chunked scan solves the whole side with ONE
-    instance of the block-recursive batched Cholesky (compile-time
-    bound — see ``_SOLVE_CHUNK``). No scatter anywhere in the program.
-    Catalogs too large for the solve buffer solve inside each bucket
-    body instead (memory flat in catalog size).
+    The walk over a side is written once, in ``half``: the dense head
+    and then every bucket (``bucket_systems``) build ridged normal
+    equations and hand them to ``finish``. While the solve buffer fits
+    (``_SOLVE_BUF_MB``) ``finish`` passes ``(A, b)`` on, all parts are
+    concatenated into one buffer and a single chunked scan solves the
+    whole side with ONE instance of the block-recursive batched
+    Cholesky (compile-time bound — see ``_SOLVE_CHUNK``); catalogs too
+    large for the buffer solve in ``finish`` itself, inside each bucket
+    body (memory flat in catalog size). No scatter anywhere in the
+    program but the segmented bucket's few hundred blocks.
 
     ``pvary`` marks created constants as varying over the mesh axis
     when tracing inside ``shard_map`` (vma typing); identity otherwise.
-    ``platform`` is the platform the trace will RUN on (mesh/device
-    platform — may differ from the default backend): it routes the
-    solve to the Pallas VMEM kernel on TPU, XLA elsewhere.
 
     ``gram_mode`` selects the gather→Gram implementation (resolved by
     :func:`predictionio_tpu.ops.resolve_gram_mode` from
-    ``PIO_PALLAS_GRAM``): ``"off"`` keeps today's XLA gather + packed
-    einsum with its per-bucket slab ``lax.scan``s; ``"pallas"`` /
-    ``"interpret"`` route every bucket of width ≥ 128 through the fused
+    ``PIO_PALLAS_GRAM`` and the platform the trace will run on):
+    ``"off"`` is the XLA gather + packed einsum with its per-bucket
+    slab ``lax.scan``s; ``"pallas"`` / ``"interpret"`` route every
+    bucket of width ≥ 128 through the fused
     :func:`predictionio_tpu.ops.gather_gram` kernel — the slab scans
     flatten into ONE fat kernel dispatch per bucket, the seg merge
-    becomes one einsum + one (tiny) scatter-add, and the solve pass
-    prefers the VMEM Cholesky kernel (narrower buckets stay on XLA —
-    :func:`predictionio_tpu.ops.gram.kernel_takes_width`).
+    becomes one einsum + one (tiny) scatter-add (narrower buckets stay
+    on XLA — :func:`predictionio_tpu.ops.gram.kernel_takes_width`).
+    The solve follows the Gram mode: under ``"pallas"`` it is the VMEM
+    Cholesky kernel (the ~50-op XLA solve recursion would re-create
+    the dispatch wall the Gram fusion removes), else the XLA recursion.
     """
-    import functools
-
     import jax
     import jax.numpy as jnp
 
@@ -751,10 +744,7 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
     # profiler's trace only (the op_name of every operation of a stage
     # starts with its scope); the programs compute what they computed
     chol_solve_batched = jax.named_scope("als.solve")(functools.partial(
-        _csb, platform=platform,
-        # fat-dispatch regime: the ~50-op XLA solve recursion would
-        # re-create the dispatch wall the Gram fusion removes
-        prefer_pallas=(gram_mode == "pallas")))
+        _csb, kernel=(_solve_mode(gram_mode) == "pallas")))
 
     # reg/alpha are bound per trace by ``half`` (traced scalars shared
     # by every helper below via this cell — threading them through five
@@ -785,17 +775,16 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
         is ~1e-4 relative — f32 solve noise level, far inside the
         parity-test tolerances."""
         F = F_other[oi_s]                               # (slab, C, k)
+        wo, wb = weights(v_s, m_s)
         if bf16_gather:
             # F_other arrives pre-cast to bf16 (one pass per half
             # step); weights round to bf16 and the MXU runs a single
             # pass with f32 accumulation
-            wo, wb = weights(v_s, m_s)
             H = jnp.concatenate(
                 [(wo[..., None] * F).astype(jnp.bfloat16),
                  wb[..., None].astype(jnp.bfloat16)], axis=-1)
             return jnp.einsum("nck,ncl->nkl", F, H,
                               preferred_element_type=jnp.float32)
-        wo, wb = weights(v_s, m_s)
         H = jnp.concatenate([wo[..., None] * F, wb[..., None]], axis=-1)
         return jnp.einsum("nck,ncl->nkl", F, H,
                           precision=prec,
@@ -824,50 +813,75 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
         return ops_gram.gather_gram(F_g, oi2, wo, wb, lengths,
                                     interpret=interp)
 
-    def seg_equations(F_g, buf, nb, slab, G):
-        """Heavy bucket: entities span rows; each slab aggregates its
-        per-row partials into ≤ slab consecutive entities with one
-        (slab, slab) × (slab, k·(k+1)) matmul (slab-local one-hot, no
-        scatter), accumulated into the per-entity buffer at the slab's
-        entity offset. Buffer is over-allocated by one slab so the
-        update-slice never clamps.
+    def bucket_systems(F_g, geom, buf, G, finish):
+        """One bucket → ``finish(A_ridged, b)``, rows in bucket order
+        (a segmented bucket's ``nb`` entities, a regular bucket's
+        ``n_slabs · slab`` padded rows). The ONE place that routes a
+        bucket between the fused kernel and XLA's gather + einsum —
+        ``fused and kernel_takes_width(C)`` — and that flattens
+        ``(n_slabs, slab, C)`` to the kernel's ``(R, C)``.
 
-        Fused mode drops the slab scan: one kernel call over ALL rows,
-        one batched aggregation einsum, and one scatter-add of the
-        slab-local blocks at their entity offsets. (The no-scatter rule
-        targets ~nnz/W-row scatters — this one moves n_seg_rows ≈
-        hundreds of k×(k+1) blocks, noise next to the kernel call.)"""
-        oi, vv, mm, cnt, seg, seg_off = buf
-        n_slabs, _, C = oi.shape
-        if fused and ops_gram.kernel_takes_width(C):
-            R = n_slabs * slab
+        Through the kernel a bucket is one dispatch over all its rows
+        (the kernel streams (RB, C) row blocks through VMEM itself) and
+        ``finish`` runs once; through XLA it is a ``lax.scan`` over the
+        slabs with ``finish`` inside the body, so a caller that solves
+        in ``finish`` never holds a narrow bucket's Grams for all rows.
+
+        Segmented bucket: entities span rows; each slab aggregates its
+        per-row partials into ≤ slab consecutive entities with one
+        (slab, slab) × (slab, k·(k+1)) matmul (slab-local one-hot),
+        added into the per-entity buffer at the slab's entity offset
+        (over-allocated by one slab so the update never clamps) —
+        through the kernel, one batched einsum and ONE scatter-add of
+        the slab-local blocks (n_seg_rows ≈ hundreds of k×(k+1)
+        blocks; the no-scatter rule is about ~nnz/W-row scatters)."""
+        C, nb, slab, n_slabs, is_seg = geom
+        oi, vv, mm, cnt = buf[:4]
+        R = n_slabs * slab
+        kernel = fused and ops_gram.kernel_takes_width(C)
+        if kernel:
             A_r, b_r = fused_grams(F_g, oi.reshape(R, C),
                                    vv.reshape(R, C), mm.reshape(R, C))
-            Ab_r = jnp.concatenate([A_r, b_r[:, :, None]], axis=-1)
-            Ab_l = jnp.einsum("nre,nrkm->nekm", seg,
-                              Ab_r.reshape(n_slabs, slab, k, k + 1),
-                              precision=prec,
-                              preferred_element_type=jnp.float32)
-            ids = seg_off[:, None] + jnp.arange(slab, dtype=jnp.int32)
-            Ab_e = pv(jnp.zeros((nb + slab, k, k + 1),
-                                jnp.float32)).at[ids].add(Ab_l)
-            return ridge(Ab_e[:nb, :, :k], cnt, G), Ab_e[:nb, :, k]
+        if is_seg:
+            seg, seg_off = buf[4:]
+            if kernel:
+                Ab_r = jnp.concatenate([A_r, b_r[:, :, None]], axis=-1)
+                Ab_l = jnp.einsum("nre,nrkm->nekm", seg,
+                                  Ab_r.reshape(n_slabs, slab, k, k + 1),
+                                  precision=prec,
+                                  preferred_element_type=jnp.float32)
+                ids = seg_off[:, None] + jnp.arange(slab, dtype=jnp.int32)
+                Ab_e = pv(jnp.zeros((nb + slab, k, k + 1),
+                                    jnp.float32)).at[ids].add(Ab_l)
+            else:
+                def seg_body(Ab_e, chunk):
+                    oi_s, v_s, m_s, seg_s, off_s = chunk
+                    Ab_r = row_grams(F_g, oi_s, v_s, m_s)  # (slab, k, k+1)
+                    Ab_l = jnp.einsum("ne,nkm->ekm", seg_s, Ab_r,
+                                      precision=prec,
+                                      preferred_element_type=jnp.float32)
+                    blk = jax.lax.dynamic_slice(Ab_e, (off_s, 0, 0),
+                                                (slab, k, k + 1))
+                    Ab_e = jax.lax.dynamic_update_slice(Ab_e, blk + Ab_l,
+                                                        (off_s, 0, 0))
+                    return Ab_e, None
 
-        def seg_body(Ab_e, chunk):
-            oi_s, v_s, m_s, seg_s, off_s = chunk
-            Ab_r = row_grams(F_g, oi_s, v_s, m_s)   # (slab, k, k+1)
-            Ab_l = jnp.einsum("ne,nkm->ekm", seg_s, Ab_r,
-                              precision=prec,
-                              preferred_element_type=jnp.float32)
-            blk = jax.lax.dynamic_slice(Ab_e, (off_s, 0, 0),
-                                        (slab, k, k + 1))
-            Ab_e = jax.lax.dynamic_update_slice(Ab_e, blk + Ab_l,
-                                                (off_s, 0, 0))
-            return Ab_e, None
+                init = pv(jnp.zeros((nb + slab, k, k + 1), jnp.float32))
+                Ab_e, _ = jax.lax.scan(seg_body, init,
+                                       (oi, vv, mm, seg, seg_off))
+            return finish(ridge(Ab_e[:nb, :, :k], cnt, G), Ab_e[:nb, :, k])
+        if kernel:
+            return finish(ridge(A_r, cnt.reshape(R), G), b_r)
 
-        init = pv(jnp.zeros((nb + slab, k, k + 1), jnp.float32))
-        Ab_e, _ = jax.lax.scan(seg_body, init, (oi, vv, mm, seg, seg_off))
-        return ridge(Ab_e[:nb, :, :k], cnt, G), Ab_e[:nb, :, k]
+        def body(_, chunk):
+            oi_s, v_s, m_s, cnt_s = chunk
+            Ab = row_grams(F_g, oi_s, v_s, m_s)
+            return None, finish(ridge(Ab[..., :k], cnt_s, G), Ab[..., k])
+
+        if n_slabs == 1:
+            return body(None, (oi[0], vv[0], mm[0], cnt[0]))[1]
+        _, out = jax.lax.scan(body, None, (oi, vv, mm, cnt))
+        return jax.tree.map(lambda a: a.reshape((R,) + a.shape[2:]), out)
 
     @jax.named_scope("als.dense_head")
     def dense_equations(F_other, dbuf, G):
@@ -895,80 +909,31 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
                        preferred_element_type=jnp.float32)
         return ridge(A, cnt, G), b
 
-    def half_materialized(F_other, F_g, dense_buf, bufs, geometry, G,
-                          spans, chunk, n_chunks):
-        """Two-phase half-step: the dense head and every bucket emit
-        (ridged) normal equations, concatenated into one solve buffer a
-        single chunked scan then solves — ONE Cholesky instance in the
-        program. Emitting via scan ``ys`` (not a carried buffer updated
-        with dynamic_update_slice) matters: the carry pattern measured
-        +116 ms per ML-20M half-step in buffer copies."""
+    def solve_buffer(systems, chunk, n_chunks):
+        """The materialised finish: every part's ridged ``(A, b)``
+        concatenated into one solve buffer that a single chunked scan
+        solves — ONE Cholesky instance in the program. The parts come
+        out of the bucket scans as ``ys`` (not a carried buffer updated
+        with dynamic_update_slice): the carry pattern measured +116 ms
+        per ML-20M half-step in buffer copies."""
         N_pad = n_chunks * chunk
-        n_self, dense_geom, bucket_geoms = geometry
-        A_parts, b_parts = [], []
-        if dense_geom is not None:
-            A_d, b_d = dense_equations(F_other, dense_buf, G)
-            A_parts.append(A_d)
-            b_parts.append(b_d)
-        F_other = F_g  # buckets below gather from the cast copy
-        for (C, nb, slab, n_slabs, is_seg), buf in zip(bucket_geoms, bufs):
-            if is_seg:
-                A_e, b_e = seg_equations(F_other, buf, nb, slab, G)
-                A_parts.append(A_e)
-                b_parts.append(b_e)
-            elif fused and ops_gram.kernel_takes_width(C):
-                # the whole bucket — every slab — as ONE fused kernel
-                # dispatch (no slab scan; the kernel streams (RB, C)
-                # row blocks through VMEM itself)
-                oi, vv, mm, cnt = buf
-                R = n_slabs * slab
-                A, b = fused_grams(F_other, oi.reshape(R, C),
-                                   vv.reshape(R, C), mm.reshape(R, C))
-                A_parts.append(ridge(A, cnt.reshape(R), G))
-                b_parts.append(b)
-            else:
-                oi, vv, mm, cnt = buf
-
-                def body(_, chunk):
-                    oi_s, v_s, m_s, cnt_s = chunk
-                    Ab = row_grams(F_other, oi_s, v_s, m_s)
-                    return None, (ridge(Ab[..., :k], cnt_s, G), Ab[..., k])
-
-                if n_slabs == 1:
-                    A, b = body(None, (oi[0], vv[0], mm[0], cnt[0]))[1]
-                else:
-                    _, (A, b) = jax.lax.scan(body, None, (oi, vv, mm, cnt))
-                    A = A.reshape(-1, k, k)
-                    b = b.reshape(-1, k)
-                A_parts.append(A)
-                b_parts.append(b)
-        if sum(spans) < N_pad:  # tail pad: identity systems, x = 0
-            A_parts.append(pv(jnp.zeros((N_pad - sum(spans), k, k),
+        A_parts = [A for A, _ in systems]
+        b_parts = [b for _, b in systems]
+        n_rows = sum(b.shape[0] for b in b_parts)
+        if n_rows < N_pad:  # tail pad: identity systems, x = 0
+            A_parts.append(pv(jnp.zeros((N_pad - n_rows, k, k),
                                         jnp.float32) + eye))
-            b_parts.append(pv(jnp.zeros((N_pad - sum(spans), k),
+            b_parts.append(pv(jnp.zeros((N_pad - n_rows, k),
                                         jnp.float32)))
         A_all = jnp.concatenate(A_parts) if len(A_parts) > 1 else A_parts[0]
         b_all = jnp.concatenate(b_parts) if len(b_parts) > 1 else b_parts[0]
         if n_chunks == 1:
-            x_all = chol_solve_batched(A_all, b_all)
-        else:
-            _, xc = jax.lax.scan(
-                lambda _, ab: (None, chol_solve_batched(*ab)), None,
-                (A_all.reshape(n_chunks, chunk, k, k),
-                 b_all.reshape(n_chunks, chunk, k)))
-            x_all = xc.reshape(N_pad, k)
-        outs, off, total = [], 0, 0
-        nbs = ([dense_geom[0]] if dense_geom is not None else []) + \
-            [nb for (C, nb, slab, n_slabs, is_seg) in bucket_geoms]
-        for nb, span in zip(nbs, spans):
-            outs.append(x_all[off:off + nb])
-            off += span
-            total += nb
-        if total < n_self:  # zero-rating tail entities → zero factors
-            outs.append(pv(jnp.zeros((n_self - total, k), jnp.float32)))
-        out = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
-        # forced (merged) boundaries can exceed n_self; extras are zeros
-        return out[:n_self] if total > n_self else out
+            return chol_solve_batched(A_all, b_all)
+        _, xc = jax.lax.scan(
+            lambda _, ab: (None, chol_solve_batched(*ab)), None,
+            (A_all.reshape(n_chunks, chunk, k, k),
+             b_all.reshape(n_chunks, chunk, k)))
+        return xc.reshape(N_pad, k)
 
     def half(F_other, bufs_side, geometry, reg, alpha):
         # bind the traced scalars for every helper above; pv marks them
@@ -987,71 +952,61 @@ def _make_half(k: int, implicit: bool, weighted_reg: bool, pvary=None,
                 G = jnp.einsum("nk,nl->kl", F_other, F_other,
                                precision=prec,
                                preferred_element_type=jnp.float32)
-        # spans in the solve buffer: the dense head and seg buckets
-        # emit nb exact rows once, regular buckets their padded slabs
-        spans = ([dense_geom[0]] if dense_geom is not None else []) + \
-            [nb if is_seg else n_slabs * slab
+        # the parts of a side, in output order: [dense head] + buckets,
+        # each (real entities, rows it hands on): the dense head and
+        # seg buckets emit nb exact rows, regular buckets their padded
+        # slabs
+        sizes = ([(dense_geom[0], dense_geom[0])]
+                 if dense_geom is not None else []) + \
+            [(nb, nb if is_seg else n_slabs * slab)
              for (C, nb, slab, n_slabs, is_seg) in bucket_geoms]
+        nbs = [nb for nb, _ in sizes]
+        spans = [span for _, span in sizes]
         # solve chunk shrinks for small sides (sharded per-device
         # blocks) so the floor isn't thousands of padded identity solves
         chunk = min(_SOLVE_CHUNK, max(256, -(-sum(spans) // 256) * 256))
         n_chunks = max(1, -(-sum(spans) // chunk))
-        if n_chunks * chunk * k * k * 4 <= _SOLVE_BUF_MB << 20:
-            return half_materialized(F_other, F_g, dense_buf, bufs,
-                                     geometry, G, spans, chunk, n_chunks)
-        # huge catalog: solve inside each bucket body (memory flat in
-        # catalog size; compiles one Cholesky per bucket)
-        outs = []
-        total = 0
+        # two ways of finishing a part: hand its (A, b) on to the one
+        # solve buffer while that fits, else solve it where it is built
+        # (huge catalog: memory flat in catalog size; compiles one
+        # Cholesky per bucket)
+        materialised = n_chunks * chunk * k * k * 4 <= _SOLVE_BUF_MB << 20
+        finish = ((lambda A, b: (A, b)) if materialised
+                  else chol_solve_batched)
+        parts = []
         if dense_geom is not None:
-            A_d, b_d = dense_equations(F_other, dense_buf, G)
-            outs.append(chol_solve_batched(A_d, b_d))
-            total += dense_geom[0]
-        for (C, nb, slab, n_slabs, is_seg), buf in zip(bucket_geoms, bufs):
-            if is_seg:
-                A_e, b_e = seg_equations(F_g, buf, nb, slab, G)
-                x = chol_solve_batched(A_e, b_e)
-            elif fused and ops_gram.kernel_takes_width(C):
-                oi, vv, mm, cnt = buf
-                R = n_slabs * slab
-                A, b = fused_grams(F_g, oi.reshape(R, C),
-                                   vv.reshape(R, C), mm.reshape(R, C))
-                x = chol_solve_batched(ridge(A, cnt.reshape(R), G),
-                                       b)[:nb]
-            else:
-                oi, vv, mm, cnt = buf
-
-                def body(_, chunk):
-                    oi_s, v_s, m_s, cnt_s = chunk
-                    Ab = row_grams(F_g, oi_s, v_s, m_s)
-                    return None, chol_solve_batched(
-                        ridge(Ab[..., :k], cnt_s, G), Ab[..., k])
-
-                if n_slabs == 1:
-                    x = body(None, (oi[0], vv[0], mm[0], cnt[0]))[1]
-                else:
-                    _, xs = jax.lax.scan(body, None, (oi, vv, mm, cnt))
-                    x = xs.reshape(-1, k)
-                x = x[:nb]
-            outs.append(x)
-            total += nb
+            parts.append(finish(*dense_equations(F_other, dense_buf, G)))
+        for geom, buf in zip(bucket_geoms, bufs):
+            parts.append(bucket_systems(F_g, geom, buf, G, finish))
+        offs = [0] * len(parts)
+        if materialised:  # every part's solution is a window of x_all
+            parts = [solve_buffer(parts, chunk, n_chunks)] * len(parts)
+            offs = [sum(spans[:i]) for i in range(len(spans))]
+        outs = [x[off:off + nb] for x, off, nb in zip(parts, offs, nbs)]
+        total = sum(nbs)
         if total < n_self:  # zero-rating tail entities → zero factors
             outs.append(pv(jnp.zeros((n_self - total, k), jnp.float32)))
         out = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
+        # forced (merged) boundaries can exceed n_self; extras are zeros
         return out[:n_self] if total > n_self else out
 
     return half
 
 
+def _solve_mode(gram_mode: str) -> str:
+    """The solve follows the Gram mode: the VMEM Cholesky kernel
+    (``"pallas"``) exactly when the Gram is the compiled fused kernel,
+    else the XLA recursion (``"xla"``) — the interpreter mode included,
+    which exists to test the Gram routing on a CPU."""
+    return "pallas" if gram_mode == "pallas" else "xla"
+
+
 def log_train_modes(platform: str, gram_mode: str, n_devices: int) -> str:
     """Say which Gram and which solve implementation this train runs —
-    the selection is by rule (``ops.resolve_gram_mode`` /
-    ``cholesky.resolve_solve_mode``), so it can be stated up front.
+    the selection is by rule (``ops.resolve_gram_mode``, and the solve
+    follows it: :func:`_solve_mode`), so it can be stated up front.
     Returns the solve mode."""
-    from predictionio_tpu.ops.cholesky import resolve_solve_mode
-
-    solve = resolve_solve_mode(platform,
-                               prefer_pallas=(gram_mode == "pallas"))
+    solve = _solve_mode(gram_mode)
     log.info("ALS train: platform=%s devices=%d gram=%s solve=%s",
              platform, n_devices, gram_mode, solve)
     return solve
@@ -1078,13 +1033,16 @@ def _compiled_bucketed(geom_u, geom_i, n_users: int, n_items: int,
     ``train(u_bufs, i_bufs, V0p, reg, alpha)``, so a `pio eval` grid
     over regularization/alpha shares ONE executable; candidates
     recompile only when rank/iterations (or the implicit/weighted_reg
-    program structure) change."""
+    program structure) change. ``platform`` is not read — the Gram
+    mode, resolved for the platform by the caller, decides everything
+    the platform did; it keeps its place in the positional list that
+    ``benchmark/compile_check.py`` calls (ROADMAP D1b)."""
     import jax
     import jax.numpy as jnp
 
     k = rank
     half = _make_half(k, bool(implicit), bool(weighted_reg),
-                      platform=platform, bf16_gather=bf16_gather,
+                      bf16_gather=bf16_gather,
                       precision=precision, gram_mode=gram_mode)
 
     def train(u_bufs, i_bufs, V0p, reg, alpha):
@@ -1253,16 +1211,10 @@ def als_train_prepared(prep: ALSPrepared, p: ALSParams, device=None,
     return packed[:prep.n_users], packed[prep.n_users:]
 
 
-def _als_train_single(coo: RatingsCOO, p: ALSParams,
-                      device=None) -> Tuple[np.ndarray, np.ndarray]:
-    return als_train_prepared(als_prepare(coo), p, device=device)
-
-
 @functools.lru_cache(maxsize=8)
 def als_train_scored(geom_u, geom_i, n_users: int, n_items: int,
                      rank: int, iterations: int,
                      implicit: bool, weighted_reg: bool,
-                     platform: Optional[str] = None,
                      bf16_gather: bool = False,
                      precision: str = "high",
                      gram_mode: str = "off"):
@@ -1282,7 +1234,7 @@ def als_train_scored(geom_u, geom_i, n_users: int, n_items: int,
 
     k = rank
     half = _make_half(k, bool(implicit), bool(weighted_reg),
-                      platform=platform, bf16_gather=bf16_gather,
+                      bf16_gather=bf16_gather,
                       precision=precision, gram_mode=gram_mode)
 
     def one(hyper, u_bufs, i_bufs, V0p, uq, iq, rq, valid):
@@ -1338,7 +1290,7 @@ def als_sweep_program(prep: ALSPrepared, p0: ALSParams,
         return als_train_scored(
             prep.u_side.geometry, prep.i_side.geometry,
             prep.n_users, prep.n_items, int(p0.rank), int(p0.iterations),
-            bool(p0.implicit), bool(p0.weighted_reg), platform,
+            bool(p0.implicit), bool(p0.weighted_reg),
             bool(p0.bf16_gather), precision, gram_mode)
 
     return geometry, build, data

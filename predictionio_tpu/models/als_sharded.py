@@ -93,24 +93,11 @@ class ALSShardedPrepared:
     def _stacked(self, sides: List[_BucketSide]):
         """Per-bucket (and dense-head) arrays stacked over the leading
         device dim, in the (dense, buckets) structure ``_make_half``
-        consumes."""
-        dense = ()
-        if sides[0].dense is not None:
-            dense = (np.stack([s.dense.w_cnt for s in sides]),
-                     np.stack([s.dense.w_val for s in sides]),
-                     np.stack([s.dense.counts for s in sides]))
-        out = []
-        for j in range(len(sides[0].buckets)):
-            bs = [s.buckets[j] for s in sides]
-            arrs = [np.stack([b.other_idx for b in bs]),
-                    np.stack([b.vals for b in bs]),
-                    np.stack([b.mask for b in bs]),
-                    np.stack([b.counts for b in bs])]
-            if bs[0].seg is not None:
-                arrs += [np.stack([b.seg for b in bs]),
-                         np.stack([b.seg_off for b in bs])]
-            out.append(tuple(arrs))
-        return (dense, tuple(out))
+        consumes (every device has the same one: forced bounds)."""
+        import jax
+
+        return jax.tree.map(lambda *per_device: np.stack(per_device),
+                            *(s.arrays() for s in sides))
 
     def device_buffers(self, mesh):
         """Stacked layouts placed on the mesh, cached per mesh — a
@@ -222,7 +209,6 @@ def _compiled_sharded(mesh, geom_u, geom_i, rank: int, iterations: int,  # varia
     block_u = geom_u[0]
     half = _make_half(k, implicit, weighted_reg,
                       pvary=lambda x: pvary(x, "data"),
-                      platform=mesh.devices.flat[0].platform,
                       bf16_gather=bf16_gather, precision=precision,
                       gram_mode=gram_mode)
 

@@ -14,8 +14,7 @@ by rule, never by trying the kernel and catching its failure.
 """
 
 from predictionio_tpu.ops.gram import (gather_gram, gather_gram_xla,
-                                       resolve_gram_mode, rows_gram,
-                                       rows_gram_xla)
+                                       resolve_gram_mode)
 from predictionio_tpu.ops.segment import segment_count, segment_mean, segment_sum
 from predictionio_tpu.ops.topk import (adc_scores, adc_shortlist,
                                        merge_shortlists, rerank_partial,
@@ -32,12 +31,7 @@ def use_pallas(platform=None) -> bool:
     must pass it, because ``jax.default_backend()`` can differ from the
     execution platform (a CPU mesh on a host that also has a TPU, or a
     compile for a described, unattached chip).
-    ``PIO_NO_PALLAS=1`` forces the XLA fallbacks (A/B benching, triage).
     """
-    import os
-
-    if os.environ.get("PIO_NO_PALLAS"):
-        return False
     if platform is None:
         import jax
 
@@ -48,7 +42,6 @@ def use_pallas(platform=None) -> bool:
 __all__ = [
     "adc_scores", "adc_shortlist", "gather_gram", "gather_gram_xla",
     "merge_shortlists", "rerank_partial", "rerank_topk",
-    "resolve_gram_mode",
-    "rows_gram", "rows_gram_xla", "score_topk", "score_topk_xla",
+    "resolve_gram_mode", "score_topk", "score_topk_xla",
     "segment_sum", "segment_count", "segment_mean", "use_pallas",
 ]

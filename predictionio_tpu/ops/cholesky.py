@@ -143,65 +143,29 @@ def _chol_solve(A, b):
     return x[..., :k]
 
 
-def resolve_solve_mode(platform=None, prefer_pallas: bool = False) -> str:
-    """``"pallas"`` or ``"xla"`` for a solve traced for ``platform`` —
-    the platform and ``PIO_PALLAS_SOLVE``, nothing else: unset is the
-    XLA recursion (``auto`` when ``prefer_pallas``), ``1`` and ``auto``
-    are the VMEM kernel on a TPU. Nothing is tried and caught: on a
-    TPU a selected kernel compiles or the caller fails with the
-    compiler's message."""
-    import os
-
-    from predictionio_tpu import ops
-
-    flag = os.environ.get("PIO_PALLAS_SOLVE", "")
-    if flag == "" and prefer_pallas:
-        flag = "auto"
-    if flag in ("1", "auto") and ops.use_pallas(platform):
-        return "pallas"
-    return "xla"
-
-
-def chol_solve_batched(A, b, platform=None, prefer_pallas=False):
+def chol_solve_batched(A, b, kernel: bool = False):
     """Solve the batched SPD systems ``A x = b``.
 
     A: (..., k, k) SPD (symmetric positive definite — ALS adds a ridge),
     b: (..., k) → x: (..., k). Any k ≥ 1.
 
-    The default is the XLA block-recursive path (internally padded to
-    a power of two with an identity block, which factors to itself and
-    leaves the k×k solve untouched). ``PIO_PALLAS_SOLVE=1`` (or
-    ``auto``) selects the Pallas VMEM-resident kernel
-    (:func:`chol_solve_pallas`) on TPU for (N ≥ 256, k, k) batches —
-    see :func:`resolve_solve_mode`.
-
-    ``prefer_pallas=True`` flips the UNSET-flag default to ``auto``:
-    callers already committed to the fat-dispatch regime (the fused
-    gather→Gram ALS mode, ``PIO_PALLAS_GRAM``) also want the ~50-op
-    XLA solve recursion collapsed to one kernel per chunk — otherwise
-    the solve pass alone re-creates the dispatch wall the Gram fusion
-    just removed. An explicit ``PIO_PALLAS_SOLVE`` setting still wins.
+    The XLA block-recursive path (internally padded to a power of two
+    with an identity block, which factors to itself and leaves the k×k
+    solve untouched), or with ``kernel`` the Pallas VMEM-resident
+    kernel (:func:`chol_solve_pallas`) for the batches that have its
+    shape, (N ≥ 256, k, k). ``kernel`` is the caller's rule, not a
+    preference: ``models/als.py`` sets it exactly when the Gram is the
+    compiled fused kernel — a caller already in the fat-dispatch regime
+    wants the ~50-op XLA solve recursion collapsed to one kernel per
+    chunk, or the solve pass alone re-creates the dispatch wall the
+    Gram fusion removed. Nothing is tried and caught: on a TPU a
+    selected kernel compiles or the caller fails with the compiler's
+    message.
     """
     A = jnp.asarray(A, jnp.float32)
     b = jnp.asarray(b, jnp.float32)
-    import os
-
-    kernel_shape = A.ndim == 3 and A.shape[0] >= 256
-    if kernel_shape and resolve_solve_mode(platform,
-                                           prefer_pallas) == "pallas":
+    if kernel and A.ndim == 3 and A.shape[0] >= 256:
         return chol_solve_pallas(A, b)
-    if os.environ.get("PIO_PALLAS_SOLVE", "") == "1":
-        # The flag promises "force the kernel" — an A/B run that
-        # silently measured the XLA path instead would be dishonest.
-        import warnings
-
-        reason = (f"batch rank {A.ndim} != 3" if A.ndim != 3
-                  else f"batch {A.shape[0]} < 256" if A.shape[0] < 256
-                  else f"platform {platform or 'default'} is not TPU")
-        warnings.warn(
-            f"PIO_PALLAS_SOLVE=1 set but the Pallas solve kernel cannot "
-            f"dispatch ({reason}); falling back to the XLA path",
-            RuntimeWarning, stacklevel=2)
     return _chol_solve(A, b)
 
 

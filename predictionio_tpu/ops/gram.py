@@ -1,24 +1,19 @@
-"""Batched weighted Gram accumulation — the ALS inner op, as Pallas kernels.
+"""Batched weighted Gram accumulation — the ALS inner op, as a Pallas kernel.
 
-Two kernels live here:
-
-- :func:`rows_gram` — the original fused weighted Gram over a
-  PRE-GATHERED ``(R, W, k)`` factor block (kept as the Pallas reference
-  implementation; exercised by tests/test_ops).
-- :func:`gather_gram` — the fused **gather→Gram** kernel: the gather
-  itself moves inside the kernel. Per grid program, ``F_other`` rows
-  are DMA'd tile-by-tile straight from HBM into a VMEM tile using the
-  ``other_idx`` row block (prefetched into SMEM), the weighted normal
-  equations accumulate in a VMEM register block, and only the
-  ``(R, k, k)`` / ``(R, k)`` results are written back. The gathered
-  ``(R, C, k)`` block never materializes in HBM and the weighting never
-  round-trips. What bounds it (PERF_LEDGER.jsonl, PR 24: nine bucket
-  shapes, widths 128 to 8192, two configurations) is neither the MXU
-  nor HBM but the scalar core starting and retiring one 512-byte line
-  copy at a time, a constant ~32 ns each — so the kernel is given
-  every row's REAL length and starts no copy for a padded slot (PR 25).
-  ``models/als.py _make_half`` selects it via ``PIO_PALLAS_GRAM`` (see
-  :func:`resolve_gram_mode`).
+:func:`gather_gram` is the fused **gather→Gram** kernel
+(:func:`gather_gram_xla` its XLA twin): the gather itself runs inside
+the kernel. Per grid program, ``F_other`` rows are DMA'd tile-by-tile
+straight from HBM into a VMEM tile using the ``other_idx`` row block
+(prefetched into SMEM), the weighted normal equations accumulate in a
+VMEM register block, and only the ``(R, k, k)`` / ``(R, k)`` results
+are written back. The gathered ``(R, C, k)`` block never materializes
+in HBM and the weighting never round-trips. What bounds it
+(PERF_LEDGER.jsonl, PR 24: nine bucket shapes, widths 128 to 8192, two
+configurations) is neither the MXU nor HBM but the scalar core starting
+and retiring one 512-byte line copy at a time, a constant ~32 ns each —
+so the kernel is given every row's REAL length and starts no copy for a
+padded slot (PR 25). ``models/als.py _make_half`` selects it via
+``PIO_PALLAS_GRAM`` (see :func:`resolve_gram_mode`).
 
 Per padded rating row r:
 
@@ -48,81 +43,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def rows_gram_xla(F_g, w_outer, w_b):
-    """XLA fallback: (R,W,k),(R,W),(R,W) → A (R,k,k), b (R,k)."""
-    A = jnp.einsum("rw,rwk,rwl->rkl", w_outer, F_g, F_g,
-                   preferred_element_type=jnp.float32)
-    b = jnp.einsum("rw,rwk->rk", w_b, F_g,
-                   preferred_element_type=jnp.float32)
-    return A, b
-
-
-def _gram_kernel(Fg_ref, wo_ref, wb_ref, A_ref, b_ref, *, block_rows: int):
-    # Mosaic has no batched dot_general — unroll the block into per-row
-    # 2D (k,W)x(W,k) MXU dots. block_rows is small and static.
-    for r in range(block_rows):
-        F = Fg_ref[r]                      # (W, k)
-        Fw = F * wo_ref[r][:, None]        # VPU; fused, never hits HBM
-        A_ref[r] = jax.lax.dot_general(
-            Fw, F, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)  # f32 normal equations
-            # (+13% kernel time over bf16, err 6e-5 vs 3e-1; ALS solves
-            # are sensitive to Gram precision)
-        b_ref[r] = jnp.sum(F * wb_ref[r][:, None], axis=0)
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def rows_gram(F_g, w_outer, w_b, *, block_rows: int = 8,
-              interpret: bool = False):
-    """Pallas fused weighted-Gram: same contract as :func:`rows_gram_xla`.
-
-    ``interpret=True`` runs the Mosaic interpreter (CPU tests).
-    """
-    R, W, k = F_g.shape
-    if R % block_rows != 0:
-        block_rows = 1 if R == 0 else next(
-            b for b in (8, 4, 2, 1) if R % b == 0)
-    grid = (R // block_rows,)
-    return pl.pallas_call(
-        functools.partial(_gram_kernel, block_rows=block_rows),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, W, k), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_rows, W), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_rows, W), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=(
-            pl.BlockSpec((block_rows, k, k), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_rows, k), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((R, k, k), jnp.float32),
-            jax.ShapeDtypeStruct((R, k), jnp.float32),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * R * W * k * (k + 1),
-            bytes_accessed=4 * (R * W * k + 2 * R * W + R * k * k + R * k),
-            transcendentals=0,
-        ),
-        interpret=interpret,
-    )(F_g, w_outer, w_b)
-
-
 # -- fused gather→Gram ---------------------------------------------------------
 #
-# The XLA path above still pays for the gather as a SEPARATE HLO: the
-# (R, C, k) gathered block round-trips through HBM between the gather
-# and the Gram einsum. This kernel moves the gather inside: the index
-# block is DMA'd into SMEM up front (the scalar core needs the row ids
-# to program the data DMAs), factor rows stream HBM→VMEM in T-row tiles
-# with per-row async copies, and the weighted normal equations
-# accumulate in VMEM.
+# The XLA path (``gather_gram_xla``; ``row_grams`` in models/als.py)
+# pays for the gather as a SEPARATE HLO: the (R, C, k) gathered block
+# round-trips through HBM between the gather and the Gram einsum. This
+# kernel moves the gather inside: the index block is DMA'd into SMEM up
+# front (the scalar core needs the row ids to program the data DMAs),
+# factor rows stream HBM→VMEM in T-row tiles with per-row async copies,
+# and the weighted normal equations accumulate in VMEM.
 #
 # What the chip's compiler accepts (Mosaic, v5e): a DMA slice must be a
 # whole number of (1, 128) f32 lane tiles, so a ``1 × k`` copy with
@@ -207,8 +136,8 @@ def _gather_gram_kernel(idx_hbm, len_ref, idx_ref, wo_ref, wb_ref, F_hbm,
             F = jnp.where(keep, f_tile[...], 0.0)
             wo = wo_ref[r, pl.ds(t * T, T)]
             wb = wb_ref[r, pl.ds(t * T, T)]
-            # f32 normal equations (see rows_gram: bf16 Gram error ~3e-1
-            # vs 6e-5 and the Cholesky solve amplifies it)
+            # f32 normal equations (+13% kernel time over bf16, Gram
+            # error 6e-5 vs 3e-1, and the Cholesky solve amplifies it)
             accA[...] += jax.lax.dot_general(
                 F * wo[:, None], F, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -260,7 +189,7 @@ def _line_width(k: int):
     return kp, _LANES // kp
 
 
-def gather_gram(F_other, idx, wo, wb, lengths=None, *,
+def gather_gram(F_other, idx, wo, wb, lengths, *,
                 interpret: bool = False):
     """Fused gather→weighted-Gram: ONE Pallas kernel computing
 
@@ -271,7 +200,7 @@ def gather_gram(F_other, idx, wo, wb, lengths=None, *,
     ``lengths`` (R,) int32 says how many LEADING slots of each row hold
     an interaction: only those are fetched and summed (the rest must
     carry zero weight — ``models/als.py _bucket_side`` pads rows at
-    their end). Without it every slot of every row is fetched.
+    their end).
     ``F_other`` may be f32 or bf16 (bf16 rows are upcast before the
     call — see the block comment above). ``interpret=True`` runs the
     Mosaic interpreter (CPU tests).
@@ -281,8 +210,6 @@ def gather_gram(F_other, idx, wo, wb, lengths=None, *,
     if R == 0:
         return (jnp.zeros((0, k, k), jnp.float32),
                 jnp.zeros((0, k), jnp.float32))
-    if lengths is None:
-        lengths = jnp.full((R,), C, jnp.int32)
     T = min(C, _GATHER_TILE)
     while C % T:  # ladder widths always divide; guard odd test shapes
         T -= 1
@@ -366,29 +293,18 @@ def resolve_gram_mode(platform: Optional[str] = None) -> str:
     - ``"off"`` — the XLA gather + packed einsum path.
 
     The rule is the platform and the flag, nothing else: ``auto``
-    (default) is the kernel on a TPU and XLA elsewhere; ``0`` forces
-    XLA everywhere; ``1`` forces the kernel (off-TPU it cannot
-    dispatch: warns and resolves to XLA); ``interpret`` is the tests'
+    (default, and any other spelling) is the kernel on a TPU and XLA
+    elsewhere; ``0`` forces XLA everywhere; ``interpret`` is the tests'
     escape hatch. Nothing is tried and caught here — on a TPU a
     selected kernel compiles, or the train fails with the compiler's
     message (tests/test_chip_compile.py holds the kernel to the chip's
     compiler at every ladder width).
     """
     flag = os.environ.get("PIO_PALLAS_GRAM", "auto").strip().lower()
-    if flag in ("0", "off"):
+    if flag == "0":
         return "off"
     if flag == "interpret":
         return "interpret"
     from predictionio_tpu import ops
 
-    if ops.use_pallas(platform):
-        return "pallas"
-    if flag == "1":
-        import warnings
-
-        warnings.warn(
-            f"PIO_PALLAS_GRAM=1 set but the fused gather→Gram kernel "
-            f"cannot dispatch (platform {platform or 'default'} is not "
-            f"TPU); falling back to the XLA path",
-            RuntimeWarning, stacklevel=2)
-    return "off"
+    return "pallas" if ops.use_pallas(platform) else "off"

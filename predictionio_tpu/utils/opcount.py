@@ -19,12 +19,11 @@ would run, expanding control flow the way XLA does:
 The count is an upper-bound proxy (XLA fusion merges some elementwise
 neighbors), but it is stable, cheap, and moves in lockstep with the
 dispatch wall: `bench.py` emits it next to ``mfu_device`` and
-`profile_als.py --opcount` guards the ≥10× collapse without hardware.
+`tests/test_als.py::TestFusedGram::test_dispatch_collapse_ratio` guards
+the ≥10× collapse without hardware.
 """
 
 from __future__ import annotations
-
-from typing import Optional
 
 # primitives that recurse into exactly one inner jaxpr
 _CALL_PRIMS = ("pjit", "closed_call", "core_call", "xla_call", "remat",
@@ -95,23 +94,17 @@ def _struct_tree(tree):
 
 
 def _host_side_bufs(side):
-    """Mirror of ``ALSPrepared.device_buffers``'s per-side structure,
-    built from the HOST numpy arrays (nothing touches a device)."""
-    dense = (() if side.dense is None else
-             (side.dense.w_cnt, side.dense.w_val, side.dense.counts))
-    return (dense, tuple(
-        tuple((b.other_idx, b.vals, b.mask, b.counts)
-              + ((b.seg, b.seg_off) if b.seg is not None else ()))
-        for b in side.buckets))
+    """``side.arrays()``: the structure ``ALSPrepared.device_buffers``
+    uploads, as HOST numpy arrays (nothing touches a device). Kept
+    under this name for ``benchmark/compile_check.py`` (ROADMAP D9)."""
+    return side.arrays()
 
 
-def als_iteration_ops(prep, params, gram_mode: str = "off",
-                      platform: Optional[str] = "tpu") -> int:
+def als_iteration_ops(prep, params, gram_mode: str = "off") -> int:
     """Device ops for ONE ALS iteration (two half-steps) at ``prep``'s
-    geometry under ``gram_mode`` — traced abstractly for ``platform``
-    (default "tpu": count what the CHIP would dispatch, even from a
-    chip-free host). The fused modes prefer the Pallas solve by rule
-    (``cholesky.resolve_solve_mode``), so the trace holds it too.
+    geometry under ``gram_mode`` — traced abstractly, so ``"pallas"``
+    counts what the CHIP would dispatch, even from a chip-free host
+    (the solve is the Pallas kernel too: ``als._solve_mode``).
     """
     import jax
     import jax.numpy as jnp
@@ -121,10 +114,8 @@ def als_iteration_ops(prep, params, gram_mode: str = "off",
     p = params
     half = als_mod._make_half(
         p.rank, bool(p.implicit), bool(p.weighted_reg),
-        platform=platform, bf16_gather=bool(p.bf16_gather),
-        precision=als_mod._gram_precision(),
-        gram_mode=("pallas" if gram_mode == "interpret" and
-                   platform == "tpu" else gram_mode))
+        bf16_gather=bool(p.bf16_gather),
+        precision=als_mod._gram_precision(), gram_mode=gram_mode)
     geom_u, geom_i = prep.u_side.geometry, prep.i_side.geometry
 
     def step(u_bufs, i_bufs, U, V, reg, alpha):
@@ -141,13 +132,12 @@ def als_iteration_ops(prep, params, gram_mode: str = "off",
     return count_fn_ops(step, u_bufs, i_bufs, U, V, s, s)
 
 
-def als_dispatch_report(prep, params, platform: Optional[str] = "tpu"
-                        ) -> dict:
+def als_dispatch_report(prep, params) -> dict:
     """Baseline-vs-fused dispatch counts for one ALS iteration:
     ``{"xla": n, "fused": n, "ratio": xla/fused}`` — the chip-free
     evidence for the dispatch-collapse claim (ISSUE 17 acceptance)."""
-    xla = als_iteration_ops(prep, params, "off", platform)
-    fused = als_iteration_ops(prep, params, "pallas", platform)
+    xla = als_iteration_ops(prep, params, "off")
+    fused = als_iteration_ops(prep, params, "pallas")
     return {"device_ops_per_iter_xla": xla,
             "device_ops_per_iter": fused,
             "dispatch_collapse_ratio": xla / max(1, fused)}

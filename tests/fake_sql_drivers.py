@@ -42,6 +42,8 @@ test's job (``test_pgsql_live_smoke``) on an image with a real server.
 Shared state: connections with the same ``(host, database)`` hit the
 same sqlite file under a process-wide temp dir — two fake connections
 see each other's committed writes, like two sessions of one server.
+:func:`reset_all` gives the next test empty servers in a directory of
+its own.
 """
 
 from __future__ import annotations
@@ -53,11 +55,14 @@ import sqlite3
 import tempfile
 import threading
 import types
+import weakref
 from typing import Optional
 
-_DIR = tempfile.mkdtemp(prefix="pio_fake_sql_")
-atexit.register(shutil.rmtree, _DIR, ignore_errors=True)
+_ROOT = tempfile.mkdtemp(prefix="pio_fake_sql_")
+atexit.register(shutil.rmtree, _ROOT, ignore_errors=True)
 _LOCK = threading.Lock()
+_DIR = tempfile.mkdtemp(dir=_ROOT)   # the servers' state since the last reset
+_OPEN = weakref.WeakSet()            # connections handed out since then
 
 
 def _db_path(host: str, database: str) -> str:
@@ -65,11 +70,31 @@ def _db_path(host: str, database: str) -> str:
         return os.path.join(_DIR, f"{host}_{database}.db")
 
 
+def _connect(path: str) -> sqlite3.Connection:
+    sq = sqlite3.connect(path, timeout=30.0)
+    sq.execute("PRAGMA journal_mode=WAL")
+    return sq
+
+
 def reset_all() -> None:
-    """Wipe every fake server's state (fresh-test isolation)."""
+    """Fresh-test isolation: close the connections the previous test
+    left open and give the fake servers an empty directory of their
+    own. No file is unlinked here (the root goes at exit). Unlinking
+    raced the garbage collector: a store nobody closes keeps its
+    connection until it is collected, sqlite deletes ``-wal`` and
+    ``-shm`` by PATH when a database's last connection closes, and a
+    collection in the middle of the wipe took ``…_db0.db-shm`` from
+    under it (``FileNotFoundError``), every test naming its first
+    database ``db0``."""
+    global _DIR
     with _LOCK:
-        for f in os.listdir(_DIR):
-            os.unlink(os.path.join(_DIR, f))
+        for conn in list(_OPEN):
+            try:
+                conn.close()
+            except sqlite3.ProgrammingError:
+                pass  # made on another thread, which alone may close it
+        _OPEN.clear()
+        _DIR = tempfile.mkdtemp(dir=_ROOT)
 
 
 # -- fake psycopg2 ------------------------------------------------------------
@@ -149,9 +174,9 @@ class _PGCursor:
 
 class _PGConnection:
     def __init__(self, path: str):
-        self._sq = sqlite3.connect(path, timeout=30.0)
-        self._sq.execute("PRAGMA journal_mode=WAL")
+        self._sq = _connect(path)
         self._failed = False
+        _OPEN.add(self)
 
     def _check_usable(self):
         if self._failed:
@@ -276,8 +301,8 @@ class _MyCursor:
 
 class _MyConnection:
     def __init__(self, path: str):
-        self._sq = sqlite3.connect(path, timeout=30.0)
-        self._sq.execute("PRAGMA journal_mode=WAL")
+        self._sq = _connect(path)
+        _OPEN.add(self)
 
     def cursor(self, cursor=None):
         assert cursor is None or cursor is SSCursor

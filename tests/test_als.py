@@ -209,7 +209,7 @@ def _layout_cases():
         "sharded_skewed_devices": (
             small, lambda mp: skewed_devices(), 8, {}),
         "sharded_dense_seg_regular": (
-            {}, lambda mp: TestFusedGram._wide_layout(mp), 4, {}),
+            {}, lambda mp: _wide_layout(mp), 4, {}),
         "sharded_uneven_empty_device": (
             head, lambda mp: power_law(47, 9, 21, 300, dedupe=False), 8,
             {}),
@@ -271,7 +271,7 @@ class TestBucketedLayout:
         np.testing.assert_allclose(V, Vr, rtol=2e-3, atol=2e-3)
 
     def test_dense_head_byte_cap_spills_to_buckets(self, monkeypatch):
-        """PIO_ALS_DENSE_HEAD_MB caps the head's weight-row bytes; the
+        """``_DENSE_HEAD_MB`` caps the head's weight-row bytes; the
         spilled entities run through the bucket path with identical
         results (ADVICE r3: unbounded head risks host/device OOM)."""
         import predictionio_tpu.models.als as als_mod
@@ -293,7 +293,7 @@ class TestBucketedLayout:
 
         # MB granularity can't isolate single rows on a tiny catalog, so
         # cap to zero: every head entity must spill to the buckets
-        monkeypatch.setenv("PIO_ALS_DENSE_HEAD_MB", "0")
+        monkeypatch.setattr(als_mod, "_DENSE_HEAD_MB", 0)
         prep_capped = als_mod.als_prepare(coo)
         side = prep_capped.u_side
         assert side.dense is None or side.dense.nb == 0
@@ -347,25 +347,15 @@ class TestBucketedLayout:
         # factors agree to bf16-accumulation noise
         np.testing.assert_allclose(U16, U32, rtol=0.15, atol=0.1)
 
-    def test_in_body_solve_fallback_matches_materialized(self, monkeypatch):
-        """The huge-catalog fallback (solve inside each bucket body,
-        taken when the solve buffer would exceed PIO_ALS_SOLVE_BUF_MB)
-        must produce the same factors as the materialized path."""
+    @staticmethod
+    def _both_finishes(coo, p, gram, monkeypatch):
+        """Train with the one solve buffer, then with a buffer nothing
+        fits (every part solved where it is built), same Gram mode."""
         import predictionio_tpu.models.als as als_mod
 
-        rng = np.random.default_rng(7)
-        n_u, n_i = 50, 30
-        uu = rng.integers(0, n_u, 500).astype(np.int32)
-        ii = rng.integers(0, n_i, 500).astype(np.int32)
-        keep = np.unique(uu.astype(np.int64) * n_i + ii, return_index=True)[1]
-        uu, ii = uu[keep], ii[keep]
-        rr = rng.uniform(1, 5, len(uu)).astype(np.float32)
-        coo = RatingsCOO(uu, ii, rr, n_u, n_i)
-        p = ALSParams(rank=4, iterations=3, reg=0.1, seed=2)
-        # include a dense head so the fallback's dense branch is covered
-        monkeypatch.setattr(als_mod, "_DENSE_MIN_COUNT", 8)
-        prep = als_mod.als_prepare(coo)
-        assert prep.u_side.dense is not None and prep.u_side.dense.nb > 0
+        monkeypatch.setenv("PIO_PALLAS_GRAM",
+                           {"off": "0", "interpret": "interpret"}[gram])
+        als_mod._compiled_bucketed.cache_clear()
         U_m, V_m = als_mod.als_train(coo, p)
         monkeypatch.setattr(als_mod, "_SOLVE_BUF_MB", 0)
         als_mod._compiled_bucketed.cache_clear()
@@ -373,35 +363,85 @@ class TestBucketedLayout:
             U_f, V_f = als_mod.als_train(coo, p)
         finally:
             als_mod._compiled_bucketed.cache_clear()
+        return (U_m, V_m), (U_f, V_f)
+
+    @pytest.mark.parametrize("implicit", [False, True],
+                             ids=["explicit", "implicit"])
+    @pytest.mark.parametrize("gram", ["off", "interpret"])
+    def test_in_body_solve_fallback_matches_materialized(
+            self, monkeypatch, gram, implicit):
+        """The huge-catalog fallback (solve inside each bucket body,
+        taken when the solve buffer would exceed ``_SOLVE_BUF_MB``)
+        must produce the same factors as the materialized path — with
+        XLA's Gram and with the fused kernel (a width-128 bucket on the
+        user side), explicit and implicit: in-body × kernel × implicit
+        is the path of the Last.fm configuration."""
+        import predictionio_tpu.models.als as als_mod
+
+        rng = np.random.default_rng(7)
+        n_u, n_i = 60, 150
+        deg = np.minimum(rng.zipf(1.4, n_u) + 2, 30)
+        deg[:2] = (140, 120)                   # the dense head
+        deg[2:8] = (90, 70, 60, 50, 40, 33)    # a width-128 bucket
+        uu = np.repeat(np.arange(n_u), deg).astype(np.int32)
+        ii = np.concatenate([rng.choice(n_i, d, replace=False)
+                             for d in deg]).astype(np.int32)
+        rr = rng.uniform(1, 5, len(uu)).astype(np.float32)
+        coo = RatingsCOO(uu, ii, rr, n_u, n_i)
+        p = ALSParams(rank=4, iterations=3, reg=0.1, seed=2,
+                      implicit=implicit, alpha=2.0)
+        # include a dense head so the fallback's dense branch is covered
+        monkeypatch.setattr(als_mod, "_DENSE_MIN_COUNT", 100)
+        prep = als_mod.als_prepare(coo)
+        assert prep.u_side.dense is not None and prep.u_side.dense.nb == 2
+        assert {b.C for b in prep.u_side.buckets} >= {8, 128}
+        (U_m, V_m), (U_f, V_f) = self._both_finishes(coo, p, gram,
+                                                     monkeypatch)
         np.testing.assert_allclose(U_f, U_m, rtol=1e-4, atol=1e-5)
         np.testing.assert_allclose(V_f, V_m, rtol=1e-4, atol=1e-5)
 
-    def test_slab_size_parity(self, monkeypatch):
-        """The slab size (PIO_ALS_SLAB_ELEMS — an on-device tuning knob,
-        default 2^20 after the r5 v5e A/B) only re-batches rows into
-        scan steps; training results must be invariant to it. Small
-        ladder + tiny slabs force multi-slab scans on a small dataset,
-        covering regular AND segmented buckets."""
+    @pytest.mark.parametrize("gram", ["off", "interpret"])
+    def test_in_body_solve_fallback_seg_and_dense(self, monkeypatch, gram):
+        """The same with every kind of part in one side: a dense head,
+        a segmented bucket the kernel takes, and regular buckets on
+        both sides of the kernel's width rule."""
         import predictionio_tpu.models.als as als_mod
 
-        monkeypatch.setattr(als_mod, "_LADDER", (2, 8))
-        monkeypatch.setattr(als_mod, "_C_MAX", 8)
-        rng = np.random.default_rng(11)
-        n_u, n_i = 40, 25
-        uu = (rng.zipf(1.3, 600) % n_u).astype(np.int32)
-        ii = (rng.zipf(1.3, 600) % n_i).astype(np.int32)
-        keep = np.unique(uu.astype(np.int64) * n_i + ii, return_index=True)[1]
-        uu, ii = uu[keep], ii[keep]
-        rr = rng.uniform(1, 5, len(uu)).astype(np.float32)
-        coo = RatingsCOO(uu, ii, rr, n_u, n_i)
+        coo = _wide_layout(monkeypatch)
+        prep = als_mod.als_prepare(coo)
+        assert prep.u_side.dense is not None
+        assert any(b.seg is not None for b in prep.u_side.buckets)
+        p = ALSParams(rank=4, iterations=2, reg=0.1, seed=2)
+        (U_m, V_m), (U_f, V_f) = self._both_finishes(coo, p, gram,
+                                                     monkeypatch)
+        np.testing.assert_allclose(U_f, U_m, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(V_f, V_m, rtol=1e-4, atol=1e-5)
+        Ur, Vr = _ref_als(coo, p)
+        np.testing.assert_allclose(U_f, Ur, rtol=2e-3, atol=2e-3)
+
+    @pytest.mark.parametrize("gram", ["off", "interpret"])
+    def test_slab_size_parity(self, monkeypatch, gram):
+        """The slab size (``_SLAB_ELEMS``, 2^20 after the r5 v5e A/B)
+        only re-batches rows into scan steps — or, through the kernel,
+        into the groups the segmented bucket's rows are aggregated by;
+        training results must be invariant to it. Tiny slabs force
+        multi-slab buckets on a small dataset, regular AND segmented,
+        of widths on both sides of the kernel's rule."""
+        import predictionio_tpu.models.als as als_mod
+
+        monkeypatch.setenv("PIO_PALLAS_GRAM",
+                           {"off": "0", "interpret": "interpret"}[gram])
+        coo = _wide_layout(monkeypatch)
         p = ALSParams(rank=4, iterations=2, reg=0.1, seed=2)
 
         results = []
-        for slab_elems in (16, 64, 1 << 20):
+        for slab_elems in (256, 1024, 1 << 20):
             monkeypatch.setattr(als_mod, "_SLAB_ELEMS", slab_elems)
             prep = als_mod.als_prepare(coo)
-            if slab_elems == 16:  # smallest: must actually multi-slab
-                assert any(b.n_slabs > 1 for b in prep.u_side.buckets)
+            if slab_elems == 256:  # smallest: must actually multi-slab
+                multi = [b for b in prep.u_side.buckets if b.n_slabs > 1]
+                assert any(b.seg is not None for b in multi)
+                assert any(b.seg is None for b in multi)
             results.append(als_mod.als_train_prepared(prep, p))
         als_mod._compiled_bucketed.cache_clear()
         # slab grouping changes f32 accumulation order in the seg
@@ -410,6 +450,50 @@ class TestBucketedLayout:
         for U, V in rest:
             np.testing.assert_allclose(U, U0, rtol=5e-4, atol=1e-5)
             np.testing.assert_allclose(V, V0, rtol=5e-4, atol=1e-5)
+
+    @pytest.mark.parametrize("gram_mode", ["pallas", "off", "interpret"])
+    def test_solve_is_the_kernel_exactly_under_pallas(self, monkeypatch,
+                                                      gram_mode):
+        """The solve follows the Gram mode and nothing else: the VMEM
+        Cholesky kernel when the Gram is the compiled fused kernel, the
+        XLA recursion under ``off`` and ``interpret`` — in the one
+        solve buffer and in the in-body finish (a ≥ 256-row bucket),
+        and the train's ``gram=… solve=…`` line says the same."""
+        import jax
+        import jax.numpy as jnp
+        import predictionio_tpu.models.als as als_mod
+        from predictionio_tpu.ops import cholesky as chol_mod
+
+        rng = np.random.default_rng(5)
+        n_u, n_i = 300, 40
+        uu = np.repeat(np.arange(n_u), 3).astype(np.int32)
+        ii = rng.integers(0, n_i, len(uu)).astype(np.int32)
+        coo = RatingsCOO(uu, ii, np.ones(len(uu), np.float32), n_u, n_i)
+        prep = als_mod.als_prepare(coo)
+        assert [b.nb for b in prep.u_side.buckets] == [300]
+
+        kernel_batches = []
+
+        def spy(A, b, interpret=False):
+            kernel_batches.append(A.shape[0])
+            return jnp.zeros(b.shape, jnp.float32)
+
+        monkeypatch.setattr(chol_mod, "chol_solve_pallas", spy)
+        half = als_mod._make_half(4, False, True, gram_mode=gram_mode)
+        bufs = prep.u_side.arrays()
+        F = jax.ShapeDtypeStruct((n_i, 4), jnp.float32)
+        want = gram_mode == "pallas"
+        for buf_mb in (als_mod._SOLVE_BUF_MB, 0):  # one buffer, in-body
+            monkeypatch.setattr(als_mod, "_SOLVE_BUF_MB", buf_mb)
+            del kernel_batches[:]
+            out = jax.eval_shape(
+                lambda F, bufs: half(F, bufs, prep.u_side.geometry,
+                                     0.1, 1.0), F, bufs)
+            assert out.shape == (n_u, 4)
+            assert bool(kernel_batches) == want, (buf_mb, kernel_batches)
+            assert all(n >= 256 for n in kernel_batches)
+        assert als_mod.log_train_modes("tpu", gram_mode, 1) == (
+            "pallas" if want else "xla")
 
     def test_default_ladder_matches_dense_reference(self):
         rng = np.random.default_rng(6)
@@ -538,6 +622,27 @@ def _zipf_coo(seed, n_u, n_i, nnz):
     return RatingsCOO(uu, ii, rr, n_u, n_i)
 
 
+def _wide_layout(monkeypatch):
+    """A layout with a dense head, a segmented bucket and regular
+    buckets the kernel takes (width 128), on both sides."""
+    import predictionio_tpu.models.als as als_mod
+
+    monkeypatch.setattr(als_mod, "_LADDER", (8, 128))
+    monkeypatch.setattr(als_mod, "_C_MAX", 128)
+    monkeypatch.setattr(als_mod, "_DENSE_MIN_COUNT", 300)
+    monkeypatch.setattr(als_mod, "_DENSE_RATIO", 0.75)
+    rng = np.random.default_rng(31)
+    n_u, n_i = 90, 400
+    deg = np.minimum(rng.zipf(1.25, n_u) + 2, n_i)
+    deg[:3] = (390, 350, 330)          # the dense head
+    deg[3:9] = (290, 260, 200, 170, 140, 129)   # segmented rows
+    uu = np.repeat(np.arange(n_u), deg).astype(np.int32)
+    ii = np.concatenate([rng.choice(n_i, d, replace=False)
+                         for d in deg]).astype(np.int32)
+    rr = rng.uniform(1, 5, len(uu)).astype(np.float32)
+    return RatingsCOO(uu, ii, rr, n_u, n_i)
+
+
 class TestFusedGram:
     """ISSUE 17: whole-train parity of the fused gather→Gram Pallas
     path (Mosaic interpreter on CPU) against the XLA gather+einsum
@@ -588,27 +693,6 @@ class TestFusedGram:
         np.testing.assert_allclose(Uf, Ur, rtol=2e-3, atol=2e-3)
 
     @staticmethod
-    def _wide_layout(monkeypatch):
-        """A layout with a dense head, a segmented bucket and regular
-        buckets the kernel takes (width 128), on both sides."""
-        import predictionio_tpu.models.als as als_mod
-
-        monkeypatch.setattr(als_mod, "_LADDER", (8, 128))
-        monkeypatch.setattr(als_mod, "_C_MAX", 128)
-        monkeypatch.setattr(als_mod, "_DENSE_MIN_COUNT", 300)
-        monkeypatch.setattr(als_mod, "_DENSE_RATIO", 0.75)
-        rng = np.random.default_rng(31)
-        n_u, n_i = 90, 400
-        deg = np.minimum(rng.zipf(1.25, n_u) + 2, n_i)
-        deg[:3] = (390, 350, 330)          # the dense head
-        deg[3:9] = (290, 260, 200, 170, 140, 129)   # segmented rows
-        uu = np.repeat(np.arange(n_u), deg).astype(np.int32)
-        ii = np.concatenate([rng.choice(n_i, d, replace=False)
-                             for d in deg]).astype(np.int32)
-        rr = rng.uniform(1, 5, len(uu)).astype(np.float32)
-        return RatingsCOO(uu, ii, rr, n_u, n_i)
-
-    @staticmethod
     def _kernel_buckets(sides):
         from predictionio_tpu.ops.gram import kernel_takes_width
 
@@ -625,7 +709,7 @@ class TestFusedGram:
         import predictionio_tpu.models.als as als_mod
         from predictionio_tpu.models.als_sharded import als_prepare_sharded
 
-        coo = self._wide_layout(monkeypatch)
+        coo = _wide_layout(monkeypatch)
         if sharded:
             prep = als_prepare_sharded(coo, 4)
             sides = prep.u_sides + prep.i_sides
@@ -650,7 +734,7 @@ class TestFusedGram:
         from predictionio_tpu.ops import gram as gram_mod
         import predictionio_tpu.models.als as als_mod
 
-        coo = self._wide_layout(monkeypatch)
+        coo = _wide_layout(monkeypatch)
         prep = als_mod.als_prepare(coo)
         given = []
         orig = gram_mod.gather_gram

@@ -68,7 +68,7 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
-# -- the four kernels at the widths of ML-20M, rank 64 ------------------------
+# -- the three kernels at the widths of ML-20M, rank 64 ------------------------
 
 
 def test_chol_solve_pallas(one_chip):
@@ -77,16 +77,6 @@ def test_chol_solve_pallas(one_chip):
     c = jax.jit(chol_solve_pallas).lower(
         _sds((4096, 64, 64), jnp.float32, one_chip),
         _sds((4096, 64), jnp.float32, one_chip)).compile()
-    assert _has_kernel(c)
-
-
-def test_rows_gram(one_chip):
-    from predictionio_tpu.ops.gram import rows_gram
-
-    c = rows_gram.lower(
-        _sds((64, 128, 64), jnp.float32, one_chip),
-        _sds((64, 128), jnp.float32, one_chip),
-        _sds((64, 128), jnp.float32, one_chip)).compile()
     assert _has_kernel(c)
 
 

@@ -6,45 +6,8 @@ import pytest
 import jax.numpy as jnp
 
 from predictionio_tpu.ops import (
-    rows_gram, rows_gram_xla, score_topk, score_topk_xla,
-    segment_count, segment_mean, segment_sum,
+    score_topk, score_topk_xla, segment_count, segment_mean, segment_sum,
 )
-
-
-class TestRowsGram:
-    def _data(self, R=32, W=16, k=8, seed=0):
-        rng = np.random.default_rng(seed)
-        F = rng.standard_normal((R, W, k)).astype(np.float32)
-        wo = rng.uniform(0, 2, (R, W)).astype(np.float32)
-        wb = rng.uniform(0, 2, (R, W)).astype(np.float32)
-        return F, wo, wb
-
-    def _ref(self, F, wo, wb):
-        A = np.einsum("rw,rwk,rwl->rkl", wo, F, F)
-        b = np.einsum("rw,rwk->rk", wb, F)
-        return A, b
-
-    def test_pallas_matches_numpy(self):
-        F, wo, wb = self._data()
-        A, b = rows_gram(jnp.asarray(F), jnp.asarray(wo), jnp.asarray(wb),
-                         interpret=True)
-        An, bn = self._ref(F, wo, wb)
-        np.testing.assert_allclose(np.asarray(A), An, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(b), bn, rtol=1e-5, atol=1e-5)
-
-    def test_xla_matches_numpy(self):
-        F, wo, wb = self._data(R=7, W=5, k=3, seed=1)
-        A, b = rows_gram_xla(jnp.asarray(F), jnp.asarray(wo), jnp.asarray(wb))
-        An, bn = self._ref(F, wo, wb)
-        np.testing.assert_allclose(np.asarray(A), An, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(b), bn, rtol=1e-5, atol=1e-5)
-
-    def test_odd_row_count_falls_back_to_divisor_block(self):
-        F, wo, wb = self._data(R=20, W=4, k=4, seed=2)  # 20 % 8 != 0 → block 4
-        A, b = rows_gram(jnp.asarray(F), jnp.asarray(wo), jnp.asarray(wb),
-                         interpret=True)
-        An, bn = self._ref(F, wo, wb)
-        np.testing.assert_allclose(np.asarray(A), An, rtol=1e-5, atol=1e-5)
 
 
 class TestScoreTopK:
@@ -255,17 +218,6 @@ class TestTPULowering:
                      jax.ShapeDtypeStruct((512, 64, 64), jnp.float32),
                      jax.ShapeDtypeStruct((512, 64), jnp.float32))
 
-    def test_rows_gram(self):
-        import functools
-
-        import jax
-        from predictionio_tpu.ops.gram import rows_gram
-
-        self._lowers(functools.partial(rows_gram, block_rows=8),
-                     jax.ShapeDtypeStruct((64, 128, 16), jnp.float32),
-                     jax.ShapeDtypeStruct((64, 128), jnp.float32),
-                     jax.ShapeDtypeStruct((64, 128), jnp.float32))
-
     def test_score_topk(self):
         import functools
 
@@ -318,7 +270,7 @@ class TestGatherGram:
         F, idx, wo, wb = self._data(R, C, k, **kw)
         A, b = gather_gram(jnp.asarray(F), jnp.asarray(idx),
                            jnp.asarray(wo), jnp.asarray(wb),
-                           interpret=True)
+                           jnp.full((R,), C, jnp.int32), interpret=True)
         An, bn = self._ref(F, idx, wo, wb)
         assert A.shape == (R, k, k) and b.shape == (R, k)
         # f32 accumulation error grows with the C-length reduction;
@@ -342,12 +294,13 @@ class TestGatherGram:
         from predictionio_tpu.ops.gram import gather_gram
 
         F, idx, wo, wb = self._data(16, 32, 8, dtype=np.float32)
+        every = jnp.full((16,), 32, jnp.int32)
         A32, b32 = gather_gram(jnp.asarray(F), jnp.asarray(idx),
-                               jnp.asarray(wo), jnp.asarray(wb),
+                               jnp.asarray(wo), jnp.asarray(wb), every,
                                interpret=True)
         A16, b16 = gather_gram(jnp.asarray(F, jnp.bfloat16),
                                jnp.asarray(idx), jnp.asarray(wo),
-                               jnp.asarray(wb), interpret=True)
+                               jnp.asarray(wb), every, interpret=True)
         assert A16.dtype == jnp.float32  # accumulation stays f32
         # bf16 carries an 8-bit mantissa: products of two quantized
         # values drift ~1%, so judge by absolute error at this scale
@@ -413,27 +366,14 @@ class TestGatherGram:
         np.testing.assert_allclose(np.asarray(b[1]), bn[0], rtol=1e-4,
                                    atol=1e-4)
 
-    @pytest.mark.parametrize("C", [128, 512])
-    def test_no_lengths_is_every_slot(self, C):
-        """Lengths are an input, not a mode: omitted, every slot is
-        fetched — bit for bit what lengths all C give, zero weights
-        anywhere in the row."""
-        from predictionio_tpu.ops.gram import gather_gram
-
-        args = [jnp.asarray(a) for a in self._data(11, C, 13, seed=4)]
-        A0, b0 = gather_gram(*args, interpret=True)
-        A1, b1 = gather_gram(*args, jnp.full((11,), C, jnp.int32),
-                             interpret=True)
-        np.testing.assert_array_equal(np.asarray(A0), np.asarray(A1))
-        np.testing.assert_array_equal(np.asarray(b0), np.asarray(b1))
-
     def test_empty_rows(self):
         from predictionio_tpu.ops.gram import gather_gram
 
         F = jnp.zeros((10, 5), jnp.float32)
         A, b = gather_gram(F, jnp.zeros((0, 8), jnp.int32),
                            jnp.zeros((0, 8), jnp.float32),
-                           jnp.zeros((0, 8), jnp.float32), interpret=True)
+                           jnp.zeros((0, 8), jnp.float32),
+                           jnp.zeros((0,), jnp.int32), interpret=True)
         assert A.shape == (0, 5, 5) and b.shape == (0, 5)
 
     def test_xla_reference_matches_numpy(self):
@@ -451,13 +391,14 @@ class TestGatherGram:
 
         monkeypatch.setenv("PIO_PALLAS_GRAM", "0")
         assert g.resolve_gram_mode("tpu") == "off"
-        monkeypatch.setenv("PIO_PALLAS_GRAM", "off")
-        assert g.resolve_gram_mode("tpu") == "off"
         monkeypatch.setenv("PIO_PALLAS_GRAM", "interpret")
         assert g.resolve_gram_mode("cpu") == "interpret"
-        # force on a non-TPU platform warns and falls back to off
-        monkeypatch.setenv("PIO_PALLAS_GRAM", "1")
-        assert g.resolve_gram_mode("cpu") == "off"
-        # auto never picks the kernel off-TPU
-        monkeypatch.delenv("PIO_PALLAS_GRAM")
-        assert g.resolve_gram_mode("cpu") == "off"
+        assert g.resolve_gram_mode("tpu") == "interpret"
+        # auto (the default) is the kernel on a TPU and never off it
+        for auto in ("auto", None):
+            if auto is None:
+                monkeypatch.delenv("PIO_PALLAS_GRAM")
+            else:
+                monkeypatch.setenv("PIO_PALLAS_GRAM", auto)
+            assert g.resolve_gram_mode("tpu") == "pallas"
+            assert g.resolve_gram_mode("cpu") == "off"

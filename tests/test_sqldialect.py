@@ -317,6 +317,31 @@ class TestServerDialectStores:
         assert got is not None and got.entity_id == "u"
 
 
+def test_reset_between_tests_leaves_open_connections_alone(monkeypatch):
+    """The fixture's isolation (this file's one unsteady test, PR 29):
+    a store the previous test never closed holds a connection on
+    ``db0``, as the next test's first database is named. The reset
+    must neither trip over the files sqlite removes when that
+    connection goes, nor let its going touch the next test's files."""
+    import gc
+
+    from tests import fake_sql_drivers as fsd
+
+    fsd.reset_all()
+    monkeypatch.setitem(sys.modules, "pymysql", fsd.make_pymysql_module())
+    left_open = SQLModelStore(MySQLDialect({"DATABASES": "db0"}))
+    left_open.put("old", b"1")
+    fsd.reset_all()
+    fresh = SQLModelStore(MySQLDialect({"DATABASES": "db0"}))
+    assert fresh.list_ids() == []      # an empty server, same name
+    fresh.put("new", b"2")
+    del left_open
+    gc.collect()                       # the old connection goes NOW
+    assert fresh.get("new") == b"2"
+    assert SQLModelStore(
+        MySQLDialect({"DATABASES": "db0"})).list_ids() == ["new"]
+
+
 class TestSQLiteModelStore:
     def test_sqlite_dialect_model_store(self, tmp_path):
         st = SQLModelStore(SqliteDialect(str(tmp_path / "m.db")))
