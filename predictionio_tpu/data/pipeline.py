@@ -91,6 +91,8 @@ class InteractionData:
         self.item_ids = item_ids
         self._chunk_factory = chunk_factory
         self.n_events = n_events
+        # which passes interactions_from_columnar ran (its docstring)
+        self.index_paths: Dict[str, Any] = {}
 
     def chunks(self) -> Iterator[Tuple[np.ndarray, np.ndarray, np.ndarray]]:
         """Yield (user_idx, item_idx, value) int32/int32/f32 chunks."""
@@ -409,6 +411,41 @@ def merge_columnar_segments(
         entity_ids=e_tab, target_ids=t_tab, names=n_tab)
 
 
+def _first_seen(codes: np.ndarray, table: List[str]):
+    """First-seen vocabulary of ``codes`` (indices into ``table``):
+    ``(index column int32, BiMap id → index, path)``.
+
+    A scan numbers its ids in first-seen order, so the usual input is
+    already dense: every new running maximum is the last one plus one,
+    from 0. That is checked, not assumed, in two passes over the code
+    column; then the vocabulary is the table's prefix and the column
+    its own index (``identity``). Otherwise (dropped events moved or
+    emptied an id's first kept position, a table not in scan order)
+    each id's first position comes from ``np.minimum.at`` into a
+    table-sized array and the present ids are ordered by it
+    (``first_pos``): a sort over ≤ ``len(table)`` values, never over
+    the events."""
+    def vocab(ids: List[str]) -> BiMap:
+        return BiMap({s: i for i, s in enumerate(ids)})
+
+    n = codes.shape[0]
+    if n == 0:
+        return np.zeros(0, np.int32), vocab([]), "identity"
+    top = np.maximum.accumulate(codes)
+    n_seen = int(top[-1]) + 1
+    if (codes[0] == 0 and n_seen <= len(table)
+            and np.count_nonzero(top[1:] != top[:-1]) == n_seen - 1):
+        return codes.astype(np.int32), vocab(table[:n_seen]), "identity"
+    del top
+    first = np.full(len(table), n, np.intp)
+    np.minimum.at(first, codes, np.arange(n, dtype=np.intp))
+    present = np.flatnonzero(first < n)
+    seen = present[np.argsort(first[present], kind="stable")]
+    remap = np.full(len(table), -1, np.int32)
+    remap[seen] = np.arange(seen.shape[0], dtype=np.int32)
+    return remap[codes], vocab([table[int(c)] for c in seen]), "first_pos"
+
+
 def interactions_from_columnar(
     cols: ColumnarEvents,
     value_spec: Optional[Dict[str, Any]] = None,
@@ -423,6 +460,12 @@ def interactions_from_columnar(
     Unlisted names take ``default_spec``. Vocabularies are re-densified
     to kept events only (first-seen order), so the result is
     indistinguishable from :func:`read_interactions` over ``find()``.
+
+    O(n) passes over the columns, and only those the input makes
+    necessary; ``index_paths`` of the result says which ran:
+    ``masked`` (1 where an event was dropped and the columns were
+    copied through the mask) and ``densify_e`` / ``densify_t``
+    (:func:`_first_seen`'s path a side).
     """
     # per-NAME lookup arrays, then one gather over name_idx — O(n),
     # independent of how many distinct event names the log holds
@@ -431,37 +474,36 @@ def interactions_from_columnar(
     is_prop = np.asarray([s == "prop" for s in specs], bool)
     consts = np.asarray([1.0 if s == "prop" else float(s) for s in specs],
                         np.float64)
-    prop_row = is_prop[cols.name_idx]
-    vals = np.where(prop_row, cols.values, consts[cols.name_idx])
-    keep = ~prop_row | np.isfinite(cols.values)
-
-    def densify(idx_arr: np.ndarray, table: List[str]):
-        """Trim the vocab to kept events, preserving first-seen order."""
-        uniq, first_pos = np.unique(idx_arr, return_index=True)
-        order = np.argsort(first_pos, kind="stable")
-        uniq = uniq[order]
-        remap = np.full(len(table), -1, np.int32)
-        remap[uniq] = np.arange(len(uniq), dtype=np.int32)
-        ids = [table[int(u)] for u in uniq]
-        return remap, BiMap({s: i for i, s in enumerate(ids)})
-
-    ent_kept = cols.entity_idx[keep]
-    tgt_kept = cols.target_idx[keep]
-    v_kept = vals[keep].astype(np.float32)
-    remap_e, user_ids = densify(ent_kept, cols.entity_ids)
-    remap_t, item_ids = densify(tgt_kept, cols.target_ids)
-    uu = remap_e[ent_kept]
-    ii = remap_t[tgt_kept]
+    ent_kept, tgt_kept, masked = cols.entity_idx, cols.target_idx, False
+    if not is_prop.any():
+        v_kept = consts.astype(np.float32)[cols.name_idx]
+    else:
+        if is_prop.all():
+            vals, keep = cols.values, np.isfinite(cols.values)
+        else:
+            prop_row = is_prop[cols.name_idx]
+            vals = np.where(prop_row, cols.values, consts[cols.name_idx])
+            keep = ~prop_row | np.isfinite(cols.values)
+        # only a non-finite "prop" value drops an event; with none,
+        # the scan's columns ARE the kept ones and nothing is copied
+        masked = not keep.all()
+        if masked:
+            ent_kept, tgt_kept, vals = (ent_kept[keep], tgt_kept[keep],
+                                        vals[keep])
+        v_kept = vals.astype(np.float32)
+    uu, user_ids, path_e = _first_seen(ent_kept, cols.entity_ids)
+    ii, item_ids, path_t = _first_seen(tgt_kept, cols.target_ids)
     n_events = int(uu.shape[0])
 
     def chunk_factory():
-        for s in range(0, max(n_events, 1), chunk_size):
-            if s >= n_events:
-                return
+        for s in range(0, n_events, chunk_size):
             yield (uu[s:s + chunk_size], ii[s:s + chunk_size],
                    v_kept[s:s + chunk_size])
 
-    return InteractionData(user_ids, item_ids, chunk_factory, n_events)
+    data = InteractionData(user_ids, item_ids, chunk_factory, n_events)
+    data.index_paths = {"densify_e": path_e, "densify_t": path_t,
+                        "masked": int(masked)}
+    return data
 
 
 def _vocab_add(vocab: Dict[str, int], keys) -> None:

@@ -384,6 +384,8 @@ def read_training_interactions(
                 sp.set_attr("kept", int(data.n_events))
                 sp.set_attr("n_entities", len(data.user_ids))
                 sp.set_attr("n_targets", len(data.item_ids))
+                for key, path in data.index_paths.items():
+                    sp.set_attr(key, path)
             return data
 
     def value_fn(e):

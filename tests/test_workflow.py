@@ -177,7 +177,8 @@ SPAN_PARENTS = {
 SPAN_ATTRS = {
     "train.run": ("engine_factory", "instance_id", "status"),
     "storage.scan": ("scan_cache", "records"),
-    "train.read.index": ("kept", "n_entities", "n_targets"),
+    "train.read.index": ("kept", "n_entities", "n_targets", "densify_e",
+                         "densify_t", "masked"),
     "als.index": ("nnz",),
     "als.prepare": ("nnz", "kernel_real_rows", "kernel_padded_rows",
                     "kernel_bucket_rows", "kernel_dma_rows",
