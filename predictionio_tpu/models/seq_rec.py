@@ -272,31 +272,65 @@ def seq_rec_train(sequences, n_items: int, p: SeqRecParams, mesh=None,
     opt_state.hyperparams["learning_rate"] = jnp.float32(p.lr)
     l2 = jnp.float32(p.l2)
 
-    if not p.checkpoint_dir:
-        params, _, losses = compiled(p.epochs)(params, opt_state, X, Y, l2)
-        return params, np.asarray(losses)
+    def run_block(state, n):
+        params, opt_state, losses = compiled(n)(
+            state["params"], state["opt_state"], X, Y, l2)
+        return {"params": params, "opt_state": opt_state}, np.asarray(losses)
 
-    # checkpointed path: epoch blocks between saves; params + optimizer
-    # state fully determine the remainder (batches are fixed per seed),
-    # so resume reproduces the uninterrupted run
+    def set_lr(state):
+        state["opt_state"].hyperparams["learning_rate"] = jnp.float32(p.lr)
+
+    state, loss_parts = run_epoch_blocks(
+        p.epochs, p.checkpoint_dir, p.checkpoint_every,
+        {"params": params, "opt_state": opt_state}, run_block, set_lr)
+    # losses cover only the epochs run in THIS process (a resumed run
+    # reports the remainder)
+    return state["params"], (np.concatenate(loss_parts) if loss_parts
+                             else np.zeros(0, np.float32))
+
+
+def run_epoch_blocks(epochs: int, checkpoint_dir: Optional[str],
+                     checkpoint_every: int, state: Dict, run_block, set_lr
+                     ) -> Tuple[Dict, list]:
+    """The epoch loop every neural trainer here shares: ``run_block(
+    state, n) -> (state, record)`` runs ``n`` epochs as one compiled
+    program; returns the final state and the records of the blocks run
+    in THIS process. ``state`` is a dict of device pytrees that fully
+    determines the remainder (batches are fixed per seed), so resume
+    reproduces the uninterrupted run.
+
+    Without ``checkpoint_dir`` all epochs are one block. With it, the
+    loop runs in blocks of ``checkpoint_every`` epochs and writes a
+    checkpoint BETWEEN blocks, never after the last: the model save
+    follows it at once and the workflow deletes the directory on
+    completion, so that write (a fetch of params + optimizer state
+    with the chip idle) would be for nothing. ``set_lr(state)`` makes
+    THIS run's learning rate win over a restored one."""
+    import jax
+    import jax.numpy as jnp
+
+    if not checkpoint_dir:
+        state, record = run_block(state, epochs)
+        return state, [record]
+
     from predictionio_tpu.utils.checkpoint import (CheckpointGeometryError,
                                                    TrainCheckpointer)
 
-    ckpt = TrainCheckpointer(p.checkpoint_dir)
+    def to_host(state):
+        return jax.tree.map(np.asarray, state)
+
+    ckpt = TrainCheckpointer(checkpoint_dir)
     start = 0
     if ckpt.latest_step() is not None:
-        template = {"params": jax.tree.map(np.asarray, params),
-                    "opt_state": jax.tree.map(np.asarray, opt_state)}
         try:
             # newest→oldest walk: a crash-truncated newest save falls
             # back to the previous good step instead of a full retrain
-            state, latest = ckpt.restore_latest_compatible(template)
-            params = jax.tree.map(jnp.asarray, state["params"])
-            opt_state = jax.tree.map(jnp.asarray, state["opt_state"])
+            restored, latest = ckpt.restore_latest_compatible(to_host(state))
+            state = jax.tree.map(jnp.asarray, restored)
             # THIS run's lr wins over the checkpointed one (annealing
             # restarts must not silently keep the old rate)
-            opt_state.hyperparams["learning_rate"] = jnp.float32(p.lr)
-            start = min(int(latest), p.epochs)
+            set_lr(state)
+            start = min(int(latest), epochs)
         except CheckpointGeometryError:
             # CONFIRMED stale (different geometry) → fresh start; WIPE
             # the dir, else the fresh run's lower step numbers stay
@@ -309,20 +343,17 @@ def seq_rec_train(sequences, n_items: int, p: SeqRecParams, mesh=None,
                 "seq_rec checkpoints are stale (geometry/format change) — wiped; training restarts from scratch",
                 RuntimeWarning)
             ckpt.clear()
-    loss_parts = []
+    records = []
     epoch = start
-    while epoch < p.epochs:
-        n = min(max(1, p.checkpoint_every), p.epochs - epoch)
-        params, opt_state, losses = compiled(n)(params, opt_state, X, Y, l2)
-        loss_parts.append(np.asarray(losses))
+    while epoch < epochs:
+        n = min(max(1, checkpoint_every), epochs - epoch)
+        state, record = run_block(state, n)
+        records.append(record)
         epoch += n
-        ckpt.save(epoch, {"params": jax.tree.map(np.asarray, params),
-                          "opt_state": jax.tree.map(np.asarray, opt_state)})
+        if epoch < epochs:
+            ckpt.save(epoch, to_host(state))
     ckpt.close()
-    # losses cover only the epochs run in THIS process (a resumed run
-    # reports the remainder)
-    return params, (np.concatenate(loss_parts) if loss_parts
-                    else np.zeros(0, np.float32))
+    return state, records
 
 
 @functools.lru_cache(maxsize=8)

@@ -17,6 +17,7 @@ import re
 import shutil
 import threading
 from abc import ABC, abstractmethod
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional
 
 from predictionio_tpu.utils import faults, integrity
@@ -154,10 +155,15 @@ class LocalFSModelStore(ModelStore):
         # blob first, digest last: a crash between the two leaves a
         # mismatched pair that get() REFUSES — fail-safe, never a
         # silently unverified serve
-        atomic_write_bytes(os.path.join(d, "model.bin"), blob)
+        # (the digest is COMPUTED while the blob is written — both
+        # release the GIL, and a 2.8 GB model pays each for seconds —
+        # and written after it)
+        with ThreadPoolExecutor(max_workers=1) as pool:
+            digest = pool.submit(integrity.sha256_hex, blob)
+            atomic_write_bytes(os.path.join(d, "model.bin"), blob)
         atomic_write_bytes(
             os.path.join(d, "model.bin" + integrity.DIGEST_SUFFIX),
-            integrity.sha256_hex(blob).encode("ascii"))
+            digest.result().encode("ascii"))
 
     def get(self, instance_id: str) -> Optional[bytes]:
         p = os.path.join(self._dir(instance_id), "model.bin")

@@ -16,6 +16,11 @@ DASE shape mirrors the other recommenders:
     POST /queries.json {"user": "u1", "num": 4}
     → {"itemScores": [{"item": "i9", "score": 3.1}, ...]}
 
+The backbone is chosen by the algorithm's parameters: an
+``architecture`` object (a published ``glm4_moe_lite`` config's keys —
+:mod:`predictionio_tpu.models.glm4_moe_lite`: latent attention, sparse
+experts, an MTP module, packed histories) or, absent, the SASRec stack.
+
 Optional query keys: ``history`` (explicit item list overriding the
 live lookup — supports anonymous sessions), ``blackList``.
 """
@@ -23,6 +28,7 @@ live lookup — supports anonymous sessions), ``blackList``.
 from __future__ import annotations
 
 import pickle
+import struct
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
@@ -46,7 +52,11 @@ from predictionio_tpu.models.seq_rec import (
     seq_rec_scores,
     seq_rec_train,
 )
+from predictionio_tpu.utils import tracing
 from predictionio_tpu.utils.bimap import BiMap
+
+#: a model blob whose arrays follow its pickled head as raw bytes
+_RAW_MAGIC = b"PIOSEQRAW1\n"
 
 
 @dataclass
@@ -128,6 +138,12 @@ class SeqRecAlgorithmParams:
     # sequential consumption is often repeat-friendly (music, groceries);
     # flip on to ban already-seen items like the ALS recommenders do
     exclude_seen: bool = False
+    # the backbone: None = the SASRec stack above; an object holding a
+    # published glm4_moe_lite config's keys (and the training job's:
+    # seq_len, seqs_per_step, ep_size, … — models/glm4_moe_lite.GlmConfig)
+    # = that block stack on packed histories. ``hidden`` … ``batch_size``
+    # above are then unused; ``epochs``, ``lr`` and ``seed`` apply.
+    architecture: Optional[Dict[str, Any]] = None
 
 
 class SeqRecModel:
@@ -135,6 +151,7 @@ class SeqRecModel:
                  hp: SeqRecParams, algo_params: "SeqRecAlgorithmParams",
                  losses: np.ndarray) -> None:
         self.params = params
+        self._on_device = None
         self.item_ids = item_ids  # raw item id → 1-based index
         self._inv = item_ids.inverse()
         self.app_name = app_name
@@ -154,12 +171,29 @@ class SeqRecModel:
         ordered = sorted(evs, key=lambda e: e.event_time)
         return [e.target_entity_id for e in ordered if e.target_entity_id]
 
+    def device_params(self) -> Dict:
+        """The parameters on the device, put there once (a loaded
+        model's arrays are host views of its 2.8 GB blob)."""
+        if self._on_device is None:
+            import jax
+
+            self._on_device = jax.device_put(self.params)
+        return self._on_device
+
     def next_items(self, history_raw: List[str], num: int,
                    black_list: Optional[List[str]] = None
                    ) -> List[Dict[str, Any]]:
         hist = [self.item_ids[i] + 1 for i in history_raw
                 if i in self.item_ids]
-        scores = seq_rec_scores(self.params, hist, self.hp)  # PAD = -inf
+        if isinstance(self.hp, SeqRecParams):
+            scores = seq_rec_scores(self.params, hist, self.hp)  # PAD = -inf
+        else:
+            from predictionio_tpu.models.glm4_moe_lite import next_item_scores
+
+            # rows past the catalog (a vocabulary slice's spare rows)
+            # are no items
+            scores = next_item_scores(self.device_params(), hist, self.hp)[
+                :len(self.item_ids) + 1]
         banned = set(black_list or [])
         if self.algo_params.exclude_seen:
             banned |= set(history_raw)
@@ -183,11 +217,13 @@ class SeqRecAlgorithm(Algorithm):
 
     def train(self, ctx: WorkflowContext, pd: TrainingData) -> SeqRecModel:
         p: SeqRecAlgorithmParams = self.params
-        item_ids = BiMap.string_int(
-            i for seq in pd.sequences.values() for i in seq)
-        # vocab ids are 1-based (0 = PAD)
-        sequences = [[item_ids[i] + 1 for i in seq]
-                     for seq in pd.sequences.values()]
+        with tracing.span("seqrec.index") as sp:
+            item_ids = BiMap.string_int(
+                i for seq in pd.sequences.values() for i in seq)
+            # vocab ids are 1-based (0 = PAD)
+            sequences = [[item_ids[i] + 1 for i in seq]
+                         for seq in pd.sequences.values()]
+            sp.set_attr("items", len(item_ids))
         # the workflow's per-run checkpoint dir enables mid-train
         # restart-from-checkpoint (SURVEY §5), like the ALS/two-tower
         # templates
@@ -196,6 +232,14 @@ class SeqRecAlgorithm(Algorithm):
             import os
 
             ckpt_dir = os.path.join(ctx.checkpoint_dir, "seq_rec")
+        if p.architecture is not None:
+            from predictionio_tpu.models.glm4_moe_lite import (GlmConfig,
+                                                               glm_train)
+
+            cfg = GlmConfig.from_architecture(p.architecture)
+            params, losses = glm_train(sequences, cfg, p.epochs, p.lr,
+                                       p.seed, checkpoint_dir=ckpt_dir)
+            return SeqRecModel(params, item_ids, pd.app_name, cfg, p, losses)
         hp = SeqRecParams(hidden=p.hidden, num_blocks=p.num_blocks,
                           num_heads=p.num_heads, seq_len=p.seq_len,
                           epochs=p.epochs, lr=p.lr,
@@ -220,22 +264,50 @@ class SeqRecAlgorithm(Algorithm):
 
     def save_model(self, model: SeqRecModel, instance_dir: Optional[str]
                    ) -> bytes:
+        """``magic | head length | pickled head | the arrays' raw
+        bytes``: no compression and ONE copy of the parameters (the
+        join) — pickling the tree would copy 2.8 GB of a 706 M
+        parameter model a second time, zlib would take minutes."""
         import jax
 
-        return pickle.dumps({
-            "params": jax.tree.map(np.asarray, model.params),
+        leaves, treedef = jax.tree.flatten(
+            jax.tree.map(np.asarray, model.params))
+        head = pickle.dumps({
+            # the tree with each array's number in its place
+            "tree": jax.tree.unflatten(treedef, range(len(leaves))),
+            "leaves": [(a.dtype.str, a.shape) for a in leaves],
             "item_ids": model.item_ids.to_dict(),
             "app_name": model.app_name,
             "hp": model.hp,
             "algo_params": model.algo_params,
             "losses": model.losses,
         })
+        return b"".join(
+            [_RAW_MAGIC, struct.pack("<Q", len(head)), head]
+            + [memoryview(np.ascontiguousarray(a)).cast("B")
+               for a in leaves])
 
     def load_model(self, blob: Optional[bytes],
                    instance_dir: Optional[str]) -> SeqRecModel:
         assert blob is not None
-        d = pickle.loads(blob)
-        return SeqRecModel(d["params"], BiMap(d["item_ids"]), d["app_name"],
+        if not blob.startswith(_RAW_MAGIC):     # saved the old way
+            d = pickle.loads(blob)
+            params = d["params"]
+        else:
+            at = len(_RAW_MAGIC) + 8
+            (n,) = struct.unpack("<Q", blob[len(_RAW_MAGIC):at])
+            d = pickle.loads(blob[at:at + n])
+            at += n
+            leaves = []
+            for dtype, shape in d["leaves"]:
+                a = np.frombuffer(blob, np.dtype(dtype),
+                                  int(np.prod(shape, dtype=np.int64)), at)
+                leaves.append(a.reshape(shape))
+                at += a.nbytes
+            import jax
+
+            params = jax.tree.map(lambda i: leaves[i], d["tree"])
+        return SeqRecModel(params, BiMap(d["item_ids"]), d["app_name"],
                            d["hp"], d["algo_params"], d["losses"])
 
 

@@ -199,3 +199,54 @@ def test_als_step_sharded_four_chips(topo, ml20m_shape_coo):
     per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                   + mem.temp_size_in_bytes)
     assert per_device < 16e9
+
+
+def _ragged_calls(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("held", [8, 1])
+def test_expert_dispatch_at_published_widths(one_chip, held):
+    """``ops/moe_dispatch`` for one chip's share of GLM-4.7-Flash's
+    expert layer — 16,384 tokens, top-4 of 64, 2048 × 1536 experts,
+    the 65,536-row pair buffer — forward and backward: the grouped
+    products must be the compiler's own ragged-dot kernels (a dense
+    expansion over the groups would be ``held`` times the work), for
+    the lhs AND the weights' gradients."""
+    from predictionio_tpu.ops import moe_dispatch
+
+    T, d, f, E, k = 16384, 2048, 1536, 64, 4
+
+    def layer(x, scores, wg, wu, wd):
+        ids, gates = moe_dispatch.route(scores, jnp.zeros(E), k, 1.8)
+        p = moe_dispatch.plan(ids, tuple(range(held)), E)
+        return moe_dispatch.experts_swiglu(x, wg, wu, wd, gates, p)
+
+    args = (_sds((T, d), jnp.bfloat16, one_chip),
+            _sds((T, E), jnp.float32, one_chip),
+            _sds((held, d, f), jnp.bfloat16, one_chip),
+            _sds((held, d, f), jnp.bfloat16, one_chip),
+            _sds((held, f, d), jnp.bfloat16, one_chip))
+    fwd = jax.jit(layer).lower(*args).compile()
+    assert _ragged_calls(fwd) >= 3
+    bwd = jax.jit(jax.grad(
+        lambda *a: layer(*a).sum(), (0, 1, 2, 3, 4))).lower(*args).compile()
+    assert _ragged_calls(bwd) >= 9
+    assert bwd.memory_analysis().temp_size_in_bytes < 3e9
+
+
+def test_attention_block_at_published_widths(one_chip):
+    """One sequence's causal, segment-masked attention at 20 heads of
+    256 over 4,096 positions, with its recomputing backward pass."""
+    from predictionio_tpu.models import glm4_moe_lite as glm
+
+    c = glm.GlmConfig()
+    S, H, D = c.seq_len, c.num_attention_heads, c.qk_head_dim
+    qkv = _sds((S, H, D), jnp.bfloat16, one_chip)
+    seg = _sds((S,), jnp.int32, one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v, seg: glm._attention(q, k, v, seg, c).sum(),
+        (0, 1, 2))).lower(qkv, qkv, qkv, seg).compile()
+    # the scores of ONE block of query rows at a time, never the
+    # sequence's 20 × 4,096 × 4,096
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
