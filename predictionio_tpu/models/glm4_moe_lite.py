@@ -43,12 +43,13 @@ Histories are PACKED into ``seq_len``-slot sequences with segment ids
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass, fields
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
-from predictionio_tpu.ops import moe_dispatch
+from predictionio_tpu.ops import moe_dispatch, seq_attention
 
 #: what the published config may say and this file can honour
 _REQUIRED = {"hidden_act": "silu", "attention_bias": False, "n_group": 1,
@@ -92,8 +93,8 @@ class GlmConfig:
     clip_norm: float = 1.0
     init_std: float = 0.02
     matmul_dtype: str = "bfloat16"
-    #: query rows per attention block; tokens per chunk of a SwiGLU and
-    #: of the loss: what bounds the program's temporaries
+    #: most query rows an attention tile holds; tokens per chunk of a
+    #: SwiGLU and of the loss: what bounds the program's temporaries
     attn_block: int = 512
     token_chunk: int = 4096
 
@@ -373,6 +374,13 @@ def pack_histories(histories: Sequence[Sequence[int]], seq_len: int,
 # -- the block ----------------------------------------------------------------
 
 
+def _attn_tiles(c: GlmConfig, S: int) -> Tuple[int, int]:
+    """(query rows, keys) of an attention tile on ``S`` slots: the
+    most that ``attn_block`` and the kernels' key tile allow and that
+    divide S."""
+    return math.gcd(c.attn_block, S), math.gcd(seq_attention.KEY_TILE, S)
+
+
 def _dt(c: GlmConfig):
     import jax.numpy as jnp
 
@@ -409,39 +417,14 @@ def _rope(x, pos, theta: float):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
-def _attend_block(q, k, v, seg, row0: int, bq: int, scale: float):
-    """Query rows ``row0 … row0+bq`` of one sequence against the keys
-    up to its last row: q, k, v [S, H, D] WHOLE (sliced in here, so
-    that the backward pass keeps one k and one v, not a slice for
-    every block). Recomputed in the backward pass: the scores are never
-    kept."""
-    import jax
-    import jax.numpy as jnp
-
-    hi = row0 + bq
-    s = jnp.einsum("qhd,khd->hqk", q[row0:hi], k[:hi],
-                   preferred_element_type=jnp.float32) * scale
-    rows = jnp.arange(row0, hi)[:, None]
-    mask = ((seg[row0:hi, None] == seg[None, :hi])
-            & (rows >= jnp.arange(hi)[None, :]) & (seg[row0:hi, None] > 0))
-    p = jax.nn.softmax(jnp.where(mask[None], s, -1e30), axis=-1)
-    return jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v[:hi],
-                      preferred_element_type=jnp.float32)
-
-
 def _attention(q, k, v, seg, c: GlmConfig):
     """Causal, segment-masked attention of ONE sequence: [S, H, D]
-    each, query rows in blocks that skip the keys behind the
-    diagonal."""
-    import jax
-    import jax.numpy as jnp
-
-    S = q.shape[0]
-    bq = min(c.attn_block, S)
-    block = jax.checkpoint(_attend_block, static_argnums=(4, 5, 6))
-    return jnp.concatenate([
-        block(q, k, v, seg, a, bq, 1.0 / np.sqrt(c.qk_head_dim))
-        for a in range(0, S, bq)], axis=0)
+    each → [S, H, Dv] in v's dtype. Tiles of at most ``attn_block``
+    query rows, and only those between a block's earliest segment and
+    the diagonal (:mod:`predictionio_tpu.ops.seq_attention`)."""
+    return seq_attention.segment_attention(
+        q, k, v, seg, *_attn_tiles(c, q.shape[0]),
+        1.0 / np.sqrt(c.qk_head_dim))
 
 
 def _mla(w, x, seg, pos, c: GlmConfig):
@@ -472,7 +455,10 @@ def _mla(w, x, seg, pos, c: GlmConfig):
         with jax.named_scope("seqrec.mla.attention"):
             out = _attention(q.astype(_dt(c)), k.astype(_dt(c)),
                              kv[..., dn:].astype(_dt(c)), seg, c)
-        return _mm(out.reshape(S, H * dv), w["wo"], c)
+        # W_o takes the heads' outputs as attention leaves them: ONE
+        # array for both backward passes to keep, not it and a reshape
+        return jnp.tensordot(out, w["wo"].astype(_dt(c)).reshape(H, dv, -1),
+                             2, preferred_element_type=jnp.float32)
 
     return jax.lax.map(one, (x, seg, pos))
 
@@ -763,6 +749,14 @@ def glm_train(histories: Sequence[Sequence[int]], c: GlmConfig,
                              f"{c.vocab_size} rows")
         for k, v in packed.counters.items():
             sp.set_attr(k, v)
+        # pairs inside the tiles attention visits (one epoch and head),
+        # and inside those that blocks of ``attn_block`` rows walking
+        # to the diagonal would
+        bq, bk = _attn_tiles(c, c.seq_len)
+        sp.set_attr("attn_tile_pairs",
+                    seq_attention.tile_pairs(packed.seg, bq, bk))
+        sp.set_attr("attn_dense_pairs",
+                    seq_attention.tile_pairs(packed.seg, bq, bq, skip=False))
     with tracing.span("seqrec.init") as sp:
         data = _device_batches(packed, c)
         params, opt_state, bias = init_state(c, seed, with_optimizer=True)

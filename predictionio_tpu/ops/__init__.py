@@ -7,10 +7,14 @@ Pallas TPU kernels for the ops where fusion/streaming matters:
 - :mod:`.gram` — batched weighted Gram accumulation (the ALS inner op).
 - :mod:`.topk` — streaming score+top-k over item tiles (serving path).
 - :mod:`.segment` — segment reductions (Naive Bayes, CCO counts).
+- :mod:`.seq_attention` — causal attention inside the segments of a
+  packed sequence, only the tiles a segment reaches (sequence backbone).
 
-Every Pallas kernel has an XLA twin; ``use_pallas()`` decides by
-platform (compiled on TPU, XLA elsewhere, interpret-mode in tests) —
-by rule, never by trying the kernel and catching its failure.
+The kernels above the last have an XLA twin; ``use_pallas()`` decides
+by platform (compiled on TPU, XLA elsewhere, interpret-mode in tests)
+— by rule, never by trying the kernel and catching its failure.
+:mod:`.seq_attention` has no twin: compiled for a TPU, interpreted
+anywhere else, decided where the program is lowered.
 """
 
 from predictionio_tpu.ops.gram import (gather_gram, gather_gram_xla,

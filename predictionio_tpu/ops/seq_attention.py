@@ -1,0 +1,352 @@
+"""Causal attention inside the segments of a packed sequence, tile by
+tile — three Pallas kernels (forward, dq, dk/dv) under one
+``jax.custom_vjp``.
+
+A packed sequence holds many histories back to back
+(``models/glm4_moe_lite.pack_histories``); a query row sees the keys of
+its OWN segment up to itself, i.e. the keys ``first[r] … r`` where
+``first[r]`` is the first row of r's segment. Two things follow:
+
+- **Only the tiles a segment reaches are visited.** A block of ``bq``
+  query rows needs the key tiles from the one holding the first key of
+  its EARLIEST segment up to its diagonal tile; a key tile is needed
+  by the query blocks from its diagonal one to the last whose earliest
+  segment starts at or before the tile's last key. Segments are
+  contiguous, so both are intervals (:func:`tile_intervals`); they
+  reach the kernels as scalar-prefetched loop bounds, and a tile
+  outside them is neither read nor multiplied.
+- **A tile's scores live on the chip only.** The forward pass keeps a
+  running maximum, a running sum and the output accumulator in float32
+  (the online softmax) and leaves the per-row log-sum-exp; the backward
+  kernels recompute a tile's probabilities from it. Nothing of size
+  ``rows × keys`` is ever in HBM.
+
+The kernels see the arrays head-major ([H, S, D]; where they are made
+XLA lays them out so, and the transposes cost no copy). The operand
+that a kernel walks over (k and v for forward and dq, q and the
+output's cotangent for dk/dv) stays in VMEM for a whole head: one grid
+step is one block of rows of one head, the walk over its tiles is a
+loop INSIDE the kernel, so a sequence of many short segments costs no
+grid steps for the tiles it skips.
+
+Precision: operands as given (bfloat16 in training), scores, mask,
+maximum, sum and accumulators float32, the probabilities enter the
+second product in the operands' dtype. Rows of segment 0 (padding) see
+no key: their output is finite and means nothing, their gradients are
+zero, and no real row depends on them.
+
+Compiled for a TPU, interpreted anywhere else — decided when the
+program is LOWERED (``jax.lax.platform_dependent``), so a program
+lowered for a described chip from a CPU process gets the kernels.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: keys a tile holds at most (its rows are the caller's ``bq``). On the
+#: v5e, 20 heads of 256 over 4,096 slots packed ~30 segments a
+#: sequence: 512 rows × 512 keys and 256 × 256 take the same time
+#: (wider tiles multiply faster, 99 against 55 TFLOP/s forward, and
+#: visit more: 30 % against 47 % of the pairs inside them are real);
+#: 128 keys cost a third more (PERF.md §6, PR 32)
+KEY_TILE = 512
+_LANES = 128
+#: what a masked score is set to; finite, so a row whose first visited
+#: tile holds none of its keys gives exp(0) there and is wiped by the
+#: first real maximum
+_MASKED = -1e30
+_NT = (((1,), (1,)), ((), ()))   # a · bᵀ
+_VMEM_LIMIT = 96 * 1024 * 1024
+
+
+# -- which tiles ---------------------------------------------------------------
+
+
+def first_keys(seg, xp=jnp):
+    """Per row of ``seg`` [..., S], the first row of its segment (a
+    maximal run of one id); ``r + 1`` for a padding row (id 0), which
+    so has no key at all. ``xp``: numpy on the host, jax.numpy in a
+    program — the same lines count the tiles and steer the kernels."""
+    S = seg.shape[-1]
+    r = xp.arange(S, dtype=xp.int32)
+    new = xp.concatenate([xp.ones_like(seg[..., :1], dtype=bool),
+                          seg[..., 1:] != seg[..., :-1]], axis=-1)
+    starts = xp.where(new, r, 0)
+    # NOT jnp.maximum.accumulate: that is a scan of S sequential steps,
+    # 8 ms a call on the chip where the whole forward kernel takes less
+    first = (np.maximum.accumulate(starts, axis=-1) if xp is np
+             else jax.lax.cummax(starts, axis=starts.ndim - 1))
+    return xp.where(seg > 0, first, r + 1).astype(xp.int32)
+
+
+def tile_intervals(first, bq: int, bk: int, xp=jnp):
+    """(lo [..., S/bq], hi [..., S/bk]) from :func:`first_keys`: query
+    block i visits key tiles ``lo[i] … diagonal``, key tile j is
+    visited by query blocks ``diagonal … hi[j]``. ``first`` never
+    falls along a sequence, so ``lo`` does not either and the blocks
+    that reach tile j are the first ``hi[j] + 1``."""
+    S = first.shape[-1]
+    lead = first.shape[:-1]
+    diag = ((xp.arange(S // bq, dtype=xp.int32) + 1) * bq - 1) // bk
+    lo = xp.minimum(first.reshape(lead + (S // bq, bq)).min(-1) // bk, diag)
+    tiles = xp.arange(S // bk, dtype=xp.int32)
+    hi = (lo[..., None, :] <= tiles[:, None]).sum(-1) - 1
+    return lo.astype(xp.int32), hi.astype(xp.int32)
+
+
+def tile_pairs(seg: np.ndarray, bq: int, bk: int,
+               skip: bool = True) -> int:
+    """(query, key) pairs inside the tiles the forward pass visits for
+    the sequences ``seg`` [N, S] (``skip`` False: inside those a walk
+    from the first tile to the diagonal would) — one head, counted on
+    the host by the function that steers the kernels."""
+    lo, _ = tile_intervals(first_keys(seg, np), bq, bk, np)
+    diag = ((np.arange(seg.shape[-1] // bq) + 1) * bq - 1) // bk
+    return int((diag - lo * skip + 1).sum()) * bq * bk
+
+
+# -- the kernels ---------------------------------------------------------------
+
+
+def _scores(q, k, first, rows, at, scale):
+    """One tile: float32 scores [bq, bk] and which of them are real —
+    key ``at + column`` lies in ``first … row`` of its query row."""
+    s = jax.lax.dot_general(q, k, _NT,
+                            preferred_element_type=jnp.float32) * scale
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return s, (col >= first - at) & (col <= rows - at)
+
+
+def _rows_of(block, bq):
+    return block * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+
+
+def _fwd_kernel(lo_ref, q_ref, k_ref, v_ref, first_ref, o_ref, lse_ref,
+                m_ref, l_ref, acc_ref, *, scale, bk):
+    i = pl.program_id(1)
+    bq = q_ref.shape[0]
+    q, first, rows = q_ref[...], first_ref[:, :1], _rows_of(i, bq)
+    m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def tile(j, _):
+        at = pl.multiple_of(j * bk, bk)
+        s, real = _scores(q, k_ref[pl.ds(at, bk), :], first, rows, at, scale)
+        s = jnp.where(real, s, _MASKED)
+        m = jnp.maximum(m_ref[...], s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_ref[...] - m)
+        p = jnp.exp(s - m)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[pl.ds(at, bk), :],
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m
+        return _
+
+    jax.lax.fori_loop(lo_ref[i], ((i + 1) * bq - 1) // bk + 1, tile, None)
+    o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    lse_ref[...] = jnp.broadcast_to(m_ref[...] + jnp.log(l_ref[...]),
+                                    lse_ref.shape)
+
+
+def _dq_kernel(lo_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               first_ref, dq_ref, acc_ref, *, scale, bk):
+    """A block of query rows against the key tiles it reaches: the
+    probabilities recomputed from the rows' log-sum-exp, the scores'
+    cotangent, its product with the keys."""
+    i = pl.program_id(1)
+    bq = q_ref.shape[0]
+    q, do, rows = q_ref[...], do_ref[...], _rows_of(i, bq)
+    lse, delta, first = lse_ref[:, :1], delta_ref[:, :1], first_ref[:, :1]
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def tile(j, _):
+        at = pl.multiple_of(j * bk, bk)
+        k = k_ref[pl.ds(at, bk), :]
+        s, real = _scores(q, k, first, rows, at, scale)
+        p = jnp.where(real, jnp.exp(s - lse), 0.0)
+        dp = jax.lax.dot_general(do, v_ref[pl.ds(at, bk), :], _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta) * scale
+        acc_ref[...] += jnp.dot(ds.astype(k.dtype), k,
+                                preferred_element_type=jnp.float32)
+        return _
+
+    jax.lax.fori_loop(lo_ref[i], ((i + 1) * bq - 1) // bk + 1, tile, None)
+    dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(hi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                first_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, bq):
+    """A key tile against the query blocks that reach it, TRANSPOSED:
+    scores [keys, rows], so that both products into dk and dv take
+    their left operand as it lies and nothing is turned; the rows'
+    numbers come as rows ([blocks, 1, bq], a block by its index)."""
+    j = pl.program_id(1)
+    bk = k_ref.shape[0]
+    k, v = k_ref[...], v_ref[...]
+    keys = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+    dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+    dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    def block(i, _):
+        rs = pl.ds(pl.multiple_of(i * bq, bq), bq)
+        q, do = q_ref[rs, :], do_ref[rs, :]
+        rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+        s = jax.lax.dot_general(k, q, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        p = jnp.where((keys >= first_ref[i]) & (keys <= rows),
+                      jnp.exp(s - lse_ref[i]), 0.0)
+        dp = jax.lax.dot_general(v, do, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[i]) * scale
+        dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dk_acc[...] += jnp.dot(ds.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32)
+        return _
+
+    jax.lax.fori_loop(j * bk // bq, hi_ref[j] + 1, block, None)
+    dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+    dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+# -- the calls -----------------------------------------------------------------
+
+
+def _head(rows: int, width: int, whole: bool = False):
+    """``rows`` rows of the grid's head ([H, S, width]): the grid's
+    block of them, or (``whole``) all — fetched once a head, walked by
+    the kernel's own loop."""
+    return pl.BlockSpec((None, rows, width),
+                        lambda h, i, _: (h, 0 if whole else i, 0))
+
+
+def _first(rows: int):
+    """The grid's block of every row's first key (one for all heads)."""
+    return pl.BlockSpec((rows, _LANES), lambda h, i, _: (i, 0))
+
+
+def _call(kernel, name, blocks, bounds, operands, in_specs, outs, out_specs,
+          scratch, interpret):
+    """One kernel over the grid (heads, blocks of rows), its loop
+    bounds prefetched into SMEM."""
+    return pl.pallas_call(
+        kernel, name=name, out_shape=outs, interpret=interpret,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(operands[0].shape[0], blocks),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+    )(bounds, *operands)
+
+
+def _by_platform(run, *args):
+    return jax.lax.platform_dependent(
+        *args, tpu=functools.partial(run, interpret=False),
+        default=functools.partial(run, interpret=True))
+
+
+def _forward(q, k, v, first, lo, bq, bk, scale, interpret):
+    H, S, D = q.shape
+    Dv = v.shape[-1]
+    return _call(
+        functools.partial(_fwd_kernel, scale=scale, bk=bk),
+        "seq_attention_fwd", S // bq, lo, (q, k, v, _lanes(first)),
+        [_head(bq, D), _head(S, D, True), _head(S, Dv, True), _first(bq)],
+        [jax.ShapeDtypeStruct(v.shape, v.dtype),
+         jax.ShapeDtypeStruct((H, S, _LANES), jnp.float32)],
+        [_head(bq, Dv), _head(bq, _LANES)],
+        [pltpu.VMEM((bq, 1), jnp.float32), pltpu.VMEM((bq, 1), jnp.float32),
+         pltpu.VMEM((bq, Dv), jnp.float32)], interpret)
+
+
+def _backward(q, k, v, do, lse, delta, first, lo, hi, bq, bk, scale,
+              interpret):
+    """``lse``, ``delta`` [H, S] and ``first`` [S]: dq reads a row's
+    number as a column (its 128 lanes), dk/dv as part of a row."""
+    H, S, D = q.shape
+    Dv = v.shape[-1]
+    dq, = _call(
+        functools.partial(_dq_kernel, scale=scale, bk=bk),
+        "seq_attention_dq", S // bq, lo,
+        (q, k, v, do, _lanes(lse), _lanes(delta), _lanes(first)),
+        [_head(bq, D), _head(S, D, True), _head(S, Dv, True), _head(bq, Dv),
+         _head(bq, _LANES), _head(bq, _LANES), _first(bq)],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype)], [_head(bq, D)],
+        [pltpu.VMEM((bq, D), jnp.float32)], interpret)
+    as_rows = pl.BlockSpec((None, S // bq, 1, bq), lambda h, j, _: (h, 0, 0, 0))
+    dk, dv = _call(
+        functools.partial(_dkv_kernel, scale=scale, bq=bq),
+        "seq_attention_dkv", S // bk, hi,
+        (q, k, v, do, lse.reshape(H, -1, 1, bq), delta.reshape(H, -1, 1, bq),
+         first.reshape(-1, 1, bq)),
+        [_head(S, D, True), _head(bk, D), _head(bk, Dv), _head(S, Dv, True),
+         as_rows, as_rows,
+         pl.BlockSpec((S // bq, 1, bq), lambda h, j, _: (0, 0, 0))],
+        [jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [_head(bk, D), _head(bk, Dv)],
+        [pltpu.VMEM((bk, D), jnp.float32), pltpu.VMEM((bk, Dv), jnp.float32)],
+        interpret)
+    return dq, dk, dv
+
+
+def _lanes(x):
+    """[..., S] → [..., S, 128]: a per-row number where a kernel can
+    read it as a column."""
+    return jnp.broadcast_to(x[..., None], x.shape + (_LANES,))
+
+
+def _heads_first(x):
+    """[S, H, D] ↔ [H, S, D]: the kernels' grid walks heads, and a
+    head's rows are one block. XLA gives the arrays this layout where
+    they are made; no copy comes of it."""
+    return x.transpose(1, 0, 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def segment_attention(q, k, v, seg, bq: int, bk: int, scale: float):
+    """softmax(scale · q kᵀ, causal AND inside one segment) v for ONE
+    packed sequence: q, k [S, H, D], v [S, H, Dv], ``seg`` [S] int32
+    (0 = padding) → [S, H, Dv] in v's dtype. ``bq`` query rows and
+    ``bk`` keys a tile; both divide S."""
+    return _attend(q, k, v, seg, bq, bk, scale)[0]
+
+
+def _attend(q, k, v, seg, bq, bk, scale):
+    S = q.shape[0]
+    if S % bq or S % bk:
+        raise ValueError(f"tiles of {bq} rows × {bk} keys do not divide "
+                         f"a sequence of {S}")
+    first = first_keys(seg)
+    lo, _ = tile_intervals(first, bq, bk)
+    out, lse = _by_platform(
+        functools.partial(_forward, bq=bq, bk=bk, scale=scale),
+        *map(_heads_first, (q, k, v)), first, lo)
+    out = _heads_first(out)
+    # one number a row is kept for the backward pass, not its 128 lanes
+    return out, (q, k, v, out, lse[..., 0], seg)
+
+
+def _attend_bwd(bq, bk, scale, res, do):
+    q, k, v, out, lse, seg = res
+    first = first_keys(seg)
+    lo, hi = tile_intervals(first, bq, bk)
+    delta = (out.astype(jnp.float32) * do.astype(jnp.float32)).sum(-1).T
+    grads = _by_platform(
+        functools.partial(_backward, bq=bq, bk=bk, scale=scale),
+        *map(_heads_first, (q, k, v, do)), lse, delta, first, lo, hi)
+    return (*map(_heads_first, grads), None)
+
+
+segment_attention.defvjp(_attend, _attend_bwd)

@@ -237,7 +237,9 @@ def test_expert_dispatch_at_published_widths(one_chip, held):
 
 def test_attention_block_at_published_widths(one_chip):
     """One sequence's causal, segment-masked attention at 20 heads of
-    256 over 4,096 positions, with its recomputing backward pass."""
+    256 over 4,096 positions, forward and backward: the three kernels
+    of ops/seq_attention, chosen because the program is lowered FOR a
+    TPU (this process is a CPU one)."""
     from predictionio_tpu.models import glm4_moe_lite as glm
 
     c = glm.GlmConfig()
@@ -245,8 +247,28 @@ def test_attention_block_at_published_widths(one_chip):
     qkv = _sds((S, H, D), jnp.bfloat16, one_chip)
     seg = _sds((S,), jnp.int32, one_chip)
     compiled = jax.jit(jax.grad(
-        lambda q, k, v, seg: glm._attention(q, k, v, seg, c).sum(),
+        lambda q, k, v, seg: glm._attention(q, k, v, seg, c).astype(
+            jnp.float32).sum(),
         (0, 1, 2))).lower(qkv, qkv, qkv, seg).compile()
-    # the scores of ONE block of query rows at a time, never the
-    # sequence's 20 × 4,096 × 4,096
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # no score block in HBM (20 × 512 × 4,096 float32 was 0.17 GB, and
+    # the sequence's 20 × 4,096 × 4,096 would be 1.3 GB): the rows'
+    # statistics in their 128 lanes and the cotangents only
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_attention_at_the_sample_widths(one_chip, dtype):
+    """The benchmark's ``sample`` configuration on a real chip: heads
+    16 (keys) and 8 (values) wide, narrower than a lane tile, 64 slots
+    in tiles of 32 rows. The same three kernels take them — there is no
+    dense path to fall back to."""
+    from predictionio_tpu.ops import seq_attention
+
+    S, H = 64, 2
+    q, v = (_sds((S, H, D), jnp.dtype(dtype), one_chip) for D in (16, 8))
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v, seg: seq_attention.segment_attention(
+            q, k, v, seg, 32, 64, 0.25).astype(jnp.float32).sum(),
+        (0, 1, 2))).lower(q, q, v, _sds((S,), jnp.int32, one_chip)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3

@@ -1,0 +1,215 @@
+"""ops/seq_attention: the tiled walk over a packed sequence against the
+dense masked softmax (forward and gradients, the kernels in interpret
+mode), the tiles it skips, and the count of those it visits."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from predictionio_tpu.models import glm4_moe_lite as glm
+from predictionio_tpu.ops import seq_attention as sa
+
+H, D, DV = 2, 16, 8
+
+
+def _dense(q, k, v, seg, scale):
+    """The plain form: every score of the sequence, masked (same
+    segment, causal, not padding), one softmax."""
+    r = jnp.arange(q.shape[0])
+    mask = ((seg[:, None] == seg[None, :]) & (r[:, None] >= r[None, :])
+            & (seg[:, None] > 0))
+    s = jnp.einsum("qhd,khd->hqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(mask[None], s, -1e30), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+def _segments(*lengths, S):
+    """Segments 1, 2, … of these lengths from slot 0, padding behind."""
+    seg = np.zeros(S, np.int32)
+    at = 0
+    for j, n in enumerate(lengths):
+        seg[at:at + n] = j + 1
+        at += n
+    assert at <= S
+    return seg
+
+
+#: name → (segment ids, query rows a tile, keys a tile[, head widths])
+PACKINGS = {
+    "one_segment": (_segments(128, S=128), 32, 32),
+    "many_short": (_segments(*([5, 9, 2, 17, 3, 11, 7] * 4), S=256), 32, 32),
+    "crosses_three_tiles": (_segments(20, 70, 38, S=128), 32, 32),
+    "ends_on_tile_edges": (_segments(32, 64, 32, S=128), 32, 32),
+    "padding_tail": (_segments(40, 30, S=128), 32, 32),
+    "one_tile": (_segments(12, 20, S=32), 32, 32),
+    "wide_key_tiles": (_segments(50, 90, 40, 60, S=256), 32, 128),
+    "narrow_key_tiles": (_segments(50, 90, 40, 60, S=256), 64, 16),
+    "lane_wide_heads": (_segments(20, 70, 30, S=128), 32, 64, 128, 128),
+    "lane_wide_heads_one_tile": (_segments(12, 20, S=32), 32, 32, 256, 128),
+}
+
+
+def _operands(S, dtype, D=D, DV=DV):
+    rng = np.random.default_rng(0)
+    q, k = (jnp.asarray(rng.normal(size=(S, H, D)), dtype) for _ in range(2))
+    return q, k, jnp.asarray(rng.normal(size=(S, H, DV)), dtype)
+
+
+def _value_and_grads(attend, q, k, v, seg):
+    """Σ w · attend(q, k, v) over the REAL rows and its gradients."""
+    w = jnp.asarray(np.random.default_rng(9).normal(size=v.shape)
+                    * (seg > 0)[:, None, None], jnp.float32)
+    return jax.jit(jax.value_and_grad(
+        lambda q, k, v: (attend(q, k, v).astype(jnp.float32) * w).sum(),
+        (0, 1, 2)))(q, k, v)
+
+
+def _tiled(seg, bq, bk):
+    return lambda q, k, v: sa.segment_attention(
+        q, k, v, jnp.asarray(seg), bq, bk, q.shape[-1] ** -0.5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+def test_tiled_equals_dense_forward_and_gradients(packing, dtype):
+    seg, bq, bk, *widths = PACKINGS[packing]
+    q, k, v = _operands(len(seg), dtype, *widths)
+    real = seg > 0
+    out = _tiled(seg, bq, bk)(q, k, v)
+    assert out.dtype == v.dtype and bool(jnp.isfinite(out).all())
+    want = _dense(q, k, v, jnp.asarray(seg), q.shape[-1] ** -0.5)
+    # float32: the same sums in another order; bfloat16: the
+    # probabilities are rounded before they are normalised, not after
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32)[real],
+                               np.asarray(want)[real], atol=tol, rtol=tol)
+    got = _value_and_grads(_tiled(seg, bq, bk), q, k, v, seg)
+    ref = _value_and_grads(
+        lambda q, k, v: _dense(q, k, v, jnp.asarray(seg), q.shape[-1] ** -0.5), q, k, v, seg)
+    for name, a, b in zip("qkv", got[1], ref[1]):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert a.dtype == b.dtype and np.isfinite(a).all()
+        scale = np.sqrt((b ** 2).mean())
+        assert np.abs(a - b).max() <= 2.5 * tol * max(scale, 1.0), name
+        # a padding row has no key and is nobody's key
+        assert not a[~real].any(), name
+
+
+def test_a_skipped_tile_is_never_read():
+    """Two tile-aligned segments, the first one's keys and values NaN:
+    the second's outputs and gradients are those of the clean run — a
+    masked product would have given 0 · NaN."""
+    seg, bq, bk = _segments(64, 64, S=128), 32, 32
+    q, k, v = _operands(128, jnp.float32)
+    second = seg == 2
+    rows = jnp.asarray(second)[:, None, None]
+    poison = jnp.where(jnp.asarray(seg == 1)[:, None, None], jnp.nan, 0.0)
+
+    def run(k, v):
+        out, grads = jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.where(
+                rows, _tiled(seg, bq, bk)(q, k, v), 0.0).sum(),
+            (0, 1, 2)))(q, k, v)
+        return [np.asarray(out)] + [np.asarray(g)[second] for g in grads]
+
+    clean, dirty = run(k, v), run(k + poison, v + poison)
+    for a, b in zip(clean, dirty):
+        assert np.isfinite(b).all()
+        np.testing.assert_array_equal(a, b)
+
+
+def test_intervals_and_tile_pairs_by_hand():
+    """Three sequences of 8 slots, tiles of 2 rows × 2 keys (or 4 × 2):
+    which tiles each block visits, counted by hand."""
+    seg = np.array([[1, 1, 1, 1, 1, 1, 1, 1],      # one history
+                    [1, 1, 2, 2, 2, 3, 3, 0],      # 2 + 3 + 2, a PAD
+                    [1, 2, 2, 0, 0, 0, 0, 0]],     # mostly padding
+                   np.int32)
+    first = sa.first_keys(seg, np)
+    np.testing.assert_array_equal(first, [[0, 0, 0, 0, 0, 0, 0, 0],
+                                          [0, 0, 2, 2, 2, 5, 5, 8],
+                                          [0, 1, 1, 4, 5, 6, 7, 8]])
+    np.testing.assert_array_equal(first, sa.first_keys(jnp.asarray(seg)))
+    lo, hi = sa.tile_intervals(first, 2, 2, np)
+    # block i of rows 2i, 2i+1 starts at the tile of its earliest first
+    # key; tile j ends at the last block that starts at or before it
+    np.testing.assert_array_equal(lo, [[0, 0, 0, 0], [0, 1, 1, 2],
+                                       [0, 0, 2, 3]])
+    np.testing.assert_array_equal(hi, [[3, 3, 3, 3], [0, 2, 3, 3],
+                                       [1, 1, 2, 3]])
+    for got, want in zip(sa.tile_intervals(jnp.asarray(first), 2, 2),
+                         (lo, hi)):
+        np.testing.assert_array_equal(got, want)
+    # tiles visited: 1+2+3+4, 1+1+2+2, 1+2+1+1 = 21 of 4 pairs each;
+    # to the diagonal alone 10 tiles a sequence
+    assert sa.tile_pairs(seg, 2, 2) == 84
+    assert sa.tile_pairs(seg, 2, 2, skip=False) == 120
+    # blocks of 4 rows: tiles 0-1 and 0-3; 0-1 and 1-3; 0-1 and 2-3
+    lo4, hi4 = sa.tile_intervals(first, 4, 2, np)
+    np.testing.assert_array_equal(lo4, [[0, 0], [0, 1], [0, 2]])
+    np.testing.assert_array_equal(hi4, [[1, 1, 1, 1], [0, 1, 1, 1],
+                                        [0, 0, 1, 1]])
+    assert sa.tile_pairs(seg, 4, 2) == (6 + 5 + 4) * 8
+    assert sa.tile_pairs(seg, 4, 2, skip=False) == 3 * 6 * 8
+
+
+def test_every_real_pair_lies_in_a_visited_tile():
+    """The packer's own sequences: each real (query, key) pair is inside
+    the forward interval of its block and the backward interval of its
+    tile, and the two walks visit the same tiles."""
+    rng = np.random.default_rng(4)
+    packed = glm.pack_histories(
+        [rng.integers(1, 50, n) for n in rng.integers(2, 90, 40)], 128, 1)
+    for bq, bk in ((32, 32), (32, 16), (16, 64)):
+        first = sa.first_keys(packed.seg, np)
+        lo, hi = sa.tile_intervals(first, bq, bk, np)
+        tiles = 0
+        for s in range(packed.seg.shape[0]):
+            fwd = {(i, j) for i in range(128 // bq)
+                   for j in range(lo[s, i], ((i + 1) * bq - 1) // bk + 1)}
+            bwd = {(i, j) for j in range(128 // bk)
+                   for i in range(j * bk // bq, hi[s, j] + 1)}
+            assert fwd == bwd
+            tiles += len(fwd)
+            real = np.flatnonzero(packed.seg[s] > 0)
+            assert all((r // bq, key // bk) in fwd
+                       for r in real for key in (first[s, r], r))
+        assert sa.tile_pairs(packed.seg, bq, bk) == tiles * bq * bk
+
+
+def test_tiles_must_divide_the_sequence():
+    q, k, v = _operands(96, jnp.float32)
+    with pytest.raises(ValueError, match="do not divide"):
+        sa.segment_attention(q, k, v, jnp.ones(96, jnp.int32), 64, 32, 1.0)
+
+
+def test_the_model_counts_its_tiles():
+    """``glm_train`` records the pairs inside the tiles its attention
+    visits beside the real ones, by the tiles ``_attention`` uses."""
+    from predictionio_tpu.utils import tracing
+
+    c = glm.GlmConfig(
+        hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
+        num_attention_heads=2, q_lora_rank=16, kv_lora_rank=16,
+        qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+        n_routed_experts=4, num_experts_per_tok=2, num_hidden_layers=2,
+        vocab_size=40, seq_len=64, seqs_per_step=1, attn_block=16,
+        token_chunk=64)
+    rng = np.random.default_rng(2)
+    hist = [rng.integers(1, 40, n) for n in (30, 20, 9, 5)]
+    with tracing.verb("train.run"):
+        glm.glm_train(hist, c, 1, 1e-3, 0)
+    attrs = next(s["attrs"] for s in tracing.last_verb("train.run")
+                 if s["name"] == "seqrec.pack")
+    assert glm._attn_tiles(c, 64) == (16, 64)
+    # one sequence of 64 slots, key tiles as wide as the sequence: four
+    # blocks of 16 rows, each visiting the one tile
+    assert attrs["attn_tile_pairs"] == 4 * 16 * 64
+    # today's block walk to the diagonal: 16 × (16 + 32 + 48 + 64)
+    assert attrs["attn_dense_pairs"] == 16 * 160
+    assert attrs["attn_pairs"] == sum(n * (n + 1) // 2
+                                      for n in (30, 20, 9, 5))
