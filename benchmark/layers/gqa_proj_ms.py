@@ -1,0 +1,12 @@
+"""Layer "kernels": device milliseconds of ONE traced train under the
+scope ``seqrec.gqa`` outside its attention product: the projections, the
+heads' norms, RoPE and W_o (``scope_reduce``: the operations' ``tf_op``
+paths), forward, recomputation and backward. Absent where the trace names
+no such scope."""
+
+import roofline_lfm2
+
+
+def read(obs):
+    secs = roofline_lfm2.seconds(obs, "gqa_proj")
+    return None if secs is None else secs * 1e3

@@ -1,0 +1,580 @@
+"""What the block-stack backbones of the ``sequentialrec`` template
+share, and the table that names them.
+
+A backbone is a module of this package that trains a published block
+on PACKED item histories (the item catalog in place of the token
+vocabulary; id 0 is PAD) as one chip's share of an expert-parallel
+job. Each keeps only what is its block's: its config, parameter
+shapes, operators and stack. Everything else is here, once:
+
+- the table: ``architecture["model_type"]`` → :class:`Backbone`
+  (:func:`backbone`);
+- :func:`pack_histories`: histories → ``seq_len``-slot sequences with
+  segment ids, positions that restart with each segment, targets that
+  never cross a segment's end;
+- the pieces of a block: ``_mm`` (operands in the matmul dtype,
+  float32 accumulation), ``_rms``, ``_rope``, :func:`attention`
+  (:mod:`predictionio_tpu.ops.seq_attention`), ``_swiglu``, ``_moe``
+  (:mod:`predictionio_tpu.ops.moe_dispatch`; a shared expert where the
+  layer's weights hold one), ``_cast_in_loop``, ``_chunked_ce``;
+- :func:`init_program` (one jitted program makes parameters, Adam's
+  state and the router bias on the device), :func:`train_program` (the
+  step: gradients, group norms, clipping, Adam, the router-bias rule;
+  a scan over epochs of a scan over steps) and :func:`train_histories`
+  (the verb's ``seqrec.pack`` / ``.init`` / ``.fit`` / ``.fetch`` spans
+  with their counters, through ``seq_rec.run_epoch_blocks``);
+- :func:`next_item_scores`: one history, one segment, through the
+  same stack.
+
+Precision, for every backbone: float32 master parameters, gradients,
+Adam moments and residual stream; matmul operands ``matmul_dtype``
+(bfloat16) with float32 accumulation; router scores, softmax, the
+norms' statistics and the loss in float32.
+"""
+
+from __future__ import annotations
+
+import importlib
+import math
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
+
+import numpy as np
+
+from predictionio_tpu.ops import moe_dispatch, seq_attention
+
+# -- the table ----------------------------------------------------------------
+
+
+class Backbone(NamedTuple):
+    """What the template and the benchmark ask of a backbone."""
+    model_type: str
+    config: type                   # .from_architecture(arch) -> config
+    #: (histories, config, epochs, lr, seed, checkpoint_dir=) ->
+    #: ({"params", "bias"} on the host, the losses of the steps run)
+    train: Callable
+    #: (model, batch, config) -> each head's logits [B, S, V]
+    sequence_logits: Callable
+    #: (model, history, config) -> scores over the vocabulary
+    next_item_scores: Callable
+    #: per head, the name of its loss in the step's record
+    heads: Tuple[str, ...]
+    #: what a packed batch holds for this backbone (of ``Packed``)
+    batch_keys: Tuple[str, ...]
+    init_state: Callable           # (config, seed, with_optimizer=)
+    n_params: Callable             # (config) -> int
+    group_squares: Callable        # (gradient tree) -> {group: Σ g²}
+
+
+#: ``model_type`` → the module that defines ``BACKBONE``
+_MODULES = {"glm4_moe_lite": "predictionio_tpu.models.glm4_moe_lite",
+            "lfm2_moe": "predictionio_tpu.models.lfm2_moe"}
+#: an ``architecture`` without ``model_type``, and a model saved before
+#: the table existed
+DEFAULT = "glm4_moe_lite"
+
+
+def backbone(model_type: Optional[str] = None) -> Backbone:
+    model_type = model_type or DEFAULT
+    if model_type not in _MODULES:
+        raise ValueError(f"architecture.model_type = {model_type!r}: "
+                         f"implemented are {sorted(_MODULES)}")
+    return importlib.import_module(_MODULES[model_type]).BACKBONE
+
+
+# -- packing ------------------------------------------------------------------
+
+
+class Packed(NamedTuple):
+    tokens: np.ndarray    # [N, S] int32 item ids, 0 = PAD
+    seg: np.ndarray       # [N, S] int32 segment of the slot, 0 = PAD
+    pos: np.ndarray       # [N, S] int32 position inside the segment
+    tgt1: np.ndarray      # [N, S] int32 next item of the segment, 0 = none
+    tgt2: np.ndarray      # [N, S] int32 the item after it, 0 = none
+    counters: Dict[str, int]
+
+
+def _targets(tokens: np.ndarray, seg: np.ndarray, ahead: int) -> np.ndarray:
+    out = np.zeros_like(tokens)
+    same = (seg[:, ahead:] == seg[:, :-ahead]) & (seg[:, :-ahead] > 0)
+    out[:, :-ahead] = np.where(same, tokens[:, ahead:], 0)
+    return out
+
+
+def pack_histories(histories: Sequence[Sequence[int]], seq_len: int,
+                   seqs_per_step: int = 1, seed: int = 0) -> Packed:
+    """Histories (item ids ≥ 1, oldest first) → ``seq_len``-slot
+    sequences with segment ids. A history longer than a sequence is
+    cut into ``seq_len`` pieces; pieces go whole, longest first, into
+    the first of ⌈tokens / seq_len⌉ sequences with room, and one that
+    fits nowhere whole is cut to fill the gaps. Each piece is a
+    segment: attention, a short convolution's taps, RoPE positions and
+    targets stay inside it. The sequence count is padded to a multiple
+    of ``seqs_per_step`` and the order shuffled by ``seed``."""
+    S = int(seq_len)
+    pieces: List[np.ndarray] = []
+    n_hist = n_split = 0
+    for h in histories:
+        h = np.asarray(h, np.int32)
+        h = h[h > 0]
+        if h.size < 2:
+            continue
+        n_hist += 1
+        n_split += h.size > S
+        pieces += [h[a:a + S] for a in range(0, h.size, S)]
+    if not pieces:
+        raise ValueError("no trainable history (all shorter than 2)")
+    total = sum(p.size for p in pieces)
+    n_seq = -(-total // S)
+    room = np.full(n_seq, S, np.int64)
+    bins: List[List[np.ndarray]] = [[] for _ in range(n_seq)]
+    left: List[np.ndarray] = []
+    for p in sorted(pieces, key=lambda p: -p.size):
+        fit = np.flatnonzero(room >= p.size)
+        if fit.size:
+            bins[fit[0]].append(p)
+            room[fit[0]] -= p.size
+        else:
+            left.append(p)
+    for p in left:
+        n_split += 1
+        while p.size:
+            b = int(np.argmax(room > 0))
+            take = int(min(room[b], p.size))
+            bins[b].append(p[:take])
+            room[b] -= take
+            p = p[take:]
+    n_all = -(-n_seq // seqs_per_step) * seqs_per_step
+    tokens = np.zeros((n_all, S), np.int32)
+    seg = np.zeros((n_all, S), np.int32)
+    pos = np.zeros((n_all, S), np.int32)
+    for b, rows in enumerate(bins):
+        at = 0
+        for j, p in enumerate(rows):
+            tokens[b, at:at + p.size] = p
+            seg[b, at:at + p.size] = j + 1
+            pos[b, at:at + p.size] = np.arange(p.size)
+            at += p.size
+    order = np.random.default_rng(seed).permutation(n_all)
+    tokens, seg, pos = tokens[order], seg[order], pos[order]
+    tgt1, tgt2 = _targets(tokens, seg, 1), _targets(tokens, seg, 2)
+    sizes = np.asarray([p.size for rows in bins for p in rows], np.int64)
+    return Packed(tokens, seg, pos, tgt1, tgt2, {
+        "histories": n_hist, "split": int(n_split), "sequences": n_all,
+        "slots": n_all * S, "real_tokens": int(total),
+        # (query, key) pairs causal attention inside the segments sees
+        "attn_pairs": int((sizes * (sizes + 1) // 2).sum()),
+        "targets": int((tgt1 > 0).sum()),
+        "mtp_targets": int((tgt2 > 0).sum())})
+
+
+# -- parameter trees ----------------------------------------------------------
+
+
+def _swiglu_shapes(d: int, f: int, lead: tuple = ()) -> Dict[str, tuple]:
+    return {"wg": lead + (d, f), "wu": lead + (d, f), "wd": lead + (f, d)}
+
+
+def _is_shape(x) -> bool:
+    return isinstance(x, tuple) and all(isinstance(i, int) for i in x)
+
+
+def _stacked(tree, n: int):
+    """A layer's shapes with a leading layer axis: identical layers
+    are ONE scanned body."""
+    import jax
+
+    return jax.tree.map(lambda s: (n,) + s, tree, is_leaf=_is_shape)
+
+
+def _path_name(path) -> str:
+    return ".".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                    for k in path)
+
+
+def count_params(shapes) -> int:
+    import jax
+
+    return sum(int(np.prod(s)) for s in
+               jax.tree.leaves(shapes, is_leaf=_is_shape))
+
+
+def init_program(c, shapes, bias_shape: tuple, with_optimizer: bool):
+    """``init(seed) -> (params, [Adam's zeroed state,] router bias)``,
+    made ON the device by one jitted program: normal(0, init_std)
+    matrices, unit gains for every leaf named ``*norm``, zero bias, the
+    PAD row of the embedding zero."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.models.seq_rec import _make_tx
+
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=_is_shape)
+
+    def init(seed):
+        # the hardware generator: hundreds of millions of normals
+        # through threefry would cost more to compile than to draw
+        keys = jax.random.split(jax.random.key(seed, impl="rbg"),
+                                len(leaves))
+        out = []
+        for key, (path, shape) in zip(keys, leaves):
+            if _path_name(path).endswith("norm"):
+                out.append(jnp.ones(shape, jnp.float32))
+            else:
+                out.append(c.init_std * jax.random.normal(
+                    key, shape, jnp.float32))
+        params = jax.tree_util.tree_unflatten(treedef, out)
+        params["embed"] = params["embed"].at[0].set(0.0)
+        bias = jnp.zeros(bias_shape, jnp.float32)
+        if with_optimizer:
+            return params, _make_tx().init(params), bias
+        return params, bias
+
+    return jax.jit(init)
+
+
+# -- the pieces of a block ----------------------------------------------------
+
+
+def _attn_tiles(c, S: int) -> Tuple[int, int]:
+    """(query rows, keys) of an attention tile on ``S`` slots: the
+    most that ``attn_block`` and the kernels' key tile allow and that
+    divide S."""
+    return math.gcd(c.attn_block, S), math.gcd(seq_attention.KEY_TILE, S)
+
+
+def _dt(c):
+    import jax.numpy as jnp
+
+    return jnp.dtype(c.matmul_dtype)
+
+
+def _mm(x, w, c):
+    """Operands in the matmul dtype, float32 accumulation and result."""
+    import jax.numpy as jnp
+
+    return jnp.dot(x.astype(_dt(c)), w.astype(_dt(c)),
+                   preferred_element_type=jnp.float32)
+
+
+def _rms(x, g, eps: float):
+    import jax
+    import jax.numpy as jnp
+
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * g
+
+
+def _rope(x, pos, theta: float):
+    """Rotate-half RoPE over the last axis, in float32; ``pos``
+    broadcasts against x's leading axes."""
+    import jax.numpy as jnp
+
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos[..., None].astype(jnp.float32) * freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+def attention(q, k, v, seg, c, scale: float):
+    """Causal, segment-masked attention of ONE sequence: q [S, H, D],
+    k [S, Hkv, D], v [S, Hkv, Dv] (query head h reads key-value head
+    h ÷ (H ÷ Hkv)) → [S, H, Dv] in v's dtype. Tiles of at most
+    ``attn_block`` query rows, and only those between a block's
+    earliest segment and the diagonal
+    (:mod:`predictionio_tpu.ops.seq_attention`)."""
+    return seq_attention.segment_attention(
+        q, k, v, seg, *_attn_tiles(c, q.shape[0]), scale)
+
+
+def _swiglu(w, x, c):
+    """W_d(silu(W_g x) ⊙ W_u x), ``token_chunk`` tokens at a time; the
+    wide intermediates are recomputed in the backward pass, never
+    kept for all the tokens at once."""
+    import jax
+
+    @jax.checkpoint
+    def chunk(x):
+        return _mm(jax.nn.silu(_mm(x, w["wg"], c)) * _mm(x, w["wu"], c),
+                   w["wd"], c)
+
+    d = x.shape[-1]
+    rows = x.reshape(-1, d)
+    n = min(c.token_chunk, rows.shape[0])
+    if rows.shape[0] % n:
+        raise ValueError(f"{rows.shape[0]} tokens are no multiple of "
+                         f"token_chunk {n}")
+    return jax.lax.map(chunk, rows.reshape(-1, n, d)).reshape(x.shape)
+
+
+def _moe(w, x, valid, bias, c):
+    """x [T, d] float32 (normed) → this chip's part of the layer's
+    result [T, d], and what the step records of the routing. The
+    shared expert is added where the layer has one (``w["shared"]``)."""
+    import jax
+    import jax.numpy as jnp
+
+    E, k = c.router_experts, c.num_experts_per_tok
+    with jax.named_scope("seqrec.moe.route"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x, w["router"], precision=jax.lax.Precision.HIGHEST))
+        ids, gates = moe_dispatch.route(
+            scores, bias, k, c.routed_scaling_factor, c.norm_topk_prob)
+        p = moe_dispatch.plan(ids, c.held, E, valid)
+        load = jnp.zeros(E, jnp.float32).at[ids.reshape(-1)].add(
+            jnp.repeat(valid, k).astype(jnp.float32))
+    out = moe_dispatch.experts_swiglu(
+        x.astype(_dt(c)), w["experts"]["wg"].astype(_dt(c)),
+        w["experts"]["wu"].astype(_dt(c)), w["experts"]["wd"].astype(_dt(c)),
+        gates, p)
+    if "shared" in w:
+        with jax.named_scope("seqrec.ffn"):
+            out = out + _swiglu(w["shared"], x, c)
+    held = load[jnp.asarray(c.held)]
+    return out, {
+        "load": load, "pairs": valid.sum() * k, "pairs_here": p.pairs_here,
+        "dropped": p.pairs_here - p.rows,
+        "load_max_over_mean": held.max() / jnp.maximum(held.mean(), 1e-9)}
+
+
+def _cast_in_loop(w, c, turn, aside: Tuple[str, ...] = ("router",)):
+    """A scanned layer's matrices in the matmul dtype, those named in
+    ``aside`` left alone (the router: its product is float32), cast
+    INSIDE the loop. Left to itself the compiler hoists the casts out
+    of the loop — a bfloat16 copy of ALL the layers' weights, 0.7 GB at
+    the GLM cell's size, for the whole step — and no
+    ``optimization_barrier`` stops it; a factor of one that is computed
+    from the loop's counter ``turn`` does, in the same fused pass as
+    the cast."""
+    import jax.numpy as jnp
+
+    one = jnp.where(turn >= 0, 1.0, 0.0).astype(jnp.float32)
+    return {k: (v if k in aside else _cast_in_loop(v, c, turn, aside)
+                if isinstance(v, dict)
+                else (v * one).astype(_dt(c)) if v.ndim >= 2 else v)
+            for k, v in w.items()}
+
+
+def _chunked_ce(logits_of, x, targets, c):
+    """Σ cross-entropy over the real targets; ``logits_of`` (rows
+    [n, d] → float32 logits [n, V]) is applied ``token_chunk`` tokens
+    at a time and its logits never kept."""
+    import jax
+    import jax.numpy as jnp
+
+    d = x.shape[-1]
+    x, t = x.reshape(-1, d), targets.reshape(-1)
+    n = min(c.token_chunk, x.shape[0])
+
+    @jax.checkpoint
+    def chunk(xt):
+        x, t = xt
+        logits = logits_of(x)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        hit = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
+        return jnp.where(t > 0, lse - hit, 0.0).sum()
+
+    with jax.named_scope("seqrec.head"):
+        return jax.lax.map(chunk, (x.reshape(-1, n, d),
+                                   t.reshape(-1, n))).sum()
+
+
+# -- the train program --------------------------------------------------------
+
+
+def grad_groups(group_squares, shapes) -> Tuple[str, ...]:
+    """The parameter groups, in the order ``group_norms`` records."""
+    import jax
+    import jax.numpy as jnp
+
+    return tuple(sorted(jax.eval_shape(group_squares, jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.float32), shapes,
+        is_leaf=_is_shape))))
+
+
+def train_program(c, epochs: int, loss_fn, group_squares,
+                  groups: Tuple[str, ...]):
+    """``train(state, data) -> (state, records)``: ``epochs`` passes
+    over ``data`` ([steps, B, S] per key) as ONE compiled program, a
+    scan over epochs of a scan over steps. ``state``: params, opt_state
+    (:func:`predictionio_tpu.models.seq_rec._make_tx`), bias; the
+    learning rate rides in the optimizer state. ``loss_fn(params, bias,
+    batch, c) -> (loss, {a loss per head, "moe": the expert layers'
+    routing records [layers, …]})``."""
+    import jax
+    import jax.numpy as jnp
+
+    import optax
+
+    from predictionio_tpu.models.seq_rec import _make_tx
+
+    tx = _make_tx()
+
+    def step(state, batch):
+        params, opt_state, bias = state
+        (_, rec), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, bias, batch, c)
+        moe = rec.pop("moe")
+        with jax.named_scope("seqrec.optimizer"):
+            squares = group_squares(grads)
+            norm = jnp.sqrt(sum(squares.values()))
+            scale = jnp.minimum(1.0, c.clip_norm / (norm + 1e-6))
+            grads = jax.tree.map(lambda g: g * scale, grads)
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            load = moe["load"]
+            bias = bias + c.bias_update_rate * jnp.sign(
+                load.mean(axis=-1, keepdims=True) - load)
+        record = dict(
+            rec, grad_norm=norm,
+            group_norms=jnp.stack([jnp.sqrt(squares[g]) for g in groups]),
+            moe_pairs=moe["pairs"].sum(),
+            moe_pairs_here=moe["pairs_here"].sum(),
+            moe_dropped_pairs=moe["dropped"].sum(),
+            moe_load_max_over_mean=moe["load_max_over_mean"].max(),
+            router_bias_absmax=jnp.abs(bias).max())
+        return (params, opt_state, bias), record
+
+    def train(state, data):
+        def epoch(state, _):
+            return jax.lax.scan(step, state, data)
+
+        if epochs == 1:
+            return epoch(state, None)
+        state, records = jax.lax.scan(epoch, state, None, length=epochs)
+        return state, jax.tree.map(
+            lambda a: a.reshape((-1,) + a.shape[2:]), records)
+
+    return jax.jit(train, donate_argnums=(0,))
+
+
+def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
+                    lr: float, seed: int, *, model_type: str, init_state,
+                    program, n_params: int, groups: Tuple[str, ...],
+                    batch_keys: Tuple[str, ...],
+                    pack_attrs: Optional[Callable] = None,
+                    fit_attrs: Optional[Dict[str, Any]] = None,
+                    checkpoint_dir: Optional[str] = None,
+                    checkpoint_every: int = 1) -> Tuple[Dict, np.ndarray]:
+    """Train on per-user item-id histories; returns the model's arrays
+    on the HOST (``{"params", "bias"}``) and the loss of every step run
+    in this process. Spans ``seqrec.pack`` / ``.init`` / ``.fit`` /
+    ``.fetch`` land in the verb record (docs/observability.md).
+    ``program(c, n)`` is the backbone's compiled train of ``n`` epochs;
+    ``pack_attrs(packed)`` and ``fit_attrs`` add the backbone's own
+    counters to the two spans."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.models.seq_rec import run_epoch_blocks
+    from predictionio_tpu.utils import tracing
+
+    if c.seq_len % min(c.attn_block, c.seq_len):
+        raise ValueError("seq_len must be a multiple of attn_block")
+    with tracing.span("seqrec.pack") as sp:
+        packed = pack_histories(histories, c.seq_len, c.seqs_per_step, seed)
+        top = max(int(packed.tokens.max()), 0)
+        if top >= c.vocab_size:
+            raise ValueError(f"item id {top} outside the vocabulary of "
+                             f"{c.vocab_size} rows")
+        for k, v in packed.counters.items():
+            sp.set_attr(k, v)
+        # pairs inside the tiles attention visits (one epoch and head),
+        # and inside those that blocks of ``attn_block`` rows walking
+        # to the diagonal would
+        bq, bk = _attn_tiles(c, c.seq_len)
+        sp.set_attr("attn_tile_pairs",
+                    seq_attention.tile_pairs(packed.seg, bq, bk))
+        sp.set_attr("attn_dense_pairs",
+                    seq_attention.tile_pairs(packed.seg, bq, bq, skip=False))
+        for k, v in (pack_attrs(packed) if pack_attrs else {}).items():
+            sp.set_attr(k, v)
+    with tracing.span("seqrec.init") as sp:
+        B = c.seqs_per_step
+        data = {k: jnp.asarray(getattr(packed, k).reshape(
+            -1, B, packed.tokens.shape[1])) for k in batch_keys}
+        params, opt_state, bias = init_state(c, seed, with_optimizer=True)
+        opt_state.hyperparams["learning_rate"] = jnp.float32(lr)
+        state = jax.block_until_ready(
+            {"params": params, "opt_state": opt_state, "bias": bias})
+        sp.set_attr("params", n_params)
+        sp.set_attr("bytes", 16 * n_params)
+    steps = packed.tokens.shape[0] // c.seqs_per_step
+    with tracing.span("seqrec.fit", steps=steps * epochs,
+                      tokens_per_step=c.seqs_per_step * c.seq_len,
+                      backbone=model_type, **(fit_attrs or {})) as sp:
+        def run_block(state, n):
+            out, rec = program(c, int(n))(
+                (state["params"], state["opt_state"], state["bias"]), data)
+            return (dict(zip(("params", "opt_state", "bias"), out)),
+                    jax.device_get(rec))
+
+        def set_lr(state):
+            state["opt_state"].hyperparams["learning_rate"] = jnp.float32(lr)
+
+        state, records = run_epoch_blocks(
+            epochs, checkpoint_dir, checkpoint_every, state, run_block,
+            set_lr)
+        rec = ({k: np.concatenate([r[k] for r in records])
+                for k in records[0]} if records else {})
+        if rec:
+            losses = [k for k in rec if k.endswith("loss")]
+            for k in losses:
+                sp.set_attr(f"{k}_first", float(rec[k][0]))
+            sp.set_attr("loss_last4", float(rec["loss"][-4:].mean()))
+            sp.set_attr("losses_finite", bool(all(
+                np.isfinite(rec[k]).all() for k in losses)))
+            sp.set_attr("grad_norms_first", {
+                g: float(v) for g, v in zip(groups, rec["group_norms"][0])})
+            for k in ("moe_pairs", "moe_pairs_here", "moe_dropped_pairs"):
+                sp.set_attr(k, int(rec[k].sum()))
+            sp.set_attr("moe_load_max_over_mean",
+                        float(rec["moe_load_max_over_mean"].mean()))
+            sp.set_attr("router_bias_absmax",
+                        float(rec["router_bias_absmax"][-1]))
+    with tracing.span("seqrec.fetch") as sp:
+        host = jax.device_get({"params": state["params"],
+                               "bias": state["bias"]})
+        sp.set_attr("bytes", sum(a.nbytes for a in jax.tree.leaves(host)))
+    del state
+    return host, (rec["loss"] if rec else np.zeros(0, np.float32))
+
+
+# -- serving ------------------------------------------------------------------
+
+
+def next_program(last_logits):
+    """``score(params, bias, tokens [S], n) -> logits [V]`` of the item
+    after the first ``n`` tokens: ONE segment through the backbone's
+    stack; ``last_logits(params, bias, batch, n)`` is its part."""
+    import jax
+    import jax.numpy as jnp
+
+    def score(params, bias, tokens, n):
+        S = tokens.shape[0]
+        batch = {"tokens": tokens[None],
+                 "seg": (jnp.arange(S) < n).astype(jnp.int32)[None],
+                 "pos": jnp.arange(S, dtype=jnp.int32)[None]}
+        return last_logits(params, bias, batch, n)
+
+    return jax.jit(score)
+
+
+def next_item_scores(program, model: Dict, history: Sequence[int],
+                     c) -> np.ndarray:
+    """Scores over the vocabulary for the item after ``history`` (its
+    last ``seq_len`` items, right-padded to a power-of-two bucket so
+    that a handful of programs serve every length); PAD = -inf.
+    ``program``: the backbone's :func:`next_program`."""
+    seq = [i for i in history if i > 0][-c.seq_len:]
+    bucket = min(c.seq_len, max(16, 1 << max(len(seq) - 1, 0).bit_length()))
+    tokens = np.zeros(bucket, np.int32)
+    tokens[:len(seq)] = seq
+    logits = np.array(program(
+        model["params"], model["bias"], tokens, np.int32(max(len(seq), 1))))
+    logits[0] = -np.inf
+    return logits

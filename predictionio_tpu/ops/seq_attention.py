@@ -21,6 +21,14 @@ its OWN segment up to itself, i.e. the keys ``first[r] … r`` where
   kernels recompute a tile's probabilities from it. Nothing of size
   ``rows × keys`` is ever in HBM.
 
+Keys and values may have FEWER heads than the queries (grouped-query
+attention): with ``Hkv`` key-value heads, ``g = H ÷ Hkv`` adjacent
+query heads read key-value head ``h ÷ g``. Forward and dq fetch that
+head's keys and values (once for the group: the block index does not
+change between its heads); dk/dv walks the group's ``g`` query heads
+and sums their parts in its float32 accumulators. With ``Hkv = H``
+the kernels are the ungrouped ones.
+
 The kernels see the arrays head-major ([H, S, D]; where they are made
 XLA lays them out so, and the transposes cost no copy). The operand
 that a kernel walks over (k and v for forward and dq, q and the
@@ -185,36 +193,42 @@ def _dq_kernel(lo_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dkv_kernel(hi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                first_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, bq):
-    """A key tile against the query blocks that reach it, TRANSPOSED:
-    scores [keys, rows], so that both products into dk and dv take
-    their left operand as it lies and nothing is turned; the rows'
-    numbers come as rows ([blocks, 1, bq], a block by its index)."""
+                first_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, bq,
+                group):
+    """A key tile against the query blocks that reach it, of each of
+    the ``group`` query heads that read this key-value head (their
+    rows lie one head after another: head g's block i is block
+    ``g · blocks + i``), TRANSPOSED: scores [keys, rows], so that both
+    products into dk and dv take their left operand as it lies and
+    nothing is turned; the rows' numbers come as rows ([blocks, 1, bq],
+    a block by its index)."""
     j = pl.program_id(1)
     bk = k_ref.shape[0]
+    blocks = first_ref.shape[0]
     k, v = k_ref[...], v_ref[...]
     keys = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
     dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
     dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
-    def block(i, _):
-        rs = pl.ds(pl.multiple_of(i * bq, bq), bq)
-        q, do = q_ref[rs, :], do_ref[rs, :]
-        rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
-        s = jax.lax.dot_general(k, q, _NT,
-                                preferred_element_type=jnp.float32) * scale
-        p = jnp.where((keys >= first_ref[i]) & (keys <= rows),
-                      jnp.exp(s - lse_ref[i]), 0.0)
-        dp = jax.lax.dot_general(v, do, _NT,
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[i]) * scale
-        dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
-                               preferred_element_type=jnp.float32)
-        dk_acc[...] += jnp.dot(ds.astype(q.dtype), q,
-                               preferred_element_type=jnp.float32)
-        return _
+    for g in range(group):
+        def block(i, _, at=g * blocks):
+            rs = pl.ds(pl.multiple_of((at + i) * bq, bq), bq)
+            q, do = q_ref[rs, :], do_ref[rs, :]
+            rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+            s = jax.lax.dot_general(
+                k, q, _NT, preferred_element_type=jnp.float32) * scale
+            p = jnp.where((keys >= first_ref[i]) & (keys <= rows),
+                          jnp.exp(s - lse_ref[at + i]), 0.0)
+            dp = jax.lax.dot_general(v, do, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_ref[at + i]) * scale
+            dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+            dk_acc[...] += jnp.dot(ds.astype(q.dtype), q,
+                                   preferred_element_type=jnp.float32)
+            return _
 
-    jax.lax.fori_loop(j * bk // bq, hi_ref[j] + 1, block, None)
+        jax.lax.fori_loop(j * bk // bq, hi_ref[j] + 1, block, None)
     dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
     dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
@@ -222,12 +236,14 @@ def _dkv_kernel(hi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # -- the calls -----------------------------------------------------------------
 
 
-def _head(rows: int, width: int, whole: bool = False):
-    """``rows`` rows of the grid's head ([H, S, width]): the grid's
-    block of them, or (``whole``) all — fetched once a head, walked by
-    the kernel's own loop."""
+def _head(rows: int, width: int, whole: bool = False, group: int = 1):
+    """``rows`` rows of the grid's head ([H, S, width]) — with
+    ``group``, of the key-value head that ``group`` query heads share:
+    the grid's block of them, or (``whole``) all — fetched once a
+    head, walked by the kernel's own loop."""
     return pl.BlockSpec((None, rows, width),
-                        lambda h, i, _: (h, 0 if whole else i, 0))
+                        lambda h, i, _: (h // group if group > 1 else h,
+                                         0 if whole else i, 0))
 
 
 def _first(rows: int):
@@ -258,12 +274,13 @@ def _by_platform(run, *args):
 
 def _forward(q, k, v, first, lo, bq, bk, scale, interpret):
     H, S, D = q.shape
-    Dv = v.shape[-1]
+    Dv, g = v.shape[-1], H // k.shape[0]
     return _call(
         functools.partial(_fwd_kernel, scale=scale, bk=bk),
         "seq_attention_fwd", S // bq, lo, (q, k, v, _lanes(first)),
-        [_head(bq, D), _head(S, D, True), _head(S, Dv, True), _first(bq)],
-        [jax.ShapeDtypeStruct(v.shape, v.dtype),
+        [_head(bq, D), _head(S, D, True, g), _head(S, Dv, True, g),
+         _first(bq)],
+        [jax.ShapeDtypeStruct((H, S, Dv), v.dtype),
          jax.ShapeDtypeStruct((H, S, _LANES), jnp.float32)],
         [_head(bq, Dv), _head(bq, _LANES)],
         [pltpu.VMEM((bq, 1), jnp.float32), pltpu.VMEM((bq, 1), jnp.float32),
@@ -273,25 +290,30 @@ def _forward(q, k, v, first, lo, bq, bk, scale, interpret):
 def _backward(q, k, v, do, lse, delta, first, lo, hi, bq, bk, scale,
               interpret):
     """``lse``, ``delta`` [H, S] and ``first`` [S]: dq reads a row's
-    number as a column (its 128 lanes), dk/dv as part of a row."""
+    number as a column (its 128 lanes), dk/dv as part of a row. dk/dv's
+    grid walks the KEY-VALUE heads: the ``g`` query heads of one are
+    adjacent, so their rows are one head of ``g · S`` rows."""
     H, S, D = q.shape
-    Dv = v.shape[-1]
+    Hkv, Dv = v.shape[0], v.shape[-1]
+    g = H // Hkv
     dq, = _call(
         functools.partial(_dq_kernel, scale=scale, bk=bk),
         "seq_attention_dq", S // bq, lo,
         (q, k, v, do, _lanes(lse), _lanes(delta), _lanes(first)),
-        [_head(bq, D), _head(S, D, True), _head(S, Dv, True), _head(bq, Dv),
-         _head(bq, _LANES), _head(bq, _LANES), _first(bq)],
+        [_head(bq, D), _head(S, D, True, g), _head(S, Dv, True, g),
+         _head(bq, Dv), _head(bq, _LANES), _head(bq, _LANES), _first(bq)],
         [jax.ShapeDtypeStruct(q.shape, q.dtype)], [_head(bq, D)],
         [pltpu.VMEM((bq, D), jnp.float32)], interpret)
-    as_rows = pl.BlockSpec((None, S // bq, 1, bq), lambda h, j, _: (h, 0, 0, 0))
+    as_rows = pl.BlockSpec((None, g * S // bq, 1, bq),
+                           lambda h, j, _: (h, 0, 0, 0))
     dk, dv = _call(
-        functools.partial(_dkv_kernel, scale=scale, bq=bq),
+        functools.partial(_dkv_kernel, scale=scale, bq=bq, group=g),
         "seq_attention_dkv", S // bk, hi,
-        (q, k, v, do, lse.reshape(H, -1, 1, bq), delta.reshape(H, -1, 1, bq),
+        (q.reshape(Hkv, g * S, D), k, v, do.reshape(Hkv, g * S, Dv),
+         lse.reshape(Hkv, -1, 1, bq), delta.reshape(Hkv, -1, 1, bq),
          first.reshape(-1, 1, bq)),
-        [_head(S, D, True), _head(bk, D), _head(bk, Dv), _head(S, Dv, True),
-         as_rows, as_rows,
+        [_head(g * S, D, True), _head(bk, D), _head(bk, Dv),
+         _head(g * S, Dv, True), as_rows, as_rows,
          pl.BlockSpec((S // bq, 1, bq), lambda h, j, _: (0, 0, 0))],
         [jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)],
@@ -317,9 +339,10 @@ def _heads_first(x):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def segment_attention(q, k, v, seg, bq: int, bk: int, scale: float):
     """softmax(scale · q kᵀ, causal AND inside one segment) v for ONE
-    packed sequence: q, k [S, H, D], v [S, H, Dv], ``seg`` [S] int32
-    (0 = padding) → [S, H, Dv] in v's dtype. ``bq`` query rows and
-    ``bk`` keys a tile; both divide S."""
+    packed sequence: q [S, H, D], k [S, Hkv, D], v [S, Hkv, Dv] with
+    ``Hkv`` dividing ``H`` (query head h reads key-value head
+    h ÷ (H ÷ Hkv)), ``seg`` [S] int32 (0 = padding) → [S, H, Dv] in v's
+    dtype. ``bq`` query rows and ``bk`` keys a tile; both divide S."""
     return _attend(q, k, v, seg, bq, bk, scale)[0]
 
 
@@ -328,6 +351,9 @@ def _attend(q, k, v, seg, bq, bk, scale):
     if S % bq or S % bk:
         raise ValueError(f"tiles of {bq} rows × {bk} keys do not divide "
                          f"a sequence of {S}")
+    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
+        raise ValueError(f"{k.shape[1]} key and {v.shape[1]} value heads "
+                         f"do not group {q.shape[1]} query heads")
     first = first_keys(seg)
     lo, _ = tile_intervals(first, bq, bk)
     out, lse = _by_platform(

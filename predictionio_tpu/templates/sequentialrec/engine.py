@@ -17,9 +17,12 @@ DASE shape mirrors the other recommenders:
     → {"itemScores": [{"item": "i9", "score": 3.1}, ...]}
 
 The backbone is chosen by the algorithm's parameters: an
-``architecture`` object (a published ``glm4_moe_lite`` config's keys —
-:mod:`predictionio_tpu.models.glm4_moe_lite`: latent attention, sparse
-experts, an MTP module, packed histories) or, absent, the SASRec stack.
+``architecture`` object — a published config's keys, its ``model_type``
+naming the block stack that trains on packed histories
+(:func:`predictionio_tpu.models.seq_backbone.backbone`:
+``glm4_moe_lite`` — latent attention, sparse experts, an MTP module;
+``lfm2_moe`` — gated short convolutions, grouped-query attention,
+sparse experts, a tied head) — or, absent, the SASRec stack.
 
 Optional query keys: ``history`` (explicit item list overriding the
 live lookup — supports anonymous sessions), ``blackList``.
@@ -57,6 +60,15 @@ from predictionio_tpu.utils.bimap import BiMap
 
 #: a model blob whose arrays follow its pickled head as raw bytes
 _RAW_MAGIC = b"PIOSEQRAW1\n"
+
+
+def _backbone(model_type: Optional[str]):
+    """The block-stack backbone an ``architecture`` (or a saved model)
+    names; None = the default one. Imported here: the table's module
+    imports jax."""
+    from predictionio_tpu.models.seq_backbone import backbone
+
+    return backbone(model_type)
 
 
 @dataclass
@@ -139,18 +151,23 @@ class SeqRecAlgorithmParams:
     # flip on to ban already-seen items like the ALS recommenders do
     exclude_seen: bool = False
     # the backbone: None = the SASRec stack above; an object holding a
-    # published glm4_moe_lite config's keys (and the training job's:
-    # seq_len, seqs_per_step, ep_size, … — models/glm4_moe_lite.GlmConfig)
-    # = that block stack on packed histories. ``hidden`` … ``batch_size``
-    # above are then unused; ``epochs``, ``lr`` and ``seed`` apply.
+    # published config's keys (and the training job's: seq_len,
+    # seqs_per_step, ep_size, …) = the block stack its ``model_type``
+    # names (models/seq_backbone: glm4_moe_lite — the default —,
+    # lfm2_moe) on packed histories. ``hidden`` … ``batch_size`` above
+    # are then unused; ``epochs``, ``lr`` and ``seed`` apply.
     architecture: Optional[Dict[str, Any]] = None
 
 
 class SeqRecModel:
     def __init__(self, params: Dict, item_ids: BiMap, app_name: str,
                  hp: SeqRecParams, algo_params: "SeqRecAlgorithmParams",
-                 losses: np.ndarray) -> None:
+                 losses: np.ndarray, model_type: Optional[str] = None
+                 ) -> None:
         self.params = params
+        #: the block-stack backbone that reads ``params`` and ``hp``
+        #: (its config); None = the SASRec stack
+        self.model_type = model_type
         self._on_device = None
         self.item_ids = item_ids  # raw item id → 1-based index
         self._inv = item_ids.inverse()
@@ -185,15 +202,13 @@ class SeqRecModel:
                    ) -> List[Dict[str, Any]]:
         hist = [self.item_ids[i] + 1 for i in history_raw
                 if i in self.item_ids]
-        if isinstance(self.hp, SeqRecParams):
+        if self.model_type is None:
             scores = seq_rec_scores(self.params, hist, self.hp)  # PAD = -inf
         else:
-            from predictionio_tpu.models.glm4_moe_lite import next_item_scores
-
             # rows past the catalog (a vocabulary slice's spare rows)
             # are no items
-            scores = next_item_scores(self.device_params(), hist, self.hp)[
-                :len(self.item_ids) + 1]
+            scores = _backbone(self.model_type).next_item_scores(
+                self.device_params(), hist, self.hp)[:len(self.item_ids) + 1]
         banned = set(black_list or [])
         if self.algo_params.exclude_seen:
             banned |= set(history_raw)
@@ -233,13 +248,12 @@ class SeqRecAlgorithm(Algorithm):
 
             ckpt_dir = os.path.join(ctx.checkpoint_dir, "seq_rec")
         if p.architecture is not None:
-            from predictionio_tpu.models.glm4_moe_lite import (GlmConfig,
-                                                               glm_train)
-
-            cfg = GlmConfig.from_architecture(p.architecture)
-            params, losses = glm_train(sequences, cfg, p.epochs, p.lr,
-                                       p.seed, checkpoint_dir=ckpt_dir)
-            return SeqRecModel(params, item_ids, pd.app_name, cfg, p, losses)
+            backbone = _backbone(p.architecture.get("model_type"))
+            cfg = backbone.config.from_architecture(p.architecture)
+            params, losses = backbone.train(sequences, cfg, p.epochs, p.lr,
+                                            p.seed, checkpoint_dir=ckpt_dir)
+            return SeqRecModel(params, item_ids, pd.app_name, cfg, p, losses,
+                               backbone.model_type)
         hp = SeqRecParams(hidden=p.hidden, num_blocks=p.num_blocks,
                           num_heads=p.num_heads, seq_len=p.seq_len,
                           epochs=p.epochs, lr=p.lr,
@@ -279,6 +293,7 @@ class SeqRecAlgorithm(Algorithm):
             "item_ids": model.item_ids.to_dict(),
             "app_name": model.app_name,
             "hp": model.hp,
+            "model_type": model.model_type,
             "algo_params": model.algo_params,
             "losses": model.losses,
         })
@@ -307,8 +322,14 @@ class SeqRecAlgorithm(Algorithm):
             import jax
 
             params = jax.tree.map(lambda i: leaves[i], d["tree"])
+        # a model saved before the backbones had a table says nothing:
+        # its config is then the default backbone's
+        model_type = d.get("model_type") or (
+            None if isinstance(d["hp"], SeqRecParams)
+            else _backbone(None).model_type)
         return SeqRecModel(params, BiMap(d["item_ids"]), d["app_name"],
-                           d["hp"], d["algo_params"], d["losses"])
+                           d["hp"], d["algo_params"], d["losses"],
+                           model_type)
 
 
 def engine_factory() -> Engine:

@@ -272,3 +272,80 @@ def test_attention_at_the_sample_widths(one_chip, dtype):
             q, k, v, seg, 32, 64, 0.25).astype(jnp.float32).sum(),
         (0, 1, 2))).lower(q, q, v, _sds((S,), jnp.int32, one_chip)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+# -- the lfm2_moe backbone at published widths ---------------------------------
+
+
+def _lfm2_cell_config():
+    """The benchmark's ``seqrec-lfm2-8b-a1b-ep4`` as the template
+    builds it: the configuration's published keys and its job."""
+    import json
+    import os
+
+    from predictionio_tpu.models import lfm2_moe as lfm
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "seqrec-lfm2-8b-a1b-ep4.json")
+    with open(path) as f:
+        conf = json.load(f)
+    arch = {k: v for k, v in conf.items()
+            if k in lfm.Lfm2Config.known_keys()}
+    return lfm.Lfm2Config.from_architecture(dict(arch, **conf["job"]))
+
+
+def test_grouped_query_attention_at_published_widths(one_chip):
+    """One sequence's attention at 32 query heads over 8 key-value
+    heads of 64, 4,096 positions, forward and backward: the same three
+    kernels, a key-value head fetched for its four query heads."""
+    from predictionio_tpu.models import seq_backbone
+
+    c = _lfm2_cell_config()
+    S, H, Hkv, D = (c.seq_len, c.num_attention_heads,
+                    c.num_key_value_heads, c.head_dim)
+    assert (H, Hkv, D) == (32, 8, 64)
+    q = _sds((S, H, D), jnp.bfloat16, one_chip)
+    kv = _sds((S, Hkv, D), jnp.bfloat16, one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v, seg: seq_backbone.attention(
+            q, k, v, seg, c, 0.125).astype(jnp.float32).sum(),
+        (0, 1, 2))).lower(q, kv, kv, _sds((S,), jnp.int32,
+                                         one_chip)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # the rows' statistics in their 128 lanes (32 × 4,096 × 128 float32
+    # = 67 MB each) and the cotangents; the sequence's scores would be
+    # 2.1 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_lfm2_train_program_at_published_widths(one_chip):
+    """The cell's whole train program — 507.8 M parameters with Adam's
+    state, 16 steps of 8 × 4,096 slots, three scanned bodies (conv +
+    dense, attention + experts, 3 × conv + experts), the tied head —
+    for the described chip: the attention kernels and the grouped
+    products are in it, and it fits the chip's 16 GB."""
+    from predictionio_tpu.models import lfm2_moe as lfm
+    from predictionio_tpu.models import seq_backbone
+    from predictionio_tpu.models.seq_rec import _make_tx
+
+    c = _lfm2_cell_config()
+    assert lfm.n_params(c) == 507_820_160
+    params = jax.tree.map(lambda s: _sds(s, jnp.float32, one_chip),
+                          lfm.param_shapes(c),
+                          is_leaf=seq_backbone._is_shape)
+    opt = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                       jax.eval_shape(_make_tx().init, params))
+    bias = _sds((c.n_moe_layers, c.router_experts), jnp.float32, one_chip)
+    data = {k: _sds((16, c.seqs_per_step, c.seq_len), jnp.int32, one_chip)
+            for k in lfm.BATCH_KEYS}
+    compiled = lfm.train_program(c, 1).lower((params, opt, bias),
+                                             data).compile()
+    # forward, recomputation and backward of one attention layer's
+    # three kernels, and of four expert layers' three grouped products
+    assert _ragged_calls(compiled) >= 30
+    mem = compiled.memory_analysis()
+    # the donated state is counted in the arguments AND (updated) in
+    # the temporaries: what the program holds at once is the latter
+    assert mem.argument_size_in_bytes < 6.2e9
+    assert mem.temp_size_in_bytes < 12.5e9

@@ -15,7 +15,12 @@ H, D, DV = 2, 16, 8
 
 def _dense(q, k, v, seg, scale):
     """The plain form: every score of the sequence, masked (same
-    segment, causal, not padding), one softmax."""
+    segment, causal, not padding), one softmax; a key-value head
+    repeated for each query head of its group (in float32, so that a
+    group's cotangents are summed before they are rounded, not after)."""
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(a.astype(jnp.float32), q.shape[1] // a.shape[1],
+                           axis=1) for a in (k, v))
     r = jnp.arange(q.shape[0])
     mask = ((seg[:, None] == seg[None, :]) & (r[:, None] >= r[None, :])
             & (seg[:, None] > 0))
@@ -37,8 +42,14 @@ def _segments(*lengths, S):
     return seg
 
 
-#: name → (segment ids, query rows a tile, keys a tile[, head widths])
+#: name → (segment ids, query rows a tile, keys a tile[, head widths
+#: [, query heads, key-value heads]])
 PACKINGS = {
+    "grouped_4_of_4": (_segments(20, 70, 30, S=128), 32, 64, 64, 64, 4, 4),
+    "grouped_8_over_2": (_segments(50, 90, 40, 60, S=256), 32, 128, 64, 64,
+                         8, 2),
+    "grouped_32_over_8": (_segments(20, 70, 38, S=128), 32, 32, 64, 64,
+                          32, 8),
     "one_segment": (_segments(128, S=128), 32, 32),
     "many_short": (_segments(*([5, 9, 2, 17, 3, 11, 7] * 4), S=256), 32, 32),
     "crosses_three_tiles": (_segments(20, 70, 38, S=128), 32, 32),
@@ -52,16 +63,18 @@ PACKINGS = {
 }
 
 
-def _operands(S, dtype, D=D, DV=DV):
+def _operands(S, dtype, D=D, DV=DV, H=H, Hkv=None):
     rng = np.random.default_rng(0)
-    q, k = (jnp.asarray(rng.normal(size=(S, H, D)), dtype) for _ in range(2))
-    return q, k, jnp.asarray(rng.normal(size=(S, H, DV)), dtype)
+    q, k = (jnp.asarray(rng.normal(size=(S, h, D)), dtype)
+            for h in (H, Hkv or H))
+    return q, k, jnp.asarray(rng.normal(size=(S, Hkv or H, DV)), dtype)
 
 
 def _value_and_grads(attend, q, k, v, seg):
     """Σ w · attend(q, k, v) over the REAL rows and its gradients."""
-    w = jnp.asarray(np.random.default_rng(9).normal(size=v.shape)
-                    * (seg > 0)[:, None, None], jnp.float32)
+    w = jnp.asarray(np.random.default_rng(9).normal(
+        size=q.shape[:2] + v.shape[2:]) * (seg > 0)[:, None, None],
+        jnp.float32)
     return jax.jit(jax.value_and_grad(
         lambda q, k, v: (attend(q, k, v).astype(jnp.float32) * w).sum(),
         (0, 1, 2)))(q, k, v)
@@ -97,6 +110,35 @@ def test_tiled_equals_dense_forward_and_gradients(packing, dtype):
         assert np.abs(a - b).max() <= 2.5 * tol * max(scale, 1.0), name
         # a padding row has no key and is nobody's key
         assert not a[~real].any(), name
+
+
+def test_one_key_value_head_a_query_head_is_the_ungrouped_kernel():
+    """Grouped against ungrouped, bit for bit: 8 query heads over 2
+    key-value heads, and the same with each key-value head handed over
+    four times as heads of its own (``Hkv = H``: the grid's head IS
+    the key-value head, the kernels every caller had before there were
+    groups). Forward and dq do the same sums in the same order; dk/dv
+    sums a group inside the kernel and is compared to rounding."""
+    seg, bq, bk, D_, DV_, H_, Hkv_ = PACKINGS["grouped_8_over_2"]
+    q, k, v = _operands(len(seg), jnp.float32, D_, DV_, H_, Hkv_)
+    rep = lambda a: jnp.repeat(a, H_ // Hkv_, axis=1)  # noqa: E731
+    grouped = _value_and_grads(_tiled(seg, bq, bk), q, k, v, seg)
+    alone = _value_and_grads(_tiled(seg, bq, bk), q, rep(k), rep(v), seg)
+    np.testing.assert_array_equal(
+        np.asarray(_tiled(seg, bq, bk)(q, k, v)),
+        np.asarray(_tiled(seg, bq, bk)(q, rep(k), rep(v))))
+    np.testing.assert_array_equal(np.asarray(grouped[1][0]),
+                                  np.asarray(alone[1][0]))
+    for a, b in zip(grouped[1][1:], alone[1][1:]):
+        summed = np.asarray(b).reshape(len(seg), Hkv_, H_ // Hkv_, -1).sum(2)
+        np.testing.assert_allclose(np.asarray(a), summed, atol=2e-5,
+                                   rtol=2e-5)
+
+
+def test_key_value_heads_must_divide_the_query_heads():
+    q, k, v = _operands(64, jnp.float32, H=4, Hkv=3)
+    with pytest.raises(ValueError, match="do not group"):
+        sa.segment_attention(q, k, v, jnp.ones(64, jnp.int32), 32, 32, 1.0)
 
 
 def test_a_skipped_tile_is_never_read():
