@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import pickle
 from abc import ABC, abstractmethod
-from typing import Any, Generic, List, Optional, Sequence, TypeVar
+from typing import Any, Generic, List, Optional, Sequence, TypeVar, Union
 
 from predictionio_tpu.controller.base import WorkflowContext
 
@@ -135,9 +135,17 @@ class Algorithm(ABC, Generic[PD, M, Q, PR]):
 
     # -- persistence (PersistentModel analogue) --------------------------------
 
-    def save_model(self, model: M, instance_dir: Optional[str]) -> Optional[bytes]:
+    def save_model(self, model: M, instance_dir: Optional[str]
+                   ) -> Union[None, bytes, Sequence[Any]]:
         """Serialize the model. Return bytes for the blob store, or None if
-        everything was written into ``instance_dir`` (structured artifacts)."""
+        everything was written into ``instance_dir`` (structured artifacts)
+        — or a sequence of bytes-like PARTS (``bytes``, ``memoryview``,
+        contiguous numpy arrays): a small head and the arrays' own
+        buffers, which the store writes one after the other without
+        joining or copying them (``utils/model_parts.py`` packs and
+        unpacks such a blob). ``load_model`` is then handed a read-only
+        ``memoryview`` of the joined parts, where a ``bytes`` result
+        comes back as ``bytes``."""
         return pickle.dumps(model)
 
     def load_model(self, blob: Optional[bytes], instance_dir: Optional[str]) -> M:

@@ -36,6 +36,7 @@ from predictionio_tpu.data.event import utcnow
 from predictionio_tpu.parallel.mesh import MeshConfig, make_mesh
 from predictionio_tpu.storage.meta import EngineInstance, EvaluationInstance
 from predictionio_tpu.storage.registry import Storage, get_storage
+from predictionio_tpu.utils import model_parts
 
 
 def _algorithms_params_json(engine_params: EngineParams) -> str:
@@ -66,6 +67,70 @@ def _ckpt_root(storage: Storage, engine_factory: str, variant_id: str) -> str:
     safe = "".join(ch if ch.isalnum() else "_"
                    for ch in f"{engine_factory}_{variant_id}")
     return os.path.join(storage.config.home, "train_ckpt", safe)
+
+
+#: first bytes of a ``model.bin`` that :func:`frame_models` laid out;
+#: one from before starts with a pickle's 0x80
+_FRAME_MAGIC = b"PIOMODEL1\n"
+
+
+def frame_models(saved: Sequence[Any]) -> List[memoryview]:
+    """The parts of one instance's ``model.bin``, from what each
+    algorithm's ``save_model`` returned (None, ``bytes``, or a sequence
+    of bytes-like parts), in order and WITHOUT joining them::
+
+        magic | <Q n | header: n bytes of JSON | stretch | stretch ...
+
+    The header says, per algorithm, ``null`` or where its stretch lies
+    after the header (``offset``, ``bytes``) and whether ``load_model``
+    is handed a ``view`` of it (the result was parts) or ``bytes`` (it
+    was ``bytes``: an engine that knows nothing of parts gets back what
+    it gave). Header and stretches are padded to a multiple of
+    ``model_parts.ALIGN``, so that arrays an algorithm aligned in its
+    own blob are aligned in the file."""
+    header, body, at = [], [], 0
+    for result in saved:
+        if result is None:
+            header.append(None)
+            continue
+        if at % model_parts.ALIGN:
+            pad = memoryview(bytes(-at % model_parts.ALIGN))
+            body.append(pad)
+            at += pad.nbytes
+        whole = isinstance(result, (bytes, bytearray, memoryview))
+        views = [model_parts.byte_view(p)
+                 for p in ([result] if whole else result)]
+        n = sum(v.nbytes for v in views)
+        header.append({"offset": at, "bytes": n, "view": not whole})
+        body += views
+        at += n
+    # filled with spaces: JSON's own padding
+    return [memoryview(model_parts.lead(
+        _FRAME_MAGIC, json.dumps(header).encode("ascii"), b" "))] + body
+
+
+def unframe_models(raw: bytes) -> List[Any]:
+    """Per algorithm what ``load_model`` is handed, from the bytes of a
+    ``model.bin``: None, ``bytes``, or a zero-copy read-only view of
+    the algorithm's stretch. A blob that does not start with the magic
+    was written before the framing: a pickled list of ``bytes``."""
+    got = model_parts.split_lead(raw, _FRAME_MAGIC)
+    if got is None:
+        return pickle.loads(raw)
+    header, body = got
+    blobs = []
+    for entry in json.loads(bytes(header)):
+        if entry is None:
+            blobs.append(None)
+            continue
+        stretch = body[entry["offset"]:entry["offset"] + entry["bytes"]]
+        if stretch.nbytes != entry["bytes"]:
+            raise ValueError(
+                f"model blob is cut short: {len(raw)} bytes, its header "
+                f"places {entry['bytes']} at {entry['offset']} of the "
+                f"{body.nbytes} after it")
+        blobs.append(stretch if entry["view"] else bytes(stretch))
+    return blobs
 
 
 @contextlib.contextmanager
@@ -199,7 +264,7 @@ def run_train(
                 with tracing.span("train.save", instance_id=instance_id,
                                   algorithms=len(models)):
                     instance_dir = storage.models.model_dir(instance_id)
-                    blobs: List[Optional[bytes]] = []
+                    saved = []
                     with tracing.span("model.serialize") as sp:
                         for (name, algo), model in zip(
                                 engine.make_algorithms(engine_params), models):
@@ -207,12 +272,16 @@ def run_train(
                             if instance_dir is not None:
                                 algo_dir = os.path.join(instance_dir, name)
                                 os.makedirs(algo_dir, exist_ok=True)
-                            blobs.append(algo.save_model(model, algo_dir))
-                        sp.set_attr("bytes", sum(len(b) for b in blobs if b))
+                            saved.append(algo.save_model(model, algo_dir))
+                        parts = frame_models(saved)
+                        sp.set_attr("bytes",
+                                    sum(p.nbytes for p in parts[1:]))
                     with tracing.span("model.put") as sp:
-                        payload = pickle.dumps(blobs)
-                        storage.models.put(instance_id, payload)
-                        sp.set_attr("bytes", len(payload))
+                        streamed = storage.models.put_parts(instance_id,
+                                                            parts)
+                        sp.set_attr("bytes", sum(p.nbytes for p in parts))
+                        sp.set_attr("parts", len(parts))
+                        sp.set_attr("streamed", int(streamed))
 
                 with tracing.span("train.finish"):
                     ei.status = "COMPLETED"
@@ -321,7 +390,7 @@ def prepare_deploy(
     raw = storage.models.get(ei.id)
     if raw is None:
         raise ValueError(f"no model blob for instance {ei.id}")
-    blobs: List[Optional[bytes]] = pickle.loads(raw)
+    blobs = unframe_models(raw)
     instance_dir = storage.models.model_dir(ei.id)
     models = []
     for (name, algo), blob in zip(algorithms, blobs):

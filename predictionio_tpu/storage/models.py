@@ -11,6 +11,7 @@ the analogue of the reference's ``PersistentModel`` escape hatch.
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import re
@@ -18,15 +19,22 @@ import shutil
 import threading
 from abc import ABC, abstractmethod
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterable, List, Optional
 
 from predictionio_tpu.utils import faults, integrity
-from predictionio_tpu.utils.atomic_write import atomic_write_bytes
+from predictionio_tpu.utils.atomic_write import atomic_file, atomic_write_bytes
 
 
 class ModelStore(ABC):
     @abstractmethod
     def put(self, instance_id: str, blob: bytes) -> None: ...
+
+    def put_parts(self, instance_id: str, parts: Iterable[Any]) -> bool:
+        """Store the blob that ``parts`` (bytes-like, in order) make
+        when joined. True where the backend wrote them one after the
+        other; this default joins them and says False."""
+        self.put(instance_id, b"".join(parts))
+        return False
 
     @abstractmethod
     def get(self, instance_id: str) -> Optional[bytes]: ...
@@ -150,20 +158,31 @@ class LocalFSModelStore(ModelStore):
         return os.path.join(self._root, safe)
 
     def put(self, instance_id: str, blob: bytes) -> None:
+        self.put_parts(instance_id, [blob])
+
+    def put_parts(self, instance_id: str, parts: Iterable[Any]) -> bool:
         d = self._dir(instance_id)
         os.makedirs(d, exist_ok=True)
+        path = os.path.join(d, "model.bin")
         # blob first, digest last: a crash between the two leaves a
         # mismatched pair that get() REFUSES — fail-safe, never a
         # silently unverified serve
-        # (the digest is COMPUTED while the blob is written — both
-        # release the GIL, and a 2.8 GB model pays each for seconds —
-        # and written after it)
+        # (each part goes from the memory it lies in to the file and to
+        # ONE running digest, which a helper thread computes while the
+        # blob is written — both release the GIL, and a 2.8 GB model
+        # pays each for seconds; the digest is written after the blob)
+        sha = hashlib.sha256()
         with ThreadPoolExecutor(max_workers=1) as pool:
-            digest = pool.submit(integrity.sha256_hex, blob)
-            atomic_write_bytes(os.path.join(d, "model.bin"), blob)
-        atomic_write_bytes(
-            os.path.join(d, "model.bin" + integrity.DIGEST_SUFFIX),
-            digest.result().encode("ascii"))
+            hashed = []
+            with atomic_file(path) as f:
+                for part in parts:
+                    hashed.append(pool.submit(sha.update, part))
+                    f.write(part)
+            for h in hashed:
+                h.result()
+        atomic_write_bytes(path + integrity.DIGEST_SUFFIX,
+                           sha.hexdigest().encode("ascii"))
+        return True
 
     def get(self, instance_id: str) -> Optional[bytes]:
         p = os.path.join(self._dir(instance_id), "model.bin")

@@ -18,8 +18,6 @@ serving-time business rules stay out of the compiled path.
 
 from __future__ import annotations
 
-import io
-import pickle
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Set
 
@@ -39,6 +37,7 @@ from predictionio_tpu.controller import (
 )
 from predictionio_tpu.data import store as event_store
 from predictionio_tpu.models.als import ALSParams, RatingsCOO, als_train, recommend
+from predictionio_tpu.utils import model_parts
 from predictionio_tpu.utils.bimap import BiMap
 
 
@@ -293,22 +292,19 @@ class ECommAlgorithm(Algorithm):
             storage=self.serving_storage,  # live rules read the deploy Storage
         )}
 
-    def save_model(self, model: ECommModel, instance_dir: Optional[str]) -> bytes:
-        buf = io.BytesIO()
-        np.savez_compressed(buf, U=model.U, V=model.V, pop=model.popularity)
-        return pickle.dumps({
-            "npz": buf.getvalue(),
+    def save_model(self, model: ECommModel, instance_dir: Optional[str]
+                   ) -> List[Any]:
+        return model_parts.pack_named({
             "user_ids": model.user_ids.to_dict(),
             "item_ids": model.item_ids.to_dict(),
             "cats": model.item_categories,
             "app_name": model.app_name,
             "params": self.params,
-        })
+        }, U=model.U, V=model.V, pop=model.popularity)
 
     def load_model(self, blob: Optional[bytes], instance_dir: Optional[str]) -> ECommModel:
         assert blob is not None
-        d = pickle.loads(blob)
-        arrs = np.load(io.BytesIO(d["npz"]))
+        d, arrs = model_parts.unpack_named(blob)
         return ECommModel(arrs["U"], arrs["V"], BiMap(d["user_ids"]),
                           BiMap(d["item_ids"]), d["cats"], arrs["pop"],
                           d["app_name"], d["params"])

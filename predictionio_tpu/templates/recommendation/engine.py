@@ -14,8 +14,6 @@ The compute is :mod:`predictionio_tpu.models.als` (JAX, mesh-aware).
 
 from __future__ import annotations
 
-import io
-import pickle
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
@@ -40,7 +38,7 @@ from predictionio_tpu.models.als import (
     als_train,
     recommend,
 )
-from predictionio_tpu.utils import tracing
+from predictionio_tpu.utils import model_parts, tracing
 from predictionio_tpu.utils.bimap import BiMap
 
 
@@ -393,20 +391,19 @@ class ALSAlgorithm(Algorithm):
             return {"targets": 0, "compiled": 0, "cached": 0}
         return scorer.warm_buckets(ladder, ks)
 
-    # structured persistence: npz for factors (compact, zero-copy load)
-    def save_model(self, model: ALSModel, instance_dir: Optional[str]) -> bytes:
-        buf = io.BytesIO()
-        np.savez_compressed(buf, U=model.U, V=model.V)
-        return pickle.dumps({
-            "npz": buf.getvalue(),
+    # structured persistence: a pickled head, then the factors' own
+    # buffers — float32 factors do not compress, and the store writes
+    # the parts as they lie in memory (utils/model_parts.py)
+    def save_model(self, model: ALSModel, instance_dir: Optional[str]
+                   ) -> List[Any]:
+        return model_parts.pack_named({
             "user_ids": model.user_ids.to_dict(),
             "item_ids": model.item_ids.to_dict(),
-        })
+        }, U=model.U, V=model.V)
 
     def load_model(self, blob: Optional[bytes], instance_dir: Optional[str]) -> ALSModel:
         assert blob is not None
-        d = pickle.loads(blob)
-        arrs = np.load(io.BytesIO(d["npz"]))
+        d, arrs = model_parts.unpack_named(blob)
         return ALSModel(arrs["U"], arrs["V"],
                         BiMap(d["user_ids"]), BiMap(d["item_ids"]))
 

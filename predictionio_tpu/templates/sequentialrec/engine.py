@@ -31,7 +31,6 @@ live lookup — supports anonymous sessions), ``blackList``.
 from __future__ import annotations
 
 import pickle
-import struct
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
@@ -55,7 +54,7 @@ from predictionio_tpu.models.seq_rec import (
     seq_rec_scores,
     seq_rec_train,
 )
-from predictionio_tpu.utils import tracing
+from predictionio_tpu.utils import model_parts, tracing
 from predictionio_tpu.utils.bimap import BiMap
 
 #: a model blob whose arrays follow its pickled head as raw bytes
@@ -277,50 +276,38 @@ class SeqRecAlgorithm(Algorithm):
             history, num, query.get("blackList"))}
 
     def save_model(self, model: SeqRecModel, instance_dir: Optional[str]
-                   ) -> bytes:
-        """``magic | head length | pickled head | the arrays' raw
-        bytes``: no compression and ONE copy of the parameters (the
-        join) — pickling the tree would copy 2.8 GB of a 706 M
-        parameter model a second time, zlib would take minutes."""
+                   ) -> List[Any]:
+        """``magic | head length | pickled head`` and then each array's
+        own buffer, as parts (utils/model_parts.py): no compression and
+        NO copy of the parameters — a join or a pickle of the tree
+        would copy 2.8 GB of a 706 M parameter model, zlib would take
+        minutes."""
         import jax
 
         leaves, treedef = jax.tree.flatten(
             jax.tree.map(np.asarray, model.params))
-        head = pickle.dumps({
+        return model_parts.pack_arrays({
             # the tree with each array's number in its place
             "tree": jax.tree.unflatten(treedef, range(len(leaves))),
-            "leaves": [(a.dtype.str, a.shape) for a in leaves],
             "item_ids": model.item_ids.to_dict(),
             "app_name": model.app_name,
             "hp": model.hp,
             "model_type": model.model_type,
             "algo_params": model.algo_params,
             "losses": model.losses,
-        })
-        return b"".join(
-            [_RAW_MAGIC, struct.pack("<Q", len(head)), head]
-            + [memoryview(np.ascontiguousarray(a)).cast("B")
-               for a in leaves])
+        }, leaves, _RAW_MAGIC)
 
     def load_model(self, blob: Optional[bytes],
                    instance_dir: Optional[str]) -> SeqRecModel:
         assert blob is not None
-        if not blob.startswith(_RAW_MAGIC):     # saved the old way
+        got = model_parts.unpack_arrays(blob, _RAW_MAGIC)
+        if got is None:                         # saved the old way
             d = pickle.loads(blob)
             params = d["params"]
         else:
-            at = len(_RAW_MAGIC) + 8
-            (n,) = struct.unpack("<Q", blob[len(_RAW_MAGIC):at])
-            d = pickle.loads(blob[at:at + n])
-            at += n
-            leaves = []
-            for dtype, shape in d["leaves"]:
-                a = np.frombuffer(blob, np.dtype(dtype),
-                                  int(np.prod(shape, dtype=np.int64)), at)
-                leaves.append(a.reshape(shape))
-                at += a.nbytes
             import jax
 
+            d, leaves = got
             params = jax.tree.map(lambda i: leaves[i], d["tree"])
         # a model saved before the backbones had a table says nothing:
         # its config is then the default backbone's

@@ -12,8 +12,6 @@ items, with category/whitelist/blacklist filters; SURVEY.md §2c).
 
 from __future__ import annotations
 
-import io
-import pickle
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
@@ -38,6 +36,7 @@ from predictionio_tpu.models.als import (
     als_train,
     similar_items,
 )
+from predictionio_tpu.utils import model_parts
 from predictionio_tpu.utils.bimap import BiMap
 
 
@@ -299,11 +298,9 @@ class ALSAlgorithm(Algorithm):
             query.get("blackList"),
         )}
 
-    def save_model(self, model: SimilarProductModel, instance_dir: Optional[str]) -> bytes:
-        buf = io.BytesIO()
-        np.savez_compressed(buf, V=model.V)
+    def save_model(self, model: SimilarProductModel,
+                   instance_dir: Optional[str]) -> List[Any]:
         d = {
-            "npz": buf.getvalue(),
             "item_ids": model.item_ids.to_dict(),
             "cats": model.item_categories,
             "ann_shortlist": model.ann_shortlist,
@@ -318,12 +315,11 @@ class ALSAlgorithm(Algorithm):
             d["ann_index"] = model.ann_index.to_bytes()
             if instance_dir:
                 ann.save_index(model.ann_index, instance_dir)
-        return pickle.dumps(d)
+        return model_parts.pack_named(d, V=model.V)
 
     def load_model(self, blob: Optional[bytes], instance_dir: Optional[str]) -> SimilarProductModel:
         assert blob is not None
-        d = pickle.loads(blob)
-        arrs = np.load(io.BytesIO(d["npz"]))
+        d, arrs = model_parts.unpack_named(blob)
         ann_index = None
         if instance_dir:
             from predictionio_tpu import ann

@@ -83,7 +83,6 @@ from __future__ import annotations
 import argparse
 import http.client
 import json
-import pickle
 import time
 
 import numpy as np
@@ -93,6 +92,7 @@ def fabricate_instance(storage, n_users: int, n_items: int, rank: int,
                        instance_id: str = "profile-serving", seed: int = 0):
     """Persist a synthetic ALS model + COMPLETED EngineInstance the way
     `pio train` would, so prepare_deploy loads the real thing."""
+    from predictionio_tpu.core.workflow import frame_models
     from predictionio_tpu.storage.meta import EngineInstance
     from predictionio_tpu.templates.recommendation.engine import (
         ALSAlgorithm,
@@ -125,7 +125,7 @@ def fabricate_instance(storage, n_users: int, n_items: int, rank: int,
             [{"name": "als", "params": {"rank": rank}}]),
         serving_params="{}")
     storage.meta.insert_engine_instance(ei)
-    storage.models.put(ei.id, pickle.dumps([blob]))
+    storage.models.put_parts(ei.id, frame_models([blob]))
     return factory
 
 

@@ -519,8 +519,8 @@ def _fabricate(storage, n_users=200, n_items=2500, rank=8):
     """A synthetic COMPLETED ALS instance, the way pio train would
     persist one (profile_serving.py pattern)."""
     import json as _json
-    import pickle
 
+    from predictionio_tpu.core.workflow import frame_models
     from predictionio_tpu.data.event import utcnow
     from predictionio_tpu.storage.meta import EngineInstance
     from predictionio_tpu.templates.recommendation.engine import (
@@ -548,7 +548,7 @@ def _fabricate(storage, n_users=200, n_items=2500, rank=8):
             [{"name": "als", "params": {"rank": rank}}]),
         serving_params="{}")
     storage.meta.insert_engine_instance(ei)
-    storage.models.put(ei.id, pickle.dumps([blob]))
+    storage.models.put_parts(ei.id, frame_models([blob]))
     return factory
 
 

@@ -488,7 +488,8 @@ def test_a_saved_model_names_its_backbone_and_an_older_one_is_glm(
 
     magic = eng._RAW_MAGIC
 
-    def head_of(blob):
+    def head_of(parts):
+        blob = b"".join(parts)
         (n,) = struct.unpack("<Q", blob[len(magic):len(magic) + 8])
         at = len(magic) + 8
         return pickle.loads(blob[at:at + n]), blob[at + n:]
@@ -511,10 +512,10 @@ def test_a_saved_model_names_its_backbone_and_an_older_one_is_glm(
             jax.device_get({"params": params, "bias": bias}),
             BiMap.string_int(f"i{i}" for i in range(8)), "LfmApp", config,
             algo.params, np.zeros(0, np.float32), config.model_type)
-        blob = algo.save_model(model, None)
-        head, arrays = head_of(blob)
+        parts = algo.save_model(model, None)
+        head, arrays = head_of(parts)
         assert head["model_type"] == want
-        assert algo.load_model(blob, None).model_type == want
+        assert algo.load_model(b"".join(parts), None).model_type == want
         if want == "glm4_moe_lite":
             del head["model_type"]                # as saved before PR 33
             old = pickle.dumps(head)

@@ -189,7 +189,7 @@ SPAN_ATTRS = {
     "als.checkpoint": ("step", "bytes"),
     "als.fetch": ("bytes",),
     "model.serialize": ("bytes",),
-    "model.put": ("bytes",),
+    "model.put": ("bytes", "parts", "streamed"),
 }
 
 SPAN_VARIANT = dict(VARIANT, algorithms=[{"name": "als", "params": {
