@@ -57,7 +57,7 @@ from predictionio_tpu.models.seq_backbone import (  # noqa: F401 — the
     # names this module had before the pieces moved, kept for its callers
     _attn_tiles, _cast_in_loop, _chunked_ce, _dt, _is_shape, _mm,
     _moe, _path_name, _rms, _rope, _stacked, _swiglu, _swiglu_shapes,
-    pack_histories)
+    pack_histories, scope)
 
 #: what the published config may say and this file can honour
 _REQUIRED = {"hidden_act": "silu", "attention_bias": False, "n_group": 1,
@@ -287,7 +287,7 @@ def _mla(w, x, seg, pos, c: GlmConfig):
         k = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(k_r[:, None, :], (S, H, dr))],
             -1)
-        with jax.named_scope("seqrec.mla.attention"):
+        with scope("seqrec.mla.attention"):
             out = _attention(q.astype(_dt(c)), k.astype(_dt(c)),
                              kv[..., dn:].astype(_dt(c)), seg, c)
         # W_o takes the heads' outputs as attention leaves them: ONE
@@ -301,18 +301,18 @@ def _mla(w, x, seg, pos, c: GlmConfig):
 def _block(w, x, seg, pos, bias, c: GlmConfig):
     """One layer on the residual stream x [B, S, d] float32; ``bias``
     None marks a dense layer."""
-    import jax
-
     B, S, d = x.shape
-    with jax.named_scope("seqrec.mla"):
+    with scope("seqrec.mla"):
         x = x + _mla(w["attn"], _rms(x, w["attn_norm"], c.rms_norm_eps),
                      seg, pos, c)
-    h = _rms(x, w["ffn_norm"], c.rms_norm_eps)
+    with scope("seqrec.norm"):
+        h = _rms(x, w["ffn_norm"], c.rms_norm_eps)
     if bias is None:
-        with jax.named_scope("seqrec.ffn"):
+        with scope("seqrec.ffn"):
             return x + _swiglu(w["ffn"], h, c), None
     y, stats = _moe(w, h.reshape(B * S, d), seg.reshape(-1) > 0, bias, c)
-    return x + y.reshape(B, S, d), stats
+    with scope("seqrec.residual"):
+        return x + y.reshape(B, S, d), stats
 
 
 def _stack(params, bias, batch, c: GlmConfig, mtp: bool = True):
@@ -328,7 +328,7 @@ def _stack(params, bias, batch, c: GlmConfig, mtp: bool = True):
     import jax.numpy as jnp
 
     tokens, seg, pos = batch["tokens"], batch["seg"], batch["pos"]
-    with jax.named_scope("seqrec.embed"):
+    with scope("seqrec.embed"):
         x = params["embed"][tokens]
         nxt = params["embed"][batch["tgt1"]] if mtp else None
     n, w_mtp, eps = c.n_moe_layers, params["mtp"], c.rms_norm_eps
@@ -338,7 +338,7 @@ def _stack(params, bias, batch, c: GlmConfig, mtp: bool = True):
             lambda w, x: _block(w, x, seg, pos, None, c)[0])(w, x), None
 
     def enter_mtp(x):
-        with jax.named_scope("seqrec.mtp"):
+        with scope("seqrec.mtp"):
             return _mm(jnp.concatenate(
                 [_rms(nxt, w_mtp["enorm"], eps),
                  _rms(x, w_mtp["hnorm"], eps)], -1), w_mtp["eh_proj"], c), x
@@ -354,12 +354,14 @@ def _stack(params, bias, batch, c: GlmConfig, mtp: bool = True):
     def sparse(carry, iwb):
         return jax.checkpoint(turn)(*iwb, *carry)
 
-    x, _ = jax.lax.scan(dense, x, params["dense"])
     turns = n + 1 if mtp else n
-    (x, x_last), stats = jax.lax.scan(
-        sparse, (x, x),
-        (jnp.arange(turns), jax.tree.map(lambda a: a[:turns], params["moe"]),
-         bias[:turns]))
+    with scope("seqrec.stack"):
+        x, _ = jax.lax.scan(dense, x, params["dense"])
+        (x, x_last), stats = jax.lax.scan(
+            sparse, (x, x),
+            (jnp.arange(turns),
+             jax.tree.map(lambda a: a[:turns], params["moe"]),
+             bias[:turns]))
     return (x_last, x, stats) if mtp else (x, None, stats)
 
 

@@ -56,7 +56,7 @@ import numpy as np
 from predictionio_tpu.models import seq_backbone
 from predictionio_tpu.models.seq_backbone import (
     _cast_in_loop, _chunked_ce, _dt, _mm, _moe, _path_name, _rms, _rope,
-    _stacked, _swiglu, _swiglu_shapes)
+    _stacked, _swiglu, _swiglu_shapes, scope)
 
 #: what the published config may say and this file can honour
 _REQUIRED = {"model_type": "lfm2_moe", "conv_bias": False,
@@ -274,11 +274,10 @@ def _conv_mix(b, cc, u, taps, pos):
 
 def _shortconv(w, x, pos, c: Lfm2Config):
     """x [B, S, d] (normed) → [B, S, d]."""
-    import jax
     import jax.numpy as jnp
 
     b, cc, u = jnp.split(_mm(x, w["w_in"], c), 3, axis=-1)
-    with jax.named_scope("seqrec.conv.mix"):
+    with scope("seqrec.conv.mix"):
         y = _conv_mix(b, cc, u, w["taps"].astype(jnp.float32), pos)
     return _mm(y, w["w_out"], c)
 
@@ -299,7 +298,7 @@ def _gqa(w, x, seg, pos, c: Lfm2Config):
         k = _rope(_rms(_mm(x, w["wk"], c).reshape(S, Hkv, D), w["k_norm"],
                        eps), pos[:, None], c.rope_theta)
         v = _mm(x, w["wv"], c).reshape(S, Hkv, D)
-        with jax.named_scope("seqrec.gqa.attention"):
+        with scope("seqrec.gqa.attention"):
             out = seq_backbone.attention(
                 q.astype(_dt(c)), k.astype(_dt(c)), v.astype(_dt(c)), seg,
                 c, scale)
@@ -312,23 +311,23 @@ def _gqa(w, x, seg, pos, c: Lfm2Config):
 def _layer(w, x, seg, pos, bias, c: Lfm2Config, op: str):
     """One layer on the residual stream x [B, S, d] float32; ``bias``
     None marks a dense layer."""
-    import jax
-
     B, S, d = x.shape
     if op == "conv":
-        with jax.named_scope("seqrec.conv"):
+        with scope("seqrec.conv"):
             x = x + _shortconv(w["conv"], _rms(x, w["op_norm"], c.norm_eps),
                                pos, c)
     else:
-        with jax.named_scope("seqrec.gqa"):
+        with scope("seqrec.gqa"):
             x = x + _gqa(w["attn"], _rms(x, w["op_norm"], c.norm_eps), seg,
                          pos, c)
-    h = _rms(x, w["ffn_norm"], c.norm_eps)
+    with scope("seqrec.norm"):
+        h = _rms(x, w["ffn_norm"], c.norm_eps)
     if bias is None:
-        with jax.named_scope("seqrec.ffn"):
+        with scope("seqrec.ffn"):
             return x + _swiglu(w["ffn"], h, c), None
     y, stats = _moe(w, h.reshape(B * S, d), seg.reshape(-1) > 0, bias, c)
-    return x + y.reshape(B, S, d), stats
+    with scope("seqrec.residual"):
+        return x + y.reshape(B, S, d), stats
 
 
 def _stack(params, bias, batch, c: Lfm2Config):
@@ -338,7 +337,7 @@ def _stack(params, bias, batch, c: Lfm2Config):
     import jax.numpy as jnp
 
     seg, pos = batch["seg"], batch["pos"]
-    with jax.named_scope("seqrec.embed"):
+    with scope("seqrec.embed"):
         x = params["embed"][batch["tokens"]]
     stats, at = [], 0
     for (op, dense, n), w in zip(c.runs, params["runs"]):
@@ -348,8 +347,9 @@ def _stack(params, bias, batch, c: Lfm2Config):
             return _layer(w, x, seg, pos, b[0] if b else None, c, op)
 
         xs = (jnp.arange(n), w) + (() if dense else (bias[at:at + n],))
-        x, s = jax.lax.scan(lambda x, iwb: jax.checkpoint(turn)(x, iwb),
-                            x, xs)
+        with scope("seqrec.stack"):
+            x, s = jax.lax.scan(
+                lambda x, iwb: jax.checkpoint(turn)(x, iwb), x, xs)
         if not dense:
             stats.append(s)
             at += n

@@ -17,6 +17,9 @@ shapes, operators and stack. Everything else is here, once:
   (:mod:`predictionio_tpu.ops.seq_attention`), ``_swiglu``, ``_moe``
   (:mod:`predictionio_tpu.ops.moe_dispatch`; a shared expert where the
   layer's weights hold one), ``_cast_in_loop``, ``_chunked_ce``;
+- :func:`scope`: the ``seqrec.*`` names the device trace is read by
+  (:data:`SCOPES`), and :func:`program_name`, which puts them into the
+  train program's identity;
 - :func:`init_program` (one jitted program makes parameters, Adam's
   state and the router bias on the device), :func:`train_program` (the
   step: gradients, group norms, clipping, Adam, the router-bias rule;
@@ -34,6 +37,7 @@ norms' statistics and the loss in float32.
 
 from __future__ import annotations
 
+import hashlib
 import importlib
 import math
 from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
@@ -234,6 +238,55 @@ def init_program(c, shapes, bias_shape: tuple, with_optimizer: bool):
     return jax.jit(init)
 
 
+# -- the scopes ---------------------------------------------------------------
+
+#: Every ``jax.named_scope`` a backbone's train program opens: the
+#: device trace is read by them (``benchmark/scope_reduce.py`` gives an
+#: operation to the INNERMOST one on its path, so a scope goes around
+#: or beside another, never into one whose seconds a metric reads).
+#: ``moe_dispatch`` opens its three itself.
+SCOPES = frozenset({
+    "seqrec.step",          # a step's body; self: what no other names
+    "seqrec.embed", "seqrec.head", "seqrec.optimizer",
+    "seqrec.stack",         # a scan over a run of layers; self: the
+                            # scan's own slices, stacking and carries
+    "seqrec.stack.cast",    # _cast_in_loop
+    "seqrec.norm",          # the norm between operator and FFN / experts,
+                            # and the experts' copy in the matmul dtype
+    "seqrec.residual",      # the expert branch's residual add
+    "seqrec.ffn", "seqrec.moe.route", "seqrec.moe.dispatch",
+    "seqrec.moe.experts", "seqrec.moe.combine",
+    "seqrec.mla", "seqrec.mla.attention", "seqrec.mtp",   # glm4_moe_lite
+    "seqrec.conv", "seqrec.conv.mix", "seqrec.gqa",       # lfm2_moe
+    "seqrec.gqa.attention"})
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`SCOPES`; any
+    other is refused, because :func:`program_name` would not know it."""
+    import jax
+
+    if name not in SCOPES:
+        raise ValueError(f"scope {name!r} is not in seq_backbone.SCOPES")
+    return jax.named_scope(name)
+
+
+def program_name() -> str:
+    """The train function's name, with a digest of :data:`SCOPES`.
+
+    A scope lives only in an operation's debug info, and JAX keys its
+    persistent compilation cache on the module AFTER stripping that: a
+    program that differs from a cached one in its scopes alone is
+    answered with the cached executable, and the trace shows the old
+    names. The function's name (the module's ``sym_name``) is hashed,
+    so a program whose scope set changed is compiled once more and hit
+    ever after. Not ``jax_compilation_cache_include_metadata_in_key``:
+    that puts every traced frame's file path into the key, and a
+    checkout unpacked elsewhere would never hit."""
+    digest = hashlib.sha256(" ".join(sorted(SCOPES)).encode("ascii"))
+    return f"train_{digest.hexdigest()[:8]}"
+
+
 # -- the pieces of a block ----------------------------------------------------
 
 
@@ -319,7 +372,7 @@ def _moe(w, x, valid, bias, c):
     import jax.numpy as jnp
 
     E, k = c.router_experts, c.num_experts_per_tok
-    with jax.named_scope("seqrec.moe.route"):
+    with scope("seqrec.moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             x, w["router"], precision=jax.lax.Precision.HIGHEST))
         ids, gates = moe_dispatch.route(
@@ -327,12 +380,14 @@ def _moe(w, x, valid, bias, c):
         p = moe_dispatch.plan(ids, c.held, E, valid)
         load = jnp.zeros(E, jnp.float32).at[ids.reshape(-1)].add(
             jnp.repeat(valid, k).astype(jnp.float32))
+    with scope("seqrec.norm"):      # the normed rows as the products take them
+        rows = x.astype(_dt(c))
     out = moe_dispatch.experts_swiglu(
-        x.astype(_dt(c)), w["experts"]["wg"].astype(_dt(c)),
+        rows, w["experts"]["wg"].astype(_dt(c)),
         w["experts"]["wu"].astype(_dt(c)), w["experts"]["wd"].astype(_dt(c)),
         gates, p)
     if "shared" in w:
-        with jax.named_scope("seqrec.ffn"):
+        with scope("seqrec.ffn"):
             out = out + _swiglu(w["shared"], x, c)
     held = load[jnp.asarray(c.held)]
     return out, {
@@ -352,11 +407,14 @@ def _cast_in_loop(w, c, turn, aside: Tuple[str, ...] = ("router",)):
     the cast."""
     import jax.numpy as jnp
 
-    one = jnp.where(turn >= 0, 1.0, 0.0).astype(jnp.float32)
-    return {k: (v if k in aside else _cast_in_loop(v, c, turn, aside)
-                if isinstance(v, dict)
-                else (v * one).astype(_dt(c)) if v.ndim >= 2 else v)
-            for k, v in w.items()}
+    def cast(w):
+        return {k: (v if k in aside else cast(v) if isinstance(v, dict)
+                    else (v * one).astype(_dt(c)) if v.ndim >= 2 else v)
+                for k, v in w.items()}
+
+    with scope("seqrec.stack.cast"):
+        one = jnp.where(turn >= 0, 1.0, 0.0).astype(jnp.float32)
+        return cast(w)
 
 
 def _chunked_ce(logits_of, x, targets, c):
@@ -378,7 +436,7 @@ def _chunked_ce(logits_of, x, targets, c):
         hit = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
         return jnp.where(t > 0, lse - hit, 0.0).sum()
 
-    with jax.named_scope("seqrec.head"):
+    with scope("seqrec.head"):
         return jax.lax.map(chunk, (x.reshape(-1, n, d),
                                    t.reshape(-1, n))).sum()
 
@@ -414,12 +472,13 @@ def train_program(c, epochs: int, loss_fn, group_squares,
 
     tx = _make_tx()
 
+    @scope("seqrec.step")
     def step(state, batch):
         params, opt_state, bias = state
         (_, rec), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, bias, batch, c)
         moe = rec.pop("moe")
-        with jax.named_scope("seqrec.optimizer"):
+        with scope("seqrec.optimizer"):
             squares = group_squares(grads)
             norm = jnp.sqrt(sum(squares.values()))
             scale = jnp.minimum(1.0, c.clip_norm / (norm + 1e-6))
@@ -449,6 +508,7 @@ def train_program(c, epochs: int, loss_fn, group_squares,
         return state, jax.tree.map(
             lambda a: a.reshape((-1,) + a.shape[2:]), records)
 
+    train.__name__ = program_name()
     return jax.jit(train, donate_argnums=(0,))
 
 

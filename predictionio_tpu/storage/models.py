@@ -19,9 +19,10 @@ import shutil
 import threading
 from abc import ABC, abstractmethod
 from concurrent.futures import ThreadPoolExecutor
+from contextlib import ExitStack
 from typing import Any, Dict, Iterable, List, Optional
 
-from predictionio_tpu.utils import faults, integrity
+from predictionio_tpu.utils import faults, integrity, tracing
 from predictionio_tpu.utils.atomic_write import atomic_file, atomic_write_bytes
 
 
@@ -170,18 +171,30 @@ class LocalFSModelStore(ModelStore):
         # (each part goes from the memory it lies in to the file and to
         # ONE running digest, which a helper thread computes while the
         # blob is written — both release the GIL, and a 2.8 GB model
-        # pays each for seconds; the digest is written after the blob)
+        # pays each for seconds. Under a verb the three stretches are
+        # child spans of the caller's ``model.put``: ``.write`` the
+        # copies into the page cache with the hash running beside them,
+        # ``.sync`` what ``atomic_file`` does when it closes — flush,
+        # fsync, replace, fsync of the directory —, ``.digest`` the wait
+        # for the hash's tail and the sidecar, after the blob)
         sha = hashlib.sha256()
-        with ThreadPoolExecutor(max_workers=1) as pool:
-            hashed = []
-            with atomic_file(path) as f:
+        with ThreadPoolExecutor(max_workers=1) as pool, ExitStack() as blob:
+            f = blob.enter_context(atomic_file(path))
+            with tracing.span("model.put.write") as sp:
+                hashed, size = [], 0
                 for part in parts:
                     hashed.append(pool.submit(sha.update, part))
                     f.write(part)
-            for h in hashed:
-                h.result()
-        atomic_write_bytes(path + integrity.DIGEST_SUFFIX,
-                           sha.hexdigest().encode("ascii"))
+                    size += memoryview(part).nbytes
+                sp.set_attr("bytes", size)
+                sp.set_attr("parts", len(hashed))
+            with tracing.span("model.put.sync"):
+                blob.close()
+            with tracing.span("model.put.digest"):
+                for h in hashed:
+                    h.result()
+                atomic_write_bytes(path + integrity.DIGEST_SUFFIX,
+                                   sha.hexdigest().encode("ascii"))
         return True
 
     def get(self, instance_id: str) -> Optional[bytes]:

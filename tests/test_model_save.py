@@ -506,6 +506,94 @@ def test_model_put_span_carries_parts_streamed_bytes(trained):
     assert 0 < serialize["attrs"]["bytes"] < size
 
 
+# -- (i) model.put's inside: write, sync, digest -------------------------------
+
+PUT_CHILDREN = ["model.put.write", "model.put.sync", "model.put.digest"]
+
+
+def _children_of_put(tree):
+    (put,) = [s for s in tree if s["name"] == "model.put"]
+    return put, [s for s in tree if s["parentId"] == put["spanId"]]
+
+
+@cells
+def test_model_put_has_three_children_that_cover_it(trained):
+    put, kids = _children_of_put(trained["tree"])
+    assert [k["name"] for k in kids] == PUT_CHILDREN
+    for a, b in zip(kids, kids[1:]):
+        assert put["startNs"] <= a["startNs"] <= a["endNs"] \
+            <= b["startNs"] <= b["endNs"] <= put["endNs"]
+    # host_untraced_s subtracts LEAF spans: what the three leave of
+    # their parent is host time that no span names
+    covered = sum(k["endNs"] - k["startNs"] for k in kids)
+    assert 0 <= put["endNs"] - put["startNs"] - covered < 10e6
+    size = os.path.getsize(os.path.join(trained["dir"], "model.bin"))
+    assert kids[0]["attrs"] == {"bytes": size,
+                                "parts": put["attrs"]["parts"]}
+
+
+@pytest.mark.parametrize("in_verb", [True, False])
+def test_the_blob_is_synced_under_its_name_before_the_digest_is_written(
+        tmp_path, monkeypatch, in_verb):
+    """The durable order, by the calls the store makes: the written
+    file's fsync, then its name, then the directory's fsync — all
+    three inside ``model.put.sync`` — and only then the same three for
+    the sidecar, inside ``model.put.digest``. Outside a verb, with
+    tracing off, the same calls and no span."""
+    calls = []
+
+    def noting(what, real):
+        def call(*args):
+            target = args[-1] if what == "replace" else os.readlink(
+                f"/proc/self/fd/{args[0]}")
+            calls.append((what, os.path.basename(target),
+                          tracing.time.perf_counter_ns()))
+            return real(*args)
+        return call
+
+    monkeypatch.setattr(os, "fsync", noting("fsync", os.fsync))
+    monkeypatch.setattr(os, "replace", noting("replace", os.replace))
+    store = LocalFSModelStore(str(tmp_path))
+    parts = [b"head", memoryview(np.arange(6, dtype=np.float32)),
+             np.arange(5, dtype=np.int64)]
+    assert not tracing.TRACER.enabled
+    before = tracing.last_verb("put.test")
+    if in_verb:
+        with tracing.verb("put.test"), tracing.span("model.put"):
+            assert store.put_parts("m", parts) is True
+    else:
+        assert store.put_parts("m", parts) is True
+    kinds = [(what, "tmp" if name.startswith(".atomic-") else name)
+             for what, name, _ in calls]
+    assert kinds == [("fsync", "tmp"), ("replace", "model.bin"),
+                     ("fsync", "m"), ("fsync", "tmp"),
+                     ("replace", "model.bin.sha256"), ("fsync", "m")]
+    blob = b"".join(bytes(p) for p in parts)
+    assert store.get("m") == blob
+    with open(tmp_path / "m" / "model.bin.sha256") as f:
+        assert f.read() == hashlib.sha256(blob).hexdigest()
+    if not in_verb:
+        assert tracing.last_verb("put.test") == before
+        assert len(tracing.TRACER.ring) == 0
+        return
+    put, (write, sync, digest) = _children_of_put(
+        tracing.last_verb("put.test"))
+    assert [s["name"] for s in (write, sync, digest)] == PUT_CHILDREN
+    assert write["attrs"] == {"bytes": len(blob), "parts": 3}
+    for span, stamps in ((sync, calls[:3]), (digest, calls[3:])):
+        assert all(span["startNs"] <= t <= span["endNs"]
+                   for _, _, t in stamps)
+
+
+def test_a_store_that_joins_opens_no_child_span():
+    """Only the local file system has a write, a sync and a digest to
+    tell apart; the default ``put_parts`` joins and ``put``s."""
+    with tracing.verb("put.test"), tracing.span("model.put"):
+        assert MemoryModelStore().put_parts("m", [b"a", b"b"]) is False
+    assert [s["name"] for s in tracing.last_verb("put.test")] == [
+        "put.test", "model.put"]
+
+
 # -- the helper's own layout ---------------------------------------------------
 
 

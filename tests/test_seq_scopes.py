@@ -1,0 +1,238 @@
+"""The ``seqrec.*`` scopes of the backbones' train programs (PR 36):
+which names a lowered program carries and where, and that the
+program's identity in JAX's persistent compilation cache changes with
+them — the cache strips debug info before it hashes a module, and a
+scope lives only there.
+"""
+
+import contextlib
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from predictionio_tpu.models import glm4_moe_lite as glm
+from predictionio_tpu.models import lfm2_moe as lfm
+from predictionio_tpu.models import seq_backbone
+from predictionio_tpu.models.seq_rec import _make_tx
+
+TINY = dict(hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+            ep_size=1, num_experts_per_tok=2, vocab_size=50, seq_len=64,
+            seqs_per_step=2, attn_block=32, token_chunk=64, init_std=0.2)
+BACKBONES = {
+    "glm4_moe_lite": (glm, glm.GlmConfig.from_architecture(dict(
+        TINY, model_type="glm4_moe_lite", num_attention_heads=2,
+        q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=8,
+        num_hidden_layers=3))),
+    "lfm2_moe": (lfm, lfm.Lfm2Config.from_architecture(dict(
+        TINY, model_type="lfm2_moe", num_attention_heads=4,
+        num_key_value_heads=2, conv_L_cache=3, conv_bias=False,
+        use_expert_bias=True, num_hidden_layers=4, num_dense_layers=1,
+        layer_types=["conv", "full_attention", "conv", "conv"],
+        num_experts=8))),
+}
+#: what this PR added: around and beside the operators' scopes
+NEW = {"seqrec.step", "seqrec.stack", "seqrec.stack.cast", "seqrec.norm",
+       "seqrec.residual"}
+#: the scopes only one backbone opens
+OWN = {"glm4_moe_lite": {"seqrec.mla", "seqrec.mla.attention", "seqrec.mtp"},
+       "lfm2_moe": {"seqrec.conv", "seqrec.conv.mix", "seqrec.gqa",
+                    "seqrec.gqa.attention"}}
+SCOPE = re.compile(r"seqrec\.[a-z_.]+[a-z_]")    # scope_reduce's pattern
+
+
+def _abstract_args(module, c):
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    params = jax.tree.map(sds, module.param_shapes(c),
+                          is_leaf=seq_backbone._is_shape)
+    _, bias = jax.eval_shape(lambda: module.init_state(c, 0))
+    data = {k: sds((2, c.seqs_per_step, c.seq_len), jnp.int32)
+            for k in module.BATCH_KEYS}
+    return (params, jax.eval_shape(_make_tx().init, params), bias), data
+
+
+def _program(module, c):
+    """The backbone's train program, traced anew (``module.train_program``
+    keeps one per config)."""
+    return seq_backbone.train_program(c, 1, module.loss_fn,
+                                      module.group_squares,
+                                      module.grad_groups(c))
+
+
+@contextlib.contextmanager
+def _cache_in(directory):
+    """JAX's persistent compilation cache in ``directory``, every
+    program kept; the process's own settings put back after."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    was = {k: getattr(jax.config, k) for k in keys}
+    try:
+        for k, v in zip(keys, (str(directory), True, 0.0, 0)):
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+        yield
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module", params=sorted(BACKBONES))
+def compiled(request, tmp_path_factory):
+    """A backbone's train program at a tiny size, compiled once (into a
+    cache directory of its own: nothing stale can answer): the
+    module's name and the ``op_name`` paths of its operations — what a
+    device trace shows as ``tf_op`` — that hold a scope."""
+    module, c = BACKBONES[request.param]
+    with _cache_in(tmp_path_factory.mktemp("cache")):
+        text = _program(module, c).lower(
+            *_abstract_args(module, c)).compile().as_text()
+    paths = {p for p in re.findall(r'op_name="([^"]*)"', text)
+             if SCOPE.search(p)}
+    return {"backbone": request.param, "paths": paths,
+            "module": re.match(r"HloModule (\w+)", text).group(1),
+            "found": {s for p in paths for s in SCOPE.findall(p)}}
+
+
+# -- (a) the names and where they lie ----------------------------------------
+
+
+@pytest.mark.parametrize("scope", sorted(seq_backbone.SCOPES))
+def test_the_program_names_the_scope(compiled, scope):
+    """Every new scope and every scope the program had before — and
+    none of the other backbone's."""
+    other = set().union(*OWN.values()) - OWN[compiled["backbone"]]
+    assert (scope in compiled["found"]) == (scope not in other)
+
+
+def test_the_table_holds_every_name_the_program_opens(compiled):
+    """``moe_dispatch`` opens its scopes itself, past ``scope()``'s
+    check: the table that the program's name is made from lists them
+    too."""
+    assert compiled["found"] <= seq_backbone.SCOPES
+    assert NEW | OWN[compiled["backbone"]] <= compiled["found"]
+
+
+def test_a_new_scope_lies_around_or_beside_the_old_ones(compiled):
+    """The device trace's reader gives an operation to the INNERMOST
+    scope of its path: a new scope inside an old one would take
+    operations out of a metric that is already read."""
+    innermost = set()
+    for path in compiled["paths"]:
+        # (an interpreted Pallas call repeats its caller's path; an
+        # operation of an inner function may carry the path from THAT
+        # function's top only)
+        scopes = SCOPE.findall(path[max(path.rfind("jit(train"), 0):])
+        old_seen = False
+        for s in scopes:
+            assert not (old_seen and s in NEW), path
+            old_seen = old_seen or s not in NEW
+        if "seqrec.step" in scopes:
+            assert scopes.index("seqrec.step") == 0, path
+            if scopes[-1] in ("seqrec.stack.cast", "seqrec.norm",
+                              "seqrec.residual"):
+                assert "seqrec.stack" in scopes[:-1], path
+        innermost.add(scopes[-1])
+    # each name is some operation's innermost: a metric has seconds to read
+    assert NEW <= innermost
+
+
+def test_the_backward_pass_lands_under_the_same_names(compiled):
+    """(Not ``seqrec.residual``: an add's cotangent is no operation.)"""
+    backward = {SCOPE.findall(p)[-1] for p in compiled["paths"]
+                if "transpose(jvp(seqrec.stack))" in p}
+    assert {"seqrec.stack", "seqrec.stack.cast", "seqrec.norm"} <= backward
+
+
+def test_scope_refuses_a_name_the_table_lacks():
+    with seq_backbone.scope("seqrec.norm"):
+        pass
+    with pytest.raises(ValueError, match="seqrec.nrom"):
+        seq_backbone.scope("seqrec.nrom")
+
+
+# -- (b) the program's identity carries the scope set -------------------------
+
+
+def test_the_programs_name_is_made_from_the_scope_set(compiled, monkeypatch):
+    name = seq_backbone.program_name()
+    assert re.fullmatch(r"train_[0-9a-f]{8}", name)
+    assert compiled["module"] == f"jit_{name}"
+    monkeypatch.setattr(seq_backbone, "SCOPES",
+                        seq_backbone.SCOPES - {"seqrec.norm"})
+    assert seq_backbone.program_name() != name
+
+
+class _NoScope(contextlib.ContextDecorator):
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def _compiled_text(monkeypatch, old_scopes_only: bool) -> str:
+    """The tiny ``lfm2_moe`` train program's compiled HLO, through the
+    persistent cache; ``old_scopes_only``: this PR's scopes are no-ops
+    and the table lacks them — the program of the parent commit."""
+    module, c = BACKBONES["lfm2_moe"]
+    with monkeypatch.context() as m:
+        if old_scopes_only:
+            old = seq_backbone.SCOPES - NEW
+
+            def scope(name):
+                return jax.named_scope(name) if name in old else _NoScope()
+
+            m.setattr(seq_backbone, "SCOPES", old)
+            for mod in (seq_backbone, module):
+                m.setattr(mod, "scope", scope)
+        return _program(module, c).lower(
+            *_abstract_args(module, c)).compile().as_text()
+
+
+def _train_entries(directory):
+    return sorted(f.rsplit("-", 2)[0] for f in os.listdir(directory)
+                  if f.startswith("jit_train"))
+
+
+def test_a_program_with_new_scopes_is_a_new_cache_entry(tmp_path,
+                                                        monkeypatch):
+    """A cache directory the parent's program filled: the program as
+    committed misses it once, its executable names the new scopes, and
+    the next trace of it hits that entry with the names in it."""
+    with _cache_in(tmp_path):
+        parent = _compiled_text(monkeypatch, old_scopes_only=True)
+        assert "seqrec.conv" in parent and "seqrec.stack" not in parent
+        assert len(_train_entries(tmp_path)) == 1
+        first = _compiled_text(monkeypatch, old_scopes_only=False)
+        assert "seqrec.stack" in first and "seqrec.norm" in first
+        entries = _train_entries(tmp_path)
+        assert entries[-1] == f"jit_{seq_backbone.program_name()}"
+        assert len(entries) == 2
+        again = _compiled_text(monkeypatch, old_scopes_only=False)
+        assert "seqrec.stack" in again
+        assert _train_entries(tmp_path) == entries
+
+
+def test_under_one_name_the_cache_answers_with_the_old_scopes(tmp_path,
+                                                              monkeypatch):
+    """The trap itself, in this JAX: the cache's key is taken after
+    debug info is stripped, so with a name that ignores the scope set
+    the program as committed is answered with the parent's executable
+    and a trace of it would show the parent's names. (Should a later
+    JAX hash the scopes, this fails and ``program_name`` can go.)"""
+    monkeypatch.setattr(seq_backbone, "program_name", lambda: "train")
+    with _cache_in(tmp_path):
+        parent = _compiled_text(monkeypatch, old_scopes_only=True)
+        stale = _compiled_text(monkeypatch, old_scopes_only=False)
+        assert "seqrec.stack" not in parent
+        assert "seqrec.stack" not in stale
+        assert _train_entries(tmp_path) == ["jit_train"]
