@@ -540,13 +540,14 @@ class ALSPrepared:
         ``bucket_systems`` in ``_make_half`` routes by, the one place
         that does: real (unpadded) interactions, padded
         slots (the layout's padding, still streamed as index and
-        weights), bucket rows, and the factor-line copies the kernel
-        starts. ``real ÷ padded`` is the share of the slots that hold
-        an interaction, ``real ÷ dma`` the share of the copies that
-        fetch a row somebody rated."""
-        from predictionio_tpu.ops.gram import kernel_takes_width
+        weights), bucket rows, the factor-line copies the kernel
+        starts and the DMA waits that retire them. ``real ÷ padded`` is
+        the share of the slots that hold an interaction, ``real ÷ dma``
+        the share of the copies that fetch a row somebody rated,
+        ``waits ÷ dma`` what is left of one wait a copy."""
+        from predictionio_tpu.ops.gram import dma_waits, kernel_takes_width
 
-        real = padded = rows = 0
+        real = padded = rows = waits = 0
         for side in (self.u_side, self.i_side):
             for b in side.buckets:
                 if kernel_takes_width(b.C):
@@ -555,12 +556,16 @@ class ALSPrepared:
                     real += int(b.counts.sum(dtype=np.float64))
                     rows += b.n_slabs * b.slab
                     padded += b.n_slabs * b.slab * b.C
+                    # by the function the kernel takes its group sizes
+                    # from (a segmented entity counts as one long row)
+                    waits += dma_waits(b.counts, b.C)
         # the kernel is given each row's real length and starts exactly
         # that many copies (``_gather_gram_kernel``: no rounding of a
         # length) — a kernel that rounded lengths up would count the
         # rounded ones here
         return {"kernel_real_rows": real, "kernel_padded_rows": padded,
-                "kernel_bucket_rows": rows, "kernel_dma_rows": real}
+                "kernel_bucket_rows": rows, "kernel_dma_rows": real,
+                "kernel_dma_waits": waits}
 
     def layout_paths(self) -> dict:
         """Which data-dependent path :func:`_bucket_side` took on each

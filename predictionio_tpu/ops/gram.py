@@ -9,10 +9,14 @@ VMEM register block, and only the ``(R, k, k)`` / ``(R, k)`` results
 are written back. The gathered ``(R, C, k)`` block never materializes
 in HBM and the weighting never round-trips. What bounds it
 (PERF_LEDGER.jsonl, PR 24: nine bucket shapes, widths 128 to 8192, two
-configurations) is neither the MXU nor HBM but the scalar core starting
-and retiring one 512-byte line copy at a time, a constant ~32 ns each —
-so the kernel is given every row's REAL length and starts no copy for a
-padded slot (PR 25). ``models/als.py _make_half`` selects it via
+configurations) is neither the MXU nor HBM but the scalar core, which
+starts one 512-byte line copy at a time — so the kernel is given every
+row's REAL length and starts no copy for a padded slot (PR 25), starts
+its copies sixteen to a loop trip, retires a whole tile of them with ONE
+wait per set bit of their count, and has the next program's index
+block fetched while this program runs (PR 37: ~32 → ~16 ns a copy;
+the ~0.7 µs of fixed work a row stayed — it is the tile's own vector
+work). ``models/als.py _make_half`` selects it via
 ``PIO_PALLAS_GRAM`` (see :func:`resolve_gram_mode`).
 
 Per padded rating row r:
@@ -39,6 +43,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -67,15 +72,72 @@ from jax.experimental.pallas import tpu as pltpu
 # before the call: a line is 512 B whatever the dtype, so bf16 saves
 # no gather traffic here.
 #
+# The copy pipeline (PR 37; PERF.md §6 has the chip's numbers). Per tile
+# of T slots the scalar core (1) starts the row's `live` real copies,
+# _ISSUE_UNROLL to a loop trip so that the ids' SMEM loads and the
+# address arithmetic of neighbouring copies overlap, (2) waits for them
+# — all copies signal one semaphore and a wait takes the semaphore and
+# an AMOUNT, so a stand-in descriptor of g lines retires g copies: one
+# wait per set bit of `live`, ONE for a full tile (`_wait_sizes`) —
+# and (3) masks and multiplies the tile. The (RB, C) index block of
+# program i + 1 is fetched into a second SMEM buffer while program i
+# runs. A row's tiles land in TWO tile buffers in turn. Measured on the
+# chip (PR 37): starting the next tile's copies AHEAD, while this tile
+# is multiplied, gives nothing (+2…4 % in every bucket but one) — by
+# the time a tile's last copy is started the earlier ones have landed,
+# and the fixed ~0.7 µs a row is the tile's own vector work, which the
+# same instruction stream issues. But where a bucket's copies do NOT
+# keep up with the issue loop (seen once, kernel alone: ML-20M's
+# segmented rows of the most-rated items at 21–25 ns a copy against 15;
+# inside the train that bucket keeps up), it runs 15 % quicker when a
+# tile does not land in the buffer the previous tile was just read
+# from, and no bucket is slower for it: hence two buffers and nothing
+# started ahead.
+#
 # VMEM sizing (per program): 3·RB·C·4 (weights + index block) +
-# T·L·4 (line tile) + (L+1)·L·4 (accumulators) + RB·kp·(kp+1)·4
+# 2·T·L·4 (line tiles) + (L+1)·L·4 (accumulators) + RB·kp·(kp+1)·4
 # (output block), with L = max(128, kp), T = min(C, 256), RB = 8 —
 # worst case (C = 8192) ≈ 1 MB, ~2 MB with the runtime's double
-# buffering of the blocked operands. SMEM: the (RB, C) index block
-# (256 KB at C = 8192) and two (8, 128) blocks of row lengths (8 KB).
+# buffering of the blocked operands. SMEM: two (RB, C) index blocks
+# (512 KB at C = 8192 — the v5e's compiler takes it, held by
+# tests/test_chip_compile.py, and the chip runs it) and two (8, 128)
+# blocks of row lengths (8 KB).
 
 _GATHER_TILE = 256  # factor rows per DMA burst (T)
 _LANES = 128
+_ISSUE_UNROLL = 16  # line copies started per trip of the issue loop
+
+
+def _tile(C: int) -> int:
+    """T: the slots of a bucket row that one burst of line copies
+    fills — 256, or the widest divisor of a narrower or odd C."""
+    T = min(C, _GATHER_TILE)
+    while C % T:  # ladder widths always divide; guard odd test shapes
+        T -= 1
+    return T
+
+
+def _wait_sizes(T: int):
+    """The group sizes a tile's copies are retired in: the powers of
+    two up to T, largest first. A tile of ``live`` copies takes one
+    wait per set bit of ``live`` — a full tile ONE — which serves both
+    the kernel's ``retire`` and the host's :func:`dma_waits`."""
+    return tuple(1 << s for s in reversed(range(T.bit_length())))
+
+
+def dma_waits(lengths, C: int) -> int:
+    """The DMA waits the kernel makes for bucket rows of these real
+    ``lengths`` at width C, counted on the host. An entity of a
+    segmented bucket counts as ONE length: its rows are cut at
+    multiples of C and T divides C, so its tiles are those of one long
+    row."""
+    T = _tile(C)
+
+    def waits(live):
+        return sum((live & g) != 0 for g in _wait_sizes(T))
+
+    n = np.asarray(lengths).astype(np.int64)
+    return int((n // T * waits(T) + waits(n % T)).sum())
 
 
 def _gather_gram_kernel(idx_hbm, len_ref, idx_ref, wo_ref, wb_ref, F_hbm,
@@ -83,57 +145,97 @@ def _gather_gram_kernel(idx_hbm, len_ref, idx_ref, wo_ref, wb_ref, F_hbm,
                         sem_row, *, RB: int, C: int, T: int, kp: int,
                         G: int):
     i = pl.program_id(0)
-    L = f_tile.shape[1]
-    # index block HBM→SMEM first: row ids live on the scalar core, which
-    # issues the factor-line DMAs below
-    cp = pltpu.make_async_copy(
-        idx_hbm.at[pl.ds(i * RB, RB), :], idx_smem, sem_idx)
-    cp.start()
-    cp.wait()
+    L = f_tile.shape[2]
+    ib = i & 1
+
+    # index block HBM→SMEM: row ids live on the scalar core, which
+    # issues the factor-line DMAs below. Two buffers: program i waits
+    # for the block program i - 1 started (the first starts its own)
+    # and starts program i + 1's — the grid runs in order
+    def idx_copy(step, buf):
+        return pltpu.make_async_copy(
+            idx_hbm.at[pl.ds(step * RB, RB), :], idx_smem.at[buf],
+            sem_idx.at[buf])
+
+    @pl.when(i == 0)
+    def _():
+        idx_copy(0, 0).start()
+
+    idx_copy(0, ib).wait()
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _():
+        idx_copy(i + 1, 1 - ib).start()
+
+    shift = G.bit_length() - 1  # row → line: G is a power of two
+
+    def issue(r, base, live, buf):
+        """Start the `live` line copies of one tile — slots
+        [base, base + live) of block row r — into tile buffer `buf`.
+        All signal sem_row and have the same (1, L) shape. The loop is
+        unrolled: a trip starts _ISSUE_UNROLL copies, the remainder
+        goes one by one."""
+        def one(j):
+            row = idx_smem[ib, r, base + j]
+            pltpu.make_async_copy(
+                F_hbm.at[pl.ds(jax.lax.shift_right_logical(row, shift), 1),
+                         :],
+                f_tile.at[buf, pl.ds(j, 1), :],
+                sem_row).start()
+
+        def burst(q, _):
+            for u in range(_ISSUE_UNROLL):
+                one(q * _ISSUE_UNROLL + u)
+            return 0
+
+        def single(j, _):
+            one(j)
+            return 0
+
+        bursts = live // _ISSUE_UNROLL
+        jax.lax.fori_loop(0, bursts, burst, 0)
+        jax.lax.fori_loop(bursts * _ISSUE_UNROLL, live, single, 0)
+
+    def retire(live, buf):
+        """Wait until the tile's `live` copies have landed: a wait
+        takes the semaphore and an AMOUNT, so a stand-in descriptor of
+        g lines retires g copies at once — one wait per set bit of
+        `live` (``_wait_sizes``)."""
+        for g in _wait_sizes(T):
+            @pl.when((live & g) != 0)
+            def _(g=g):
+                pltpu.make_async_copy(
+                    F_hbm.at[pl.ds(0, g), :],
+                    f_tile.at[buf, pl.ds(0, g), :],
+                    sem_row).wait()
+
     tile_row = jax.lax.broadcasted_iota(jnp.int32, (T, L), 0)
     lane_slot = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1) // kp
     # this program's RB lengths within the (8, 128) block of lengths
     # (RB consecutive lanes of one line: RB divides 128)
     len_at = (i * RB) % (8 * _LANES)
     len_line, len_lane = len_at // _LANES, len_at % _LANES
-    for r in range(RB):  # static unroll: RB is small (≤ 8)
+    def row_body(r, _):
         accA[...] = jnp.zeros((L, L), jnp.float32)
         accB[...] = jnp.zeros((1, L), jnp.float32)
         n = len_ref[len_line, len_lane + r]
 
         def tile_body(t, _):
-            # only the row's real slots are fetched: burst-issue `live`
-            # line copies, then drain the semaphore as many times —
-            # each wait retires one completed copy (all copies share
-            # sem_row and the same (1, L) shape)
+            # only the row's real slots are fetched
             live = jnp.minimum(n - t * T, T)
-
-            def issue(j, _):
-                row = idx_smem[r, t * T + j]
-                pltpu.make_async_copy(
-                    F_hbm.at[pl.ds(row // G, 1), :],
-                    f_tile.at[pl.ds(j, 1), :],
-                    sem_row).start()
-                return 0
-
-            jax.lax.fori_loop(0, live, issue, 0)
-
-            def drain(j, _):
-                pltpu.make_async_copy(
-                    F_hbm.at[pl.ds(0, 1), :],
-                    f_tile.at[pl.ds(0, 1), :],
-                    sem_row).wait()
-                return 0
-
-            jax.lax.fori_loop(0, live, drain, 0)
+            # a row's tiles land in the two tile buffers in turn
+            buf = t & 1
+            issue(r, t * T, live, buf)
+            retire(live, buf)
             # tile rows past `live` still hold what an earlier tile or
-            # row fetched (or nothing yet): masked by ROW, because a
-            # zero weight does not make a stale inf or NaN a zero
+            # row fetched into this buffer (or nothing yet): masked by
+            # ROW, because a zero weight does not make a stale inf or
+            # NaN a zero
             keep = tile_row < live
             if G > 1:
                 slot = idx_ref[r, pl.ds(t * T, T)] % G
                 keep &= lane_slot == slot[:, None]
-            F = jnp.where(keep, f_tile[...], 0.0)
+            F = jnp.where(keep, f_tile[buf], 0.0)
             wo = wo_ref[r, pl.ds(t * T, T)]
             wb = wb_ref[r, pl.ds(t * T, T)]
             # f32 normal equations (+13% kernel time over bf16, Gram
@@ -154,7 +256,12 @@ def _gather_gram_kernel(idx_hbm, len_ref, idx_ref, wo_ref, wb_ref, F_hbm,
             A = A + accA[g * kp:(g + 1) * kp, g * kp:(g + 1) * kp]
             b = b + accB[:, g * kp:(g + 1) * kp]
         A_ref[r] = A
-        b_ref[r] = b[0]
+        b_ref[pl.ds(r, 1), :] = b
+        return 0
+
+    # the block's RB rows in a LOOP, not unrolled: one copy of the
+    # row's code keeps the kernel small for the chip's compiler
+    jax.lax.fori_loop(0, RB, row_body, 0)
 
 
 def gather_gram_xla(F_other, idx, wo, wb):
@@ -210,15 +317,14 @@ def gather_gram(F_other, idx, wo, wb, lengths, *,
     if R == 0:
         return (jnp.zeros((0, k, k), jnp.float32),
                 jnp.zeros((0, k), jnp.float32))
-    T = min(C, _GATHER_TILE)
-    while C % T:  # ladder widths always divide; guard odd test shapes
-        T -= 1
+    T = _tile(C)
     # lines of G rows × kp lanes (a pure reshape when k divides 128 and
     # N divides G — rank 64 on an even catalog)
     kp, G = _line_width(k)
     L = kp * G
     F = F_other.astype(jnp.float32)
-    Np = -(-N // G) * G
+    # at least T lines: the group waits' stand-in source is F[0:g], g ≤ T
+    Np = max(-(-N // G), T) * G
     if kp != k or Np != N:
         F = jnp.pad(F, [(0, Np - N), (0, kp - k)])
     F = F.reshape(Np // G, L)
@@ -264,13 +370,17 @@ def gather_gram(F_other, idx, wo, wb, lengths, *,
             jax.ShapeDtypeStruct((Rp, kp), jnp.float32),
         ),
         scratch_shapes=[
-            pltpu.SMEM((RB, C), jnp.int32),
-            pltpu.VMEM((T, L), jnp.float32),
+            pltpu.SMEM((2, RB, C), jnp.int32),
+            pltpu.VMEM((2, T, L), jnp.float32),
             pltpu.VMEM((L, L), jnp.float32),
             pltpu.VMEM((1, L), jnp.float32),
-            pltpu.SemaphoreType.DMA,
+            pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA,
         ],
+        # in order: a program takes the index block its predecessor
+        # started
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         cost_estimate=pl.CostEstimate(
             flops=2 * R * C * L * (L + 1),
             bytes_accessed=(R * C * (8 + 4 * L)

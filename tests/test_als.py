@@ -758,6 +758,11 @@ class TestFusedGram:
                 lengths, b.mask.reshape(-1, b.C).sum(1).astype(np.int32))
         rows = prep.kernel_rows()
         assert rows["kernel_dma_rows"] == sum(int(g.sum()) for g in given)
+        # the waits are counted from the entities' counts; the kernel
+        # makes them for the ROW lengths it is given — the same number,
+        # a segmented entity's rows being cut at whole tiles
+        assert rows["kernel_dma_waits"] == sum(
+            gram_mod.dma_waits(g, b.C) for g, b in zip(given, buckets))
         # no slot that holds an interaction is skipped
         assert rows["kernel_dma_rows"] >= rows["kernel_real_rows"] > 0
 

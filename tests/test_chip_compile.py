@@ -97,7 +97,11 @@ def test_score_topk(one_chip):
 @pytest.mark.parametrize("C", [128, 512, 2048, 8192])
 def test_gather_gram(one_chip, C, dtype):
     """Rank 64 at every gathered ladder width, against both factor
-    sides (26,744 items; 138,493 users padded to the solve chunk)."""
+    sides (26,744 items; 138,493 users padded to the solve chunk).
+    Since PR 37 this is the pipelined kernel: two (T, 128) tile
+    buffers, waits through stand-in descriptors of up to T lines, and
+    two (8, C) index blocks in SMEM — 512 KB at C = 8192, which the
+    v5e's compiler takes."""
     from predictionio_tpu.ops.gram import gather_gram
 
     for n_other in (26_744, 138_496):

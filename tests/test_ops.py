@@ -1,9 +1,14 @@
 """Pallas kernels (interpret mode on CPU) vs numpy/XLA references."""
 
+import functools
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from predictionio_tpu.ops import (
     score_topk, score_topk_xla, segment_count, segment_mean, segment_sum,
@@ -242,6 +247,121 @@ class TestTPULowering:
         assert "tpu_custom_call" in txt, txt[:300]
 
 
+def _parent_gather_gram_kernel(idx_hbm, len_ref, idx_ref, wo_ref, wb_ref,
+                               F_hbm, A_ref, b_ref, idx_smem, f_tile, accA,
+                               accB, sem_idx, sem_row, *, RB, C, T, kp, G):
+    """The kernel body as it was before PR 37 (PR 25's): ONE tile
+    buffer, a tile's copies started, then retired one wait a copy, then
+    multiplied; every program waits for its own index block. Kept here
+    as the reference the pipelined kernel must equal bit for bit."""
+    i = pl.program_id(0)
+    L = f_tile.shape[1]
+    cp = pltpu.make_async_copy(
+        idx_hbm.at[pl.ds(i * RB, RB), :], idx_smem, sem_idx)
+    cp.start()
+    cp.wait()
+    tile_row = jax.lax.broadcasted_iota(jnp.int32, (T, L), 0)
+    lane_slot = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1) // kp
+    len_at = (i * RB) % (8 * 128)
+    len_line, len_lane = len_at // 128, len_at % 128
+    for r in range(RB):
+        accA[...] = jnp.zeros((L, L), jnp.float32)
+        accB[...] = jnp.zeros((1, L), jnp.float32)
+        n = len_ref[len_line, len_lane + r]
+
+        def tile_body(t, _):
+            live = jnp.minimum(n - t * T, T)
+
+            def issue(j, _):
+                row = idx_smem[r, t * T + j]
+                pltpu.make_async_copy(
+                    F_hbm.at[pl.ds(row // G, 1), :],
+                    f_tile.at[pl.ds(j, 1), :],
+                    sem_row).start()
+                return 0
+
+            jax.lax.fori_loop(0, live, issue, 0)
+
+            def drain(j, _):
+                pltpu.make_async_copy(
+                    F_hbm.at[pl.ds(0, 1), :],
+                    f_tile.at[pl.ds(0, 1), :],
+                    sem_row).wait()
+                return 0
+
+            jax.lax.fori_loop(0, live, drain, 0)
+            keep = tile_row < live
+            if G > 1:
+                slot = idx_ref[r, pl.ds(t * T, T)] % G
+                keep &= lane_slot == slot[:, None]
+            F = jnp.where(keep, f_tile[...], 0.0)
+            wo = wo_ref[r, pl.ds(t * T, T)]
+            wb = wb_ref[r, pl.ds(t * T, T)]
+            accA[...] += jax.lax.dot_general(
+                F * wo[:, None], F, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
+            accB[...] += jnp.sum(F * wb[:, None], axis=0, keepdims=True)
+            return 0
+
+        jax.lax.fori_loop(0, (n + T - 1) // T, tile_body, 0)
+        A = accA[0:kp, 0:kp]
+        b = accB[:, 0:kp]
+        for g in range(1, G):
+            A = A + accA[g * kp:(g + 1) * kp, g * kp:(g + 1) * kp]
+            b = b + accB[:, g * kp:(g + 1) * kp]
+        A_ref[r] = A
+        b_ref[r] = b[0]
+
+
+def _parent_gather_gram(F_other, idx, wo, wb, lengths):
+    """``gather_gram`` around the parent's kernel body, interpreter."""
+    from predictionio_tpu.ops.gram import _line_width, _tile
+
+    (R, C), (N, k) = idx.shape, F_other.shape
+    T, (kp, G), RB = _tile(C), _line_width(k), 8
+    L = kp * G
+    Np, Rp = -(-N // G) * G, -(-R // RB) * RB
+    F = jnp.pad(F_other.astype(jnp.float32),
+                [(0, Np - N), (0, kp - k)]).reshape(Np // G, L)
+    idx, wo, wb = (jnp.pad(a, [(0, Rp - R), (0, 0)]) for a in (idx, wo, wb))
+    Rl = -(-R // 1024) * 1024
+    lengths = jnp.pad(jnp.clip(lengths.astype(jnp.int32), 0, C),
+                      (0, Rl - R)).reshape(Rl // 128, 128)
+    row_block = pl.BlockSpec((RB, C), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM)
+    A, b = pl.pallas_call(
+        functools.partial(_parent_gather_gram_kernel, RB=RB, C=C, T=T,
+                          kp=kp, G=G),
+        grid=(Rp // RB,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((8, 128), lambda i: (i // (1024 // RB), 0),
+                         memory_space=pltpu.SMEM),
+            row_block, row_block, row_block,
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=(
+            pl.BlockSpec((RB, kp, kp), lambda i: (i, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((RB, kp), lambda i: (i, 0),
+                         memory_space=pltpu.VMEM),
+        ),
+        out_shape=(jax.ShapeDtypeStruct((Rp, kp, kp), jnp.float32),
+                   jax.ShapeDtypeStruct((Rp, kp), jnp.float32)),
+        scratch_shapes=[
+            pltpu.SMEM((RB, C), jnp.int32),
+            pltpu.VMEM((T, L), jnp.float32),
+            pltpu.VMEM((L, L), jnp.float32),
+            pltpu.VMEM((1, L), jnp.float32),
+            pltpu.SemaphoreType.DMA,
+            pltpu.SemaphoreType.DMA,
+        ],
+        interpret=True,
+    )(idx, lengths, idx, wo, wb, F)
+    return A[:R, :k, :k], b[:R, :k]
+
+
 class TestGatherGram:
     """Fused gather→weighted-Gram kernel (ISSUE 17) vs the XLA
     gather+einsum reference, interpret mode — every bucket width the
@@ -365,6 +485,123 @@ class TestGatherGram:
                                    atol=1e-4)
         np.testing.assert_allclose(np.asarray(b[1]), bn[0], rtol=1e-4,
                                    atol=1e-4)
+
+    @staticmethod
+    def _edge_lengths(C):
+        """Row lengths around every edge of the pipeline, 16 rows = two
+        programs of 8: nothing, one copy, the issue loop's burst (U),
+        a power of two of the wait ladder, the tile (T), the row."""
+        from predictionio_tpu.ops.gram import _ISSUE_UNROLL as U, _tile
+
+        T = _tile(C)
+        return [min(n, C) for n in (
+            C, 0, 1, U - 1, U, U + 1, 63, 64, 65, T - 1, T, T + 1,
+            C - 1, 2 * T - 1, 3, C)]
+
+    @staticmethod
+    def _pipeline_case(name):
+        """(C, lengths, rows whose every slot gathers the inf row)."""
+        lengths_of = TestGatherGram._edge_lengths
+        if name.startswith("edges-"):
+            C = int(name.split("-")[1])
+            return C, lengths_of(C), ()
+        if name.startswith("inf-"):
+            # a row's tiles land in the two tile buffers in turn. Rows
+            # that gather the inf row leave inf behind: at C = 512 in
+            # both buffers (two tiles a row), at C = 128 in the one
+            # buffer a one-tile row uses. Every short row after them
+            # lands on a buffer whose rows past its own still hold it;
+            # the last row's second tile meets what row 4's left in
+            # the OTHER buffer
+            C = int(name.split("-")[1])
+            one_tile = C == 128
+            return (C, [C, C if one_tile else 3, 3, 5, C, 7, 0, 2 * C // 3],
+                    (0, 1, 4) if one_tile else (0, 4))
+        if name == "empty-between-full":
+            return 512, [512, 0, 512, 0, 0, 300, 0, 512], ()
+        if name == "ragged-row-count":
+            # R = 11: the last program holds three rows and five of
+            # padding; the index block of program 1 was fetched ahead
+            return 512, lengths_of(512)[:11], ()
+        raise AssertionError(name)
+
+    @pytest.mark.parametrize("case", [
+        "edges-128", "edges-512", "edges-2048", "edges-8192",
+        "inf-128", "inf-512", "empty-between-full", "ragged-row-count"])
+    def test_pipeline_equals_the_parents_kernel_bit_for_bit(self, case):
+        """The unrolled issue loop, group waits, two tile buffers in
+        turn and the index block fetched a program ahead change HOW a
+        copy is started and retired and WHERE a tile lands, not what
+        is summed in what order: A and b carry the bits of the parent's
+        kernel body (above), NaNs included."""
+        from predictionio_tpu.ops.gram import gather_gram
+
+        C, lengths, inf_rows = self._pipeline_case(case)
+        F, idx, wo, wb = self._ragged(C, lengths, seed=len(case))
+        for r in inf_rows:   # really gather the inf row, every slot
+            idx[r, :lengths[r]] = 0
+        args = (jnp.asarray(F), jnp.asarray(idx), jnp.asarray(wo),
+                jnp.asarray(wb), jnp.asarray(lengths, jnp.int32))
+        A, b = (np.asarray(a) for a in gather_gram(*args, interpret=True))
+        Ap, bp = (np.asarray(a) for a in _parent_gather_gram(*args))
+        assert A.shape == Ap.shape and b.shape == bp.shape
+        assert np.array_equal(A.view(np.uint32), Ap.view(np.uint32))
+        assert np.array_equal(b.view(np.uint32), bp.view(np.uint32))
+        clean = [r for r in range(len(lengths)) if r not in inf_rows]
+        assert np.isfinite(A[clean]).all() and np.isfinite(b[clean]).all()
+        for r in inf_rows:
+            assert not np.isfinite(A[r]).any()
+        for r in clean:
+            if lengths[r] == 0:   # copied nothing: exactly zero
+                assert not A[r].any() and not b[r].any()
+
+    def test_pipeline_holds_when_copies_land_as_late_as_they_may(self):
+        """``interpret=True`` lands a copy the moment it is started, so
+        it cannot see a tile multiplied before its copies were waited
+        for, or a buffer refilled while it is read. The TPU interpreter
+        can: it keeps real semaphores, runs a DMA only when a wait
+        needs it (``on_wait``) and checks every access for races — the
+        group waits' amounts must add up, or it hangs or misreads."""
+        from jax._src.pallas.mosaic.interpret import (
+            interpret_pallas_call as tpu_interpreter)
+        from predictionio_tpu.ops.gram import gather_gram
+
+        C = 512   # two programs: the second's index block comes early
+        lengths = [C, 0, 1, 9, 257, 3, 0, 300, 64, 5]
+        F, idx, wo, wb = self._ragged(C, lengths, seed=2)
+        args = (jnp.asarray(F), jnp.asarray(idx), jnp.asarray(wo),
+                jnp.asarray(wb), jnp.asarray(lengths, jnp.int32))
+        A, b = gather_gram(*args, interpret=True)
+        Al, bl = gather_gram(*args, interpret=pltpu.InterpretParams(
+            detect_races=True, dma_execution_mode="on_wait"))
+        assert not tpu_interpreter.races.races_found
+        np.testing.assert_array_equal(np.asarray(Al), np.asarray(A))
+        np.testing.assert_array_equal(np.asarray(bl), np.asarray(b))
+
+    def test_dma_waits_counts_the_ladders_waits(self):
+        """``dma_waits`` (the host's count behind ``kernel_dma_waits``)
+        against a walk of the kernel's own loop: per tile one wait for
+        each ladder size that is a set bit of the tile's copies."""
+        from predictionio_tpu.ops.gram import _tile, _wait_sizes, dma_waits
+
+        for C in (128, 512, 8192, 96):
+            T = _tile(C)
+            assert sum(_wait_sizes(T)) >= T and _wait_sizes(T)[-1] == 1
+            rng = np.random.default_rng(C)
+            lengths = np.concatenate([[0, 1, T - 1, T, C],
+                                      rng.integers(0, C + 1, 50)])
+            walked = 0
+            for n in lengths:
+                for t in range(-(-int(n) // T)):
+                    live, left = min(int(n) - t * T, T), 0
+                    for g in _wait_sizes(T):
+                        if live & g:
+                            walked, left = walked + 1, left + g
+                    assert left == live   # the waits retire every copy
+            assert dma_waits(lengths, C) == walked
+        # a full tile is ONE wait; a segmented entity is one long row
+        assert dma_waits([256, 512], 512) == 3
+        assert dma_waits([8192 * 3 + 5], 8192) == 96 + 2
 
     def test_empty_rows(self):
         from predictionio_tpu.ops.gram import gather_gram

@@ -182,7 +182,7 @@ SPAN_ATTRS = {
     "als.index": ("nnz",),
     "als.prepare": ("nnz", "kernel_real_rows", "kernel_padded_rows",
                     "kernel_bucket_rows", "kernel_dma_rows",
-                    "radix_passes_u", "radix_passes_i", "dense_fill_u",
+                    "kernel_dma_waits", "radix_passes_u", "radix_passes_i", "dense_fill_u",
                     "dense_fill_i"),
     "als.upload": ("bytes",),
     "als.iterate": ("iterations", "gram", "solve"),
@@ -479,3 +479,6 @@ def test_program_kernel_rows_equal_the_benchmarks_roofline_mirror():
     # fewer than the interactions, never more than the slots
     assert (got["kernel_real_rows"] <= got["kernel_dma_rows"]
             <= got["kernel_padded_rows"])
+    # a wait retires a group of copies (PR 37): at least one a row
+    # that holds anything, far fewer than one a copy
+    assert 0 < got["kernel_dma_waits"] < got["kernel_dma_rows"]
