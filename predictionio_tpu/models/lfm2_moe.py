@@ -220,15 +220,7 @@ def group_of(name: str) -> str:
 
 def group_squares(grads) -> Dict[str, Any]:
     """Σ g² per parameter group of a gradient tree."""
-    import jax
-    import jax.numpy as jnp
-
-    out: Dict[str, Any] = {}
-    for path, g in jax.tree_util.tree_flatten_with_path(grads)[0]:
-        group = group_of(_path_name(path))
-        out[group] = out.get(group, 0.0) + jnp.sum(
-            jnp.square(g.astype(jnp.float32)))
-    return out
+    return seq_backbone.squares_by_group(grads, group_of)
 
 
 def init_state(c: Lfm2Config, seed: int, with_optimizer: bool = False):

@@ -11,12 +11,20 @@ shapes, operators and stack. Everything else is here, once:
   (:func:`backbone`);
 - :func:`pack_histories`: histories → ``seq_len``-slot sequences with
   segment ids, positions that restart with each segment, targets that
-  never cross a segment's end;
+  never cross a segment's end; with a ``window``, the pairs it leaves;
 - the pieces of a block: ``_mm`` (operands in the matmul dtype,
-  float32 accumulation), ``_rms``, ``_rope``, :func:`attention`
-  (:mod:`predictionio_tpu.ops.seq_attention`), ``_swiglu``, ``_moe``
-  (:mod:`predictionio_tpu.ops.moe_dispatch`; a shared expert where the
-  layer's weights hold one), ``_cast_in_loop``, ``_chunked_ce``;
+  float32 accumulation), ``_rms``, ``_rope`` (the backbone applies it
+  where its layer has positions: a layer without applies none),
+  :func:`attention` (:mod:`predictionio_tpu.ops.seq_attention`; with
+  ``window``, a row's newest ``window`` keys only), ``_swiglu``, the
+  expert layer in two halves — ``_route`` (ids, gates, plan and load
+  from ONE tensor: sigmoid scores with a selection bias, or a softmax
+  over the selected logits) and ``_experts`` (dispatch → grouped gated
+  units → combine on ANOTHER, later; the unit's activation is the
+  backbone's; a shared expert where the layer's weights hold one) —
+  and ``_moe``, both halves on the same rows
+  (:mod:`predictionio_tpu.ops.moe_dispatch`), ``_cast_in_loop``,
+  ``_chunked_ce``;
 - :func:`scope`: the ``seqrec.*`` names the device trace is read by
   (:data:`SCOPES`), and :func:`program_name`, which puts them into the
   train program's identity;
@@ -51,7 +59,10 @@ from predictionio_tpu.ops import moe_dispatch, seq_attention
 
 
 class Backbone(NamedTuple):
-    """What the template and the benchmark ask of a backbone."""
+    """What the template and the benchmark ask of a backbone. Which
+    layers have a window or rotary positions, where the router reads
+    and what its experts' activation is are the backbone's own affair:
+    it passes them to :func:`attention`, ``_route`` and ``_experts``."""
     model_type: str
     config: type                   # .from_architecture(arch) -> config
     #: (histories, config, epochs, lr, seed, checkpoint_dir=) ->
@@ -72,7 +83,8 @@ class Backbone(NamedTuple):
 
 #: ``model_type`` → the module that defines ``BACKBONE``
 _MODULES = {"glm4_moe_lite": "predictionio_tpu.models.glm4_moe_lite",
-            "lfm2_moe": "predictionio_tpu.models.lfm2_moe"}
+            "lfm2_moe": "predictionio_tpu.models.lfm2_moe",
+            "smallthinker": "predictionio_tpu.models.smallthinker"}
 #: an ``architecture`` without ``model_type``, and a model saved before
 #: the table existed
 DEFAULT = "glm4_moe_lite"
@@ -105,8 +117,22 @@ def _targets(tokens: np.ndarray, seg: np.ndarray, ahead: int) -> np.ndarray:
     return out
 
 
+def window_pairs(sizes: np.ndarray, window: int) -> Tuple[int, int]:
+    """Of segments of ``sizes`` rows under a window of ``window`` keys:
+    the (query, key) pairs causal attention still sees — row i of a
+    segment its newest min(i + 1, window) keys — and the rows whose
+    window cuts keys off (those ``window`` or more behind their
+    segment's start)."""
+    sizes = np.asarray(sizes, np.int64)
+    short = np.minimum(sizes, window)
+    over = sizes - short
+    return (int((short * (short + 1) // 2 + over * window).sum()),
+            int(over.sum()))
+
+
 def pack_histories(histories: Sequence[Sequence[int]], seq_len: int,
-                   seqs_per_step: int = 1, seed: int = 0) -> Packed:
+                   seqs_per_step: int = 1, seed: int = 0,
+                   window: Optional[int] = None) -> Packed:
     """Histories (item ids ≥ 1, oldest first) → ``seq_len``-slot
     sequences with segment ids. A history longer than a sequence is
     cut into ``seq_len`` pieces; pieces go whole, longest first, into
@@ -114,7 +140,10 @@ def pack_histories(histories: Sequence[Sequence[int]], seq_len: int,
     fits nowhere whole is cut to fill the gaps. Each piece is a
     segment: attention, a short convolution's taps, RoPE positions and
     targets stay inside it. The sequence count is padded to a multiple
-    of ``seqs_per_step`` and the order shuffled by ``seed``."""
+    of ``seqs_per_step`` and the order shuffled by ``seed``. With a
+    ``window`` the counters also hold what it leaves of the segments'
+    pairs (``attn_pairs_window``) and the rows it binds
+    (``window_bound_tokens``), by :func:`window_pairs`."""
     S = int(seq_len)
     pieces: List[np.ndarray] = []
     n_hist = n_split = 0
@@ -163,13 +192,17 @@ def pack_histories(histories: Sequence[Sequence[int]], seq_len: int,
     tokens, seg, pos = tokens[order], seg[order], pos[order]
     tgt1, tgt2 = _targets(tokens, seg, 1), _targets(tokens, seg, 2)
     sizes = np.asarray([p.size for rows in bins for p in rows], np.int64)
-    return Packed(tokens, seg, pos, tgt1, tgt2, {
+    counters = {
         "histories": n_hist, "split": int(n_split), "sequences": n_all,
         "slots": n_all * S, "real_tokens": int(total),
         # (query, key) pairs causal attention inside the segments sees
         "attn_pairs": int((sizes * (sizes + 1) // 2).sum()),
         "targets": int((tgt1 > 0).sum()),
-        "mtp_targets": int((tgt2 > 0).sum())})
+        "mtp_targets": int((tgt2 > 0).sum())}
+    if window is not None:
+        (counters["attn_pairs_window"],
+         counters["window_bound_tokens"]) = window_pairs(sizes, window)
+    return Packed(tokens, seg, pos, tgt1, tgt2, counters)
 
 
 # -- parameter trees ----------------------------------------------------------
@@ -201,6 +234,20 @@ def count_params(shapes) -> int:
 
     return sum(int(np.prod(s)) for s in
                jax.tree.leaves(shapes, is_leaf=_is_shape))
+
+
+def squares_by_group(grads, group_of: Callable[[str], str]) -> Dict[str, Any]:
+    """Σ g² per parameter group of a gradient tree; ``group_of`` names
+    a leaf's group from its dotted path."""
+    import jax
+    import jax.numpy as jnp
+
+    out: Dict[str, Any] = {}
+    for path, g in jax.tree_util.tree_flatten_with_path(grads)[0]:
+        group = group_of(_path_name(path))
+        out[group] = out.get(group, 0.0) + jnp.sum(
+            jnp.square(g.astype(jnp.float32)))
+    return out
 
 
 def init_program(c, shapes, bias_shape: tuple, with_optimizer: bool):
@@ -257,8 +304,9 @@ SCOPES = frozenset({
     "seqrec.ffn", "seqrec.moe.route", "seqrec.moe.dispatch",
     "seqrec.moe.experts", "seqrec.moe.combine",
     "seqrec.mla", "seqrec.mla.attention", "seqrec.mtp",   # glm4_moe_lite
-    "seqrec.conv", "seqrec.conv.mix", "seqrec.gqa",       # lfm2_moe
-    "seqrec.gqa.attention"})
+    "seqrec.conv", "seqrec.conv.mix",                     # lfm2_moe
+    "seqrec.gqa", "seqrec.gqa.attention",   # lfm2_moe; smallthinker: global
+    "seqrec.swa", "seqrec.swa.attention"})  # smallthinker: window layers
 
 
 def scope(name: str):
@@ -333,15 +381,16 @@ def _rope(x, pos, theta: float):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
-def attention(q, k, v, seg, c, scale: float):
+def attention(q, k, v, seg, c, scale: float, window: Optional[int] = None):
     """Causal, segment-masked attention of ONE sequence: q [S, H, D],
     k [S, Hkv, D], v [S, Hkv, Dv] (query head h reads key-value head
     h ÷ (H ÷ Hkv)) → [S, H, Dv] in v's dtype. Tiles of at most
     ``attn_block`` query rows, and only those between a block's
-    earliest segment and the diagonal
+    earliest key — of its earliest segment, or ``window`` − 1 rows
+    before the block where that lies later — and the diagonal
     (:mod:`predictionio_tpu.ops.seq_attention`)."""
     return seq_attention.segment_attention(
-        q, k, v, seg, *_attn_tiles(c, q.shape[0]), scale)
+        q, k, v, seg, *_attn_tiles(c, q.shape[0]), scale, window)
 
 
 def _swiglu(w, x, c):
@@ -364,36 +413,59 @@ def _swiglu(w, x, c):
     return jax.lax.map(chunk, rows.reshape(-1, n, d)).reshape(x.shape)
 
 
-def _moe(w, x, valid, bias, c):
-    """x [T, d] float32 (normed) → this chip's part of the layer's
-    result [T, d], and what the step records of the routing. The
-    shared expert is added where the layer has one (``w["shared"]``)."""
+def _route(router, x, valid, bias, c, softmax: bool = False):
+    """The expert layer's ROUTE from x [T, d] float32, whatever rows
+    the backbone routes on: (gates [T, k] float32, the dispatch plan,
+    what the step records of the routing). Scores sigmoid(x W_r) with
+    the selection-only ``bias``, gates scaling · s_e/Σ_selected s — or
+    (``softmax``) a softmax over the selected logits, no bias and no
+    scaling. The router's product and its activation are float32."""
     import jax
     import jax.numpy as jnp
 
     E, k = c.router_experts, c.num_experts_per_tok
     with scope("seqrec.moe.route"):
-        scores = jax.nn.sigmoid(jnp.dot(
-            x, w["router"], precision=jax.lax.Precision.HIGHEST))
-        ids, gates = moe_dispatch.route(
-            scores, bias, k, c.routed_scaling_factor, c.norm_topk_prob)
+        logits = jnp.dot(x, router, precision=jax.lax.Precision.HIGHEST)
+        if softmax:
+            ids, gates = moe_dispatch.route_softmax(logits, k)
+        else:
+            ids, gates = moe_dispatch.route(
+                jax.nn.sigmoid(logits), bias, k, c.routed_scaling_factor,
+                c.norm_topk_prob)
         p = moe_dispatch.plan(ids, c.held, E, valid)
         load = jnp.zeros(E, jnp.float32).at[ids.reshape(-1)].add(
             jnp.repeat(valid, k).astype(jnp.float32))
+    held = load[jnp.asarray(c.held)]
+    return gates, p, {
+        "load": load, "pairs": valid.sum() * k, "pairs_here": p.pairs_here,
+        "dropped": p.pairs_here - p.rows,
+        "load_max_over_mean": held.max() / jnp.maximum(held.mean(), 1e-9)}
+
+
+def _experts(w, x, gates, p, c, act=None):
+    """x [T, d] float32 (normed) through the route ``gates``, ``p`` of
+    :func:`_route`: this chip's part of the layer's result [T, d] —
+    dispatch, the held experts' gated units (``act``: SiLU where none
+    is given), combine. The shared expert is added where the layer has
+    one (``w["shared"]``)."""
     with scope("seqrec.norm"):      # the normed rows as the products take them
         rows = x.astype(_dt(c))
     out = moe_dispatch.experts_swiglu(
         rows, w["experts"]["wg"].astype(_dt(c)),
         w["experts"]["wu"].astype(_dt(c)), w["experts"]["wd"].astype(_dt(c)),
-        gates, p)
+        gates, p, act)
     if "shared" in w:
         with scope("seqrec.ffn"):
             out = out + _swiglu(w["shared"], x, c)
-    held = load[jnp.asarray(c.held)]
-    return out, {
-        "load": load, "pairs": valid.sum() * k, "pairs_here": p.pairs_here,
-        "dropped": p.pairs_here - p.rows,
-        "load_max_over_mean": held.max() / jnp.maximum(held.mean(), 1e-9)}
+    return out
+
+
+def _moe(w, x, valid, bias, c):
+    """x [T, d] float32 (normed) → this chip's part of the layer's
+    result [T, d], and what the step records of the routing: route and
+    experts on the SAME rows (sigmoid router, SiLU experts)."""
+    gates, p, stats = _route(w["router"], x, valid, bias, c)
+    return _experts(w, x, gates, p, c), stats
 
 
 def _cast_in_loop(w, c, turn, aside: Tuple[str, ...] = ("router",)):
@@ -518,6 +590,7 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
                     batch_keys: Tuple[str, ...],
                     pack_attrs: Optional[Callable] = None,
                     fit_attrs: Optional[Dict[str, Any]] = None,
+                    window: Optional[int] = None,
                     checkpoint_dir: Optional[str] = None,
                     checkpoint_every: int = 1) -> Tuple[Dict, np.ndarray]:
     """Train on per-user item-id histories; returns the model's arrays
@@ -526,7 +599,10 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
     ``.fetch`` land in the verb record (docs/observability.md).
     ``program(c, n)`` is the backbone's compiled train of ``n`` epochs;
     ``pack_attrs(packed)`` and ``fit_attrs`` add the backbone's own
-    counters to the two spans."""
+    counters to the two spans; ``window``: the key window of the
+    backbone's window layers, counted on ``seqrec.pack``
+    (``attn_pairs_window``, ``window_bound_tokens``,
+    ``attn_tile_pairs_window``)."""
     import jax
     import jax.numpy as jnp
 
@@ -536,7 +612,8 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
     if c.seq_len % min(c.attn_block, c.seq_len):
         raise ValueError("seq_len must be a multiple of attn_block")
     with tracing.span("seqrec.pack") as sp:
-        packed = pack_histories(histories, c.seq_len, c.seqs_per_step, seed)
+        packed = pack_histories(histories, c.seq_len, c.seqs_per_step, seed,
+                                window)
         top = max(int(packed.tokens.max()), 0)
         if top >= c.vocab_size:
             raise ValueError(f"item id {top} outside the vocabulary of "
@@ -551,6 +628,9 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
                     seq_attention.tile_pairs(packed.seg, bq, bk))
         sp.set_attr("attn_dense_pairs",
                     seq_attention.tile_pairs(packed.seg, bq, bq, skip=False))
+        if window is not None:
+            sp.set_attr("attn_tile_pairs_window", seq_attention.tile_pairs(
+                packed.seg, bq, bk, window=window))
         for k, v in (pack_attrs(packed) if pack_attrs else {}).items():
             sp.set_attr(k, v)
     with tracing.span("seqrec.init") as sp:

@@ -1,0 +1,412 @@
+"""A ``smallthinker`` block stack as the ``sequentialrec`` backbone.
+
+The item catalog takes the place of the token vocabulary. Every
+equation is the published block's (SmallThinker-21BA3B-Instruct,
+``config.json``); h is the residual stream, float32:
+
+- **Layer kinds**: layer l is ``global`` where
+  ``sliding_window_layout[l]`` = ``rope_layout[l]`` = 0 (the published
+  layers 0, 4, 8, …), else ``window``: one global layer, then three
+  window layers, all 52 of them expert layers (no dense layer, no
+  shared expert).
+- **Router, BEFORE attention**: logits = h W_r over the router's
+  ``moe_num_primary_experts × ep_size`` experts — the layer's INPUT,
+  before any norm; ids = top-k(logits), gates = softmax(logits[ids])
+  (they sum to 1, so ``norm_topk_prob`` adds nothing). No bias, no
+  scaling, no auxiliary loss. THIS chip holds experts ``ep_rank·n …
+  (ep_rank+1)·n − 1`` and adds only their part
+  (:mod:`predictionio_tpu.ops.moe_dispatch`).
+- **Attention**: a = RMSNorm(h); q = a W_q → H × D, k = a W_k →
+  Hkv × D, v = a W_v → Hkv × D, no bias, no QK norm. A ``window``
+  layer applies RoPE (rotate halves, all D dims, positions counted from
+  the start of each segment) and a query sees its newest
+  ``sliding_window_size`` keys; a ``global`` layer applies NO positional
+  encoding and sees its whole segment. Scores q·k/√D, causal AND inside
+  one segment; query head h reads key-value head h ÷ (H ÷ Hkv);
+  h′ = h + o W_o.
+- **Experts, AFTER attention, through the route made before it**:
+  m = RMSNorm(h′); h″ = h′ + Σ_{e ∈ ids} gate_e · W_d^e(relu(W_g^e m) ⊙
+  W_u^e m) — ReGLU.
+- **Head**: RMSNorm_final(h_L) W_head, untied from the embedding. Loss:
+  the cross-entropy of the next item, a mean over the real targets.
+
+A run of consecutive layers of one kind is ONE scanned body over its
+stacked weights (``params["runs"][r]``), so the global and the window
+layers carry scopes of their own (``seqrec.gqa*`` / ``seqrec.swa*``):
+the published 52 layers are 26 runs, the benchmark's period of four
+two (1 × global, 3 × window).
+
+Precision, packing, the pieces any backbone has, the train step and
+the verb's spans are :mod:`predictionio_tpu.models.seq_backbone`'s.
+The train step's router bias is carried as zeros and never moves
+(``bias_update_rate`` 0): this router has none.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass, fields
+from typing import Any, ClassVar, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
+
+from predictionio_tpu.models import seq_backbone
+from predictionio_tpu.models.seq_backbone import (
+    _cast_in_loop, _chunked_ce, _dt, _experts, _mm, _rms, _rope, _route,
+    _stacked, _swiglu_shapes, scope)
+
+#: what the published config may say and this file can honour
+_REQUIRED = {"model_type": "smallthinker",
+             "moe_primary_router_apply_softmax": True,
+             "tie_word_embeddings": False, "rope_scaling": None}
+#: published keys that size nothing here: a name, a limit, and a switch
+#: that changes nothing (the softmax over the selected sums to 1)
+_UNUSED = ("model_name", "max_position_embeddings", "norm_topk_prob")
+KINDS = ("global", "window")
+
+
+@dataclass(frozen=True)
+class SmallThinkerConfig:
+    model_type: ClassVar[str] = "smallthinker"
+    #: this router has no bias: the step's rule moves it by nothing
+    bias_update_rate: ClassVar[float] = 0.0
+    hidden_size: int = 2560
+    head_dim: int = 128
+    num_attention_heads: int = 28
+    num_key_value_heads: int = 4
+    moe_ffn_hidden_size: int = 768
+    #: routed experts HELD here; the router is ``ep_size`` times as wide
+    moe_num_primary_experts: int = 64
+    ep_size: int = 1
+    ep_rank: int = 0
+    moe_num_active_primary_experts: int = 6
+    num_hidden_layers: int = 4
+    #: per layer, 1 = a window of ``sliding_window_size`` keys / rotary
+    #: positions; the two layouts are one (a layer without a window has
+    #: no positions)
+    sliding_window_layout: Tuple[int, ...] = (0, 1, 1, 1)
+    rope_layout: Tuple[int, ...] = (0, 1, 1, 1)
+    sliding_window_size: int = 4096
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1.5e6
+    vocab_size: int = 151936
+    # -- the training job (not in the published config) ----------------
+    seq_len: int = 16384
+    seqs_per_step: int = 2
+    clip_norm: float = 1.0
+    init_std: float = 0.02
+    matmul_dtype: str = "bfloat16"
+    #: most query rows an attention tile holds; tokens per chunk of the
+    #: loss: what bounds the program's temporaries
+    attn_block: int = 512
+    token_chunk: int = 4096
+
+    @classmethod
+    def from_architecture(cls, arch: Dict[str, Any]) -> "SmallThinkerConfig":
+        """The ``architecture`` object of the algorithm's parameters:
+        the published config's keys (and this class's own)."""
+        for key, want in _REQUIRED.items():
+            if key in arch and arch[key] != want:
+                raise ValueError(f"architecture.{key} = {arch[key]!r}: "
+                                 f"only {want!r} is implemented")
+        unknown = set(arch) - cls.known_keys()
+        if unknown:
+            raise ValueError(f"unknown architecture keys {sorted(unknown)}")
+        names = {f.name for f in fields(cls)}
+        kw = {k: v for k, v in arch.items() if k in names}
+        for key in ("sliding_window_layout", "rope_layout"):
+            if key in kw:
+                kw[key] = tuple(int(v) for v in kw[key])
+        c = cls(**kw)
+        if len(c.sliding_window_layout) != c.num_hidden_layers:
+            raise ValueError(f"{len(c.sliding_window_layout)} entries of "
+                             f"sliding_window_layout for "
+                             f"{c.num_hidden_layers} layers")
+        if c.rope_layout != c.sliding_window_layout:
+            raise ValueError("rope_layout differs from sliding_window_layout:"
+                             " only window layers with rotary positions and "
+                             "global layers without are implemented")
+        if set(c.sliding_window_layout) - {0, 1}:
+            raise ValueError("sliding_window_layout holds other than 0 and 1")
+        if c.num_attention_heads % c.num_key_value_heads:
+            raise ValueError(f"{c.num_attention_heads} query heads over "
+                             f"{c.num_key_value_heads} key-value heads")
+        if c.moe_num_active_primary_experts > c.router_experts:
+            raise ValueError(f"top-{c.moe_num_active_primary_experts} of a "
+                             f"router of {c.router_experts}")
+        return c
+
+    @classmethod
+    def known_keys(cls) -> frozenset:
+        """Every key an ``architecture`` object may hold."""
+        return frozenset({f.name for f in fields(cls)} | set(_REQUIRED)
+                         | set(_UNUSED))
+
+    @property
+    def router_experts(self) -> int:
+        return self.moe_num_primary_experts * self.ep_size
+
+    @property
+    def held(self) -> Tuple[int, ...]:
+        lo = self.ep_rank * self.moe_num_primary_experts
+        return tuple(range(lo, lo + self.moe_num_primary_experts))
+
+    @property
+    def num_experts_per_tok(self) -> int:
+        return self.moe_num_active_primary_experts
+
+    @property
+    def window(self) -> Optional[int]:
+        """The key window of the window layers; None where no layer
+        has one."""
+        return (self.sliding_window_size if any(self.sliding_window_layout)
+                else None)
+
+    @property
+    def runs(self) -> Tuple[Tuple[str, int], ...]:
+        """(kind, layers) of each run of consecutive layers of one
+        kind, in stack order."""
+        out: List[List] = []
+        for flag in self.sliding_window_layout:
+            if out and out[-1][0] == KINDS[flag]:
+                out[-1][1] += 1
+            else:
+                out.append([KINDS[flag], 1])
+        return tuple(tuple(r) for r in out)
+
+
+# -- parameters ---------------------------------------------------------------
+
+
+def _layer_shapes(c: SmallThinkerConfig) -> Dict[str, Any]:
+    d = c.hidden_size
+    q, kv = (c.num_attention_heads * c.head_dim,
+             c.num_key_value_heads * c.head_dim)
+    return {"attn_norm": (d,), "ffn_norm": (d,),
+            "attn": {"wq": (d, q), "wk": (d, kv), "wv": (d, kv),
+                     "wo": (q, d)},
+            "router": (d, c.router_experts),
+            "experts": _swiglu_shapes(d, c.moe_ffn_hidden_size,
+                                      (c.moe_num_primary_experts,))}
+
+
+def param_shapes(c: SmallThinkerConfig) -> Dict[str, Any]:
+    """The parameter tree as shapes. ``runs[r]`` carries a leading
+    layer axis: a run's identical layers are ONE scanned body."""
+    return {"embed": (c.vocab_size, c.hidden_size),
+            "runs": [_stacked(_layer_shapes(c), n) for _, n in c.runs],
+            "final_norm": (c.hidden_size,),
+            "head": (c.hidden_size, c.vocab_size)}
+
+
+def n_params(c: SmallThinkerConfig) -> int:
+    return seq_backbone.count_params(param_shapes(c))
+
+
+def group_of(name: str) -> str:
+    """The parameter group a leaf's gradient norm is recorded under:
+    by part, over all the layers that have it."""
+    parts = name.split(".")
+    if parts[-1].endswith("norm"):
+        return "norms"
+    return parts[0] if parts[0] in ("embed", "head") else parts[2]
+
+
+def group_squares(grads) -> Dict[str, Any]:
+    """Σ g² per parameter group of a gradient tree."""
+    return seq_backbone.squares_by_group(grads, group_of)
+
+
+def init_state(c: SmallThinkerConfig, seed: int,
+               with_optimizer: bool = False):
+    """(params, the zero router bias) made ON the device from the seed,
+    by one jitted program (:func:`seq_backbone.init_program`);
+    ``with_optimizer``: Adam's zeroed state too."""
+    return _init_compiled(c, with_optimizer)(np.uint32(seed % (1 << 32)))
+
+
+@functools.lru_cache(maxsize=4)
+def _init_compiled(c: SmallThinkerConfig, with_optimizer: bool):
+    return seq_backbone.init_program(
+        c, param_shapes(c), (c.num_hidden_layers, c.router_experts),
+        with_optimizer)
+
+
+# -- the block ----------------------------------------------------------------
+
+
+def _attend(w, x, seg, pos, c: SmallThinkerConfig, kind: str):
+    """x [B, S, d] (normed) → [B, S, d], one sequence at a time; a
+    ``window`` layer rotates q and k and sees ``sliding_window_size``
+    keys, a ``global`` layer does neither."""
+    import jax
+    import jax.numpy as jnp
+
+    H, Hkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    windowed = kind == "window"
+    name = "seqrec.swa.attention" if windowed else "seqrec.gqa.attention"
+
+    def one(args):
+        x, seg, pos = args
+        S = x.shape[0]
+        q = _mm(x, w["wq"], c).reshape(S, H, D)
+        k = _mm(x, w["wk"], c).reshape(S, Hkv, D)
+        v = _mm(x, w["wv"], c).reshape(S, Hkv, D)
+        if windowed:
+            q = _rope(q, pos[:, None], c.rope_theta)
+            k = _rope(k, pos[:, None], c.rope_theta)
+        with scope(name):
+            out = seq_backbone.attention(
+                q.astype(_dt(c)), k.astype(_dt(c)), v.astype(_dt(c)), seg,
+                c, 1.0 / np.sqrt(D),
+                c.sliding_window_size if windowed else None)
+        return jnp.tensordot(out, w["wo"].astype(_dt(c)).reshape(H, D, -1),
+                             2, preferred_element_type=jnp.float32)
+
+    return jax.lax.map(one, (x, seg, pos))
+
+
+def _layer(w, x, seg, pos, c: SmallThinkerConfig, kind: str):
+    """One layer on the residual stream x [B, S, d] float32: the route
+    from the layer's INPUT, attention, the experts through that route."""
+    import jax
+
+    B, S, d = x.shape
+    gates, plan, stats = _route(w["router"], x.reshape(B * S, d),
+                                seg.reshape(-1) > 0, None, c, softmax=True)
+    with scope("seqrec.swa" if kind == "window" else "seqrec.gqa"):
+        x = x + _attend(w["attn"], _rms(x, w["attn_norm"], c.rms_norm_eps),
+                        seg, pos, c, kind)
+    with scope("seqrec.norm"):
+        m = _rms(x, w["ffn_norm"], c.rms_norm_eps)
+    y = _experts(w, m.reshape(B * S, d), gates, plan, c, act=jax.nn.relu)
+    with scope("seqrec.residual"):
+        return x + y.reshape(B, S, d), stats
+
+
+def _stack(params, bias, batch, c: SmallThinkerConfig):
+    """Embedding and the stack: h_L [B, S, d] and the layers' routing
+    records (leading axis: layer, in stack order). ``bias`` is the
+    step's zero router bias: nothing reads it."""
+    import jax
+    import jax.numpy as jnp
+
+    del bias
+    seg, pos = batch["seg"], batch["pos"]
+    with scope("seqrec.embed"):
+        x = params["embed"][batch["tokens"]]
+    stats = []
+    for (kind, n), w in zip(c.runs, params["runs"]):
+        def turn(x, iw, kind=kind):
+            i, w = iw
+            return _layer(_cast_in_loop(w, c, i), x, seg, pos, c, kind)
+
+        with scope("seqrec.stack"):
+            x, s = jax.lax.scan(
+                lambda x, iw: jax.checkpoint(turn)(x, iw), x,
+                (jnp.arange(n), w))
+        stats.append(s)
+    return x, jax.tree.map(lambda *a: jnp.concatenate(a), *stats)
+
+
+def _head_logits(params, x, c: SmallThinkerConfig):
+    """The untied head: the final norm, then W_head."""
+    return _mm(_rms(x, params["final_norm"], c.rms_norm_eps),
+               params["head"], c)
+
+
+def loss_fn(params, bias, batch, c: SmallThinkerConfig):
+    """CE(next item), a mean over the real targets, and the step's
+    records; ``batch``: tokens, seg, pos, tgt1 [B, S] int32."""
+    import jax.numpy as jnp
+
+    x, stats = _stack(params, bias, batch, c)
+    n = jnp.maximum((batch["tgt1"] > 0).sum(), 1)
+    ce = _chunked_ce(lambda x: _head_logits(params, x, c), x,
+                     batch["tgt1"], c) / n
+    return ce, {"loss": ce, "moe": stats}
+
+
+# -- the train program --------------------------------------------------------
+
+
+BATCH_KEYS = ("tokens", "seg", "pos", "tgt1")
+
+
+@functools.lru_cache(maxsize=8)
+def grad_groups(c: SmallThinkerConfig) -> Tuple[str, ...]:
+    """The parameter groups, in the order ``group_norms`` records."""
+    return seq_backbone.grad_groups(group_squares, param_shapes(c))
+
+
+@functools.lru_cache(maxsize=8)
+def train_program(c: SmallThinkerConfig, epochs: int):
+    """``train(state, data) -> (state, records)``, ``epochs`` passes as
+    ONE compiled program (:func:`seq_backbone.train_program`)."""
+    return seq_backbone.train_program(c, epochs, loss_fn, group_squares,
+                                      grad_groups(c))
+
+
+def smallthinker_train(histories: Sequence[Sequence[int]],
+                       c: SmallThinkerConfig, epochs: int, lr: float,
+                       seed: int, checkpoint_dir: Optional[str] = None,
+                       checkpoint_every: int = 1) -> Tuple[Dict, np.ndarray]:
+    """Train on per-user item-id histories
+    (:func:`seq_backbone.train_histories`): the model's arrays on the
+    HOST (``{"params", "bias"}``) and the loss of every step run in
+    this process."""
+    window_layers = sum(c.sliding_window_layout)
+    return seq_backbone.train_histories(
+        histories, c, epochs, lr, seed, model_type=c.model_type,
+        init_state=init_state, program=train_program, n_params=n_params(c),
+        groups=grad_groups(c), batch_keys=BATCH_KEYS,
+        fit_attrs={"window_layers": window_layers,
+                   "global_layers": c.num_hidden_layers - window_layers},
+        window=c.window, checkpoint_dir=checkpoint_dir,
+        checkpoint_every=checkpoint_every)
+
+
+# -- serving ------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=16)
+def _logits_compiled(c: SmallThinkerConfig):
+    import jax
+
+    return jax.jit(lambda params, bias, batch: (_head_logits(
+        params, _stack(params, bias, batch, c)[0], c),))
+
+
+def sequence_logits(model: Dict, batch: Dict[str, np.ndarray],
+                    c: SmallThinkerConfig):
+    """The head's float32 logits [B, S, V] of whole packed sequences
+    (a tuple of one: a backbone gives each of its heads'), by the
+    program."""
+    return _logits_compiled(c)(model["params"], model["bias"], batch)
+
+
+@functools.lru_cache(maxsize=16)
+def _next_compiled(c: SmallThinkerConfig):
+    def last_logits(params, bias, batch, n):
+        x, _ = _stack(params, bias, batch, c)
+        return _head_logits(params, x[0, n - 1], c)
+
+    return seq_backbone.next_program(last_logits)
+
+
+def next_item_scores(model: Dict, history: Sequence[int],
+                     c: SmallThinkerConfig) -> np.ndarray:
+    """Scores over the vocabulary for the item after ``history``
+    (:func:`seq_backbone.next_item_scores`: its newest ``seq_len``
+    items, one segment, through the same stack — the window layers see
+    their window there too); PAD = -inf."""
+    return seq_backbone.next_item_scores(_next_compiled(c), model, history,
+                                         c)
+
+
+BACKBONE = seq_backbone.Backbone(
+    model_type=SmallThinkerConfig.model_type, config=SmallThinkerConfig,
+    train=smallthinker_train, sequence_logits=sequence_logits,
+    next_item_scores=next_item_scores, heads=("loss",),
+    batch_keys=BATCH_KEYS, init_state=init_state, n_params=n_params,
+    group_squares=group_squares)

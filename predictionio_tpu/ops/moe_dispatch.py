@@ -6,9 +6,11 @@ part of the result that its own experts give. Nothing stands in for
 the absent experts or their exchange.
 
     route      sigmoid scores + selection-only bias → top-k ids, gates
+               (route_softmax: logits → top-k ids, a softmax over them)
     plan       (token, expert) pairs sorted by held expert; pairs of
                absent experts (and of padding tokens) sort behind them
-    experts    gather → grouped SwiGLU over the experts held → combine
+    experts    gather → grouped gated unit (SiLU | ReLU | …) over the
+               experts held → combine
 
 No capacity and no dropped pair: the pair buffer holds every pair the
 router can produce (tokens × k rows), and the grouped product visits
@@ -42,6 +44,18 @@ def route(scores, bias, k: int, scaling: float, normalize: bool = True):
     if normalize:
         picked = picked / (picked.sum(axis=1, keepdims=True) + 1e-20)
     return ids.astype(jnp.int32), picked * scaling
+
+
+def route_softmax(logits, k: int):
+    """``logits`` [T, E] float32: the top-k experts of each token and
+    gates = softmax over the SELECTED logits (they sum to 1; the
+    unselected take no gradient). No bias, no scaling. Returns ids
+    [T, k] int32 and gates [T, k] float32."""
+    import jax
+    import jax.numpy as jnp
+
+    picked, ids = jax.lax.top_k(logits, k)
+    return ids.astype(jnp.int32), jax.nn.softmax(picked, axis=-1)
 
 
 class Plan(NamedTuple):
@@ -122,13 +136,16 @@ def grouped_matmul(lhs, rhs, group_sizes):
                               preferred_element_type=lhs.dtype)
 
 
-def experts_swiglu(x, wg, wu, wd, gates, p: Plan):
+def experts_swiglu(x, wg, wu, wd, gates, p: Plan, act=None):
     """The held experts' part of the layer's result: ``x`` [T, d]
     (matmul dtype), ``wg``/``wu`` [H, d, f], ``wd`` [H, f, d], ``gates``
     [T, k] float32. Returns [T, d] float32 — Σ over the token's pairs
-    held here of gate · SwiGLU_e(x)."""
+    held here of gate · W_d^e(act(W_g^e x) ⊙ W_u^e x); ``act`` (float32
+    → float32) is the caller's block's: SiLU where none is given."""
     import jax
     import jax.numpy as jnp
+
+    act = act or jax.nn.silu
 
     T, k = gates.shape
     # Rows behind the groups are never defined — in the products and in
@@ -154,7 +171,7 @@ def experts_swiglu(x, wg, wu, wd, gates, p: Plan):
             rows, sizes = args
             g = grouped_matmul(rows, wg, sizes)
             u = grouped_matmul(rows, wu, sizes)
-            h = (jax.nn.silu(g.astype(jnp.float32))
+            h = (act(g.astype(jnp.float32))
                  * u.astype(jnp.float32)).astype(rows.dtype)
             return grouped_matmul(h, wd, sizes)
 

@@ -3,9 +3,12 @@ tile — three Pallas kernels (forward, dq, dk/dv) under one
 ``jax.custom_vjp``.
 
 A packed sequence holds many histories back to back
-(``models/glm4_moe_lite.pack_histories``); a query row sees the keys of
+(``models/seq_backbone.pack_histories``); a query row sees the keys of
 its OWN segment up to itself, i.e. the keys ``first[r] … r`` where
-``first[r]`` is the first row of r's segment. Two things follow:
+``first[r]`` is the first row of r's segment — or, under a WINDOW of
+``W`` keys, ``max(first row of the segment, r − W + 1)``: the window
+is part of ``first``, not a mask over a full walk, and the clipped
+``first`` still never falls along a sequence. Two things follow:
 
 - **Only the tiles a segment reaches are visited.** A block of ``bq``
   query rows needs the key tiles from the one holding the first key of
@@ -35,7 +38,11 @@ that a kernel walks over (k and v for forward and dq, q and the
 output's cotangent for dk/dv) stays in VMEM for a whole head: one grid
 step is one block of rows of one head, the walk over its tiles is a
 loop INSIDE the kernel, so a sequence of many short segments costs no
-grid steps for the tiles it skips.
+grid steps for the tiles it skips. Where the rows that dk/dv walks over
+(a key-value head's ``g`` query heads, with their cotangents) are more
+than :data:`_WHOLE_HEAD_BYTES` — 28 heads over 4 at 16,384 slots — the
+grid walks the QUERY heads instead, one head's rows in VMEM at a time,
+and the group's float32 parts are summed outside the kernel.
 
 Precision: operands as given (bfloat16 in training), scores, mask,
 maximum, sum and accumulators float32, the probabilities enter the
@@ -72,16 +79,21 @@ _LANES = 128
 _MASKED = -1e30
 _NT = (((1,), (1,)), ((), ()))   # a · bᵀ
 _VMEM_LIMIT = 96 * 1024 * 1024
+#: most bytes of whole-head operands dk/dv keeps in VMEM (each is
+#: double-buffered by the pipeline): above it the grid walks query heads
+_WHOLE_HEAD_BYTES = 24 * 1024 * 1024
 
 
 # -- which tiles ---------------------------------------------------------------
 
 
-def first_keys(seg, xp=jnp):
-    """Per row of ``seg`` [..., S], the first row of its segment (a
-    maximal run of one id); ``r + 1`` for a padding row (id 0), which
-    so has no key at all. ``xp``: numpy on the host, jax.numpy in a
-    program — the same lines count the tiles and steer the kernels."""
+def first_keys(seg, xp=jnp, window=None):
+    """Per row of ``seg`` [..., S], the first key it sees: the first
+    row of its segment (a maximal run of one id), or the row ``window
+    − 1`` before it where that lies later; ``r + 1`` for a padding row
+    (id 0), which so has no key at all. ``xp``: numpy on the host,
+    jax.numpy in a program — the same lines count the tiles and steer
+    the kernels."""
     S = seg.shape[-1]
     r = xp.arange(S, dtype=xp.int32)
     new = xp.concatenate([xp.ones_like(seg[..., :1], dtype=bool),
@@ -91,6 +103,8 @@ def first_keys(seg, xp=jnp):
     # 8 ms a call on the chip where the whole forward kernel takes less
     first = (np.maximum.accumulate(starts, axis=-1) if xp is np
              else jax.lax.cummax(starts, axis=starts.ndim - 1))
+    if window is not None and window < S:
+        first = xp.maximum(first, r - (window - 1))
     return xp.where(seg > 0, first, r + 1).astype(xp.int32)
 
 
@@ -109,13 +123,13 @@ def tile_intervals(first, bq: int, bk: int, xp=jnp):
     return lo.astype(xp.int32), hi.astype(xp.int32)
 
 
-def tile_pairs(seg: np.ndarray, bq: int, bk: int,
-               skip: bool = True) -> int:
+def tile_pairs(seg: np.ndarray, bq: int, bk: int, skip: bool = True,
+               window=None) -> int:
     """(query, key) pairs inside the tiles the forward pass visits for
     the sequences ``seg`` [N, S] (``skip`` False: inside those a walk
     from the first tile to the diagonal would) — one head, counted on
     the host by the function that steers the kernels."""
-    lo, _ = tile_intervals(first_keys(seg, np), bq, bk, np)
+    lo, _ = tile_intervals(first_keys(seg, np, window), bq, bk, np)
     diag = ((np.arange(seg.shape[-1] // bq) + 1) * bq - 1) // bk
     return int((diag - lo * skip + 1).sum()) * bq * bk
 
@@ -292,10 +306,14 @@ def _backward(q, k, v, do, lse, delta, first, lo, hi, bq, bk, scale,
     """``lse``, ``delta`` [H, S] and ``first`` [S]: dq reads a row's
     number as a column (its 128 lanes), dk/dv as part of a row. dk/dv's
     grid walks the KEY-VALUE heads: the ``g`` query heads of one are
-    adjacent, so their rows are one head of ``g · S`` rows."""
+    adjacent, so their rows are one head of ``g · S`` rows — unless
+    those rows do not fit VMEM (:data:`_WHOLE_HEAD_BYTES`): then it
+    walks the query heads, each giving its float32 part of dk and dv,
+    and the group's parts are summed here."""
     H, S, D = q.shape
     Hkv, Dv = v.shape[0], v.shape[-1]
     g = H // Hkv
+    split = g > 1 and g * S * (D + Dv) * q.dtype.itemsize > _WHOLE_HEAD_BYTES
     dq, = _call(
         functools.partial(_dq_kernel, scale=scale, bk=bk),
         "seq_attention_dq", S // bq, lo,
@@ -304,22 +322,30 @@ def _backward(q, k, v, do, lse, delta, first, lo, hi, bq, bk, scale,
          _head(bq, Dv), _head(bq, _LANES), _head(bq, _LANES), _first(bq)],
         [jax.ShapeDtypeStruct(q.shape, q.dtype)], [_head(bq, D)],
         [pltpu.VMEM((bq, D), jnp.float32)], interpret)
-    as_rows = pl.BlockSpec((None, g * S // bq, 1, bq),
+    # the grid's heads: the key-value heads with their whole group, or
+    # (split) the query heads, each reading key-value head h ÷ g
+    heads, rows, per = (H, 1, g) if split else (Hkv, g, 1)
+    as_rows = pl.BlockSpec((None, rows * S // bq, 1, bq),
                            lambda h, j, _: (h, 0, 0, 0))
     dk, dv = _call(
-        functools.partial(_dkv_kernel, scale=scale, bq=bq, group=g),
+        functools.partial(_dkv_kernel, scale=scale, bq=bq, group=rows),
         "seq_attention_dkv", S // bk, hi,
-        (q.reshape(Hkv, g * S, D), k, v, do.reshape(Hkv, g * S, Dv),
-         lse.reshape(Hkv, -1, 1, bq), delta.reshape(Hkv, -1, 1, bq),
-         first.reshape(-1, 1, bq)),
-        [_head(g * S, D, True), _head(bk, D), _head(bk, Dv),
-         _head(g * S, Dv, True), as_rows, as_rows,
-         pl.BlockSpec((S // bq, 1, bq), lambda h, j, _: (0, 0, 0))],
-        [jax.ShapeDtypeStruct(k.shape, k.dtype),
-         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        (q.reshape(heads, rows * S, D), k, v,
+         do.reshape(heads, rows * S, Dv), lse.reshape(heads, -1, 1, bq),
+         delta.reshape(heads, -1, 1, bq), first.reshape(-1, 1, bq)),
+        [_head(rows * S, D, True), _head(bk, D, group=per),
+         _head(bk, Dv, group=per), _head(rows * S, Dv, True), as_rows,
+         as_rows, pl.BlockSpec((S // bq, 1, bq), lambda h, j, _: (0, 0, 0))],
+        [jax.ShapeDtypeStruct((heads, S, D),
+                              jnp.float32 if split else k.dtype),
+         jax.ShapeDtypeStruct((heads, S, Dv),
+                              jnp.float32 if split else v.dtype)],
         [_head(bk, D), _head(bk, Dv)],
         [pltpu.VMEM((bk, D), jnp.float32), pltpu.VMEM((bk, Dv), jnp.float32)],
         interpret)
+    if split:
+        dk, dv = (a.reshape(Hkv, g, S, -1).sum(1).astype(like.dtype)
+                  for a, like in ((dk, k), (dv, v)))
     return dq, dk, dv
 
 
@@ -336,17 +362,21 @@ def _heads_first(x):
     return x.transpose(1, 0, 2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def segment_attention(q, k, v, seg, bq: int, bk: int, scale: float):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def segment_attention(q, k, v, seg, bq: int, bk: int, scale: float,
+                      window=None):
     """softmax(scale · q kᵀ, causal AND inside one segment) v for ONE
     packed sequence: q [S, H, D], k [S, Hkv, D], v [S, Hkv, Dv] with
     ``Hkv`` dividing ``H`` (query head h reads key-value head
     h ÷ (H ÷ Hkv)), ``seg`` [S] int32 (0 = padding) → [S, H, Dv] in v's
-    dtype. ``bq`` query rows and ``bk`` keys a tile; both divide S."""
-    return _attend(q, k, v, seg, bq, bk, scale)[0]
+    dtype. ``bq`` query rows and ``bk`` keys a tile; both divide S.
+    ``window``: a row sees its newest ``window`` keys only (itself
+    included); None, or one of S or more, is no window — the same
+    program."""
+    return _attend(q, k, v, seg, bq, bk, scale, window)[0]
 
 
-def _attend(q, k, v, seg, bq, bk, scale):
+def _attend(q, k, v, seg, bq, bk, scale, window=None):
     S = q.shape[0]
     if S % bq or S % bk:
         raise ValueError(f"tiles of {bq} rows × {bk} keys do not divide "
@@ -354,7 +384,7 @@ def _attend(q, k, v, seg, bq, bk, scale):
     if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
         raise ValueError(f"{k.shape[1]} key and {v.shape[1]} value heads "
                          f"do not group {q.shape[1]} query heads")
-    first = first_keys(seg)
+    first = first_keys(seg, window=window)
     lo, _ = tile_intervals(first, bq, bk)
     out, lse = _by_platform(
         functools.partial(_forward, bq=bq, bk=bk, scale=scale),
@@ -364,9 +394,9 @@ def _attend(q, k, v, seg, bq, bk, scale):
     return out, (q, k, v, out, lse[..., 0], seg)
 
 
-def _attend_bwd(bq, bk, scale, res, do):
+def _attend_bwd(bq, bk, scale, window, res, do):
     q, k, v, out, lse, seg = res
-    first = first_keys(seg)
+    first = first_keys(seg, window=window)
     lo, hi = tile_intervals(first, bq, bk)
     delta = (out.astype(jnp.float32) * do.astype(jnp.float32)).sum(-1).T
     grads = _by_platform(

@@ -353,3 +353,87 @@ def test_lfm2_train_program_at_published_widths(one_chip):
     # the temporaries: what the program holds at once is the latter
     assert mem.argument_size_in_bytes < 6.2e9
     assert mem.temp_size_in_bytes < 12.5e9
+
+
+# -- the smallthinker backbone at published widths -----------------------------
+
+
+def _smallthinker_cell_config():
+    """The benchmark's ``seqrec-smallthinker-21b-ep8`` as the template
+    builds it: the configuration's published keys and its job."""
+    import json
+    import os
+
+    from predictionio_tpu.models import smallthinker as st
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "seqrec-smallthinker-21b-ep8.json")
+    with open(path) as f:
+        conf = json.load(f)
+    arch = {k: v for k, v in conf.items()
+            if k in st.SmallThinkerConfig.known_keys()}
+    return st.SmallThinkerConfig.from_architecture(dict(arch, **conf["job"]))
+
+
+@pytest.mark.parametrize("windowed", [True, False],
+                         ids=["window", "global"])
+def test_long_sequence_attention_at_published_widths(one_chip, windowed):
+    """One sequence's attention at 28 query heads over 4 key-value
+    heads of 128 and 16,384 positions, forward and backward, with the
+    window of 4,096 keys and without: a key-value head's keys and values
+    are 4 MB each in VMEM, and dk/dv — whose seven query heads' rows
+    (59 MB with their cotangents) would not fit — walks the query heads
+    and leaves their float32 parts to be summed."""
+    from predictionio_tpu.models import seq_backbone
+
+    c = _smallthinker_cell_config()
+    S, H, Hkv, D = (c.seq_len, c.num_attention_heads,
+                    c.num_key_value_heads, c.head_dim)
+    assert (S, H, Hkv, D, c.window) == (16384, 28, 4, 128, 4096)
+    q = _sds((S, H, D), jnp.bfloat16, one_chip)
+    kv = _sds((S, Hkv, D), jnp.bfloat16, one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v, seg: seq_backbone.attention(
+            q, k, v, seg, c, D ** -0.5,
+            c.window if windowed else None).astype(jnp.float32).sum(),
+        (0, 1, 2))).lower(q, kv, kv, _sds((S,), jnp.int32,
+                                         one_chip)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # the rows' statistics in their 128 lanes (28 × 16,384 × 128 float32
+    # = 235 MB) and the query heads' float32 dk, dv (2 × 235 MB); one
+    # head's scores alone would be 1.07 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
+
+
+def test_smallthinker_train_program_at_published_widths(one_chip):
+    """The cell's whole train program — 370.5 M parameters with Adam's
+    state, 16 steps of 2 × 16,384 slots, two scanned bodies (1 × global,
+    3 × window), the untied head — for the described chip: both kinds
+    of attention layer and the grouped products are in it, and it fits
+    the chip's 16 GB (by 1 GB: the pair buffer is 196,608 rows)."""
+    from predictionio_tpu.models import seq_backbone
+    from predictionio_tpu.models import smallthinker as st
+    from predictionio_tpu.models.seq_rec import _make_tx
+
+    c = _smallthinker_cell_config()
+    assert st.n_params(c) == 370_547_200
+    params = jax.tree.map(lambda s: _sds(s, jnp.float32, one_chip),
+                          st.param_shapes(c),
+                          is_leaf=seq_backbone._is_shape)
+    opt = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                       jax.eval_shape(_make_tx().init, params))
+    bias = _sds((c.num_hidden_layers, c.router_experts), jnp.float32,
+                one_chip)
+    data = {k: _sds((16, c.seqs_per_step, c.seq_len), jnp.int32, one_chip)
+            for k in st.BATCH_KEYS}
+    compiled = st.train_program(c, 1).lower((params, opt, bias),
+                                            data).compile()
+    # forward, recomputation and backward of two scanned bodies' three
+    # attention kernels and three grouped products
+    assert _ragged_calls(compiled) >= 30
+    mem = compiled.memory_analysis()
+    # the donated state is counted in the arguments AND (updated) in
+    # the temporaries: what the program holds at once is the latter
+    assert mem.argument_size_in_bytes < 4.6e9
+    assert mem.temp_size_in_bytes < 15.3e9

@@ -255,3 +255,220 @@ def test_the_model_counts_its_tiles():
     assert attrs["attn_dense_pairs"] == 16 * 160
     assert attrs["attn_pairs"] == sum(n * (n + 1) // 2
                                       for n in (30, 20, 9, 5))
+
+
+# -- the window ----------------------------------------------------------------
+
+
+def _dense_window(q, k, v, seg, scale, window):
+    """:func:`_dense` with one more term in the mask: a key fewer than
+    ``window`` rows behind its query."""
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(a.astype(jnp.float32), q.shape[1] // a.shape[1],
+                           axis=1) for a in (k, v))
+    r = jnp.arange(q.shape[0])
+    mask = ((seg[:, None] == seg[None, :]) & (r[:, None] >= r[None, :])
+            & (seg[:, None] > 0) & (r[:, None] - r[None, :] < window))
+    s = jnp.einsum("qhd,khd->hqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(mask[None], s, -1e30), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+def _windowed(seg, bq, bk, window):
+    return lambda q, k, v: sa.segment_attention(
+        q, k, v, jnp.asarray(seg), bq, bk, q.shape[-1] ** -0.5, window)
+
+
+#: name → (segment ids, query rows a tile, keys a tile, window, query
+#: heads, key-value heads): segments longer than, equal to and shorter
+#: than the window, windows that start mid-tile and on a tile's edge
+WINDOWS = {
+    "longer_equal_shorter": (_segments(100, 40, 20, 60, S=256), 32, 32, 40,
+                             4, 2),
+    "starts_mid_tile": (_segments(150, 90, S=256), 32, 32, 37, 2, 2),
+    "on_tile_edges": (_segments(128, 128, S=256), 32, 32, 64, 2, 1),
+    "one_long_segment": (_segments(256, S=256), 32, 64, 48, 8, 2),
+    "wider_than_a_block": (_segments(200, 30, S=256), 16, 32, 70, 4, 4),
+    "window_of_one": (_segments(50, 60, S=128), 32, 32, 1, 2, 2),
+    "padding_tail": (_segments(90, S=128), 32, 32, 33, 4, 2),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("name", sorted(WINDOWS))
+def test_windowed_equals_dense_forward_and_gradients(name, dtype):
+    seg, bq, bk, window, H_, Hkv_ = WINDOWS[name]
+    q, k, v = _operands(len(seg), dtype, H=H_, Hkv=Hkv_)
+    real = seg > 0
+    want_fn = lambda q, k, v: _dense_window(  # noqa: E731
+        q, k, v, jnp.asarray(seg), q.shape[-1] ** -0.5, window)
+    out = _windowed(seg, bq, bk, window)(q, k, v)
+    assert out.dtype == v.dtype and bool(jnp.isfinite(out).all())
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32)[real],
+                               np.asarray(want_fn(q, k, v))[real],
+                               atol=tol, rtol=tol)
+    got = _value_and_grads(_windowed(seg, bq, bk, window), q, k, v, seg)
+    ref = _value_and_grads(want_fn, q, k, v, seg)
+    for which, a, b in zip("qkv", got[1], ref[1]):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all()
+        scale = np.sqrt((b ** 2).mean())
+        assert np.abs(a - b).max() <= 2.5 * tol * max(scale, 1.0), which
+        assert not a[~real].any(), which
+
+
+#: the accepted cells' head shapes: (query heads, key-value heads, D, Dv)
+HEAD_SHAPES = {"glm_latent": (2, 2, 256, 256), "lfm2_grouped": (8, 2, 64, 64)}
+
+
+@pytest.mark.parametrize("window", [None, 128, 1000],
+                         ids=["none", "window_is_S", "window_over_S"])
+@pytest.mark.parametrize("shape", sorted(HEAD_SHAPES))
+def test_no_window_is_todays_program_bit_for_bit(shape, window):
+    """``window=None`` and a window of S or more: the same intervals,
+    the same first keys, the same output and gradients as the call
+    without the argument — bit for bit, and the same lowered text."""
+    H_, Hkv_, D_, Dv_ = HEAD_SHAPES[shape]
+    seg = _segments(20, 70, 30, S=128)
+    q, k, v = _operands(128, jnp.bfloat16, D_, Dv_, H_, Hkv_)
+    today = _value_and_grads(_tiled(seg, 32, 64), q, k, v, seg)
+    got = _value_and_grads(_windowed(seg, 32, 64, window), q, k, v, seg)
+    np.testing.assert_array_equal(np.asarray(today[0]), np.asarray(got[0]))
+    for a, b in zip(today[1], got[1]):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    lowered = [jax.jit(f).lower(q, k, v).as_text() for f in (
+        _tiled(seg, 32, 64), _windowed(seg, 32, 64, window))]
+    assert lowered[0] == lowered[1]
+
+
+def _brute_tiles(seg, bq, bk, window):
+    """Per sequence, the (block, tile) pairs that hold a real (query,
+    key) pair or lie between one and the diagonal — by the mask's own
+    definition, pair by pair."""
+    S = seg.shape[-1]
+    r = np.arange(S)
+    out = []
+    for row in seg:
+        mask = ((row[:, None] == row[None, :]) & (row[:, None] > 0)
+                & (r[:, None] >= r[None, :]))
+        if window is not None:
+            mask &= r[:, None] - r[None, :] < window
+        tiles = set()
+        for i in range(S // bq):
+            diag = ((i + 1) * bq - 1) // bk
+            block = mask[i * bq:(i + 1) * bq]
+            keys = np.flatnonzero(block.any(0))
+            lo = keys.min() // bk if keys.size else diag
+            tiles |= {(i, j) for j in range(min(lo, diag), diag + 1)}
+        out.append(tiles)
+    return out
+
+
+@pytest.mark.parametrize("window", [None, 1, 7, 16, 33, 64, 200])
+def test_window_intervals_and_tile_pairs_against_brute_force(window):
+    """Random packings: the forward interval of every block and the
+    backward interval of every tile are what the mask says, pair by
+    pair; ``first`` never falls; ``tile_pairs`` counts those tiles."""
+    rng = np.random.default_rng(0 if window is None else window)
+    packed = glm.pack_histories(
+        [rng.integers(1, 50, n) for n in rng.integers(2, 120, 30)], 128, 1)
+    for bq, bk in ((32, 32), (32, 16), (16, 64)):
+        first = sa.first_keys(packed.seg, np, window)
+        np.testing.assert_array_equal(
+            first, sa.first_keys(jnp.asarray(packed.seg), window=window))
+        real = packed.seg > 0
+        assert (np.diff(first, axis=-1) >= 0).all()
+        lo, hi = sa.tile_intervals(first, bq, bk, np)
+        want = _brute_tiles(packed.seg, bq, bk, window)
+        total = 0
+        for s, tiles in enumerate(want):
+            fwd = {(i, j) for i in range(128 // bq)
+                   for j in range(lo[s, i], ((i + 1) * bq - 1) // bk + 1)}
+            bwd = {(i, j) for j in range(128 // bk)
+                   for i in range(j * bk // bq, hi[s, j] + 1)}
+            assert fwd == bwd == tiles
+            total += len(tiles)
+        assert sa.tile_pairs(packed.seg, bq, bk, window=window) == (
+            total * bq * bk)
+        if window is not None:
+            r = np.arange(128)
+            assert (first[real] >= (r[None, :] - window + 1).repeat(
+                len(first), 0)[real]).all()
+
+
+def test_the_window_skips_the_tiles_it_hides():
+    """One long segment, the keys more than a window behind every row of
+    the last block NaN: that block's outputs and its queries' gradients
+    are those of the clean run — the tiles are not read, not read and
+    masked. (Earlier blocks do read them, so the keys' gradients are
+    not compared.)"""
+    S, bq, bk, window = 256, 32, 32, 40
+    seg = _segments(S, S=S)
+    q, k, v = _operands(S, jnp.float32)
+    last = np.arange(S) >= S - bq
+    hidden = np.arange(S) < (S - bq - window + 1) // bk * bk
+    rows = jnp.asarray(last)[:, None, None]
+    poison = jnp.where(jnp.asarray(hidden)[:, None, None], jnp.nan, 0.0)
+
+    def run(k, v):
+        out, dq = jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.where(
+                rows, _windowed(seg, bq, bk, window)(q, k, v), 0.0).sum()))(
+                    q, k, v)
+        return [np.asarray(out), np.asarray(dq)[last]]
+
+    for a, b in zip(run(k, v), run(k + poison, v + poison)):
+        assert np.isfinite(b).all()
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["global", "window"])
+def test_dkv_by_query_head_equals_dkv_by_group(monkeypatch, window):
+    """Where a key-value head's query rows do not fit VMEM the dk/dv
+    grid walks the query heads and the group's parts are summed outside
+    the kernel: the same gradients as the grouped walk, to rounding."""
+    seg, bq, bk = _segments(100, 40, 20, 60, S=256), 32, 32
+    q, k, v = _operands(256, jnp.float32, H=8, Hkv=2)
+    grouped = _value_and_grads(_windowed(seg, bq, bk, window), q, k, v, seg)
+    monkeypatch.setattr(sa, "_WHOLE_HEAD_BYTES", 0)
+    split = _value_and_grads(_windowed(seg, bq, bk, window), q, k, v, seg)
+    np.testing.assert_array_equal(np.asarray(grouped[1][0]),
+                                  np.asarray(split[1][0]))
+    for a, b in zip(grouped[1][1:], split[1][1:]):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   rtol=2e-5)
+
+
+def test_window_counters_against_brute_force():
+    """``pack_histories(…, window=W)``: the pairs the window leaves and
+    the rows it binds, against the mask counted pair by pair."""
+    from predictionio_tpu.models import seq_backbone
+
+    rng = np.random.default_rng(5)
+    hist = [rng.integers(1, 50, n) for n in (3, 17, 40, 64, 100, 150, 31)]
+    for window in (1, 16, 40, 64, 500):
+        packed = seq_backbone.pack_histories(hist, 64, 2, seed=3,
+                                             window=window)
+        r = np.arange(64)
+        pairs = bound = 0
+        for row in packed.seg:
+            mask = ((row[:, None] == row[None, :]) & (row[:, None] > 0)
+                    & (r[:, None] >= r[None, :]))
+            kept = mask & (r[:, None] - r[None, :] < window)
+            pairs += int(kept.sum())
+            bound += int((kept.sum(1) < mask.sum(1)).sum())
+        c = packed.counters
+        assert c["attn_pairs_window"] == pairs
+        assert c["window_bound_tokens"] == bound
+        assert c["attn_pairs"] == int(sum(
+            (row[:, None] == row[None, :]).__and__(row[:, None] > 0).__and__(
+                r[:, None] >= r[None, :]).sum() for row in packed.seg))
+        assert (c["attn_pairs_window"] == c["attn_pairs"]) == (bound == 0)
+    assert "attn_pairs_window" not in seq_backbone.pack_histories(
+        hist, 64, 2, seed=3).counters
