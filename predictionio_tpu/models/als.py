@@ -54,6 +54,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from predictionio_tpu import native
 from predictionio_tpu.utils import tracing
 
 log = logging.getLogger(__name__)
@@ -236,9 +237,12 @@ class _BucketSide:
     inv_perm: np.ndarray   # original entity id → position
     buckets: list
     dense: Optional[_DenseHead] = None
-    #: which path the data took through :func:`_bucket_side`: 16-bit
-    #: radix passes of the order (1 | 2), and how the dense head was
-    #: filled ("assign" | "bincount" | "none" without a head)
+    #: which path the data took through :func:`_bucket_side`: how the
+    #: interactions were ordered ("native" counting pass | numpy
+    #: "radix"), the radix order's 16-bit passes (1 | 2; 0 where the
+    #: native pass ran), and how the dense head was filled ("assign" |
+    #: "bincount" | "none" without a head)
+    order_path: str = "radix"
     radix_passes: int = 0
     dense_fill: str = "none"
 
@@ -256,6 +260,29 @@ class _BucketSide:
             (b.other_idx, b.vals, b.mask, b.counts)
             + ((b.seg, b.seg_off) if b.seg is not None else ())
             for b in self.buckets))
+
+
+def _lies_as(a: np.ndarray, dtype) -> bool:
+    """Whether the native layout passes can read ``a`` where it lies: a
+    C-contiguous column of ``dtype``."""
+    return a.dtype == dtype and a.ndim == 1 and a.flags.c_contiguous
+
+
+def _entity_counts(idx: np.ndarray, n: int) -> np.ndarray:
+    """Interactions per entity, ``np.bincount(idx, minlength=n)``: one
+    native pass over the column as it lies (``native/als_layout.cc``)
+    where it is a C-contiguous int32 array and the library can be
+    built — ``np.bincount`` first copies the column to int64, nnz-long
+    fresh memory for a table of ``n`` counts."""
+    lib = native.als_layout_library() if _lies_as(idx, np.int32) else None
+    if lib is None:
+        return np.bincount(idx, minlength=n)
+    counts = np.zeros(n, np.int64)
+    bad = lib.als_count(idx.shape[0], idx.ctypes.data, n, counts.ctypes.data)
+    if bad >= 0:
+        raise IndexError(f"interaction {bad}: id {idx[bad]} outside the "
+                         f"side's {n} entities")
+    return counts
 
 
 def _perm_by_count_desc(counts: np.ndarray):
@@ -322,6 +349,42 @@ def _stable_order(inv_perm: np.ndarray, idx_self: np.ndarray):
     return o1[np.argsort(hi, kind="stable")], 2
 
 
+def _native_order(idx_self, idx_other, other_pos, vals, inv_perm, starts):
+    """The ordered ``(o, v)`` of :func:`_bucket_side`'s step 1 from ONE
+    stable counting-sort pass in native code
+    (``native/als_layout.cc``): interaction i goes to slot
+    ``cursor[inv_perm[idx_self[i]]]++``, the cursors starting at
+    ``starts`` — COO order within an entity, no permutation built, and
+    ``o``, ``v`` the only nnz-long memory touched for the first time.
+    None where the library cannot be built or a column is not what it
+    takes (C-contiguous, int32 ids and positions, float32 values): the
+    caller then orders with numpy, and no nnz-long column is copied to
+    fit."""
+    nnz = idx_self.shape[0]
+    takes = (_lies_as(idx_self, np.int32) and _lies_as(idx_other, np.int32)
+             and _lies_as(vals, np.float32) and _lies_as(other_pos, np.int32)
+             and _lies_as(inv_perm, np.int32)
+             and idx_other.shape[0] == vals.shape[0] == nnz == starts[-1]
+             and inv_perm.shape[0] == len(starts) - 1)
+    lib = native.als_layout_library() if takes else None
+    if lib is None:
+        return None
+    cursor = starts[:-1].copy()
+    o, v = np.empty(nnz, np.int32), np.empty(nnz, np.float32)
+    bad = lib.als_order_scatter(
+        nnz, idx_self.ctypes.data, idx_other.ctypes.data,
+        other_pos.ctypes.data, other_pos.shape[0], vals.ctypes.data,
+        inv_perm.ctypes.data, inv_perm.shape[0], cursor.ctypes.data,
+        o.ctypes.data, v.ctypes.data)
+    if bad >= 0:
+        raise IndexError(
+            f"interaction {bad}: entity {idx_self[bad]} / other "
+            f"{idx_other[bad]} outside the layout's {inv_perm.shape[0]} "
+            f"x {other_pos.shape[0]} ids, or more interactions of the "
+            "entity than its count")
+    return o, v
+
+
 def _fill_rows(rowlen: np.ndarray, C: int, o: np.ndarray, v: np.ndarray):
     """``(other_idx, vals, mask)`` of ``len(rowlen)`` rows of width
     ``C``: row r holds the next ``rowlen[r]`` interactions of ``o``,
@@ -357,20 +420,25 @@ def _bucket_side(idx_self, idx_other, other_pos, vals, n_self, counts,
     ``tests/als_layout_oracle.py``):
 
     1. the interactions are ordered by their entity's permuted position
-       with a STABLE radix sort (:func:`_stable_order`; one or two
-       16-bit passes, read from the entity count) — stable, because a
-       row's slot order is the COO's order of appearance and the
-       Gram's float sums depend on it — and
-       ``other_pos[idx_other[order]]``, ``vals[order]`` are the only
-       nnz-long gathers of 4-byte data;
+       with a STABLE counting sort — stable, because a row's slot
+       order is the COO's order of appearance and the Gram's float
+       sums depend on it. The counts are known (``counts``), so the
+       rows' ``starts`` come first and one native pass over the COO
+       drops every interaction at its entity's cursor
+       (:func:`_native_order`); where the native library cannot be
+       built, or a column is not a C-contiguous int32 / float32 array,
+       numpy's radix sort orders them instead (:func:`_stable_order`;
+       one or two 16-bit passes, read from the entity count) and
+       ``other_pos[idx_other[order]]``, ``vals[order]`` are gathered
+       through the order. Both give the same ``o``, ``v``;
     2. the dense head is filled by assignment where no (entity, other)
        pair repeats — seen on the data: as many non-zero cells as
        interactions — and by two ``bincount``s where one does;
     3. every bucket is filled through its prefix mask
        (:func:`_fill_rows`) from the rows' lengths alone.
 
-    ``radix_passes`` and ``dense_fill`` of the result say which path
-    the data took.
+    ``order_path``, ``radix_passes`` and ``dense_fill`` of the result
+    say which path the data took.
 
     Invariant the fused kernel rests on: in every bucket row — regular
     or segmented, natural or forced boundaries — the real slots are a
@@ -380,14 +448,20 @@ def _bucket_side(idx_self, idx_other, other_pos, vals, n_self, counts,
     (tests/test_als.py holds it).
     """
     with tracing.span("als.prepare.order"):
-        order, passes = _stable_order(inv_perm, idx_self)
-        o, v = other_pos[idx_other[order]], vals[order]
-        del order
-        if n_other is None:
-            n_other = int(o.max()) + 1 if o.size else 1
         counts_perm = counts[perm].astype(np.int64)
         starts = np.zeros(n_self + 1, np.int64)
         np.cumsum(counts_perm, out=starts[1:])
+        ordered = _native_order(idx_self, idx_other, other_pos, vals,
+                                inv_perm, starts)
+        if ordered is not None:
+            (o, v), order_path, passes = ordered, "native", 0
+        else:
+            order, passes = _stable_order(inv_perm, idx_self)
+            o, v = other_pos[idx_other[order]], vals[order]
+            del order
+            order_path = "radix"
+        if n_other is None:
+            n_other = int(o.max()) + 1 if o.size else 1
 
     if bounds is None:
         bounds = _merge_bounds([counts_perm], n_other)
@@ -509,7 +583,8 @@ def _bucket_side(idx_self, idx_other, other_pos, vals, n_self, counts,
                 rowlen.astype(np.float32).reshape(n_slabs, slab)))
             e += nb
     return _BucketSide(n_self, perm, inv_perm, buckets, dense=dense,
-                       radix_passes=passes, dense_fill=dense_fill)
+                       order_path=order_path, radix_passes=passes,
+                       dense_fill=dense_fill)
 
 
 @dataclass
@@ -570,7 +645,9 @@ class ALSPrepared:
     def layout_paths(self) -> dict:
         """Which data-dependent path :func:`_bucket_side` took on each
         side (attributes of the ``als.prepare`` span)."""
-        return {"radix_passes_u": self.u_side.radix_passes,
+        return {"order_path_u": self.u_side.order_path,
+                "order_path_i": self.i_side.order_path,
+                "radix_passes_u": self.u_side.radix_passes,
                 "radix_passes_i": self.i_side.radix_passes,
                 "dense_fill_u": self.u_side.dense_fill,
                 "dense_fill_i": self.i_side.dense_fill}
@@ -598,8 +675,8 @@ class ALSPrepared:
 def als_prepare(coo: RatingsCOO) -> ALSPrepared:
     """Build the bucketed layout for single-device training."""
     with tracing.span("als.prepare.order"):
-        cnt_u = np.bincount(coo.user_idx, minlength=coo.n_users)
-        cnt_i = np.bincount(coo.item_idx, minlength=coo.n_items)
+        cnt_u = _entity_counts(coo.user_idx, coo.n_users)
+        cnt_i = _entity_counts(coo.item_idx, coo.n_items)
         perm_u, inv_u = _perm_by_count_desc(cnt_u)
         perm_i, inv_i = _perm_by_count_desc(cnt_i)
     u_side = _bucket_side(coo.user_idx, coo.item_idx, inv_i, coo.rating,

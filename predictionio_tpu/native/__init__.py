@@ -7,6 +7,17 @@ first use into ``$PIO_HOME/native/`` keyed by a source hash, so a source
 update or compiler change rebuilds automatically. Import failures (no
 g++, sandboxed FS) degrade gracefully: callers fall back to the pure-
 Python backends and say so.
+
+- ``eventlog.cc`` — the append-only event log engine (``EVENTLOG``
+  backend, import and the training scan).
+- ``als_layout.cc`` — the order stage of the ALS layout as a stable
+  counting sort: the entities' counts, then one pass over the COO that
+  places every interaction at its entity's cursor (``models/als.py
+  als_prepare``, ``_bucket_side`` step 1). Where it
+  cannot be built, or a column is not a C-contiguous int32 / float32
+  array, the layout runs its numpy radix order instead — the same
+  arrays, bit for bit — and ``order_path_u/i`` on the ``als.prepare``
+  span says which ran.
 """
 
 from __future__ import annotations
@@ -138,4 +149,23 @@ def eventlog_library() -> Optional[ctypes.CDLL]:
         ctypes.c_void_p, ctypes.POINTER(ctypes.c_longlong),
         ctypes.POINTER(ctypes.c_longlong)]
     lib.pel_free.argtypes = [ctypes.c_void_p]
+    return lib
+
+
+def als_layout_library() -> Optional[ctypes.CDLL]:
+    """The ALS layout's order pass, or None if it cannot be built here
+    (no g++, no writable ``$PIO_HOME/native``)."""
+    try:
+        lib = load_library("als_layout")
+    except (NativeBuildError, OSError):
+        return None
+    lib.als_count.restype = ctypes.c_int64
+    lib.als_count.argtypes = [ctypes.c_int64, ctypes.c_void_p,
+                              ctypes.c_int64, ctypes.c_void_p]
+    lib.als_order_scatter.restype = ctypes.c_int64
+    lib.als_order_scatter.argtypes = [
+        ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_void_p]
     return lib

@@ -107,7 +107,9 @@ def _ref_als(coo, p):
 def _layout_cases():
     """name → (knobs to monkeypatch on models/als.py, COO builder
     (given the monkeypatch), devices of the sharded layout or 0, what
-    the new builder must report about the paths it took)."""
+    the new builder must report about the paths it took where numpy's
+    radix sort orders the interactions — under the native counting pass
+    ``radix_passes_*`` read 0)."""
 
     def coo_of(uu, ii, rr, n_u, n_i, dtype=np.int32):
         return RatingsCOO(np.asarray(uu).astype(dtype),
@@ -206,6 +208,13 @@ def _layout_cases():
         "int64_indices": (
             small, lambda mp: power_law(46, 60, 40, 900, dtype=np.int64),
             0, {}),
+        "repeats_past_65536_entities": (
+            {}, lambda mp: power_law(48, 70_000, 300, 120_000,
+                                     dedupe=False), 0,
+            dict(radix_passes_u=2, radix_passes_i=1)),
+        "dense_seg_regular": (
+            {}, lambda mp: _wide_layout(mp), 0,
+            dict(radix_passes_u=1, dense_fill_u="assign")),
         "sharded_skewed_devices": (
             small, lambda mp: skewed_devices(), 8, {}),
         "sharded_dense_seg_regular": (
@@ -533,17 +542,24 @@ class TestBucketedLayout:
                 self._same_array(f"{name}.buckets[{j}] (C={b.C}).{f}",
                                  getattr(b, f), getattr(ob, f))
 
+    @pytest.mark.parametrize("order", ["native", "radix"])
     @pytest.mark.parametrize("case", list(_layout_cases()))
-    def test_layout_equals_oracle(self, monkeypatch, case):
-        """ISSUE 28: the O(nnz) builder (radix order, prefix-mask fill,
-        dense head by assignment) against the builder it replaced
+    def test_layout_equals_oracle(self, monkeypatch, case, order):
+        """ISSUE 28: the O(nnz) builder (prefix-mask fill, dense head
+        by assignment) against the builder it replaced
         (``tests/als_layout_oracle.py``, the parent's body): every
-        array of both sides equal bit for bit."""
+        array of both sides equal bit for bit — ISSUE 39: whether the
+        interactions were ordered by the native counting pass or, where
+        that library cannot be built, by numpy's radix sort."""
         import predictionio_tpu.models.als as als_mod
         import predictionio_tpu.models.als_sharded as sh_mod
         from tests.als_layout_oracle import bucket_side_oracle
 
         knobs, build, n_dev, paths = _layout_cases()[case]
+        if order == "radix":
+            _no_native_build(monkeypatch)
+        # the one case whose columns the native pass does not take
+        took = "radix" if case == "int64_indices" else order
         for k, val in knobs.items():
             monkeypatch.setattr(als_mod, k, val)
         coo = build(monkeypatch)
@@ -573,9 +589,166 @@ class TestBucketedLayout:
             seg = [s.buckets[0] for s in prep.u_sides]
             assert all(b.seg is not None for b in seg)
             assert sum(1 for b in seg if not b.mask.any()) >= 1
+        assert {s.order_path for s in new} == {took}
         if not n_dev:
+            want = dict(paths, order_path_u=took, order_path_i=took)
+            if took == "native":
+                want.update({k: 0 for k in want if k.startswith("radix_")})
             got = prep.layout_paths()
-            assert {k: got[k] for k in paths} == paths
+            assert {k: got[k] for k in want} == want
+
+    @pytest.mark.parametrize("side", ["u", "i"])
+    @pytest.mark.parametrize("case", [
+        "default_ladder_power_law", "repeats_past_65536_entities",
+        "dense_head_repeated_pair", "entities_without_interactions",
+        "empty_coo"])
+    def test_native_order_equals_radix_order(self, monkeypatch, case, side):
+        """The order stage alone: ``o``, ``v`` of the native counting
+        pass are those of the radix order and its gathers — a repeated
+        (entity, other) pair keeps its COO order, an entity without
+        interactions moves no cursor, an empty side gives empty
+        arrays."""
+        import predictionio_tpu.models.als as als_mod
+
+        knobs, build, _, _ = _layout_cases()[case]
+        for k, val in knobs.items():
+            monkeypatch.setattr(als_mod, k, val)
+        coo = build(monkeypatch)
+        idx_self, idx_other, n_self, n_other = (
+            (coo.user_idx, coo.item_idx, coo.n_users, coo.n_items)
+            if side == "u" else
+            (coo.item_idx, coo.user_idx, coo.n_items, coo.n_users))
+        _, inv, starts = _order_inputs(
+            np.bincount(idx_self, minlength=n_self))
+        _, other_pos, _ = _order_inputs(
+            np.bincount(idx_other, minlength=n_other))
+        o, v = als_mod._native_order(idx_self, idx_other, other_pos,
+                                     coo.rating, inv, starts)
+        order, _ = als_mod._stable_order(inv, idx_self)
+        self._same_array("o", o, other_pos[idx_other[order]])
+        self._same_array("v", v, coo.rating[order])
+
+    @pytest.mark.parametrize("column", [
+        "idx_self_strided", "idx_other_int64", "vals_float64",
+        "other_pos_int64", "vals_strided"])
+    def test_native_order_leaves_other_columns_to_numpy(self, monkeypatch,
+                                                        column):
+        """A column the native pass does not take as it lies in memory
+        — strided, or of another width — is not copied to fit: the
+        library is never asked for and the radix path orders the side,
+        to the arrays the native pass gives on the column's copy."""
+        import predictionio_tpu.models.als as als_mod
+        from predictionio_tpu import native
+
+        rng = np.random.default_rng(50)
+        n_u, n_i, nnz = 50, 30, 400
+        cols = dict(
+            idx_self=rng.integers(0, n_u, nnz).astype(np.int32),
+            idx_other=rng.integers(0, n_i, nnz).astype(np.int32),
+            vals=rng.uniform(1, 5, nnz).astype(np.float32),
+            other_pos=rng.permutation(n_i).astype(np.int32))
+        counts = np.bincount(cols["idx_self"], minlength=n_u)
+        perm, inv = als_mod._perm_by_count_desc(counts)
+
+        def side(c):
+            return als_mod._bucket_side(
+                c["idx_self"], c["idx_other"], c["other_pos"], c["vals"],
+                n_u, counts, perm, inv, n_other=n_i)
+
+        want = side(cols)
+        assert want.order_path == "native"
+        name, _, how = column.rpartition("_")
+        if how == "strided":
+            cols[name] = np.repeat(cols[name], 2)[::2]
+            assert not cols[name].flags.c_contiguous
+        else:
+            cols[name] = cols[name].astype(how)
+        # never asked for: calling None raises
+        monkeypatch.setattr(native, "als_layout_library", None)
+        got = side(cols)
+        assert got.order_path == "radix" and got.radix_passes == 1
+        self._same_side(column, got, want)
+
+    def test_native_order_declines_counts_that_are_not_the_columns(self):
+        """``starts`` that do not end at the number of interactions are
+        no layout of these columns: the pass is not run on them (it
+        would leave slots unwritten)."""
+        import predictionio_tpu.models.als as als_mod
+
+        idx = np.array([0, 1, 1, 2], np.int32)
+        _, inv, starts = _order_inputs(np.array([1, 1, 1]))  # 1 has two
+        assert als_mod._native_order(
+            idx, idx, np.arange(3, dtype=np.int32),
+            np.ones(4, np.float32), inv, starts) is None
+
+    @pytest.mark.parametrize("column", ["int32", "int32_empty", "int64",
+                                        "int32_strided", "int32_nobuild"])
+    def test_entity_counts_are_bincount(self, monkeypatch, column):
+        """The counts the order starts from: the native pass over an
+        int32 column as it lies, ``np.bincount`` for any other column
+        or machine — the same int64 table, entities without
+        interactions included."""
+        import predictionio_tpu.models.als as als_mod
+        from predictionio_tpu import native
+
+        kind, _, how = column.partition("_")
+        idx = np.random.default_rng(51).integers(
+            0, 90, 0 if how == "empty" else 5000).astype(kind)
+        if how == "strided":
+            idx = np.repeat(idx, 2)[::2]
+        if how == "nobuild":
+            _no_native_build(monkeypatch)
+        elif column not in ("int32", "int32_empty"):
+            # never asked for: calling None raises
+            monkeypatch.setattr(native, "als_layout_library", None)
+        got = als_mod._entity_counts(idx, 100)
+        self._same_array(column, got, np.bincount(idx, minlength=100))
+
+    @pytest.mark.parametrize("bad", ["idx_self", "idx_other"])
+    def test_native_order_refuses_an_id_outside_the_layout(self, bad):
+        """numpy's gathers raise on an id past the table; so does the
+        native pass, before it reads or writes outside an array."""
+        import predictionio_tpu.models.als as als_mod
+
+        cols = dict(idx_self=np.array([0, 1, 1, 2], np.int32),
+                    idx_other=np.array([1, 0, 2, 1], np.int32))
+        cols[bad] = cols[bad].copy()
+        cols[bad][2] = 7
+        _, inv, starts = _order_inputs(np.array([1, 2, 1]))
+        if bad == "idx_self":
+            with pytest.raises(IndexError, match="interaction 2: id 7"):
+                als_mod._entity_counts(cols[bad], 3)
+        with pytest.raises(IndexError, match="interaction 2"):
+            als_mod._native_order(
+                cols["idx_self"], cols["idx_other"],
+                np.arange(3, dtype=np.int32), np.ones(4, np.float32),
+                inv, starts)
+
+    @pytest.mark.parametrize("implicit", [False, True])
+    def test_train_is_bit_identical_under_both_orders(self, monkeypatch,
+                                                      implicit):
+        """The factors of a small train on the native order's layout
+        and on the radix order's: equal bit for bit."""
+        import predictionio_tpu.models.als as als_mod
+
+        _wide_layout(monkeypatch)     # its knobs: a head, segments, a ladder
+        coo = _layout_cases()["dense_head_power_law_repeats"][1](monkeypatch)
+        p = ALSParams(rank=8, iterations=3, reg=0.05, seed=4,
+                      implicit=implicit)
+        native_prep = als_mod.als_prepare(coo)
+        U, V = als_mod.als_train_prepared(native_prep, p)
+        _no_native_build(monkeypatch)
+        radix_prep = als_mod.als_prepare(coo)
+        Ur, Vr = als_mod.als_train_prepared(radix_prep, p)
+        assert native_prep.layout_paths()["order_path_u"] == "native"
+        assert radix_prep.layout_paths()["order_path_i"] == "radix"
+        assert native_prep.geometry == radix_prep.geometry
+        u = native_prep.u_side
+        assert u.dense is not None and u.buckets[0].seg is not None \
+            and any(b.seg is None for b in u.buckets)
+        assert native_prep.kernel_rows() == radix_prep.kernel_rows()
+        self._same_array("U", U, Ur)
+        self._same_array("V", V, Vr)
 
     def test_prepare_spans_only_inside_a_verb(self, monkeypatch):
         """``als.prepare`` has children since ISSUE 28 — ``order``,
@@ -610,6 +783,28 @@ class TestBucketedLayout:
                 <= b["startNs"] <= b["endNs"] <= parent["endNs"]
         paths = prep.layout_paths()
         assert {k: parent["attrs"][k] for k in paths} == paths
+
+
+def _no_native_build(monkeypatch):
+    """A machine without g++: every native build fails, as
+    ``native.load_library`` reports it."""
+    from predictionio_tpu import native
+
+    def refuse(name):
+        raise native.NativeBuildError(f"g++ unavailable: {name}")
+
+    monkeypatch.setattr(native, "load_library", refuse)
+
+
+def _order_inputs(counts):
+    """``(perm, inv_perm, starts)`` as ``als_prepare`` and
+    ``_bucket_side`` make them from a side's counts."""
+    import predictionio_tpu.models.als as als_mod
+
+    perm, inv = als_mod._perm_by_count_desc(counts)
+    starts = np.zeros(len(counts) + 1, np.int64)
+    np.cumsum(counts[perm], out=starts[1:])
+    return perm, inv, starts
 
 
 def _zipf_coo(seed, n_u, n_i, nnz):

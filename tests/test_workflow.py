@@ -183,7 +183,7 @@ SPAN_ATTRS = {
     "als.prepare": ("nnz", "kernel_real_rows", "kernel_padded_rows",
                     "kernel_bucket_rows", "kernel_dma_rows",
                     "kernel_dma_waits", "radix_passes_u", "radix_passes_i", "dense_fill_u",
-                    "dense_fill_i"),
+                    "dense_fill_i", "order_path_u", "order_path_i"),
     "als.upload": ("bytes",),
     "als.iterate": ("iterations", "gram", "solve"),
     "als.checkpoint": ("step", "bytes"),
