@@ -12,11 +12,23 @@ shapes, operators and stack. Everything else is here, once:
 - :func:`pack_histories`: histories → ``seq_len``-slot sequences with
   segment ids, positions that restart with each segment, targets that
   never cross a segment's end; with a ``window``, the pairs it leaves;
+  with a ``block``, the blocks and the pairs the block rule leaves;
+- the two MASKS a backbone may train under: causal inside a segment
+  (:func:`attention`: a row sees its segment's keys up to itself,
+  under a ``window`` its newest keys only), and the block rule of
+  block diffusion (:func:`block_attention`: a clean and a noised copy
+  of every sequence in one pass, a row's keys decided by BLOCKS of its
+  segment);
+- the two OBJECTIVES: the next item (``_chunked_ce`` over shifted
+  targets, every real row once) and the block-diffusion one — a
+  noised row predicts its OWN item, weighted by 1/p of its block's
+  masking rate (``_chunked_ce`` with ``weights``; :func:`block_noise`
+  draws the masks, a pure function of seed, step, sequence and slot);
 - the pieces of a block: ``_mm`` (operands in the matmul dtype,
   float32 accumulation), ``_rms``, ``_rope`` (the backbone applies it
   where its layer has positions: a layer without applies none),
-  :func:`attention` (:mod:`predictionio_tpu.ops.seq_attention`; with
-  ``window``, a row's newest ``window`` keys only), ``_swiglu``, the
+  :func:`attention` and :func:`block_attention`
+  (:mod:`predictionio_tpu.ops.seq_attention`), ``_swiglu``, the
   expert layer in two halves — ``_route`` (ids, gates, plan and load
   from ONE tensor: sigmoid scores with a selection bias, or a softmax
   over the selected logits) and ``_experts`` (dispatch → grouped gated
@@ -31,11 +43,13 @@ shapes, operators and stack. Everything else is here, once:
 - :func:`init_program` (one jitted program makes parameters, Adam's
   state and the router bias on the device), :func:`train_program` (the
   step: gradients, group norms, clipping, Adam, the router-bias rule;
-  a scan over epochs of a scan over steps) and :func:`train_histories`
+  a scan over epochs of a scan over steps; ``counted``: the loss is
+  also told how many steps were taken) and :func:`train_histories`
   (the verb's ``seqrec.pack`` / ``.init`` / ``.fit`` / ``.fetch`` spans
   with their counters, through ``seq_rec.run_epoch_blocks``);
 - :func:`next_item_scores`: one history, one segment, through the
-  same stack.
+  same stack — the item after it read at its last row, or (a backbone
+  that fills blocks) at the first of the MASK rows appended to it.
 
 Precision, for every backbone: float32 master parameters, gradients,
 Adam moments and residual stream; matmul operands ``matmul_dtype``
@@ -60,21 +74,28 @@ from predictionio_tpu.ops import moe_dispatch, seq_attention
 
 class Backbone(NamedTuple):
     """What the template and the benchmark ask of a backbone. Which
-    layers have a window or rotary positions, where the router reads
-    and what its experts' activation is are the backbone's own affair:
-    it passes them to :func:`attention`, ``_route`` and ``_experts``."""
+    layers have a window or rotary positions, where the router reads,
+    what its experts' activation is, which mask it trains under
+    (causal, or the block rule over two streams) and which objective
+    (the next item, or the items a noised copy hides) are the
+    backbone's own affair: it passes them to :func:`attention` or
+    :func:`block_attention`, ``_route``, ``_experts`` and
+    ``_chunked_ce``."""
     model_type: str
     config: type                   # .from_architecture(arch) -> config
     #: (histories, config, epochs, lr, seed, checkpoint_dir=) ->
     #: ({"params", "bias"} on the host, the losses of the steps run)
     train: Callable
-    #: (model, batch, config) -> each head's logits [B, S, V]
+    #: (model, batch, config) -> each head's logits [B, S, V] (a
+    #: next-item head's at every row; a block-diffusion head's at the
+    #: rows of the NOISED stream the batch carries)
     sequence_logits: Callable
     #: (model, history, config) -> scores over the vocabulary
     next_item_scores: Callable
     #: per head, the name of its loss in the step's record
     heads: Tuple[str, ...]
-    #: what a packed batch holds for this backbone (of ``Packed``)
+    #: what ``sequence_logits`` reads of a packed batch (of ``Packed``;
+    #: a block-diffusion backbone besides: ``noised``, ``weight``)
     batch_keys: Tuple[str, ...]
     init_state: Callable           # (config, seed, with_optimizer=)
     n_params: Callable             # (config) -> int
@@ -84,7 +105,8 @@ class Backbone(NamedTuple):
 #: ``model_type`` → the module that defines ``BACKBONE``
 _MODULES = {"glm4_moe_lite": "predictionio_tpu.models.glm4_moe_lite",
             "lfm2_moe": "predictionio_tpu.models.lfm2_moe",
-            "smallthinker": "predictionio_tpu.models.smallthinker"}
+            "smallthinker": "predictionio_tpu.models.smallthinker",
+            "sdar_moe": "predictionio_tpu.models.sdar_moe"}
 #: an ``architecture`` without ``model_type``, and a model saved before
 #: the table existed
 DEFAULT = "glm4_moe_lite"
@@ -132,7 +154,8 @@ def window_pairs(sizes: np.ndarray, window: int) -> Tuple[int, int]:
 
 def pack_histories(histories: Sequence[Sequence[int]], seq_len: int,
                    seqs_per_step: int = 1, seed: int = 0,
-                   window: Optional[int] = None) -> Packed:
+                   window: Optional[int] = None,
+                   block: Optional[int] = None) -> Packed:
     """Histories (item ids ≥ 1, oldest first) → ``seq_len``-slot
     sequences with segment ids. A history longer than a sequence is
     cut into ``seq_len`` pieces; pieces go whole, longest first, into
@@ -143,7 +166,12 @@ def pack_histories(histories: Sequence[Sequence[int]], seq_len: int,
     of ``seqs_per_step`` and the order shuffled by ``seed``. With a
     ``window`` the counters also hold what it leaves of the segments'
     pairs (``attn_pairs_window``) and the rows it binds
-    (``window_bound_tokens``), by :func:`window_pairs`."""
+    (``window_bound_tokens``), by :func:`window_pairs`; with a
+    ``block``, the blocks a segment is cut into from its first row
+    (``bd_blocks``; ``bd_partial_blocks`` of them shorter than
+    ``block``), the pairs the block rule leaves of both streams
+    (``attn_pairs_bd``, by :func:`seq_attention.block_pairs`) and the
+    rows the two streams make (``stream_rows``)."""
     S = int(seq_len)
     pieces: List[np.ndarray] = []
     n_hist = n_split = 0
@@ -202,6 +230,12 @@ def pack_histories(histories: Sequence[Sequence[int]], seq_len: int,
     if window is not None:
         (counters["attn_pairs_window"],
          counters["window_bound_tokens"]) = window_pairs(sizes, window)
+    if block is not None:
+        counters.update(
+            bd_block=int(block), bd_blocks=int((-(-sizes // block)).sum()),
+            bd_partial_blocks=int((sizes % block > 0).sum()),
+            attn_pairs_bd=seq_attention.block_pairs(sizes, block),
+            stream_rows=2 * n_all * S)
     return Packed(tokens, seg, pos, tgt1, tgt2, counters)
 
 
@@ -306,7 +340,8 @@ SCOPES = frozenset({
     "seqrec.mla", "seqrec.mla.attention", "seqrec.mtp",   # glm4_moe_lite
     "seqrec.conv", "seqrec.conv.mix",                     # lfm2_moe
     "seqrec.gqa", "seqrec.gqa.attention",   # lfm2_moe; smallthinker: global
-    "seqrec.swa", "seqrec.swa.attention"})  # smallthinker: window layers
+    "seqrec.swa", "seqrec.swa.attention",   # smallthinker: window layers
+    "seqrec.bd", "seqrec.bd.attention", "seqrec.bd.noise"})     # sdar_moe
 
 
 def scope(name: str):
@@ -391,6 +426,47 @@ def attention(q, k, v, seg, c, scale: float, window: Optional[int] = None):
     (:mod:`predictionio_tpu.ops.seq_attention`)."""
     return seq_attention.segment_attention(
         q, k, v, seg, *_attn_tiles(c, q.shape[0]), scale, window)
+
+
+def block_attention(q, k, v, seg, c, scale: float, block: int):
+    """Attention of BOTH streams of one sequence under the block rule:
+    q [2·S, H, D], k [2·S, Hkv, D], v [2·S, Hkv, Dv] — the clean rows,
+    then the noised ones — and ``seg`` [S] → [2·S, H, Dv]. A clean row
+    sees its segment's clean keys up to the end of its block of
+    ``block`` rows, a noised row the clean keys before its block and
+    its block's noised keys; the tiles are those :func:`attention`
+    takes on S slots, and only those the rule reaches are visited
+    (:func:`predictionio_tpu.ops.seq_attention.block_attention`)."""
+    return seq_attention.block_attention(
+        q, k, v, seg, *_attn_tiles(c, seg.shape[0]), scale, block)
+
+
+def block_noise(seed, step, sequence, seg, block: int, eps: float):
+    """One sequence's masks for one step of block-diffusion training:
+    (``masked`` [S] bool, ``weight`` [S] float32). Each block of
+    ``block`` rows of a segment draws p = ε + (1 − ε)·u, u ~ U(0, 1);
+    each of its rows is masked with probability p and then weighs 1/p
+    in the loss, any other row 0; a padding row is never masked. A
+    pure function of (``seed``, ``step`` — the steps taken before this
+    one, over all epochs —, ``sequence`` — its number in the packed
+    order —, slot): threefry, so the same bits on any device, and a
+    fresh draw every epoch."""
+    import jax
+    import jax.numpy as jnp
+
+    S = seg.shape[-1]
+    key = jax.random.fold_in(jax.random.fold_in(
+        jax.random.key(seed, impl="threefry2x32"), step), sequence)
+    rate, coin = (jax.random.uniform(k, (S,), jnp.float32)
+                  for k in jax.random.split(key))
+    # a block's draw is the one of its first row's slot
+    _, start, _ = seq_attention.block_spans(seg, block)
+    # the maximum changes nothing (the product is never negative) and
+    # keeps a compiler from fusing product and sum into one rounding:
+    # the same bits from every backend, jitted or not
+    p = eps + jnp.maximum((1.0 - eps) * rate[jnp.minimum(start, S - 1)], 0.0)
+    masked = (coin < p) & (seg > 0)
+    return masked, jnp.where(masked, 1.0 / p, 0.0)
 
 
 def _swiglu(w, x, c):
@@ -489,10 +565,11 @@ def _cast_in_loop(w, c, turn, aside: Tuple[str, ...] = ("router",)):
         return cast(w)
 
 
-def _chunked_ce(logits_of, x, targets, c):
-    """Σ cross-entropy over the real targets; ``logits_of`` (rows
-    [n, d] → float32 logits [n, V]) is applied ``token_chunk`` tokens
-    at a time and its logits never kept."""
+def _chunked_ce(logits_of, x, targets, c, weights=None):
+    """Σ cross-entropy over the real targets — with ``weights`` (float32,
+    like ``targets``), Σ weight · cross-entropy over every row;
+    ``logits_of`` (rows [n, d] → float32 logits [n, V]) is applied
+    ``token_chunk`` tokens at a time and its logits never kept."""
     import jax
     import jax.numpy as jnp
 
@@ -502,15 +579,19 @@ def _chunked_ce(logits_of, x, targets, c):
 
     @jax.checkpoint
     def chunk(xt):
-        x, t = xt
+        x, t, *w = xt
         logits = logits_of(x)
         lse = jax.nn.logsumexp(logits, axis=-1)
         hit = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
+        if w:
+            return (w[0] * (lse - hit)).sum()
         return jnp.where(t > 0, lse - hit, 0.0).sum()
 
+    chunks = (x.reshape(-1, n, d), t.reshape(-1, n))
+    if weights is not None:
+        chunks += (weights.reshape(-1, n),)
     with scope("seqrec.head"):
-        return jax.lax.map(chunk, (x.reshape(-1, n, d),
-                                   t.reshape(-1, n))).sum()
+        return jax.lax.map(chunk, chunks).sum()
 
 
 # -- the train program --------------------------------------------------------
@@ -527,14 +608,19 @@ def grad_groups(group_squares, shapes) -> Tuple[str, ...]:
 
 
 def train_program(c, epochs: int, loss_fn, group_squares,
-                  groups: Tuple[str, ...]):
+                  groups: Tuple[str, ...], counted: bool = False):
     """``train(state, data) -> (state, records)``: ``epochs`` passes
     over ``data`` ([steps, B, S] per key) as ONE compiled program, a
     scan over epochs of a scan over steps. ``state``: params, opt_state
     (:func:`predictionio_tpu.models.seq_rec._make_tx`), bias; the
     learning rate rides in the optimizer state. ``loss_fn(params, bias,
     batch, c) -> (loss, {a loss per head, "moe": the expert layers'
-    routing records [layers, …]})``."""
+    routing records [layers, …], any further number of the step})``.
+    ``counted``: the batch also carries ``step``, the steps taken
+    before this one over all epochs (the optimizer's count, which a
+    checkpoint restores) — what an objective that draws noise folds
+    into its key, so that a resumed run draws what the whole one
+    would have."""
     import jax
     import jax.numpy as jnp
 
@@ -547,6 +633,8 @@ def train_program(c, epochs: int, loss_fn, group_squares,
     @scope("seqrec.step")
     def step(state, batch):
         params, opt_state, bias = state
+        if counted:
+            batch = dict(batch, step=opt_state.count)
         (_, rec), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, bias, batch, c)
         moe = rec.pop("moe")
@@ -591,6 +679,8 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
                     pack_attrs: Optional[Callable] = None,
                     fit_attrs: Optional[Dict[str, Any]] = None,
                     window: Optional[int] = None,
+                    block: Optional[int] = None,
+                    draws: Optional[Callable] = None,
                     checkpoint_dir: Optional[str] = None,
                     checkpoint_every: int = 1) -> Tuple[Dict, np.ndarray]:
     """Train on per-user item-id histories; returns the model's arrays
@@ -602,7 +692,13 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
     counters to the two spans; ``window``: the key window of the
     backbone's window layers, counted on ``seqrec.pack``
     (``attn_pairs_window``, ``window_bound_tokens``,
-    ``attn_tile_pairs_window``)."""
+    ``attn_tile_pairs_window``); ``block``: the block length of a
+    backbone that trains under the block rule, counted there too
+    (``bd_*``, ``attn_pairs_bd``, ``attn_tile_pairs_bd``,
+    ``stream_rows``); ``draws(packed)``: further per-sequence arrays
+    [sequences, …] of the batches (what the backbone's noise is keyed
+    by). Every ``bd_*`` number of the steps' records is summed onto
+    ``seqrec.fit``."""
     import jax
     import jax.numpy as jnp
 
@@ -613,7 +709,7 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
         raise ValueError("seq_len must be a multiple of attn_block")
     with tracing.span("seqrec.pack") as sp:
         packed = pack_histories(histories, c.seq_len, c.seqs_per_step, seed,
-                                window)
+                                window, block)
         top = max(int(packed.tokens.max()), 0)
         if top >= c.vocab_size:
             raise ValueError(f"item id {top} outside the vocabulary of "
@@ -631,12 +727,17 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
         if window is not None:
             sp.set_attr("attn_tile_pairs_window", seq_attention.tile_pairs(
                 packed.seg, bq, bk, window=window))
+        if block is not None:
+            sp.set_attr("attn_tile_pairs_bd", seq_attention.block_tile_pairs(
+                packed.seg, bq, bk, block))
         for k, v in (pack_attrs(packed) if pack_attrs else {}).items():
             sp.set_attr(k, v)
     with tracing.span("seqrec.init") as sp:
         B = c.seqs_per_step
         data = {k: jnp.asarray(getattr(packed, k).reshape(
             -1, B, packed.tokens.shape[1])) for k in batch_keys}
+        for k, v in (draws(packed) if draws else {}).items():
+            data[k] = jnp.asarray(v.reshape((-1, B) + v.shape[1:]))
         params, opt_state, bias = init_state(c, seed, with_optimizer=True)
         opt_state.hyperparams["learning_rate"] = jnp.float32(lr)
         state = jax.block_until_ready(
@@ -670,7 +771,8 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
                 np.isfinite(rec[k]).all() for k in losses)))
             sp.set_attr("grad_norms_first", {
                 g: float(v) for g, v in zip(groups, rec["group_norms"][0])})
-            for k in ("moe_pairs", "moe_pairs_here", "moe_dropped_pairs"):
+            for k in ("moe_pairs", "moe_pairs_here", "moe_dropped_pairs",
+                      *(k for k in rec if k.startswith("bd_"))):
                 sp.set_attr(k, int(rec[k].sum()))
             sp.set_attr("moe_load_max_over_mean",
                         float(rec["moe_load_max_over_mean"].mean()))
@@ -705,16 +807,26 @@ def next_program(last_logits):
 
 
 def next_item_scores(program, model: Dict, history: Sequence[int],
-                     c) -> np.ndarray:
+                     c, mask_id: Optional[int] = None,
+                     block: int = 1) -> np.ndarray:
     """Scores over the vocabulary for the item after ``history`` (its
     last ``seq_len`` items, right-padded to a power-of-two bucket so
     that a handful of programs serve every length); PAD = -inf.
-    ``program``: the backbone's :func:`next_program`."""
+    ``program``: the backbone's :func:`next_program`. With a
+    ``mask_id`` (a backbone that fills blocks of ``block`` items): the
+    newest ``seq_len − block`` items with MASK rows appended up to the
+    end of the block after them — the program reads the FIRST of those
+    rows —, and MASK = -inf too."""
     seq = [i for i in history if i > 0][-c.seq_len:]
+    if mask_id is not None:
+        seq = [i for i in seq if i != mask_id][-(c.seq_len - block):]
+        seq += [mask_id] * (block - len(seq) % block)
     bucket = min(c.seq_len, max(16, 1 << max(len(seq) - 1, 0).bit_length()))
     tokens = np.zeros(bucket, np.int32)
     tokens[:len(seq)] = seq
     logits = np.array(program(
         model["params"], model["bias"], tokens, np.int32(max(len(seq), 1))))
     logits[0] = -np.inf
+    if mask_id is not None:
+        logits[mask_id] = -np.inf
     return logits

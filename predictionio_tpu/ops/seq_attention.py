@@ -50,6 +50,37 @@ second product in the operands' dtype. Rows of segment 0 (padding) see
 no key: their output is finite and means nothing, their gradients are
 zero, and no real row depends on them.
 
+**The block rule** (:func:`block_attention`; block-diffusion training,
+``models/sdar_moe``). A sequence goes through a layer TWICE in one
+pass, a clean copy (rows ``0 … S − 1`` of every operand) and a noised
+copy (rows ``S … 2S − 1``) with the same segments and positions; a
+segment is cut into blocks of ``block`` rows from its first row (the
+last may be partial). With i, j rows of one segment:
+
+    clean query i  sees clean key j   ⇔  block(j) ≤ block(i)
+    noised query i sees clean key j   ⇔  block(j) <  block(i)
+    noised query i sees noised key j  ⇔  block(j) =  block(i)
+    no clean query sees a noised key
+
+so a row's keys are no longer ONE interval that ends at the row: a
+clean row sees ``first[r] … last[r]`` (``last``: the end of its block,
+up to ``block − 1`` rows PAST the diagonal), a noised row the clean
+keys ``first[r] … start[r] − 1`` AND the noised keys ``start[r] …
+last[r]`` (``start``: its block's first row) — two intervals of the
+2·S-row operand (:func:`block_spans`). The same three kernels run it:
+a row carries its two intervals instead of its first key, and a block
+of query rows (a key tile, in dk/dv) TWO runs of tiles instead of one
+(:func:`block_intervals`) — a clean block the clean tiles from its
+earliest segment's first key to the one holding its last row's block
+end; a noised block the clean tiles up to the one holding its last
+row's block START, then the noised tile(s) holding its own blocks. A
+clean key so collects gradient from clean AND noised queries, a noised
+key from its own block only, and the walk stays proportional to the
+visible pairs: n(n + 4) for a segment of n = 4m rows, never the
+(2n)²/2 of a causal walk over the concatenation. With no block rule
+the kernels are traced as before it existed: same operands, same tile
+bounds, the same numbers bit for bit.
+
 Compiled for a TPU, interpreted anywhere else — decided when the
 program is LOWERED (``jax.lax.platform_dependent``), so a program
 lowered for a described chip from a CPU process gets the kernels.
@@ -134,34 +165,184 @@ def tile_pairs(seg: np.ndarray, bq: int, bk: int, skip: bool = True,
     return int((diag - lo * skip + 1).sum()) * bq * bk
 
 
+# -- which tiles, under the block rule ------------------------------------------
+
+
+def last_keys(seg, xp=jnp):
+    """Per row of ``seg`` [..., S], the last row of its segment; ``r``
+    for a padding row."""
+    S = seg.shape[-1]
+    r = xp.arange(S, dtype=xp.int32)
+    end = xp.concatenate([seg[..., 1:] != seg[..., :-1],
+                          xp.ones_like(seg[..., :1], dtype=bool)], axis=-1)
+    ends = xp.where(end, r, S - 1)
+    last = (np.minimum.accumulate(ends[..., ::-1], axis=-1)[..., ::-1]
+            if xp is np else jax.lax.cummin(ends, axis=ends.ndim - 1,
+                                            reverse=True))
+    return xp.where(seg > 0, last, r).astype(xp.int32)
+
+
+def block_spans(seg, block: int, xp=jnp):
+    """(first, start, last) per row of ``seg`` [..., S]: the first row
+    of its segment, of its block (``block`` rows, counted from the
+    segment's first) and the block's last row (the segment's where the
+    block is partial). A padding row gets ``r + 1, r + 1, r``: every
+    interval made of them is empty, and all three still never fall
+    along a sequence."""
+    r = xp.arange(seg.shape[-1], dtype=xp.int32)
+    first = first_keys(seg, xp)
+    start = first + (r - first) // block * block
+    last = xp.minimum(start + block - 1, last_keys(seg, xp))
+    real = seg > 0
+    return (first, xp.where(real, start, r + 1).astype(xp.int32),
+            xp.where(real, last, r).astype(xp.int32))
+
+
+def stream_spans(first, start, last, xp=jnp):
+    """The two key intervals ``a0 … a1``, ``b0 … b1`` of every row of
+    BOTH streams ([..., 4, 2·S], in rows of the 2·S-row operands): a
+    clean row the clean keys to its block's end and nothing else, a
+    noised row the clean keys before its block and its block's noised
+    keys."""
+    S = first.shape[-1]
+    never, none = xp.full_like(first, 2 * S), xp.full_like(first, -1)
+    return xp.stack([
+        xp.concatenate([first, first], -1),
+        xp.concatenate([last, start - 1], -1),
+        xp.concatenate([never, start + S], -1),
+        xp.concatenate([none, last + S], -1)], -2)
+
+
+def _reached(tlo, thi, tiles: int, xp):
+    """Blocks ``i`` visit tiles ``tlo[i] … thi[i]``, neither falling
+    along the sequence: tile j is visited by the blocks ``lo[j] …
+    hi[j]`` (an empty run where ``lo > hi``)."""
+    j = xp.arange(tiles, dtype=xp.int32)[:, None]
+    return ((thi[..., None, :] < j).sum(-1).astype(xp.int32),
+            (tlo[..., None, :] <= j).sum(-1).astype(xp.int32) - 1)
+
+
+def block_intervals(first, start, last, bq: int, bk: int, xp=jnp):
+    """The tile runs under the block rule, from :func:`block_spans`
+    (S slots; ``nq = S/bq`` query blocks and ``T = S/bk`` key tiles a
+    stream, the noised stream's numbered after the clean one's):
+
+    - ``fwd`` [..., 4, 2·nq]: query block i visits key tiles ``fwd[0, i]
+      … fwd[1, i]`` and then ``fwd[2, i] … fwd[3, i]`` — a clean block
+      the clean tiles to its last row's block end (and an empty second
+      run), a noised block the clean tiles to its last row's block
+      start − 1, then the noised tiles of its own blocks. Every block
+      visits at least one tile.
+    - ``bwd`` [..., 4, 2·T]: key tile j is visited by the query blocks
+      ``bwd[0, j] … bwd[1, j]`` and ``bwd[2, j] … bwd[3, j]`` — a clean
+      tile by clean blocks and by noised ones, a noised tile by the
+      noised blocks whose own blocks it holds."""
+    S = first.shape[-1]
+    lead = first.shape[:-1]
+    nq, T = S // bq, S // bk
+
+    def blk(a):
+        return a.reshape(lead + (nq, bq))
+
+    diag = ((xp.arange(nq, dtype=xp.int32) + 1) * bq - 1) // bk
+    lo = xp.minimum(blk(first).min(-1) // bk, diag)
+    hi_clean = blk(last).max(-1) // bk
+    hi_before = (blk(start).max(-1) - 1) // bk
+    lo_own = xp.minimum(blk(start).min(-1) // bk, diag)
+    none = xp.zeros_like(lo)
+    fwd = xp.stack([
+        xp.concatenate([lo, lo], -1),
+        xp.concatenate([hi_clean, hi_before], -1),
+        xp.concatenate([none, lo_own + T], -1),
+        xp.concatenate([none - 1, hi_clean + T], -1)], -2)
+    cc = _reached(lo, hi_clean, T, xp)
+    nc = _reached(lo, hi_before, T, xp)
+    nn = _reached(lo_own, hi_clean, T, xp)
+    none = xp.zeros_like(cc[0])
+    bwd = xp.stack([
+        xp.concatenate([cc[0], nn[0] + nq], -1),
+        xp.concatenate([cc[1], nn[1] + nq], -1),
+        xp.concatenate([nc[0] + nq, none], -1),
+        xp.concatenate([nc[1] + nq, none - 1], -1)], -2)
+    return fwd.astype(xp.int32), bwd.astype(xp.int32)
+
+
+def block_tile_pairs(seg: np.ndarray, bq: int, bk: int, block: int) -> int:
+    """(query, key) pairs inside the tiles the forward pass visits
+    under the block rule for the sequences ``seg`` [N, S], both streams
+    — one head, counted on the host by the function that steers the
+    kernels."""
+    fwd, _ = block_intervals(*block_spans(seg, block, np), bq, bk, np)
+    runs = (np.maximum(fwd[..., 1, :] - fwd[..., 0, :] + 1, 0)
+            + np.maximum(fwd[..., 3, :] - fwd[..., 2, :] + 1, 0))
+    return int(runs.sum()) * bq * bk
+
+
+def block_pairs(sizes, block: int) -> int:
+    """(query, key) pairs the block rule leaves of segments of ``sizes``
+    rows, both streams: a row of block b (``s_b`` rows, ``e_b`` rows of
+    the segment up to its end) sees ``e_b`` keys as a clean row and
+    ``(e_b − s_b) + s_b`` as a noised one — 2 Σ_b s_b e_b a segment,
+    n(n + block) where ``block`` divides n."""
+    n = np.asarray(sizes, np.int64)
+    full, part = n // block, n % block
+    return int((block * block * full * (full + 1) + 2 * part * n).sum())
+
+
 # -- the kernels ---------------------------------------------------------------
 
 
-def _scores(q, k, first, rows, at, scale):
-    """One tile: float32 scores [bq, bk] and which of them are real —
-    key ``at + column`` lies in ``first … row`` of its query row."""
+def _scores(q, k, visible, at, scale):
+    """One tile: float32 scores [bq, bk] and which of them are real
+    (``visible`` of :func:`_visible`, on the tile's columns)."""
     s = jax.lax.dot_general(q, k, _NT,
                             preferred_element_type=jnp.float32) * scale
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return s, (col >= first - at) & (col <= rows - at)
+    return s, visible(col, at)
 
 
 def _rows_of(block, bq):
     return block * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
 
 
+def _visible(span_ref, i, bq, two):
+    """``visible(col, at)``: which keys ``at + col`` the block's query
+    rows see. One interval that ends at the row (``span_ref``: every
+    row's first key in all its lanes) — or, ``two``, the row's two
+    intervals (lanes 0 … 3: ``a0, a1, b0, b1``)."""
+    if two:
+        a0, a1, b0, b1 = (span_ref[:, n:n + 1] for n in range(4))
+        return lambda col, at: (((col >= a0 - at) & (col <= a1 - at))
+                                | ((col >= b0 - at) & (col <= b1 - at)))
+    first, rows = span_ref[:, :1], _rows_of(i, bq)
+    return lambda col, at: (col >= first - at) & (col <= rows - at)
+
+
+def _runs(bounds_ref, i, one, two):
+    """The runs of tiles (of query blocks, in dk/dv) that grid step
+    ``i`` walks, as ``fori_loop`` bounds: ``one()`` — the single run of
+    the causal rule, from its prefetched bound — or, ``two``, the two
+    runs of the block rule (``bounds_ref``: [4 · steps], a row of
+    :func:`block_intervals` after another)."""
+    if not two:
+        return (one(),)
+    n = bounds_ref.shape[0] // 4
+    return ((bounds_ref[i], bounds_ref[n + i] + 1),
+            (bounds_ref[2 * n + i], bounds_ref[3 * n + i] + 1))
+
+
 def _fwd_kernel(lo_ref, q_ref, k_ref, v_ref, first_ref, o_ref, lse_ref,
-                m_ref, l_ref, acc_ref, *, scale, bk):
+                m_ref, l_ref, acc_ref, *, scale, bk, two=False):
     i = pl.program_id(1)
     bq = q_ref.shape[0]
-    q, first, rows = q_ref[...], first_ref[:, :1], _rows_of(i, bq)
+    q, visible = q_ref[...], _visible(first_ref, i, bq, two)
     m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
     l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     def tile(j, _):
         at = pl.multiple_of(j * bk, bk)
-        s, real = _scores(q, k_ref[pl.ds(at, bk), :], first, rows, at, scale)
+        s, real = _scores(q, k_ref[pl.ds(at, bk), :], visible, at, scale)
         s = jnp.where(real, s, _MASKED)
         m = jnp.maximum(m_ref[...], s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_ref[...] - m)
@@ -173,27 +354,29 @@ def _fwd_kernel(lo_ref, q_ref, k_ref, v_ref, first_ref, o_ref, lse_ref,
         m_ref[...] = m
         return _
 
-    jax.lax.fori_loop(lo_ref[i], ((i + 1) * bq - 1) // bk + 1, tile, None)
+    for lo, hi in _runs(lo_ref, i, two=two, one=lambda: (
+            lo_ref[i], ((i + 1) * bq - 1) // bk + 1)):
+        jax.lax.fori_loop(lo, hi, tile, None)
     o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
     lse_ref[...] = jnp.broadcast_to(m_ref[...] + jnp.log(l_ref[...]),
                                     lse_ref.shape)
 
 
 def _dq_kernel(lo_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               first_ref, dq_ref, acc_ref, *, scale, bk):
+               first_ref, dq_ref, acc_ref, *, scale, bk, two=False):
     """A block of query rows against the key tiles it reaches: the
     probabilities recomputed from the rows' log-sum-exp, the scores'
     cotangent, its product with the keys."""
     i = pl.program_id(1)
     bq = q_ref.shape[0]
-    q, do, rows = q_ref[...], do_ref[...], _rows_of(i, bq)
-    lse, delta, first = lse_ref[:, :1], delta_ref[:, :1], first_ref[:, :1]
+    q, do, visible = q_ref[...], do_ref[...], _visible(first_ref, i, bq, two)
+    lse, delta = lse_ref[:, :1], delta_ref[:, :1]
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     def tile(j, _):
         at = pl.multiple_of(j * bk, bk)
         k = k_ref[pl.ds(at, bk), :]
-        s, real = _scores(q, k, first, rows, at, scale)
+        s, real = _scores(q, k, visible, at, scale)
         p = jnp.where(real, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v_ref[pl.ds(at, bk), :], _NT,
                                  preferred_element_type=jnp.float32)
@@ -202,37 +385,45 @@ def _dq_kernel(lo_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                 preferred_element_type=jnp.float32)
         return _
 
-    jax.lax.fori_loop(lo_ref[i], ((i + 1) * bq - 1) // bk + 1, tile, None)
+    for lo, hi in _runs(lo_ref, i, two=two, one=lambda: (
+            lo_ref[i], ((i + 1) * bq - 1) // bk + 1)):
+        jax.lax.fori_loop(lo, hi, tile, None)
     dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(hi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 first_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, bq,
-                group):
+                group, two=False):
     """A key tile against the query blocks that reach it, of each of
     the ``group`` query heads that read this key-value head (their
     rows lie one head after another: head g's block i is block
     ``g · blocks + i``), TRANSPOSED: scores [keys, rows], so that both
     products into dk and dv take their left operand as it lies and
     nothing is turned; the rows' numbers come as rows ([blocks, 1, bq],
-    a block by its index)."""
+    a block by its index — ``two``: [4, blocks, 1, bq], the rows' two
+    intervals)."""
     j = pl.program_id(1)
     bk = k_ref.shape[0]
-    blocks = first_ref.shape[0]
+    blocks = first_ref.shape[-3]
     k, v = k_ref[...], v_ref[...]
     keys = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
     dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
     dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
+    def visible(i):
+        if two:
+            return (((keys >= first_ref[0, i]) & (keys <= first_ref[1, i]))
+                    | ((keys >= first_ref[2, i]) & (keys <= first_ref[3, i])))
+        rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+        return (keys >= first_ref[i]) & (keys <= rows)
+
     for g in range(group):
         def block(i, _, at=g * blocks):
             rs = pl.ds(pl.multiple_of((at + i) * bq, bq), bq)
             q, do = q_ref[rs, :], do_ref[rs, :]
-            rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
             s = jax.lax.dot_general(
                 k, q, _NT, preferred_element_type=jnp.float32) * scale
-            p = jnp.where((keys >= first_ref[i]) & (keys <= rows),
-                          jnp.exp(s - lse_ref[at + i]), 0.0)
+            p = jnp.where(visible(i), jnp.exp(s - lse_ref[at + i]), 0.0)
             dp = jax.lax.dot_general(v, do, _NT,
                                      preferred_element_type=jnp.float32)
             ds = p * (dp - delta_ref[at + i]) * scale
@@ -242,7 +433,9 @@ def _dkv_kernel(hi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                    preferred_element_type=jnp.float32)
             return _
 
-        jax.lax.fori_loop(j * bk // bq, hi_ref[j] + 1, block, None)
+        for lo, hi in _runs(hi_ref, j, two=two, one=lambda: (
+                j * bk // bq, hi_ref[j] + 1)):
+            jax.lax.fori_loop(lo, hi, block, None)
     dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
     dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
@@ -286,12 +479,17 @@ def _by_platform(run, *args):
         default=functools.partial(run, interpret=True))
 
 
-def _forward(q, k, v, first, lo, bq, bk, scale, interpret):
+def _forward(q, k, v, first, lo, bq, bk, scale, interpret, two=False):
+    """``first`` [S] and ``lo``: every row's first key and the blocks'
+    first tiles — or (``two``) ``first`` [4, S] the rows' two intervals
+    and ``lo`` [4, S/bq] the blocks' two runs of tiles, S the rows of
+    both streams."""
     H, S, D = q.shape
     Dv, g = v.shape[-1], H // k.shape[0]
     return _call(
-        functools.partial(_fwd_kernel, scale=scale, bk=bk),
-        "seq_attention_fwd", S // bq, lo, (q, k, v, _lanes(first)),
+        functools.partial(_fwd_kernel, scale=scale, bk=bk, two=two),
+        "seq_attention_bd_fwd" if two else "seq_attention_fwd", S // bq,
+        lo.reshape(-1), (q, k, v, _columns(first)),
         [_head(bq, D), _head(S, D, True, g), _head(S, Dv, True, g),
          _first(bq)],
         [jax.ShapeDtypeStruct((H, S, Dv), v.dtype),
@@ -302,8 +500,9 @@ def _forward(q, k, v, first, lo, bq, bk, scale, interpret):
 
 
 def _backward(q, k, v, do, lse, delta, first, lo, hi, bq, bk, scale,
-              interpret):
-    """``lse``, ``delta`` [H, S] and ``first`` [S]: dq reads a row's
+              interpret, two=False):
+    """``lse``, ``delta`` [H, S] and ``first`` [S] (``two``: [4, S], as
+    in :func:`_forward`, and ``hi`` [4, S/bk]): dq reads a row's
     number as a column (its 128 lanes), dk/dv as part of a row. dk/dv's
     grid walks the KEY-VALUE heads: the ``g`` query heads of one are
     adjacent, so their rows are one head of ``g · S`` rows — unless
@@ -315,9 +514,10 @@ def _backward(q, k, v, do, lse, delta, first, lo, hi, bq, bk, scale,
     g = H // Hkv
     split = g > 1 and g * S * (D + Dv) * q.dtype.itemsize > _WHOLE_HEAD_BYTES
     dq, = _call(
-        functools.partial(_dq_kernel, scale=scale, bk=bk),
-        "seq_attention_dq", S // bq, lo,
-        (q, k, v, do, _lanes(lse), _lanes(delta), _lanes(first)),
+        functools.partial(_dq_kernel, scale=scale, bk=bk, two=two),
+        "seq_attention_bd_dq" if two else "seq_attention_dq", S // bq,
+        lo.reshape(-1),
+        (q, k, v, do, _lanes(lse), _lanes(delta), _columns(first)),
         [_head(bq, D), _head(S, D, True, g), _head(S, Dv, True, g),
          _head(bq, Dv), _head(bq, _LANES), _head(bq, _LANES), _first(bq)],
         [jax.ShapeDtypeStruct(q.shape, q.dtype)], [_head(bq, D)],
@@ -327,15 +527,18 @@ def _backward(q, k, v, do, lse, delta, first, lo, hi, bq, bk, scale,
     heads, rows, per = (H, 1, g) if split else (Hkv, g, 1)
     as_rows = pl.BlockSpec((None, rows * S // bq, 1, bq),
                            lambda h, j, _: (h, 0, 0, 0))
+    spans = first.shape[:-1] + (S // bq, 1, bq)
     dk, dv = _call(
-        functools.partial(_dkv_kernel, scale=scale, bq=bq, group=rows),
-        "seq_attention_dkv", S // bk, hi,
+        functools.partial(_dkv_kernel, scale=scale, bq=bq, group=rows,
+                          two=two),
+        "seq_attention_bd_dkv" if two else "seq_attention_dkv", S // bk,
+        hi.reshape(-1),
         (q.reshape(heads, rows * S, D), k, v,
          do.reshape(heads, rows * S, Dv), lse.reshape(heads, -1, 1, bq),
-         delta.reshape(heads, -1, 1, bq), first.reshape(-1, 1, bq)),
+         delta.reshape(heads, -1, 1, bq), first.reshape(spans)),
         [_head(rows * S, D, True), _head(bk, D, group=per),
          _head(bk, Dv, group=per), _head(rows * S, Dv, True), as_rows,
-         as_rows, pl.BlockSpec((S // bq, 1, bq), lambda h, j, _: (0, 0, 0))],
+         as_rows, pl.BlockSpec(spans, lambda h, j, _: (0,) * len(spans))],
         [jax.ShapeDtypeStruct((heads, S, D),
                               jnp.float32 if split else k.dtype),
          jax.ShapeDtypeStruct((heads, S, Dv),
@@ -347,6 +550,15 @@ def _backward(q, k, v, do, lse, delta, first, lo, hi, bq, bk, scale,
         dk, dv = (a.reshape(Hkv, g, S, -1).sum(1).astype(like.dtype)
                   for a, like in ((dk, k), (dv, v)))
     return dq, dk, dv
+
+
+def _columns(first):
+    """The rows' numbers where forward and dq read them as columns:
+    [S] → [S, 128], a row's first key in all its lanes; [4, S] →
+    [S, 128], a row's two intervals in lanes 0 … 3."""
+    if first.ndim == 1:
+        return _lanes(first)
+    return jnp.pad(first.T, ((0, 0), (0, _LANES - first.shape[0])))
 
 
 def _lanes(x):
@@ -406,3 +618,70 @@ def _attend_bwd(bq, bk, scale, window, res, do):
 
 
 segment_attention.defvjp(_attend, _attend_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def block_attention(q, k, v, seg, bq: int, bk: int, scale: float,
+                    block: int):
+    """softmax(scale · q kᵀ, the block rule) v for BOTH streams of one
+    packed sequence: q [2·S, H, D], k [2·S, Hkv, D], v [2·S, Hkv, Dv] —
+    rows ``0 … S − 1`` the clean stream, ``S … 2·S − 1`` the noised one
+    — and ``seg`` [S] int32 (0 = padding), the segments of either → [2·S,
+    H, Dv] in v's dtype. A clean row sees the clean keys of its segment
+    up to the end of its block of ``block`` rows, a noised row the clean
+    keys before its block and the noised keys of its block. ``bq``
+    query rows and ``bk`` keys a tile; both divide S. Operands of S
+    rows are ONE stream under the clean stream's rule (serving: the
+    rows to fill are MASK rows of the history itself)."""
+    return _attend_blocks(q, k, v, seg, bq, bk, scale, block)[0]
+
+
+def _steer_blocks(seg, bq, bk, block, both=True):
+    """(the rows' intervals, the query blocks' runs of tiles, the key
+    tiles' runs of query blocks) of both streams — or of the clean
+    stream alone, which nothing of the noised one reaches but its own
+    rows."""
+    S = seg.shape[-1]
+    spans = block_spans(seg, block)
+    rows, fwd, bwd = (stream_spans(*spans),
+                      *block_intervals(*spans, bq, bk))
+    if both:
+        return rows, fwd, bwd
+    bwd = bwd[:, :S // bk]
+    return (rows[:, :S], fwd[:, :S // bq],
+            bwd.at[2:].set(jnp.array([[0], [-1]], jnp.int32)))
+
+
+def _attend_blocks(q, k, v, seg, bq, bk, scale, block):
+    S = seg.shape[0]
+    if S % bq or S % bk:
+        raise ValueError(f"tiles of {bq} rows × {bk} keys do not divide "
+                         f"a sequence of {S}")
+    if q.shape[0] not in (S, 2 * S) or {k.shape[0], v.shape[0]} != {
+            q.shape[0]}:
+        raise ValueError(f"one or two streams of {S} slots are {S} or "
+                         f"{2 * S} rows, not {q.shape[0]}, {k.shape[0]} "
+                         f"and {v.shape[0]}")
+    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
+        raise ValueError(f"{k.shape[1]} key and {v.shape[1]} value heads "
+                         f"do not group {q.shape[1]} query heads")
+    spans, fwd, _ = _steer_blocks(seg, bq, bk, block, q.shape[0] > S)
+    out, lse = _by_platform(
+        functools.partial(_forward, bq=bq, bk=bk, scale=scale, two=True),
+        *map(_heads_first, (q, k, v)), spans, fwd)
+    out = _heads_first(out)
+    return out, (q, k, v, out, lse[..., 0], seg)
+
+
+def _attend_blocks_bwd(bq, bk, scale, block, res, do):
+    q, k, v, out, lse, seg = res
+    spans, fwd, bwd = _steer_blocks(seg, bq, bk, block,
+                                    q.shape[0] > seg.shape[0])
+    delta = (out.astype(jnp.float32) * do.astype(jnp.float32)).sum(-1).T
+    grads = _by_platform(
+        functools.partial(_backward, bq=bq, bk=bk, scale=scale, two=True),
+        *map(_heads_first, (q, k, v, do)), lse, delta, spans, fwd, bwd)
+    return (*map(_heads_first, grads), None)
+
+
+block_attention.defvjp(_attend_blocks, _attend_blocks_bwd)

@@ -22,7 +22,10 @@ naming the block stack that trains on packed histories
 (:func:`predictionio_tpu.models.seq_backbone.backbone`:
 ``glm4_moe_lite`` — latent attention, sparse experts, an MTP module;
 ``lfm2_moe`` — gated short convolutions, grouped-query attention,
-sparse experts, a tied head) — or, absent, the SASRec stack.
+sparse experts, a tied head; ``smallthinker`` — window and full
+attention, a router before attention; ``sdar_moe`` — trained by block
+diffusion on a clean and a noised copy of every history) — or, absent,
+the SASRec stack.
 
 Optional query keys: ``history`` (explicit item list overriding the
 live lookup — supports anonymous sessions), ``blackList``.
@@ -153,7 +156,7 @@ class SeqRecAlgorithmParams:
     # published config's keys (and the training job's: seq_len,
     # seqs_per_step, ep_size, …) = the block stack its ``model_type``
     # names (models/seq_backbone: glm4_moe_lite — the default —,
-    # lfm2_moe) on packed histories. ``hidden`` … ``batch_size`` above
+    # lfm2_moe, smallthinker, sdar_moe) on packed histories. ``hidden`` … ``batch_size`` above
     # are then unused; ``epochs``, ``lr`` and ``seed`` apply.
     architecture: Optional[Dict[str, Any]] = None
 

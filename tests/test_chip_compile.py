@@ -437,3 +437,84 @@ def test_smallthinker_train_program_at_published_widths(one_chip):
     # the temporaries: what the program holds at once is the latter
     assert mem.argument_size_in_bytes < 4.6e9
     assert mem.temp_size_in_bytes < 15.3e9
+
+
+def _sdar_cell_config():
+    """The benchmark's ``seqrec-sdar-30b-a3b-ep8`` as the template
+    builds it: the configuration's published keys and its job."""
+    import json
+    import os
+
+    from predictionio_tpu.models import sdar_moe as sd
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "seqrec-sdar-30b-a3b-ep8.json")
+    with open(path) as f:
+        conf = json.load(f)
+    arch = {k: v for k, v in conf.items() if k in sd.SdarConfig.known_keys()}
+    return sd.SdarConfig.from_architecture(dict(arch, **conf["job"]))
+
+
+def test_block_rule_attention_at_published_widths(one_chip):
+    """Both streams of an 8,192-slot sequence — operands of 16,384 rows,
+    32 query heads over 4 key-value heads of 128 — under the block
+    rule, forward and all three gradients, for the described chip: the
+    kernels with two intervals a row and two runs of tiles a block."""
+    from predictionio_tpu.models import seq_backbone
+
+    c = _sdar_cell_config()
+    S, H, Hkv, D = (c.seq_len, c.num_attention_heads,
+                    c.num_key_value_heads, c.head_dim)
+    assert (S, H, Hkv, D, c.block_length) == (8192, 32, 4, 128, 4)
+    q = _sds((2 * S, H, D), jnp.bfloat16, one_chip)
+    kv = _sds((2 * S, Hkv, D), jnp.bfloat16, one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v, seg: seq_backbone.block_attention(
+            q, k, v, seg, c, D ** -0.5,
+            c.block_length).astype(jnp.float32).sum(),
+        (0, 1, 2))).lower(q, kv, kv, _sds((S,), jnp.int32,
+                                         one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "seq_attention_bd_fwd" in text and "seq_attention_bd_dkv" in text
+    # the rows' statistics in their 128 lanes (32 × 16,384 × 128 float32
+    # = 268 MB, twice), the query heads' float32 dk, dv (2 × 268 MB)
+    # and the rows' intervals: 954 MB; one head's scores alone would be
+    # 1.07 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_sdar_train_program_at_published_widths(one_chip):
+    """The cell's whole train program — 456.3 M parameters with Adam's
+    state, 32 steps of one 8,192-slot sequence as two streams, ONE
+    scanned body of four layers, the noise drawn in the step, the
+    untied head on the noised rows — for the described chip, and it
+    fits the chip's 16 GB."""
+    from predictionio_tpu.models import sdar_moe as sd
+    from predictionio_tpu.models import seq_backbone
+    from predictionio_tpu.models.seq_rec import _make_tx
+
+    c = _sdar_cell_config()
+    assert sd.n_params(c) == 456_346_624
+    params = jax.tree.map(lambda s: _sds(s, jnp.float32, one_chip),
+                          sd.param_shapes(c),
+                          is_leaf=seq_backbone._is_shape)
+    opt = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                       jax.eval_shape(_make_tx().init, params))
+    bias = _sds((c.num_hidden_layers, c.router_experts), jnp.float32,
+                one_chip)
+    data = {k: _sds((32, c.seqs_per_step, c.seq_len), jnp.int32, one_chip)
+            for k in sd.TRAIN_KEYS}
+    data["draw"] = _sds((32, c.seqs_per_step, 2), jnp.uint32, one_chip)
+    compiled = sd.train_program(c, 1).lower((params, opt, bias),
+                                            data).compile()
+    # forward, recomputation and backward of one scanned body's three
+    # attention kernels and three grouped products
+    assert _ragged_calls(compiled) >= 15
+    mem = compiled.memory_analysis()
+    # the donated state is counted in the arguments AND (updated) in
+    # the temporaries: what the program holds at once is the latter
+    assert mem.argument_size_in_bytes < 5.6e9
+    assert mem.temp_size_in_bytes < 9.6e9
+

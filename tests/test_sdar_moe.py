@@ -1,0 +1,700 @@
+"""The ``sdar_moe`` backbone of the ``sequentialrec`` template — trained by
+block diffusion — against its plain reference
+(``benchmark/reference/sdar_moe_jnp.py``), on seeded random weights and
+the step's OWN masks at a preset of hidden 64, 4 query heads over 2
+key-value heads of 16, 8 SwiGLU experts top-3, 64-slot sequences, blocks
+of 4 and two layers."""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from reference import sdar_moe_jnp as ref  # noqa: E402
+
+from predictionio_tpu.models import sdar_moe as sd  # noqa: E402
+from predictionio_tpu.models import seq_backbone  # noqa: E402
+from predictionio_tpu.ops import moe_dispatch  # noqa: E402
+
+ARCH = dict(
+    model_type="sdar_moe", hidden_size=64, head_dim=16,
+    num_attention_heads=4, num_key_value_heads=2, moe_intermediate_size=32,
+    num_experts=8, ep_size=1, num_experts_per_tok=3, norm_topk_prob=True,
+    num_hidden_layers=2, rope_theta=1e6, tie_word_embeddings=False,
+    vocab_size=50, block_length=4, noise_eps=1e-3, seq_len=64,
+    seqs_per_step=2, attn_block=32, token_chunk=64, init_std=0.2)
+SEED = 5
+
+#: the comparison's limits with bfloat16 operands (what the cell's
+#: configuration states), at the configuration's init_std of 0.02: the
+#: stated precision reads 0.0052 (the noised stream's logits,
+#: rms(diff)/rms; 0.0041 … 0.0054 over four seeds), bfloat16 EVERYWHERE
+#: 0.0096 (0.0083 … 0.0098; always 1.5 … 2.3 times the stated one's on
+#: the same seed) — the limit between the two. The loss hardly moves
+#: with the precision at this size (2e-6 … 1.1e-4): its limit only
+#: catches a wrong loss
+BF16_LOGITS_REL_RMS = 0.007
+BF16_LOSS_ABS = 2e-3
+
+
+def _config(**over):
+    return sd.SdarConfig.from_architecture(dict(ARCH, **over))
+
+
+def _histories(seed=0, n=12, top=49):
+    """Short histories — some shorter than a block, most no multiple of
+    4 — and one of 100 events: longer than a sequence, so cut by the
+    packing."""
+    rng = np.random.default_rng(seed)
+    return ([rng.integers(1, top, rng.integers(2, 40)) for _ in range(n)]
+            + [rng.integers(1, top, 3), rng.integers(1, top, 2),
+               rng.integers(1, top, 100)])
+
+
+def _setup(c, seed=3, step=0):
+    """Packed histories, seeded weights and the first batch with the
+    noise the program draws for it at ``step`` (the reference's
+    input)."""
+    packed = seq_backbone.pack_histories(_histories(), c.seq_len,
+                                         c.seqs_per_step, seed=4,
+                                         block=c.block_length)
+    params, bias = sd.init_state(c, seed)
+    B = c.seqs_per_step
+    noised, weight = sd.first_noise(packed, c, SEED, step)
+    train = {k: jnp.asarray(getattr(packed, k)[:B]) for k in sd.TRAIN_KEYS}
+    train.update(draw=jnp.asarray(sd.draws(len(packed.tokens), SEED)[:B]),
+                 step=jnp.int32(step))
+    batch = dict({k: train[k] for k in sd.TRAIN_KEYS},
+                 noised=jnp.asarray(noised[:B]),
+                 weight=jnp.asarray(weight[:B]))
+    return packed, params, bias, train, batch
+
+
+def _rel(a, b):
+    return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+
+
+def _logits(params, bias, batch, c):
+    """The program's head on the noised stream, through its own jitted
+    entry point."""
+    return sd.sequence_logits({"params": params, "bias": bias}, batch, c)[0]
+
+
+def _ref_logits(params, bias, batch, c, **kw):
+    @jax.jit
+    def run(params, bias, batch):
+        with jax.default_matmul_precision("highest"):
+            return jax.vmap(lambda s: ref.forward(
+                params, bias, s, dict(c.__dict__), c.held, **kw)[0])(batch)
+
+    return run(params, bias, batch)
+
+
+def _named(tree):
+    return dict((seq_backbone._path_name(p), g) for p, g in
+                jax.tree_util.tree_flatten_with_path(tree)[0])
+
+
+# -- 1. the system against the reference -------------------------------------
+
+
+@pytest.fixture(scope="module")
+def exact():
+    """The system with float32 operands and the reference, once, on the
+    step's own masks."""
+    c = _config(matmul_dtype="float32")
+    packed, params, bias, train, batch = _setup(c)
+    seg = np.asarray(batch["seg"])
+    sizes = [list(np.bincount(row)[1:]) for row in seg]
+    # the batch holds what the rule is for: partial last blocks,
+    # segments shorter than a block, a piece the packing cut off
+    assert sizes == [[38, 23, 3], [38, 22, 2, 2]]
+    (loss, rec), grads = jax.jit(lambda p, b, bt: jax.value_and_grad(
+        sd.loss_fn, has_aux=True)(p, b, bt, c))(params, bias, train)
+    (rloss, loads), rgrads = jax.jit(
+        lambda w, b, bt: ref.loss_and_grads(w, b, bt, dict(c.__dict__)))(
+            params, bias, batch)
+    return dict(c=c, params=params, bias=bias, train=train, batch=batch,
+                loss=loss, rec=rec, grads=grads, rloss=rloss, loads=loads,
+                rgrads=rgrads)
+
+
+def test_the_four_layers_are_one_scanned_body():
+    c = _config(num_hidden_layers=4)
+    shapes = sd.param_shapes(c)
+    assert set(shapes) == {"embed", "layers", "final_norm", "head"}
+    assert shapes["layers"]["attn"]["wq"] == (4, 64, 64)
+    assert shapes["layers"]["attn"]["q_norm"] == (4, 16)
+    assert shapes["layers"]["experts"]["wd"] == (4, 8, 32, 64)
+    assert c.mask_id == 49
+
+
+def test_parameter_count_of_the_benchmarks_share():
+    """ISSUE 40's arithmetic: 16 of 128 experts, 4 layers, an eighth of
+    the vocabulary."""
+    c = sd.SdarConfig(num_experts=16, ep_size=8, num_hidden_layers=4,
+                      vocab_size=18992)
+    d, f = 2048, 768
+    layer = (2 * d * 4096 + 2 * d * 512) + (2 * d + 2 * 128) + d * 128 \
+        + 16 * 3 * d * f
+    assert layer == 94_638_336
+    assert sd.n_params(c) == 4 * layer + 2 * 18992 * d + d == 456_346_624
+    assert (c.router_experts, c.held, c.mask_id) == (
+        128, tuple(range(16)), 18991)
+
+
+def test_noised_stream_logits_match_reference(exact):
+    got = _logits(exact["params"], exact["bias"], exact["batch"], exact["c"])
+    want = _ref_logits(exact["params"], exact["bias"], exact["batch"],
+                       exact["c"])
+    assert got.shape == (2, 64, 50)
+    assert _rel(got, want) < 2e-5
+
+
+def test_loss_matches_reference_on_the_steps_own_masks(exact):
+    assert abs(float(exact["loss"]) - float(exact["rloss"])) < 2e-5
+    np.testing.assert_array_equal(
+        np.asarray(exact["rec"]["moe"]["load"]).sum(0),
+        np.asarray(exact["loads"]).sum(0))
+    # the record: masked rows and real events of the step
+    assert int(exact["rec"]["bd_masked"]) == int(
+        (np.asarray(exact["batch"]["weight"]) > 0).sum())
+    assert int(exact["rec"]["bd_real"]) == 128
+
+
+def test_group_gradient_norms_match_reference(exact):
+    got = jax.jit(sd.group_squares)(exact["grads"])
+    want = jax.jit(sd.group_squares)(exact["rgrads"])
+    assert set(got) == set(sd.grad_groups(exact["c"]))
+    for g in got:
+        assert abs(float(got[g]) ** 0.5 / float(want[g]) ** 0.5 - 1) < 2e-5, g
+
+
+_LEAVES = [seq_backbone._path_name(p) for p, _ in
+           jax.tree_util.tree_flatten_with_path(
+               sd.param_shapes(_config()),
+               is_leaf=seq_backbone._is_shape)[0]]
+
+
+@pytest.mark.parametrize("leaf", _LEAVES)
+def test_every_gradient_leaf_matches_reference(exact, leaf):
+    got, want = _named(exact["grads"])[leaf], _named(exact["rgrads"])[leaf]
+    assert float(jnp.abs(want).max()) > 0
+    assert _rel(got, want) < 5e-5
+
+
+def test_every_leaf_has_a_group_and_the_groups_are_the_parts(exact):
+    groups = {sd.group_of(n) for n in _LEAVES}
+    assert groups == {"attn", "embed", "experts", "head", "norms", "router"}
+    assert sd.grad_groups(exact["c"]) == tuple(sorted(groups))
+
+
+def test_the_loss_is_the_weighted_ce_of_masked_rows_at_their_own_items(
+        exact):
+    """No shift, weight 1/p on masked rows and 0 elsewhere, divided by
+    the step's real events — by hand, from the program's logits."""
+    b = exact["batch"]
+    logits = np.asarray(_logits(exact["params"], exact["bias"], b,
+                                exact["c"]), np.float64)
+    lse = np.log(np.exp(logits - logits.max(-1, keepdims=True)).sum(-1)) \
+        + logits.max(-1)
+    hit = np.take_along_axis(logits, np.asarray(b["tokens"])[..., None],
+                             -1)[..., 0]
+    masked = np.asarray(b["noised"]) == exact["c"].mask_id
+    assert masked.any() and (np.asarray(b["weight"])[~masked] == 0).all()
+    want = (np.asarray(b["weight"]) * (lse - hit))[masked].sum() / 128
+    assert abs(float(exact["loss"]) - want) < 1e-5
+
+
+@pytest.fixture(scope="module")
+def stated():
+    """The configuration's own init_std and operand dtype, with the
+    reference's float32 logits and loss."""
+    c = _config(init_std=0.02)
+    _, params, bias, train, batch = _setup(c)
+    return dict(c=c, params=params, bias=bias, train=train, batch=batch,
+                want=_ref_logits(params, bias, batch, c))
+
+
+def test_stated_precision_within_its_limits(stated):
+    """bfloat16 operands, float32 accumulation, float32 router, softmax,
+    norms and RoPE: inside the limits that the lower precision below
+    breaks."""
+    c = stated["c"]
+    got = _logits(stated["params"], stated["bias"], stated["batch"], c)
+    assert _rel(got, stated["want"]) < BF16_LOGITS_REL_RMS
+    loss, _ = jax.jit(lambda p, b, bt: sd.loss_fn(p, b, bt, c))(
+        stated["params"], stated["bias"], stated["train"])
+    with jax.default_matmul_precision("highest"):
+        rloss, _ = jax.jit(lambda w, b, bt: ref.loss(
+            w, b, bt, dict(c.__dict__)))(stated["params"], stated["bias"],
+                                         stated["batch"])
+    assert abs(float(loss) - float(rloss)) < BF16_LOSS_ABS
+
+
+def test_lower_precision_fails(stated):
+    """The reference computed in bfloat16 THROUGHOUT (router logits,
+    softmax, norms, RoPE, accumulation — the nearest precision below
+    the stated one) breaks the limit the stated precision keeps."""
+    low = _ref_logits(stated["params"], stated["bias"], stated["batch"],
+                      stated["c"], dtype=jnp.bfloat16)
+    assert _rel(low, stated["want"]) > BF16_LOGITS_REL_RMS
+
+
+# -- 2. the noise ------------------------------------------------------------
+
+
+def _noise(step=0, sequence=0, seed=SEED, seg=None, eps=1e-3):
+    seg = jnp.ones(4096, jnp.int32) if seg is None else seg
+    return jax.jit(lambda st, sq: seq_backbone.block_noise(
+        jnp.uint32(seed), st, sq, seg, 4, eps))(jnp.int32(step),
+                                                jnp.uint32(sequence))
+
+
+def test_the_noise_is_a_pure_function_of_seed_step_sequence_and_slot():
+    a, b = _noise(3, 7), _noise(3, 7)
+    np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]))
+    np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b[1]))
+    for other in (_noise(4, 7), _noise(3, 8), _noise(3, 7, seed=SEED + 1)):
+        assert (np.asarray(other[0]) != np.asarray(a[0])).mean() > 0.2
+
+
+def test_the_same_bits_come_out_on_the_host():
+    """Drawn on the default device inside a program, and eagerly on the
+    host's CPU device: the same masks and weights."""
+    cpu = jax.devices("cpu")[0]
+    with jax.default_device(cpu):
+        masked, weight = seq_backbone.block_noise(
+            np.uint32(SEED), np.int32(3), np.uint32(7),
+            jnp.ones(4096, jnp.int32), 4, 1e-3)
+    a = _noise(3, 7)
+    np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(masked))
+    np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(weight))
+
+
+def test_the_next_epoch_draws_fresh_noise():
+    """The same sequence one epoch later is ``steps`` steps later."""
+    c = _config()
+    packed = seq_backbone.pack_histories(_histories(), c.seq_len,
+                                         c.seqs_per_step, seed=1)
+    steps = len(packed.tokens) // c.seqs_per_step
+    first, _ = sd.first_noise(packed, c, SEED, 0)
+    again, _ = sd.first_noise(packed, c, SEED, 0)
+    later, _ = sd.first_noise(packed, c, SEED, steps)
+    np.testing.assert_array_equal(first, again)
+    assert (first != later).mean() > 0.2
+
+
+def test_half_the_events_are_masked_and_the_weights_average_to_one():
+    """p ~ U(ε, 1) per block: the masked share is (1 + ε)/2 and
+    E[mask/p] = 1 — within four standard errors over 64 × 4,096 slots
+    (sd of a block's mean mask ≈ 0.38, of its mean weight·mask ≈ 1.2
+    with ε = 1e-3: 1/p is heavy-tailed, so the bound is loose)."""
+    draws = [_noise(0, i) for i in range(64)]
+    masked = np.stack([np.asarray(m) for m, _ in draws])
+    weight = np.stack([np.asarray(w) for _, w in draws])
+    blocks = masked.size / 4
+    assert abs(masked.mean() - 0.5005) < 4 * 0.38 / np.sqrt(blocks)
+    assert abs(weight.mean() - 1.0) < 4 * 1.2 / np.sqrt(blocks)
+    # weight 1/p on masked rows, 0 elsewhere; one p a block
+    assert (weight[~masked] == 0).all() and (weight[masked] >= 1.0).all()
+    w4, m4 = weight.reshape(-1, 4), masked.reshape(-1, 4)
+    top = w4.max(1, keepdims=True)
+    assert (w4[m4] == np.broadcast_to(top, w4.shape)[m4]).all()
+    assert weight.max() <= 1 / 1e-3
+
+
+def test_a_padding_row_is_never_masked_and_blocks_follow_the_segments():
+    seg = np.zeros(64, np.int32)
+    seg[:7], seg[7:9], seg[9:40] = 1, 2, 3
+    for i in range(8):
+        masked, weight = _noise(i, 0, seg=jnp.asarray(seg), eps=0.5)
+        masked, weight = np.asarray(masked), np.asarray(weight)
+        assert not masked[seg == 0].any() and not weight[seg == 0].any()
+        # a block restarts with its segment: rows 4 … 6, 7 … 8, 9 … 12
+        for rows in (slice(4, 7), slice(7, 9), slice(9, 13)):
+            w = weight[rows][masked[rows]]
+            assert (w == w[:1]).all()
+
+
+# -- 3. the mask, through the whole stack -------------------------------------
+
+
+def _streams(c, params, bias, batch):
+    return jax.jit(lambda p, b, bt: sd._stack(p, b, bt, c)[0])(
+        params, bias, batch)
+
+
+def test_a_masked_item_cannot_be_copied_from_the_clean_stream(exact):
+    """The leak: could a noised row see its OWN block's clean keys, a
+    MASK row would read its answer there (the loss would fall to ≈ 0 on
+    any data). A masked item replaced in the CLEAN stream only: the
+    noised rows of its block and of every earlier block read as before,
+    to the bit; rows of later blocks do not."""
+    c, b = exact["c"], exact["batch"]
+    seg, pos = np.asarray(b["seg"])[0], np.asarray(b["pos"])[0]
+    masked = np.asarray(b["noised"])[0] == c.mask_id
+    at = int(np.flatnonzero(masked & (pos >= 4) & (pos < 20))[0])
+    other = dict(b, tokens=b["tokens"].at[0, at].set(
+        (int(b["tokens"][0, at]) % 48) + 1))
+    was = np.asarray(_streams(c, exact["params"], exact["bias"], b))[0]
+    now = np.asarray(_streams(c, exact["params"], exact["bias"], other))[0]
+    S = seg.size
+    same_seg = seg == seg[at]
+    upto = same_seg & (pos // 4 <= pos[at] // 4)
+    after = same_seg & (pos // 4 > pos[at] // 4)
+    assert after.any()
+    np.testing.assert_array_equal(was[S:][upto], now[S:][upto])
+    assert np.abs(was[S:][after] - now[S:][after]).max() > 1e-4
+    # the clean stream itself sees the item, from its block on
+    assert np.abs(was[:S][at] - now[:S][at]).max() > 1e-4
+    # and no other segment sees anything
+    np.testing.assert_array_equal(was[S:][~same_seg], now[S:][~same_seg])
+
+
+def test_the_clean_stream_never_reads_the_noised_one(exact):
+    c, b = exact["c"], exact["batch"]
+    other = dict(b, noised=jnp.where(b["noised"] == c.mask_id, 7,
+                                     c.mask_id).astype(jnp.int32))
+    was = np.asarray(_streams(c, exact["params"], exact["bias"], b))
+    now = np.asarray(_streams(c, exact["params"], exact["bias"], other))
+    S = b["tokens"].shape[1]
+    np.testing.assert_array_equal(was[:, :S], now[:, :S])
+    assert np.abs(was[:, S:] - now[:, S:]).max() > 1e-3
+
+
+# -- 4. the router and the shares ---------------------------------------------
+
+
+def test_gates_are_the_softmax_over_all_renormalised_over_the_top_8():
+    """``norm_topk_prob`` true: softmax(logits)[ids] / Σ_ids — which
+    ``route_softmax`` computes as the softmax over the selected
+    logits."""
+    logits = jnp.asarray(np.random.default_rng(0).normal(size=(40, 128))
+                         * 3, jnp.float32)
+    ids, gates = moe_dispatch.route_softmax(logits, 8)
+    prob = np.asarray(jax.nn.softmax(logits, -1), np.float64)
+    top = np.argsort(-prob, -1)[:, :8]
+    np.testing.assert_array_equal(np.sort(np.asarray(ids), -1),
+                                  np.sort(top, -1))
+    picked = np.take_along_axis(prob, np.asarray(ids), -1)
+    np.testing.assert_allclose(np.asarray(gates),
+                               picked / picked.sum(-1, keepdims=True),
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(gates).sum(-1), 1.0, rtol=1e-6)
+
+
+def _one_layer(c, seed=5):
+    params, _ = sd.init_state(c, seed)
+    w = jax.tree.map(lambda a: a[0], params["layers"])
+    packed = seq_backbone.pack_histories(_histories(), c.seq_len, 1, seed=1)
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(1, 2 * c.seq_len, c.hidden_size)),
+                    jnp.float32)
+    return w, x, jnp.asarray(packed.seg[:1]), jnp.asarray(packed.pos[:1])
+
+
+def test_eight_shares_of_sixteen_experts_add_up_to_the_whole_layer():
+    """The layer run 8 times on both streams, each told it holds a
+    different eighth of 128 experts (``ep_rank`` 0 … 7): the sum of the
+    experts' parts, with what every chip computes alike (the input,
+    attention and its residual) counted once, is the uncut reference's
+    layer."""
+    whole = _config(matmul_dtype="float32", num_experts=128,
+                    num_experts_per_tok=8)
+    w, x, seg, pos = _one_layer(whole)
+    cfg = dict(whole.__dict__)
+    with jax.default_matmul_precision("highest"):
+        want, load = jax.jit(lambda w, x: ref.layer(
+            w, x, seg[0], pos[0], None, cfg))(w, x[0])
+        attended = x[0] + ref.attend(
+            w["attn"], ref.rms_norm(x[0], w["attn_norm"], 1e-6), seg[0],
+            pos[0], cfg)
+    total, pairs = attended, 0
+    for rank in range(8):
+        share = _config(matmul_dtype="float32", num_experts=16, ep_size=8,
+                        ep_rank=rank, num_experts_per_tok=8)
+        assert share.router_experts == 128
+        assert share.held == tuple(range(16 * rank, 16 * rank + 16))
+        mine = dict(w, experts=jax.tree.map(
+            lambda a: a[16 * rank:16 * rank + 16], w["experts"]))
+        out, stats = jax.jit(lambda w, x, share=share: sd._layer(
+            w, x, seg, pos, share))(mine, x)
+        assert int(stats["dropped"]) == 0
+        np.testing.assert_array_equal(np.asarray(stats["load"]),
+                                      np.asarray(load))
+        pairs += int(stats["pairs_here"])
+        total = total + (out[0] - attended)
+    real = np.tile(np.asarray(seg[0]) > 0, 2)
+    assert pairs == int(real.sum()) * 8     # both streams' rows, top-8
+    assert _rel(total[real], want[real]) < 1e-5
+
+
+def test_no_pair_dropped_under_a_skewed_router():
+    """A router column so large that every row of both streams picks
+    expert 0: the layer keeps every pair and reports the skew."""
+    c = _config(matmul_dtype="float32")
+    w, x, seg, _ = _one_layer(c)
+    rows = jnp.abs(x[0]) + 0.1
+    valid = jnp.tile(seg[0] > 0, 2)
+    router = w["router"].at[:, 0].set(10.0)
+    _, plan, stats = jax.jit(lambda r, m: seq_backbone._route(
+        r, m, valid, None, c, softmax=True))(router, rows)
+    n = int(valid.sum())
+    assert int(stats["dropped"]) == 0
+    assert int(stats["pairs_here"]) == int(stats["pairs"]) == n * 3
+    assert int(stats["load"][0]) == n
+    assert float(stats["load_max_over_mean"]) == pytest.approx(n / (3 * n / 8))
+
+
+def test_the_step_has_no_router_bias_to_move(exact):
+    c = exact["c"]
+    assert c.bias_update_rate == 0.0
+    assert "bias" not in _named(exact["grads"])
+    moved = dict(exact["train"])
+    loss, _ = jax.jit(lambda p, b, bt: sd.loss_fn(p, b, bt, c))(
+        exact["params"], exact["bias"] + 3.0, moved)
+    assert float(loss) == float(exact["loss"])
+
+
+# -- 5. packing, positions, config --------------------------------------------
+
+
+def test_pack_counts_blocks_and_the_pairs_the_rule_leaves():
+    hist = [np.arange(1, 9), np.arange(1, 4), np.arange(1, 11),
+            np.arange(1, 70)]
+    packed = seq_backbone.pack_histories(hist, 64, block=4)
+    n = packed.counters
+    sizes = np.asarray([s for i, row in enumerate(packed.seg)
+                        for s in np.bincount(row)[1:]])
+    assert n["bd_block"] == 4
+    assert n["bd_blocks"] == int(np.ceil(sizes / 4).sum())
+    assert n["bd_partial_blocks"] == int((sizes % 4 > 0).sum())
+    assert n["stream_rows"] == 2 * n["slots"]
+    # brute force over every pair of slots of a sequence
+    pairs = 0
+    for seg, pos in zip(packed.seg, packed.pos):
+        same = (seg[:, None] == seg[None, :]) & (seg[:, None] > 0)
+        bq, bk = (pos // 4)[:, None], (pos // 4)[None, :]
+        pairs += int((same & (bk <= bq)).sum() + (same & (bk < bq)).sum()
+                     + (same & (bk == bq)).sum())
+    assert n["attn_pairs_bd"] == pairs
+    assert "attn_pairs_bd" not in seq_backbone.pack_histories(
+        hist, 64).counters
+
+
+def test_a_history_reads_the_same_packed_or_alone():
+    """A segment's rows see nothing of their neighbours in either
+    stream: the noised stream's logits of a history packed between
+    others equal those of the history alone in a sequence."""
+    c = _config(matmul_dtype="float32", seqs_per_step=1)
+    params, bias = sd.init_state(c, 3)
+    rng = np.random.default_rng(2)
+    hist = [rng.integers(1, 49, n) for n in (9, 22, 14)]
+    packed = seq_backbone.pack_histories(hist, c.seq_len, seed=0)
+    noised, _ = sd.first_noise(packed, c, SEED)
+    batch = {k: jnp.asarray(getattr(packed, k)) for k in sd.TRAIN_KEYS}
+    got = np.asarray(_logits(params, bias, dict(batch, noised=noised), c))[0]
+    seg = packed.seg[0]
+    for j in (1, 2, 3):
+        rows = np.flatnonzero(seg == j)
+        n = rows.size
+        alone = {k: jnp.zeros((1, 64), jnp.int32) for k in sd.TRAIN_KEYS}
+        alone["tokens"] = alone["tokens"].at[0, :n].set(
+            packed.tokens[0, rows])
+        alone["seg"] = alone["seg"].at[0, :n].set(1)
+        alone["pos"] = alone["pos"].at[0, :n].set(np.arange(n))
+        alone["noised"] = jnp.zeros((1, 64), jnp.int32).at[0, :n].set(
+            noised[0, rows])
+        want = np.asarray(_logits(params, bias, alone, c))[0, :n]
+        np.testing.assert_allclose(got[rows], want, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("over, match", [
+    ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+    ({"mlp_only_layers": [0]}, "mlp_only_layers"),
+    ({"decoder_sparse_step": 2}, "decoder_sparse_step"),
+    ({"norm_topk_prob": False}, "norm_topk_prob"),
+    ({"hidden_act": "gelu"}, "hidden_act"),
+    ({"use_sliding_window": True}, "use_sliding_window"),
+    ({"n_shared_experts": 1}, "unknown architecture keys"),
+    ({"num_key_value_heads": 3}, "query heads over"),
+    ({"num_experts_per_tok": 9}, "top-9 of a router of 8"),
+    ({"block_length": 3}, "do not divide"),
+    ({"noise_eps": 0.0}, "noise_eps"),
+])
+def test_what_the_block_cannot_honour_is_refused(over, match):
+    with pytest.raises(ValueError, match=match):
+        _config(**over)
+
+
+def test_the_published_keys_that_size_nothing_are_taken():
+    c = _config(intermediate_size=6144, max_position_embeddings=32768,
+                max_window_layers=48, sliding_window=None,
+                attention_bias=False, mlp_only_layers=[],
+                decoder_sparse_step=1, hidden_act="silu",
+                use_sliding_window=False, rope_scaling=None)
+    assert c == _config()
+
+
+def test_the_table_names_the_backbone():
+    b = seq_backbone.backbone("sdar_moe")
+    assert b is sd.BACKBONE and b.config is sd.SdarConfig
+    assert b.heads == ("loss",)
+    assert {"noised", "weight"} <= set(b.batch_keys)
+    assert "tgt1" not in b.batch_keys        # no shifted target
+    with pytest.raises(ValueError, match="sdar_moe"):
+        seq_backbone.backbone("mamba2")
+
+
+def test_an_item_on_the_mask_row_is_refused():
+    c = _config()
+    with pytest.raises(ValueError, match="MASK row 49"):
+        sd.sdar_train([[1, 2, 49, 3]], c, 1, 1e-3, 0)
+
+
+# -- 6. through the template -------------------------------------------------
+
+FACTORY = "predictionio_tpu.templates.sequentialrec.engine:engine_factory"
+
+
+def _variant(epochs):
+    return {"id": "default", "engineFactory": FACTORY,
+            "datasource": {"params": {"appName": "BdApp"}},
+            "algorithms": [{"name": "seqrec", "params": {
+                "epochs": epochs, "lr": 0.003, "seed": 5,
+                "architecture": dict(ARCH, vocab_size=16, init_std=0.02,
+                                     seq_len=28, attn_block=14,
+                                     token_chunk=28,
+                                     matmul_dtype="float32")}}]}
+
+
+@pytest.fixture()
+def bd_app(storage):
+    import datetime as dt
+
+    from predictionio_tpu.data.event import Event
+
+    app = storage.meta.create_app("BdApp", "")
+    storage.events.init_channel(app.id)
+    t0 = dt.datetime(2026, 1, 1, tzinfo=dt.timezone.utc)
+    for u in range(20):
+        for t in range(14):           # no multiple of the block length
+            storage.events.insert(Event(
+                event="view", entity_type="user", entity_id=f"u{u}",
+                target_entity_type="item",
+                target_entity_id=f"i{(u + t) % 8}",
+                event_time=t0 + dt.timedelta(minutes=t)), app.id)
+    return app
+
+
+def test_train_deploy_predict_returns_the_references_top_items(storage,
+                                                               bd_app):
+    from predictionio_tpu.core.workflow import prepare_deploy, run_train
+    from predictionio_tpu.utils import tracing
+
+    iid = run_train(FACTORY, variant=_variant(2), storage=storage,
+                    use_mesh=False)
+    tree = tracing.last_verb("train.run")
+    spans = {s["name"]: s.get("attrs") or {} for s in tree}
+    assert {"seqrec.index", "seqrec.pack", "seqrec.init", "seqrec.fit",
+            "seqrec.fetch", "model.serialize"} <= set(spans)
+    pack = spans["seqrec.pack"]
+    # 20 histories of 14 rows, two a sequence of 28 slots: three whole
+    # blocks and a partial one each
+    assert (pack["sequences"], pack["split"]) == (10, 0)
+    assert (pack["bd_block"], pack["bd_blocks"],
+            pack["bd_partial_blocks"]) == (4, 80, 20)
+    assert pack["attn_pairs_bd"] == 20 * 2 * (4 * 4 + 4 * 8 + 4 * 12
+                                              + 2 * 14)
+    assert pack["stream_rows"] == 2 * pack["slots"] == 560
+    assert pack["attn_pairs_bd"] <= pack["attn_tile_pairs_bd"]
+    fit = spans["seqrec.fit"]
+    assert fit["backbone"] == "sdar_moe"
+    assert fit["moe_dropped_pairs"] == 0 and fit["losses_finite"]
+    assert fit["router_bias_absmax"] == 0.0
+    assert fit["bd_real"] == 2 * 20 * 14         # two epochs
+    assert 0 < fit["bd_masked"] < fit["bd_real"]
+    # both streams' rows, top-3, in each of the two layers
+    assert fit["moe_pairs"] == 2 * fit["bd_real"] * 3 * 2
+    assert set(fit["grad_norms_first"]) == set(sd.grad_groups(_config()))
+    deployed = prepare_deploy(engine_factory=FACTORY, storage=storage,
+                              instance_id=iid)
+    model = deployed.models[0]
+    assert model.model_type == "sdar_moe"
+    assert isinstance(model.hp, sd.SdarConfig)
+    mask = model.hp.mask_id
+    # a history whose length is no multiple of 4 (the MASK rows fill its
+    # last block), one that is (a whole block of MASK rows), and one
+    # longer than seq_len − 4 (28 − 4), which keeps its newest 24
+    for history in ([f"i{t % 8}" for t in range(11)],
+                    [f"i{(5 * t) % 8}" for t in range(8)],
+                    [f"i{(3 * t) % 8}" for t in range(40)]):
+        got = deployed.query({"history": history, "num": 3})["itemScores"]
+        ids = [model.item_ids[i] + 1 for i in history][-24:]
+        n = len(ids)
+        ids = jnp.asarray(ids + [mask] * (4 - n % 4), jnp.int32)
+        assert ids.size % 4 == 0 and int(ids[n]) == mask
+        # the reference has two streams only: a noised stream that
+        # holds the SAME tokens reads, in the MASK rows' block, what
+        # one stream under the clean rule reads there
+        seq = {"tokens": ids, "noised": ids, "seg": jnp.ones_like(ids),
+               "pos": jnp.arange(ids.size, dtype=jnp.int32)}
+        with jax.default_matmul_precision("highest"):
+            logits, _ = ref.forward(model.params["params"],
+                                    model.params["bias"], seq,
+                                    dict(model.hp.__dict__), model.hp.held)
+        scores = np.asarray(logits[n])[1:len(model.item_ids) + 1]
+        top = np.argsort(-scores)[:3]
+        inv = model.item_ids.inverse()
+        assert [s["item"] for s in got] == [inv[int(i)] for i in top]
+        np.testing.assert_allclose([s["score"] for s in got], scores[top],
+                                   rtol=1e-4, atol=1e-5)
+    raw = sd.next_item_scores(model.device_params(), [1, 2, 3, 4, 5],
+                              model.hp)
+    assert raw[0] == -np.inf and raw[mask] == -np.inf
+    assert np.isfinite(raw[1:mask]).all()
+
+
+def test_a_train_killed_after_an_epoch_resumes_with_the_noise_it_would_have_had(
+        tmp_path, monkeypatch):
+    from predictionio_tpu.utils.checkpoint import TrainCheckpointer
+
+    c = _config(matmul_dtype="float32", vocab_size=16, init_std=0.02)
+    hist = [list((np.arange(30) + u) % 8 + 1) for u in range(20)]
+    straight, losses = sd.sdar_train(hist, c, 2, 0.003, 5)
+    steps = len(losses) // 2
+    # the second epoch drew other masks than the first: same data, same
+    # weights would else give losses that only the updates separate
+    assert np.abs(losses[steps:] - losses[:steps]).max() > 1e-3
+
+    saves = []
+    real_save = TrainCheckpointer.save
+
+    def save_then_die(self, step, state):
+        real_save(self, step, state)
+        saves.append(step)
+        self.close()
+        raise KeyboardInterrupt("killed after the checkpoint")
+
+    ckdir = str(tmp_path / "ck")
+    monkeypatch.setattr(TrainCheckpointer, "save", save_then_die)
+    with pytest.raises(KeyboardInterrupt):
+        sd.sdar_train(hist, c, 2, 0.003, 5, checkpoint_dir=ckdir)
+    monkeypatch.setattr(TrainCheckpointer, "save", real_save)
+    assert saves == [1]           # between the blocks, never after the last
+    resumed, rest = sd.sdar_train(hist, c, 2, 0.003, 5,
+                                  checkpoint_dir=ckdir)
+    assert len(rest) == steps     # only the second epoch ran
+    np.testing.assert_allclose(rest, losses[steps:], rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(straight), jax.tree.leaves(resumed)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)

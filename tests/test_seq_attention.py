@@ -472,3 +472,268 @@ def test_window_counters_against_brute_force():
         assert (c["attn_pairs_window"] == c["attn_pairs"]) == (bound == 0)
     assert "attn_pairs_window" not in seq_backbone.pack_histories(
         hist, 64, 2, seed=3).counters
+
+
+# -- the block rule: two streams, a row's keys decided by blocks ---------------
+
+
+def _blocks_of(seg, block):
+    """A slot's block inside its segment, from the segment's first row
+    — brute force: a loop over the slots."""
+    out, at = np.zeros(len(seg), np.int64), 0
+    for r in range(len(seg)):
+        if r and seg[r] != seg[r - 1]:
+            at = r
+        out[r] = (r - at) // block
+    return out
+
+
+def _bd_mask(seg, block):
+    """The four visibility lines over both streams' 2·S rows (clean
+    rows, then noised), brute force."""
+    seg = np.asarray(seg)
+    blk = _blocks_of(seg, block)
+    same = (seg[:, None] == seg[None, :]) & (seg[:, None] > 0)
+    clean_clean = same & (blk[None, :] <= blk[:, None])
+    noised_clean = same & (blk[None, :] < blk[:, None])
+    noised_noised = same & (blk[None, :] == blk[:, None])
+    clean_noised = np.zeros_like(same)
+    return np.block([[clean_clean, clean_noised],
+                     [noised_clean, noised_noised]])
+
+
+def _bd_dense(q, k, v, mask, scale):
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(a.astype(jnp.float32), q.shape[1] // a.shape[1],
+                           axis=1) for a in (k, v))
+    s = jnp.einsum("qhd,khd->hqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(mask[None], s, -1e30), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+def _cut_by_the_packing():
+    """What ``pack_histories`` makes of a history longer than a
+    sequence and three short ones: the long one cut into a full
+    sequence and a remainder that shares the second sequence."""
+    packed = glm.pack_histories(
+        [np.arange(1, 151) % 40 + 1, np.arange(1, 23), np.arange(1, 4),
+         np.arange(1, 10)], 128, seed=0)
+    assert packed.counters["split"] >= 1
+    return packed.seg
+
+
+#: name → (segment ids, query rows a tile, keys a tile, query heads,
+#: key-value heads, block length)
+BD_PACKINGS = {
+    "shorter_than_a_block": (_segments(3, 1, 2, 33, 64, 7, S=128), 32, 32,
+                             2, 2, 4),
+    "partial_last_block": (_segments(21, 70, 30, S=128), 32, 64, 4, 4, 4),
+    "cut_by_the_packing_0": (_cut_by_the_packing()[0], 32, 32, 2, 2, 4),
+    "cut_by_the_packing_1": (_cut_by_the_packing()[1], 32, 32, 2, 2, 4),
+    "a_block_starts_a_tile": (_segments(32, 64, 32, S=128), 32, 32, 2, 2, 4),
+    "a_block_ends_a_tile": (_segments(28, 36, 64, S=128), 32, 32, 2, 2, 4),
+    "blocks_straddle_tiles": (_segments(31, 33, 40, S=128), 32, 32, 2, 1, 4),
+    "one_segment": (_segments(128, S=128), 32, 32, 2, 2, 4),
+    "grouped_8_over_2": (_segments(50, 90, 40, 60, S=256), 32, 128, 8, 2, 4),
+    "many_short_narrow_keys": (
+        _segments(*([5, 9, 2, 17, 3, 11, 7] * 4), S=256), 64, 16, 2, 2, 4),
+    "blocks_of_8": (_segments(50, 90, S=256), 32, 128, 4, 2, 8),
+}
+
+
+def _bd_case(name, dtype=jnp.float32):
+    seg, bq, bk, H_, Hkv_, block = BD_PACKINGS[name]
+    seg = np.asarray(seg)
+    q, k, v = _operands(2 * len(seg), dtype, D, D, H_, Hkv_)
+    tiled = lambda q, k, v: sa.block_attention(  # noqa: E731
+        q, k, v, jnp.asarray(seg), bq, bk, D ** -0.5, block)
+    mask = _bd_mask(seg, block)
+    dense = lambda q, k, v: _bd_dense(q, k, v, mask, D ** -0.5)  # noqa: E731
+    return seg, np.tile(seg, 2), q, k, v, tiled, dense
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("packing", sorted(BD_PACKINGS))
+def test_block_rule_equals_the_dense_mask_forward(packing, dtype):
+    _, seg2, q, k, v, tiled, dense = _bd_case(packing, dtype)
+    out = tiled(q, k, v)
+    assert out.dtype == v.dtype and bool(jnp.isfinite(out).all())
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32)[seg2 > 0],
+                               np.asarray(dense(q, k, v))[seg2 > 0],
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("packing", sorted(BD_PACKINGS))
+def test_block_rule_equals_the_dense_mask_all_three_gradients(packing):
+    """dq, dk and dv: a clean key collects from clean AND noised
+    queries, a noised key from its own block only."""
+    _, seg2, q, k, v, tiled, dense = _bd_case(packing)
+    got = _value_and_grads(tiled, q, k, v, seg2)
+    ref = _value_and_grads(dense, q, k, v, seg2)
+    np.testing.assert_allclose(float(got[0]), float(ref[0]), rtol=1e-5)
+    for name, a, b in zip("qkv", got[1], ref[1]):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.isfinite(a).all()
+        assert np.abs(a - b).max() <= 5e-5 * max(
+            np.sqrt((b ** 2).mean()), 1.0), name
+        assert not a[seg2 == 0].any(), name
+
+
+@pytest.mark.parametrize("packing", ["partial_last_block",
+                                     "blocks_straddle_tiles",
+                                     "shorter_than_a_block"])
+def test_a_noised_row_never_sees_its_own_blocks_clean_keys(packing):
+    """The leak that would let a MASK row copy its answer: the clean
+    keys and values of a block changed — every noised row of that block
+    and of the blocks before it reads as before, to the bit; the rows
+    of later blocks do not."""
+    seg, _, q, k, v, tiled, _ = _bd_case(packing)
+    S, blk = len(seg), _blocks_of(seg, BD_PACKINGS[packing][5])
+    rows = np.flatnonzero((seg == seg.max()) & (blk == 1))
+    assert rows.size                       # the last segment's second block
+    bump = np.zeros(2 * S, bool)
+    bump[rows] = True                      # clean rows only
+    k2 = jnp.where(bump[:, None, None], k + 1.0, k)
+    v2 = jnp.where(bump[:, None, None], v - 2.0, v)
+    a, b = np.asarray(tiled(q, k, v)), np.asarray(tiled(q, k2, v2))
+    earlier = np.flatnonzero((seg == seg.max()) & (blk <= 1)) + S
+    np.testing.assert_array_equal(a[earlier], b[earlier])
+    later = np.flatnonzero((seg == seg.max()) & (blk > 1)) + S
+    if later.size:
+        assert np.abs(a[later] - b[later]).max() > 1e-3
+    # the block's own CLEAN rows see those keys: the rule, not a hole
+    assert np.abs(a[rows] - b[rows]).max() > 1e-3
+
+
+@pytest.mark.parametrize("packing", ["partial_last_block",
+                                     "blocks_straddle_tiles"])
+def test_a_clean_row_never_sees_a_noised_key(packing):
+    """Every noised key and value changed: the clean stream reads as
+    before, to the bit, forward — and no gradient reaches a noised key
+    from a clean query."""
+    seg, seg2, q, k, v, tiled, _ = _bd_case(packing)
+    S = len(seg)
+    noised = np.arange(2 * S) >= S
+    k2 = jnp.where(noised[:, None, None], k * -3.0 + 1.0, k)
+    v2 = jnp.where(noised[:, None, None], v + 5.0, v)
+    np.testing.assert_array_equal(np.asarray(tiled(q, k, v))[:S],
+                                  np.asarray(tiled(q, k2, v2))[:S])
+    w = jnp.asarray((~noised & (seg2 > 0))[:, None, None], jnp.float32)
+    dk, dv = jax.grad(lambda k, v: (tiled(q, k, v) * w).sum(), (0, 1))(k, v)
+    assert not np.asarray(dk)[S:].any() and not np.asarray(dv)[S:].any()
+    assert np.asarray(dk)[:S].any()
+
+
+def test_one_stream_is_the_clean_streams_rule():
+    """Operands of S rows (serving): a row sees its segment's keys up
+    to the END of its block — the clean rows of the two-stream call, to
+    the bit."""
+    seg, _, q, k, v, tiled, _ = _bd_case("partial_last_block")
+    _, bq, bk, _, _, block = BD_PACKINGS["partial_last_block"]
+    S = len(seg)
+    one = sa.block_attention(q[:S], k[:S], v[:S], jnp.asarray(seg), bq, bk,
+                             D ** -0.5, block)
+    np.testing.assert_array_equal(np.asarray(one),
+                                  np.asarray(tiled(q, k, v))[:S])
+    with pytest.raises(ValueError, match="one or two streams"):
+        sa.block_attention(q[:S + bq], k[:S + bq], v[:S + bq],
+                           jnp.asarray(seg), bq, bk, 1.0, block)
+
+
+#: name → (query heads, key-value heads, head width, value width,
+#: window) of the three accepted backbones' attention, and the digest
+#: of (output, dq, dk, dv) on seeded bfloat16 operands as the kernels
+#: gave it BEFORE they knew a block rule (commit c7e5d9b, this JAX,
+#: interpret mode)
+ACCEPTED = {
+    "glm4_moe_lite": (4, 4, 48, 32, None, "3f0df40fc8626d11"),
+    "lfm2_moe": (8, 2, 16, 16, None, "a72c483dd54e1778"),
+    "smallthinker": (14, 2, 32, 32, 24, "6c90dd6571007fa0"),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(ACCEPTED))
+def test_no_block_rule_is_the_result_of_before_bit_for_bit(shape):
+    import hashlib
+
+    H_, Hkv_, D_, Dv_, window, want = ACCEPTED[shape]
+    seg = _segments(20, 70, 30, S=128)
+    rng = np.random.default_rng(11)
+    q, k, v = (jnp.asarray(rng.normal(size=(128, h, w)), jnp.bfloat16)
+               for h, w in ((H_, D_), (Hkv_, D_), (Hkv_, Dv_)))
+    w = jnp.asarray(rng.normal(size=(128, H_, Dv_))
+                    * (seg > 0)[:, None, None], jnp.float32)
+    attend = lambda q, k, v: sa.segment_attention(  # noqa: E731
+        q, k, v, jnp.asarray(seg), 32, 64, D_ ** -0.5, window)
+    out = jax.jit(attend)(q, k, v)
+    grads = jax.jit(jax.grad(
+        lambda q, k, v: (attend(q, k, v).astype(jnp.float32) * w).sum(),
+        (0, 1, 2)))(q, k, v)
+    h = hashlib.sha256()
+    for a in (out, *grads):
+        h.update(np.asarray(a.astype(jnp.float32)).tobytes())
+    assert h.hexdigest()[:16] == want
+
+
+def _random_packing(rng, S):
+    lengths, left = [], S - int(rng.integers(0, S // 4))
+    while left > 0:
+        n = int(min(left, rng.choice([1, 2, 3, 4, 5, 7, 8, 13, 31, 64, 90])))
+        lengths.append(n)
+        left -= n
+    return _segments(*lengths, S=S)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_block_intervals_and_tile_pairs_against_brute_force(seed):
+    """On a random packing: every visible pair lies in a visited tile;
+    a tile is visited only where its run says so; the key tiles' runs
+    of query blocks are the query blocks' runs of tiles, transposed;
+    ``block_tile_pairs`` counts exactly the visited tiles."""
+    rng = np.random.default_rng(seed)
+    S, bq, bk, block = 256, int(rng.choice([32, 64])), int(
+        rng.choice([16, 32, 128])), int(rng.choice([2, 4, 8]))
+    seg = _random_packing(rng, S)
+    mask = _bd_mask(seg, block)
+    spans = sa.block_spans(seg, block, np)
+    fwd, bwd = sa.block_intervals(*spans, bq, bk, np)
+    nq, T = 2 * S // bq, 2 * S // bk
+    visited = np.zeros((nq, T), bool)
+    for i in range(nq):
+        for lo, hi in ((fwd[0, i], fwd[1, i]), (fwd[2, i], fwd[3, i])):
+            assert 0 <= lo and hi < T
+            visited[i, lo:hi + 1] = True
+    assert visited.any(1).all()            # no block divides by zero
+    tiles = mask.reshape(nq, bq, T, bk).any((1, 3))
+    assert not (tiles & ~visited).any()
+    back = np.zeros((nq, T), bool)
+    for j in range(T):
+        for lo, hi in ((bwd[0, j], bwd[1, j]), (bwd[2, j], bwd[3, j])):
+            back[max(lo, 0):hi + 1, j] = True
+    np.testing.assert_array_equal(back, visited)
+    assert sa.block_tile_pairs(seg[None], bq, bk, block) == int(
+        visited.sum()) * bq * bk
+    # the rows' two intervals ARE the mask
+    a0, a1, b0, b1 = sa.stream_spans(*spans, np)
+    col = np.arange(2 * S)[None, :]
+    np.testing.assert_array_equal(
+        ((col >= a0[:, None]) & (col <= a1[:, None]))
+        | ((col >= b0[:, None]) & (col <= b1[:, None])), mask)
+    # and the walk follows the pairs: never the causal walk over 2·S
+    causal = sum(((i + 1) * bq - 1) // bk + 1 for i in range(nq)) * bq * bk
+    assert sa.block_tile_pairs(seg[None], bq, bk, block) < causal
+
+
+@pytest.mark.parametrize("block", [2, 4, 8])
+def test_block_pairs_against_brute_force_and_the_closed_form(block):
+    rng = np.random.default_rng(block)
+    seg = _random_packing(rng, 256)
+    sizes = np.bincount(seg)[1:]
+    assert sa.block_pairs(sizes, block) == int(_bd_mask(seg, block).sum())
+    for n in (block, 8 * block, 8192):
+        assert sa.block_pairs([n], block) == n * (n + block)
+    assert sa.block_pairs([8192], 4) == 67_141_632

@@ -15,6 +15,7 @@ import pytest
 
 from predictionio_tpu.models import glm4_moe_lite as glm
 from predictionio_tpu.models import lfm2_moe as lfm
+from predictionio_tpu.models import sdar_moe as sd
 from predictionio_tpu.models import seq_backbone
 from predictionio_tpu.models import smallthinker as st
 from predictionio_tpu.models.seq_rec import _make_tx
@@ -43,6 +44,11 @@ BACKBONES = {
         moe_num_primary_experts=8, moe_num_active_primary_experts=2,
         num_hidden_layers=4, sliding_window_layout=[0, 1, 1, 1],
         rope_layout=[0, 1, 1, 1], sliding_window_size=24))),
+    "sdar_moe": (sd, sd.SdarConfig.from_architecture(dict(
+        {k: v for k, v in TINY.items() if k != "intermediate_size"},
+        model_type="sdar_moe", head_dim=16, num_attention_heads=4,
+        num_key_value_heads=2, num_experts=8, num_hidden_layers=2,
+        block_length=4))),
 }
 #: what this PR added: around and beside the operators' scopes
 NEW = {"seqrec.step", "seqrec.stack", "seqrec.stack.cast", "seqrec.norm",
@@ -53,10 +59,11 @@ OWN = {"glm4_moe_lite": {"seqrec.mla", "seqrec.mla.attention", "seqrec.mtp"},
        "lfm2_moe": {"seqrec.conv", "seqrec.conv.mix", "seqrec.gqa",
                     "seqrec.gqa.attention"},
        "smallthinker": {"seqrec.swa", "seqrec.swa.attention", "seqrec.gqa",
-                        "seqrec.gqa.attention"}}
+                        "seqrec.gqa.attention"},
+       "sdar_moe": {"seqrec.bd", "seqrec.bd.attention", "seqrec.bd.noise"}}
 #: a scope every other backbone opens and this one has nothing for: no
 #: dense feed-forward layer and no shared expert
-LACKS = {"smallthinker": {"seqrec.ffn"}}
+LACKS = {"smallthinker": {"seqrec.ffn"}, "sdar_moe": {"seqrec.ffn"}}
 SCOPE = re.compile(r"seqrec\.[a-z_.]+[a-z_]")    # scope_reduce's pattern
 
 
@@ -68,16 +75,16 @@ def _abstract_args(module, c):
                           is_leaf=seq_backbone._is_shape)
     _, bias = jax.eval_shape(lambda: module.init_state(c, 0))
     data = {k: sds((2, c.seqs_per_step, c.seq_len), jnp.int32)
-            for k in module.BATCH_KEYS}
+            for k in getattr(module, "TRAIN_KEYS", module.BATCH_KEYS)}
+    if hasattr(module, "draws"):       # what keys the backbone's noise
+        data["draw"] = sds((2, c.seqs_per_step, 2), jnp.uint32)
     return (params, jax.eval_shape(_make_tx().init, params), bias), data
 
 
 def _program(module, c):
     """The backbone's train program, traced anew (``module.train_program``
     keeps one per config)."""
-    return seq_backbone.train_program(c, 1, module.loss_fn,
-                                      module.group_squares,
-                                      module.grad_groups(c))
+    return module.train_program.__wrapped__(c, 1)
 
 
 @contextlib.contextmanager
