@@ -608,39 +608,55 @@ class ALSPrepared:
     def geometry(self):
         return (self.u_side.geometry, self.i_side.geometry)
 
-    def kernel_rows(self) -> dict:
+    def kernel_rows(self, rank: int) -> dict:
         """What the fused gather→Gram kernel is handed per iteration
-        when the Gram mode is fused, counted over the buckets that
-        ``ops.gram.kernel_takes_width`` sends to it — the predicate
-        ``bucket_systems`` in ``_make_half`` routes by, the one place
-        that does: real (unpadded) interactions, padded
-        slots (the layout's padding, still streamed as index and
-        weights), bucket rows, the factor-line copies the kernel
-        starts and the DMA waits that retire them. ``real ÷ padded`` is
-        the share of the slots that hold an interaction, ``real ÷ dma``
-        the share of the copies that fetch a row somebody rated,
-        ``waits ÷ dma`` what is left of one wait a copy."""
-        from predictionio_tpu.ops.gram import dma_waits, kernel_takes_width
+        of a rank-``rank`` train when the Gram mode is fused, counted
+        over the buckets that ``ops.gram.kernel_takes_width`` sends to
+        it — the predicate ``bucket_systems`` in ``_make_half`` routes
+        by, the one place that does: real (unpadded) interactions,
+        padded slots (the layout's padding, still streamed as index
+        and weights), bucket rows, the factor lines the kernel fetches
+        — by either route: of them ``kernel_resident_rows`` are read
+        from a table the dispatch holds in VMEM
+        (``ops.gram.table_is_resident``, the predicate the kernel
+        branches on; ``gram_table_bytes_u/i``: the table a side's
+        half-step gathers from, the OTHER side's factors as the kernel
+        lays them out), the rest are line copies — and the DMA waits
+        that retire the copies. ``real ÷ padded`` is the share of the
+        slots that hold an interaction, ``real ÷ dma`` the share of
+        the fetches that bring a row somebody rated, ``waits ÷ dma``
+        what is left of one wait a line."""
+        from predictionio_tpu.ops.gram import (dma_waits, kernel_takes_width,
+                                               table_bytes, table_is_resident)
 
-        real = padded = rows = waits = 0
-        for side in (self.u_side, self.i_side):
+        real = padded = rows = waits = held = 0
+        for side, n_other in ((self.u_side, self.n_items),
+                              (self.i_side, self.n_users)):
+            resident = table_is_resident(n_other, rank)
             for b in side.buckets:
                 if kernel_takes_width(b.C):
                     # one mask slot per interaction = the entities'
                     # counts (exact in f64; no pass over the mask)
-                    real += int(b.counts.sum(dtype=np.float64))
+                    n = int(b.counts.sum(dtype=np.float64))
+                    real += n
                     rows += b.n_slabs * b.slab
                     padded += b.n_slabs * b.slab * b.C
-                    # by the function the kernel takes its group sizes
-                    # from (a segmented entity counts as one long row)
-                    waits += dma_waits(b.counts, b.C)
-        # the kernel is given each row's real length and starts exactly
-        # that many copies (``_gather_gram_kernel``: no rounding of a
+                    if resident:
+                        held += n
+                    else:
+                        # by the function the kernel takes its group
+                        # sizes from (a segmented entity counts as one
+                        # long row)
+                        waits += dma_waits(b.counts, b.C)
+        # the kernel is given each row's real length and fetches exactly
+        # that many lines (``_gather_gram_kernel``: no rounding of a
         # length) — a kernel that rounded lengths up would count the
         # rounded ones here
         return {"kernel_real_rows": real, "kernel_padded_rows": padded,
                 "kernel_bucket_rows": rows, "kernel_dma_rows": real,
-                "kernel_dma_waits": waits}
+                "kernel_dma_waits": waits, "kernel_resident_rows": held,
+                "gram_table_bytes_u": table_bytes(self.n_items, rank),
+                "gram_table_bytes_i": table_bytes(self.n_users, rank)}
 
     def layout_paths(self) -> dict:
         """Which data-dependent path :func:`_bucket_side` took on each
@@ -717,7 +733,8 @@ def als_train(
     device = mesh.devices.flat[0] if mesh is not None else None
     with tracing.span("als.prepare", nnz=int(coo.nnz)):
         prep = als_prepare(coo)
-        tracing.add_attrs(**prep.kernel_rows(), **prep.layout_paths())
+        tracing.add_attrs(**prep.kernel_rows(params.rank),
+                          **prep.layout_paths())
     return als_train_prepared(prep, params, device=device,
                               checkpointer=checkpointer,
                               checkpoint_every=checkpoint_every)

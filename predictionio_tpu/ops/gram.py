@@ -2,22 +2,28 @@
 
 :func:`gather_gram` is the fused **gather→Gram** kernel
 (:func:`gather_gram_xla` its XLA twin): the gather itself runs inside
-the kernel. Per grid program, ``F_other`` rows are DMA'd tile-by-tile
-straight from HBM into a VMEM tile using the ``other_idx`` row block
-(prefetched into SMEM), the weighted normal equations accumulate in a
-VMEM register block, and only the ``(R, k, k)`` / ``(R, k)`` results
-are written back. The gathered ``(R, C, k)`` block never materializes
-in HBM and the weighting never round-trips. What bounds it
-(PERF_LEDGER.jsonl, PR 24: nine bucket shapes, widths 128 to 8192, two
-configurations) is neither the MXU nor HBM but the scalar core, which
-starts one 512-byte line copy at a time — so the kernel is given every
-row's REAL length and starts no copy for a padded slot (PR 25), starts
-its copies sixteen to a loop trip, retires a whole tile of them with ONE
-wait per set bit of their count, and has the next program's index
-block fetched while this program runs (PR 37: ~32 → ~16 ns a copy;
-the ~0.7 µs of fixed work a row stayed — it is the tile's own vector
-work). ``models/als.py _make_half`` selects it via
-``PIO_PALLAS_GRAM`` (see :func:`resolve_gram_mode`).
+the kernel. Per grid program, ``F_other`` rows are fetched tile-by-tile
+into a VMEM tile using the ``other_idx`` row block (prefetched into
+SMEM), the weighted normal equations accumulate in a VMEM register
+block, and only the ``(R, k, k)`` / ``(R, k)`` results are written
+back. The gathered ``(R, C, k)`` block never materializes in HBM and
+the weighting never round-trips. What bounds it (PERF_LEDGER.jsonl,
+PR 24: nine bucket shapes, widths 128 to 8192, two configurations) is
+neither the MXU nor HBM but the scalar core, which fetches one 512-byte
+line at a time — so the kernel is given every row's REAL length and
+fetches nothing for a padded slot (PR 25), fetches sixteen lines to a
+loop trip and has the next program's index block fetched while this
+program runs (PR 37). HOW a line is fetched follows the table's size
+(:func:`table_is_resident`, PR 41): a table that fits in VMEM is
+copied there once a dispatch and a line is a vector load from it and a
+store into the tile (~3–4 ns a line on the chip); a larger one stays in
+HBM and a line is a DMA — a descriptor, a start, and ONE wait per set
+bit of a tile's copy count (~11–12 ns a line). Same tiles, mask,
+product and order of sums on both: ``A`` and ``b`` bit for bit. The
+~0.85 µs of vector work a 128-slot tile costs (mask, transpose,
+six-pass product) is what is left, and now the larger part.
+``models/als.py _make_half`` selects the kernel via ``PIO_PALLAS_GRAM``
+(see :func:`resolve_gram_mode`).
 
 Per padded rating row r:
 
@@ -56,7 +62,13 @@ from jax.experimental.pallas import tpu as pltpu
 # kernel moves the gather inside: the index block is DMA'd into SMEM up
 # front (the scalar core needs the row ids to program the data DMAs),
 # factor rows stream HBM→VMEM in T-row tiles with per-row async copies,
-# and the weighted normal equations accumulate in VMEM.
+# and the weighted normal equations accumulate in VMEM. Where the whole
+# table of lines fits in VMEM (``table_is_resident``: ML-20M's 6.8 and
+# 35.5 MB, Last.fm's 69.1 and 75.3 MB at rank 64) the first program of a
+# dispatch copies it there in ONE DMA and every line after that is a
+# single-sublane vector load at a dynamic row and a store into the tile:
+# no descriptor, no semaphore, no wait — the scalar core's part of a
+# fetch is the id's SMEM load and a shift.
 #
 # What the chip's compiler accepts (Mosaic, v5e): a DMA slice must be a
 # whole number of (1, 128) f32 lane tiles, so a ``1 × k`` copy with
@@ -72,8 +84,11 @@ from jax.experimental.pallas import tpu as pltpu
 # before the call: a line is 512 B whatever the dtype, so bf16 saves
 # no gather traffic here.
 #
-# The copy pipeline (PR 37; PERF.md §6 has the chip's numbers). Per tile
-# of T slots the scalar core (1) starts the row's `live` real copies,
+# The copy pipeline of a table that stays in HBM (PR 37; PERF.md §6 has
+# the chip's numbers; a resident table's dispatch builds none of it: no
+# line copies, no semaphore, no waits — steps (1) and (3) only, (1) a
+# load and a store a line). Per tile of T slots the scalar core
+# (1) starts the row's `live` real copies,
 # _ISSUE_UNROLL to a loop trip so that the ids' SMEM loads and the
 # address arithmetic of neighbouring copies overlap, (2) waits for them
 # — all copies signal one semaphore and a wait takes the semaphore and
@@ -98,14 +113,26 @@ from jax.experimental.pallas import tpu as pltpu
 # 2·T·L·4 (line tiles) + (L+1)·L·4 (accumulators) + RB·kp·(kp+1)·4
 # (output block), with L = max(128, kp), T = min(C, 256), RB = 8 —
 # worst case (C = 8192) ≈ 1 MB, ~2 MB with the runtime's double
-# buffering of the blocked operands. SMEM: two (RB, C) index blocks
+# buffering of the blocked operands — plus, on the resident route, the
+# table itself: ONE (lines, 128) f32 scratch (not a pipelined operand,
+# which would be two), for which the dispatch asks the compiler for
+# _VMEM_LIMIT = 96 MiB of the v5e's 128; the rule admits a table up to
+# that less 4 MiB for everything above. SMEM: two (RB, C) index blocks
 # (512 KB at C = 8192 — the v5e's compiler takes it, held by
 # tests/test_chip_compile.py, and the chip runs it) and two (8, 128)
 # blocks of row lengths (8 KB).
 
 _GATHER_TILE = 256  # factor rows per DMA burst (T)
 _LANES = 128
-_ISSUE_UNROLL = 16  # line copies started per trip of the issue loop
+_ISSUE_UNROLL = 16  # line fetches started per trip of the issue loop
+#: the VMEM a dispatch with a resident table asks the v5e's compiler for
+#: (of 128 MiB; ``ops/seq_attention.py`` asks the same)
+_VMEM_LIMIT = 96 * 1024 * 1024
+#: the most bytes of factor lines a dispatch keeps in VMEM: what it asks
+#: for less the kernel's own working set ("VMEM sizing" above, ~2 MB at
+#: C = 8192, doubled for room). ONE buffer — the table is a scratch the
+#: first program fills, not a pipelined operand
+_RESIDENT_TABLE_BYTES = _VMEM_LIMIT - 4 * 1024 * 1024
 
 
 def _tile(C: int) -> int:
@@ -142,11 +169,22 @@ def dma_waits(lengths, C: int) -> int:
 
 def _gather_gram_kernel(idx_hbm, len_ref, idx_ref, wo_ref, wb_ref, F_hbm,
                         A_ref, b_ref, idx_smem, f_tile, accA, accB, sem_idx,
-                        sem_row, *, RB: int, C: int, T: int, kp: int,
-                        G: int):
+                        via, *, RB: int, C: int, T: int, kp: int,
+                        G: int, resident: bool):
+    """``via`` is what a line is fetched through: the line copies' DMA
+    semaphore, or — ``resident`` — the VMEM scratch that holds the
+    whole table of factor lines for the dispatch."""
     i = pl.program_id(0)
     L = f_tile.shape[2]
     ib = i & 1
+
+    if resident:
+        # the first program fills the table, ONE copy at HBM speed; the
+        # grid runs in order and scratch persists, so every later
+        # program reads it where it lies
+        @pl.when(i == 0)
+        def _():
+            pltpu.sync_copy(F_hbm, via)
 
     # index block HBM→SMEM: row ids live on the scalar core, which
     # issues the factor-line DMAs below. Two buffers: program i waits
@@ -170,18 +208,22 @@ def _gather_gram_kernel(idx_hbm, len_ref, idx_ref, wo_ref, wb_ref, F_hbm,
     shift = G.bit_length() - 1  # row → line: G is a power of two
 
     def issue(r, base, live, buf):
-        """Start the `live` line copies of one tile — slots
-        [base, base + live) of block row r — into tile buffer `buf`.
-        All signal sem_row and have the same (1, L) shape. The loop is
-        unrolled: a trip starts _ISSUE_UNROLL copies, the remainder
+        """Fetch the `live` lines of one tile — slots
+        [base, base + live) of block row r — into tile buffer `buf`:
+        from the resident table a vector load and store a line, else a
+        line copy started (all signal the one semaphore and have the
+        same (1, L) shape; ``retire`` waits for them). The loop is
+        unrolled: a trip fetches _ISSUE_UNROLL lines, the remainder
         goes one by one."""
         def one(j):
             row = idx_smem[ib, r, base + j]
-            pltpu.make_async_copy(
-                F_hbm.at[pl.ds(jax.lax.shift_right_logical(row, shift), 1),
-                         :],
-                f_tile.at[buf, pl.ds(j, 1), :],
-                sem_row).start()
+            line = pl.ds(jax.lax.shift_right_logical(row, shift), 1)
+            if resident:
+                f_tile[buf, pl.ds(j, 1), :] = via[line, :]
+            else:
+                pltpu.make_async_copy(F_hbm.at[line, :],
+                                      f_tile.at[buf, pl.ds(j, 1), :],
+                                      via).start()
 
         def burst(q, _):
             for u in range(_ISSUE_UNROLL):
@@ -207,7 +249,7 @@ def _gather_gram_kernel(idx_hbm, len_ref, idx_ref, wo_ref, wb_ref, F_hbm,
                 pltpu.make_async_copy(
                     F_hbm.at[pl.ds(0, g), :],
                     f_tile.at[buf, pl.ds(0, g), :],
-                    sem_row).wait()
+                    via).wait()
 
     tile_row = jax.lax.broadcasted_iota(jnp.int32, (T, L), 0)
     lane_slot = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1) // kp
@@ -226,7 +268,8 @@ def _gather_gram_kernel(idx_hbm, len_ref, idx_ref, wo_ref, wb_ref, F_hbm,
             # a row's tiles land in the two tile buffers in turn
             buf = t & 1
             issue(r, t * T, live, buf)
-            retire(live, buf)
+            if not resident:
+                retire(live, buf)
             # tile rows past `live` still hold what an earlier tile or
             # row fetched into this buffer (or nothing yet): masked by
             # ROW, because a zero weight does not make a stale inf or
@@ -296,6 +339,31 @@ def _line_width(k: int):
     return kp, _LANES // kp
 
 
+def _table_lines(n_other: int, k: int) -> int:
+    """The 128-lane lines the kernel lays ``n_other`` factor rows of
+    rank k out in: G rows to a line, and at least a tile's worth — the
+    group waits' stand-in source is lines [0, g), g ≤ T."""
+    _, G = _line_width(k)
+    return max(-(-n_other // G), _GATHER_TILE)
+
+
+def table_bytes(n_other: int, k: int) -> int:
+    """The bytes of ``n_other`` factor rows of rank k as the kernel
+    lays them out: float32 lines of G rows × kp lanes."""
+    kp, G = _line_width(k)
+    return _table_lines(n_other, k) * kp * G * 4
+
+
+def table_is_resident(n_other: int, k: int) -> bool:
+    """Whether a dispatch that gathers from ``n_other`` factor rows of
+    rank k keeps the table in VMEM and fetches a line with a vector
+    load, not a copy: the table's bytes as the kernel lays it out
+    against ``_RESIDENT_TABLE_BYTES``. A shape the call observes — the
+    kernel branches on it and ``ALSPrepared.kernel_rows`` counts by
+    it; nothing else chooses the route."""
+    return table_bytes(n_other, k) <= _RESIDENT_TABLE_BYTES
+
+
 def gather_gram(F_other, idx, wo, wb, lengths, *,
                 interpret: bool = False):
     """Fused gather→weighted-Gram: ONE Pallas kernel computing
@@ -323,11 +391,11 @@ def gather_gram(F_other, idx, wo, wb, lengths, *,
     kp, G = _line_width(k)
     L = kp * G
     F = F_other.astype(jnp.float32)
-    # at least T lines: the group waits' stand-in source is F[0:g], g ≤ T
-    Np = max(-(-N // G), T) * G
-    if kp != k or Np != N:
-        F = jnp.pad(F, [(0, Np - N), (0, kp - k)])
-    F = F.reshape(Np // G, L)
+    lines = _table_lines(N, k)
+    if kp != k or lines * G != N:
+        F = jnp.pad(F, [(0, lines * G - N), (0, kp - k)])
+    F = F.reshape(lines, L)
+    resident = table_is_resident(N, k)
     # Mosaic block mappings need the row-block dim divisible by 8 (or
     # equal to R): pad the row count up and slice the results back —
     # pad rows have length 0: they copy nothing and come back zero
@@ -348,7 +416,7 @@ def gather_gram(F_other, idx, wo, wb, lengths, *,
                              memory_space=pltpu.VMEM)
     A, b = pl.pallas_call(
         functools.partial(_gather_gram_kernel, RB=RB, C=C, T=T, kp=kp,
-                          G=G),
+                          G=G, resident=resident),
         grid=(Rp // RB,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),   # idx: stays in HBM
@@ -357,7 +425,8 @@ def gather_gram(F_other, idx, wo, wb, lengths, *,
             row_block,      # idx again, as a vector operand (slot mask)
             row_block,
             row_block,
-            pl.BlockSpec(memory_space=pl.ANY),   # F lines: HBM source
+            pl.BlockSpec(memory_space=pl.ANY),   # F lines: either
+                                                 # fetch's HBM source
         ],
         out_specs=(
             pl.BlockSpec((RB, kp, kp), lambda i: (i, 0, 0),
@@ -375,16 +444,23 @@ def gather_gram(F_other, idx, wo, wb, lengths, *,
             pltpu.VMEM((L, L), jnp.float32),
             pltpu.VMEM((1, L), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
+            # what a line is fetched through: the table, or the line
+            # copies' semaphore
+            (pltpu.VMEM((lines, L), jnp.float32) if resident
+             else pltpu.SemaphoreType.DMA),
         ],
         # in order: a program takes the index block its predecessor
-        # started
+        # started, and the table the first one filled
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT if resident else None),
         cost_estimate=pl.CostEstimate(
             flops=2 * R * C * L * (L + 1),
-            bytes_accessed=(R * C * (8 + 4 * L)
-                            + 8 * R * C + 4 * R * kp * (kp + 1)),
+            # index twice and the weights; the table once, or a line a
+            # slot; the results
+            bytes_accessed=(16 * R * C
+                            + 4 * L * (lines if resident else R * C)
+                            + 4 * R * kp * (kp + 1)),
             transcendentals=0,
         ),
         name="gather_gram",

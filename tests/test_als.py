@@ -746,7 +746,7 @@ class TestBucketedLayout:
         u = native_prep.u_side
         assert u.dense is not None and u.buckets[0].seg is not None \
             and any(b.seg is None for b in u.buckets)
-        assert native_prep.kernel_rows() == radix_prep.kernel_rows()
+        assert native_prep.kernel_rows(8) == radix_prep.kernel_rows(8)
         self._same_array("U", U, Ur)
         self._same_array("V", V, Vr)
 
@@ -921,15 +921,31 @@ class TestFusedGram:
             # every interaction of the bucket sits in some row's prefix
             assert m.sum() == b.counts.sum()
 
-    def test_kernel_is_given_the_mask_row_sums(self, monkeypatch):
+    @pytest.mark.parametrize("held", ["both", "users_only", "none"])
+    def test_kernel_is_given_the_mask_row_sums(self, monkeypatch, held):
         """Each half-step hands ``gather_gram`` one length per bucket
         row, equal to that row's mask sum, and ``kernel_dma_rows`` is
-        their total: the kernel starts one copy per real slot."""
+        their total: the kernel fetches one line per real slot — from
+        the table it holds where ``table_is_resident`` says so
+        (``kernel_resident_rows``: the rule's constant is moved here so
+        that both tables, the users' alone — the smaller, which the
+        ITEM half-step gathers — or neither fit), else by a copy, and
+        only copies are waited for."""
         import jax.numpy as jnp
         from predictionio_tpu.ops import gram as gram_mod
         import predictionio_tpu.models.als as als_mod
 
         coo = _wide_layout(monkeypatch)
+        # tiles of 8 slots: these tables of 25 and 6 lines are laid
+        # out in at least a tile's worth, and must differ
+        monkeypatch.setattr(gram_mod, "_GATHER_TILE", 8)
+        bytes_u, bytes_i = (gram_mod.table_bytes(n, 4)
+                            for n in (coo.n_items, coo.n_users))
+        assert bytes_u > bytes_i
+        monkeypatch.setattr(
+            gram_mod, "_RESIDENT_TABLE_BYTES",
+            {"both": bytes_u, "users_only": bytes_u - 1,
+             "none": bytes_i - 1}[held])
         prep = als_mod.als_prepare(coo)
         given = []
         orig = gram_mod.gather_gram
@@ -951,13 +967,24 @@ class TestFusedGram:
         for lengths, b in zip(given, buckets):
             np.testing.assert_array_equal(
                 lengths, b.mask.reshape(-1, b.C).sum(1).astype(np.int32))
-        rows = prep.kernel_rows()
+        rows = prep.kernel_rows(4)
         assert rows["kernel_dma_rows"] == sum(int(g.sum()) for g in given)
+        assert (rows["gram_table_bytes_u"], rows["gram_table_bytes_i"]) \
+            == (bytes_u, bytes_i)
+        n_u = len(self._kernel_buckets([prep.u_side]))
+        copied = {"both": [], "users_only": list(zip(given, buckets))[:n_u],
+                  "none": list(zip(given, buckets))}[held]
+        assert rows["kernel_resident_rows"] == (
+            rows["kernel_dma_rows"] - sum(int(g.sum()) for g, _ in copied))
+        assert (held == "both") == (
+            rows["kernel_resident_rows"] == rows["kernel_real_rows"])
+        assert (held == "none") == (rows["kernel_resident_rows"] == 0)
         # the waits are counted from the entities' counts; the kernel
         # makes them for the ROW lengths it is given — the same number,
-        # a segmented entity's rows being cut at whole tiles
+        # a segmented entity's rows being cut at whole tiles — and for
+        # copied lines only
         assert rows["kernel_dma_waits"] == sum(
-            gram_mod.dma_waits(g, b.C) for g, b in zip(given, buckets))
+            gram_mod.dma_waits(g, b.C) for g, b in copied)
         # no slot that holds an interaction is skipped
         assert rows["kernel_dma_rows"] >= rows["kernel_real_rows"] > 0
 

@@ -92,26 +92,53 @@ def test_score_topk(one_chip):
     assert _has_kernel(c)
 
 
+def _gram_route(compiled) -> str:
+    """Which way the compiled ``gather_gram`` dispatch fetches its
+    factor lines, read off the custom call: only a dispatch that keeps
+    the table in VMEM asks the compiler for ``_VMEM_LIMIT`` of it."""
+    from predictionio_tpu.ops.gram import _VMEM_LIMIT
+
+    asked = ('"scoped_memory_configs":[{"memory_space":"1","offset":"0",'
+             f'"size":"{_VMEM_LIMIT}"}}]')
+    return "resident" if asked in compiled.as_text() else "copied"
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("C", [128, 512, 2048, 8192])
-def test_gather_gram(one_chip, C, dtype):
-    """Rank 64 at every gathered ladder width, against both factor
-    sides (26,744 items; 138,493 users padded to the solve chunk).
-    Since PR 37 this is the pipelined kernel: two (T, 128) tile
-    buffers, waits through stand-in descriptors of up to T lines, and
-    two (8, C) index blocks in SMEM — 512 KB at C = 8192, which the
-    v5e's compiler takes."""
-    from predictionio_tpu.ops.gram import gather_gram
+def test_gather_gram(one_chip, monkeypatch, C, dtype):
+    """Rank 64 at every gathered ladder width, against the four factor
+    tables of the benchmark's two ALS cells (26,744 items; 138,493
+    users padded to the solve chunk; Last.fm's 270,016 and 294,016):
+    6.8, 35.5, 69.1 and 75.3 MB of lines. By the rule
+    (``table_is_resident``) all four are held in VMEM for the dispatch
+    (PR 41: a line is a vector load from a (lines, 128) scratch that
+    the first program fills with one copy, under a 96 MiB limit) — and
+    the copy route, which a larger table takes, is held to the
+    compiler beside it on the largest: since PR 37 the pipelined
+    kernel, two (T, 128) tile buffers, waits through stand-in
+    descriptors of up to T lines, and two (8, C) index blocks in SMEM
+    — 512 KB at C = 8192, which the v5e's compiler takes."""
+    from predictionio_tpu.ops import gram
 
-    for n_other in (26_744, 138_496):
-        c = jax.jit(gather_gram).lower(
+    def compiled(n_other):
+        # a fresh function: the rule's constant is not part of a
+        # cached trace's key
+        c = jax.jit(lambda *a: gram.gather_gram(*a)).lower(
             _sds((n_other, 64), dtype, one_chip),
             _sds((304, C), jnp.int32, one_chip),
             _sds((304, C), jnp.float32, one_chip),
             _sds((304, C), jnp.float32, one_chip),
             _sds((304,), jnp.int32, one_chip)).compile()
         assert _has_kernel(c)
+        return c
+
+    for n_other in (26_744, 138_496, 270_016, 294_016):
+        assert gram.table_is_resident(n_other, 64)
+        assert _gram_route(compiled(n_other)) == "resident"
+    monkeypatch.setattr(gram, "_RESIDENT_TABLE_BYTES",
+                        gram.table_bytes(294_016, 64) - 1)
+    assert _gram_route(compiled(294_016)) == "copied"
 
 
 # -- the programs around them -------------------------------------------------

@@ -365,7 +365,55 @@ def _parent_gather_gram(F_other, idx, wo, wb, lengths):
 class TestGatherGram:
     """Fused gather→weighted-Gram kernel (ISSUE 17) vs the XLA
     gather+einsum reference, interpret mode — every bucket width the
-    ALS ladder produces, plus the padding/degenerate geometries."""
+    ALS ladder produces, plus the padding/degenerate geometries.
+
+    Every case that runs the kernel runs on BOTH routes (PR 41): the
+    lines read from a table the dispatch holds in VMEM — what
+    ``table_is_resident`` gives these small tables — and the lines
+    copied one by one, which a test gets by moving the rule's constant
+    under its table (``route``); no argument of the kernel chooses."""
+
+    @pytest.fixture(params=["copied", "resident"])
+    def route(self, request, monkeypatch):
+        from predictionio_tpu.ops import gram
+
+        if request.param == "copied":
+            monkeypatch.setattr(gram, "_RESIDENT_TABLE_BYTES", 0)
+        return request.param
+
+    @staticmethod
+    def _scratch(*avals):
+        """(memory space, shape) of each scratch operand of the
+        ``gather_gram`` dispatch traced for these operand shapes."""
+        from predictionio_tpu.ops.gram import gather_gram
+
+        # a fresh function: a trace cached under another value of the
+        # rule's constant (``route``) must not answer
+        jaxpr = jax.make_jaxpr(lambda *a: gather_gram(*a))(*avals)
+        (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        n = eqn.params["grid_mapping"].num_scratch_operands
+        return [(str(v.aval.memory_space), tuple(v.aval.shape))
+                for v in eqn.params["jaxpr"].invars[-n:]]
+
+    @classmethod
+    def _route_traced(cls, n_other, k, R=16, C=128):
+        """Which route the dispatch for these shapes was built for,
+        read off its scratch operands: the table of lines in VMEM
+        (resident) or one more DMA semaphore (copied)."""
+        from predictionio_tpu.ops.gram import _line_width, _table_lines
+
+        sds = jax.ShapeDtypeStruct
+        scratch = cls._scratch(
+            sds((n_other, k), jnp.float32), sds((R, C), jnp.int32),
+            sds((R, C), jnp.float32), sds((R, C), jnp.float32),
+            sds((R,), jnp.int32))
+        kp, G = _line_width(k)
+        table = ("vmem", (_table_lines(n_other, k), kp * G))
+        assert len(scratch) == 6
+        if scratch[-1] == table:
+            return "resident"
+        assert scratch[-1] == ("semaphore_mem", ()) and table not in scratch
+        return "copied"
 
     def _data(self, R, C, k, n_other=999, seed=0, dtype=np.float32):
         rng = np.random.default_rng(seed)
@@ -384,10 +432,11 @@ class TestGatherGram:
         b = np.einsum("rc,rck->rk", wb.astype(np.float64), G)
         return A, b
 
-    def _check(self, R, C, k, **kw):
+    def _check(self, route, R, C, k, **kw):
         from predictionio_tpu.ops.gram import gather_gram
 
         F, idx, wo, wb = self._data(R, C, k, **kw)
+        assert self._route_traced(F.shape[0], k, R, C) == route
         A, b = gather_gram(jnp.asarray(F), jnp.asarray(idx),
                            jnp.asarray(wo), jnp.asarray(wb),
                            jnp.full((R,), C, jnp.int32), interpret=True)
@@ -400,17 +449,27 @@ class TestGatherGram:
         np.testing.assert_allclose(np.asarray(b), bn, **tol)
 
     @pytest.mark.parametrize("C", [8, 32, 128, 512, 2048, 8192])
-    def test_every_ladder_width(self, C):
+    def test_every_ladder_width(self, route, C):
         # R=16 divides the RB=8 row block exactly — no pad rows
-        self._check(16, C, 13)
+        self._check(route, 16, C, 13)
 
     @pytest.mark.parametrize("C", [8, 512])
-    def test_pad_rows(self, C):
+    def test_pad_rows(self, route, C):
         # R=3 forces padding up to the RB=8 row block; the padded
         # rows must not leak into the first R outputs
-        self._check(3, C, 13)
+        self._check(route, 3, C, 13)
 
-    def test_bf16_factors(self):
+    @pytest.mark.parametrize("k", [64, 128])
+    def test_rank_fills_a_line(self, route, k):
+        """Rank 64 is two rows to a line (G = 2, the benchmark's);
+        rank 128 one (G = 1): no slot mask, no diagonal blocks to
+        fold, a line IS the row."""
+        from predictionio_tpu.ops.gram import _line_width
+
+        assert _line_width(k) == (k, 128 // k)
+        self._check(route, 16, 256, k, n_other=301)
+
+    def test_bf16_factors(self, route):
         from predictionio_tpu.ops.gram import gather_gram
 
         F, idx, wo, wb = self._data(16, 32, 8, dtype=np.float32)
@@ -449,7 +508,7 @@ class TestGatherGram:
         return self._ref(F, idx, wo, wb)
 
     @pytest.mark.parametrize("C", [128, 512, 2048, 8192])
-    def test_ragged_lengths(self, C):
+    def test_ragged_lengths(self, route, C):
         """Every row length around the tile edges (T = min(C, 256))
         and the row's ends, against the float64 reference."""
         from predictionio_tpu.ops.gram import gather_gram
@@ -467,7 +526,7 @@ class TestGatherGram:
         # the length-0 row copied nothing and is exactly zero
         assert not np.asarray(A[0]).any() and not np.asarray(b[0]).any()
 
-    def test_stale_tile_rows_are_masked(self):
+    def test_stale_tile_rows_are_masked(self, route):
         """Row 0 fills the line tile with inf; row 1 of the same block
         fetches 3 lines over it and must not see what row 0 left."""
         from predictionio_tpu.ops.gram import gather_gram
@@ -528,16 +587,20 @@ class TestGatherGram:
     @pytest.mark.parametrize("case", [
         "edges-128", "edges-512", "edges-2048", "edges-8192",
         "inf-128", "inf-512", "empty-between-full", "ragged-row-count"])
-    def test_pipeline_equals_the_parents_kernel_bit_for_bit(self, case):
+    def test_pipeline_equals_the_parents_kernel_bit_for_bit(self, route,
+                                                            case):
         """The unrolled issue loop, group waits, two tile buffers in
         turn and the index block fetched a program ahead change HOW a
-        copy is started and retired and WHERE a tile lands, not what
-        is summed in what order: A and b carry the bits of the parent's
-        kernel body (above), NaNs included."""
+        line is fetched and WHERE a tile lands — and so does a line
+        read from the resident table —, not what is summed in what
+        order: on either route A and b carry the bits of the parent's
+        kernel body (above), NaNs included, so the two routes equal
+        each other bit for bit."""
         from predictionio_tpu.ops.gram import gather_gram
 
         C, lengths, inf_rows = self._pipeline_case(case)
         F, idx, wo, wb = self._ragged(C, lengths, seed=len(case))
+        assert self._route_traced(F.shape[0], 13, len(lengths), C) == route
         for r in inf_rows:   # really gather the inf row, every slot
             idx[r, :lengths[r]] = 0
         args = (jnp.asarray(F), jnp.asarray(idx), jnp.asarray(wo),
@@ -555,13 +618,16 @@ class TestGatherGram:
             if lengths[r] == 0:   # copied nothing: exactly zero
                 assert not A[r].any() and not b[r].any()
 
-    def test_pipeline_holds_when_copies_land_as_late_as_they_may(self):
+    def test_pipeline_holds_when_copies_land_as_late_as_they_may(self,
+                                                                 route):
         """``interpret=True`` lands a copy the moment it is started, so
         it cannot see a tile multiplied before its copies were waited
         for, or a buffer refilled while it is read. The TPU interpreter
         can: it keeps real semaphores, runs a DMA only when a wait
         needs it (``on_wait``) and checks every access for races — the
-        group waits' amounts must add up, or it hangs or misreads."""
+        group waits' amounts must add up, or it hangs or misreads. On
+        the resident route the one copy that fills the table must have
+        landed before the first line is read."""
         from jax._src.pallas.mosaic.interpret import (
             interpret_pallas_call as tpu_interpreter)
         from predictionio_tpu.ops.gram import gather_gram
@@ -603,7 +669,27 @@ class TestGatherGram:
         assert dma_waits([256, 512], 512) == 3
         assert dma_waits([8192 * 3 + 5], 8192) == 96 + 2
 
-    def test_empty_rows(self):
+    def test_table_one_line_over_the_rule_is_copied(self):
+        """The rule is the table's bytes as the kernel lays it out
+        against ONE constant: the largest table that fits is read from
+        VMEM, one more line of factors and the dispatch copies its
+        lines — seen in the scratch operands of the traced dispatch,
+        at the benchmark's rank (two rows a line) and at rank 128."""
+        from predictionio_tpu.ops import gram
+
+        for k, G in ((64, 2), (128, 1)):
+            most = gram._RESIDENT_TABLE_BYTES // 512 * G   # rows
+            assert gram.table_is_resident(most, k)
+            assert not gram.table_is_resident(most + 1, k)
+            assert (gram.table_bytes(most + 1, k)
+                    - gram.table_bytes(most, k)) == 512
+            assert self._route_traced(most, k) == "resident"
+            assert self._route_traced(most + 1, k) == "copied"
+        # the benchmark's four tables (PERF.md §6 PR 41)
+        for n in (26_744, 138_493, 270_000, 294_015):
+            assert gram.table_is_resident(n, 64)
+
+    def test_empty_rows(self, route):
         from predictionio_tpu.ops.gram import gather_gram
 
         F = jnp.zeros((10, 5), jnp.float32)

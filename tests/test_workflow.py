@@ -182,7 +182,9 @@ SPAN_ATTRS = {
     "als.index": ("nnz",),
     "als.prepare": ("nnz", "kernel_real_rows", "kernel_padded_rows",
                     "kernel_bucket_rows", "kernel_dma_rows",
-                    "kernel_dma_waits", "radix_passes_u", "radix_passes_i", "dense_fill_u",
+                    "kernel_dma_waits", "kernel_resident_rows",
+                    "gram_table_bytes_u", "gram_table_bytes_i",
+                    "radix_passes_u", "radix_passes_i", "dense_fill_u",
                     "dense_fill_i", "order_path_u", "order_path_i"),
     "als.upload": ("bytes",),
     "als.iterate": ("iterations", "gram", "solve"),
@@ -445,12 +447,20 @@ def test_verb_spans_land_in_the_profilers_trace(tmp_path):
         assert abs(ev.duration_ns - (s["endNs"] - s["startNs"])) < 1e6
 
 
-def test_program_kernel_rows_equal_the_benchmarks_roofline_mirror():
+@pytest.mark.parametrize("route", ["copied", "resident"])
+def test_program_kernel_rows_equal_the_benchmarks_roofline_mirror(
+        monkeypatch, route):
     """``benchmark/roofline.py`` mirrors the rule by which ``_make_half``
     hands a bucket to ``gather_gram``; the program counts by the rule
     itself (``ALSPrepared.kernel_rows``). Held equal on a layout with
-    kernel-width buckets on both sides."""
+    kernel-width buckets on both sides, whichever way the kernel
+    fetches these small tables' lines (by the rule they are resident;
+    ``copied`` moves the rule's constant under them)."""
     from predictionio_tpu.models.als import RatingsCOO, als_prepare
+    from predictionio_tpu.ops import gram
+
+    if route == "copied":
+        monkeypatch.setattr(gram, "_RESIDENT_TABLE_BYTES", 0)
 
     spec = importlib.util.spec_from_file_location(
         "bench_roofline", os.path.join(REPO, "benchmark", "roofline.py"))
@@ -467,7 +477,7 @@ def test_program_kernel_rows_equal_the_benchmarks_roofline_mirror():
                      rng.random(users.size).astype(np.float32),
                      n_users, n_items)
     prep = als_prepare(coo)
-    got = prep.kernel_rows()
+    got = prep.kernel_rows(8)
     need = roofline.gather_gram_need(prep, rank=8, iterations=1)
     assert got["kernel_padded_rows"] > 0, "no kernel-width bucket: no test"
     assert {k: got[k] for k in ("kernel_real_rows", "kernel_padded_rows",
@@ -479,6 +489,13 @@ def test_program_kernel_rows_equal_the_benchmarks_roofline_mirror():
     # fewer than the interactions, never more than the slots
     assert (got["kernel_real_rows"] <= got["kernel_dma_rows"]
             <= got["kernel_padded_rows"])
-    # a wait retires a group of copies (PR 37): at least one a row
-    # that holds anything, far fewer than one a copy
-    assert 0 < got["kernel_dma_waits"] < got["kernel_dma_rows"]
+    if route == "copied":
+        # a wait retires a group of copies (PR 37): at least one a row
+        # that holds anything, far fewer than one a copy
+        assert 0 < got["kernel_dma_waits"] < got["kernel_dma_rows"]
+        assert got["kernel_resident_rows"] == 0
+    else:   # a line read from the table the dispatch holds: no wait
+        assert got["kernel_dma_waits"] == 0
+        assert got["kernel_resident_rows"] == got["kernel_real_rows"]
+    assert (got["gram_table_bytes_u"], got["gram_table_bytes_i"]) == (
+        gram.table_bytes(n_items, 8), gram.table_bytes(n_users, 8))
