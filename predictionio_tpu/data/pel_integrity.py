@@ -3,7 +3,7 @@
 A pure-Python re-implementation of the eventlog on-disk contract (see
 ``native/eventlog.cc``'s header comment — the C++ side is the writer,
 this side only ever reads), plus digest checks for the other two
-persisted artifact classes (snapshot npz + manifest, model blobs +
+persisted artifact classes (snapshot columns + manifest, model blobs +
 sidecars). Deliberately NOT the engine:
 
 - it runs without a compiler (the native engine needs g++ to build;
@@ -18,7 +18,8 @@ Verdicts per artifact: ``ok`` (all checks pass), ``corrupt`` (checksum
 or structural mismatch in the body), ``torn`` (incomplete tail — a
 crash mid-append), ``unchecksummed`` (pre-integrity artifact with no
 digest to verify), ``repaired`` (was torn, tail quarantined and
-truncated under ``--repair``).
+truncated under ``--repair``), ``stale`` (a snapshot file of an older
+schema that no train reads any more: not damage).
 
 Repair policy mirrors what each artifact can afford:
 
@@ -286,40 +287,56 @@ def check_segment_dir(dir_path: str,
     return reports
 
 
-def check_snapshot(npz_path: str, repair: bool = False) -> Dict[str, object]:
-    """Verify one snapshot pair against its manifest digests. Uses
+def check_snapshot(path: str, repair: bool = False) -> Dict[str, object]:
+    """Verify one snapshot pair (``snap_<key>.cols`` + manifest)
+    against its manifest digests. Uses
     ``data/snapshot.load_snapshot``'s own validation (same digest walk
     the training read runs), so fsck can never pass what a train would
-    reject. Under ``repair`` a bad pair is deleted — it is a cache."""
+    reject. Under ``repair`` a bad pair is deleted — it is a cache.
+
+    A ``snap_<key>.npz`` is what a schema-2 tree left behind: ``stale``
+    (no train reads it; the next one of that key rebuilds the snapshot
+    and removes it), never damage. Under ``repair`` it is removed, with
+    its manifest where that is still the older schema's."""
     from predictionio_tpu.data import snapshot as snap
 
-    report: Dict[str, object] = {"path": npz_path, "status": "ok"}
-    directory = os.path.dirname(npz_path)
-    base = os.path.basename(npz_path)
-    # snap_<fingerprint>.npz
-    fingerprint = base[len("snap_"):-len(".npz")]
+    report: Dict[str, object] = {"path": path, "status": "ok"}
+    directory = os.path.dirname(path)
+    fingerprint, ext = os.path.splitext(
+        os.path.basename(path)[len("snap_"):])
     man_path = os.path.join(directory, f"snap_{fingerprint}.json")
-    if not os.path.exists(man_path):
+    try:
+        with open(man_path, "r", encoding="utf-8") as f:
+            manifest = json.load(f)
+    except FileNotFoundError:
+        manifest = None
+    except (OSError, ValueError):
+        manifest = {}
+    doomed = [path, man_path]
+    if ext == ".npz":
+        report["status"] = "stale"
+        report["detail"] = (f"schema-{snap.SCHEMA_VERSION - 1} snapshot "
+                            "of an older tree")
+        if (manifest or {}).get("schema") == snap.SCHEMA_VERSION:
+            doomed = [path]     # the manifest is the rebuilt pair's
+    elif manifest is None:
         report["status"] = "corrupt"
         report["detail"] = "manifest missing"
-    else:
-        try:
-            with open(man_path, "r", encoding="utf-8") as f:
-                digests = json.load(f).get("digests")
-        except (OSError, ValueError):
-            digests = None
-        if not isinstance(digests, dict):
-            report["status"] = "unchecksummed"
-        elif snap.load_snapshot(directory, fingerprint) is None:
-            report["status"] = "corrupt"
-    if repair and report["status"] in ("corrupt", "unchecksummed"):
-        for p in (npz_path, man_path):
+    elif not isinstance(manifest.get("digests"), dict):
+        report["status"] = "unchecksummed"
+    elif snap.load_snapshot(directory, fingerprint) is None:
+        report["status"] = "corrupt"
+    if repair and report["status"] != "ok":
+        for p in doomed:
             try:
                 os.unlink(p)
             except OSError:
                 pass
         fsync_dir(directory)
-        report["status"] = "repaired"
+        if report["status"] == "stale":
+            report["detail"] = f"{report['detail']}; removed"
+        else:
+            report["status"] = "repaired"
     return report
 
 
@@ -573,7 +590,8 @@ def fsck_home(home: str, repair: bool = False) -> Dict[str, object]:
         home, "scan_cache")
     if os.path.isdir(snap_dir):
         for name in sorted(os.listdir(snap_dir)):
-            if name.startswith("snap_") and name.endswith(".npz"):
+            if name.startswith("snap_") and name.endswith((".cols",
+                                                            ".npz")):
                 r = check_snapshot(os.path.join(snap_dir, name),
                                    repair=repair)
                 r["artifact"] = "snapshot"
@@ -615,5 +633,6 @@ def fsck_home(home: str, repair: bool = False) -> Dict[str, object]:
         "repaired": statuses.count("repaired"),
         "unchecksummed": statuses.count("unchecksummed"),
         "cold": statuses.count("cold"),
+        "stale": statuses.count("stale"),
     }
     return report

@@ -132,7 +132,9 @@ def _cached_scan(
     events = st.events
     identity = getattr(events, "cache_identity", None)
     stats_fn = getattr(events, "creation_stats", None)
-    stats = stats_fn(app_id, channel_id) if stats_fn is not None else None
+    with _tracing.span("storage.scan.probe"):
+        stats = (stats_fn(app_id, channel_id) if stats_fn is not None
+                 else None)
     if identity is None or stats is None:
         _SNAP_MISSES.inc(("unsupported",))
         _tracing.add_attrs(scan_cache="miss:unsupported")
@@ -147,21 +149,27 @@ def _cached_scan(
         identity, app_id, channel_id, entity_type, target_entity_type,
         event_names, value_key)
 
-    loaded = _snap.load_snapshot(directory, key)
+    with _tracing.span("storage.scan.load"):
+        loaded = _snap.load_snapshot(directory, key)
     if loaded is not None:
         cols0, man = loaded
-        # count(creation ≤ old watermark) must still equal what the
-        # snapshot saw: a lower count means deletions, a higher one
-        # means events arrived bearing creationTimes inside the
-        # already-covered window — either way the delta can't see them
-        at_w = events.creation_stats(app_id, channel_id,
-                                     until_us=man.watermark_us)
-        if at_w is not None and at_w[0] == man.pre_count:
-            delta = scan(app_id, channel_id, entity_type=entity_type,
-                         target_entity_type=target_entity_type,
-                         event_names=event_names, value_key=value_key,
-                         created_after_us=man.watermark_us,
-                         created_until_us=watermark)
+        with _tracing.span("storage.scan.probe"):
+            # count(creation ≤ old watermark) must still equal what the
+            # snapshot saw: a lower count means deletions, a higher one
+            # means events arrived bearing creationTimes inside the
+            # already-covered window — either way the delta can't see
+            # them
+            at_w = events.creation_stats(app_id, channel_id,
+                                         until_us=man.watermark_us)
+            unchanged = at_w is not None and at_w[0] == man.pre_count
+            delta = None
+            if unchanged:
+                delta = scan(app_id, channel_id, entity_type=entity_type,
+                             target_entity_type=target_entity_type,
+                             event_names=event_names, value_key=value_key,
+                             created_after_us=man.watermark_us,
+                             created_until_us=watermark)
+        if unchanged:
             if delta is not None:
                 if delta.n == 0:
                     _SNAP_HITS.inc()

@@ -1159,13 +1159,16 @@ def cmd_fsck(args: argparse.Namespace) -> None:
                     extra += f" cols={a['cols_status']}"
                 if a.get("detail"):
                     extra += f" ({a['detail']})"
+            elif a["artifact"] == "snapshot" and a.get("detail"):
+                extra = f" ({a['detail']})"
             print(f"[fsck] {a['artifact']:<9} {name}: {a['status']}{extra}")
         for q in report["quarantines"]:
             print(f"[fsck] quarantine sidecar: {q}")
         print(f"[fsck] checked={report['checked']} clean={report['clean']} "
               f"corrupt={report['corrupt']} repaired={report['repaired']} "
               f"unchecksummed={report['unchecksummed']} "
-              f"cold={report.get('cold', 0)}")
+              f"cold={report.get('cold', 0)} "
+              f"stale={report.get('stale', 0)}")
     if report["corrupt"]:
         raise SystemExit(2)
     if report["repaired"]:

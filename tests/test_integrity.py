@@ -439,6 +439,60 @@ def test_fsck_detects_corruption_via_fault_site(tmp_path, monkeypatch):
     assert fsck_home(str(home))["corrupt"] == 1
 
 
+def test_fsck_checks_the_column_file_and_names_a_flipped_byte(
+        tmp_path, monkeypatch):
+    from predictionio_tpu.data.snapshot import save_snapshot
+
+    monkeypatch.delenv("PIO_SCAN_CACHE_DIR", raising=False)
+    home = tmp_path / "home"
+    d = home / "scan_cache"
+    d.mkdir(parents=True)
+    assert save_snapshot(str(d), "fp", _make_cols(), 100, 32)
+    assert save_snapshot(str(d), "other", _make_cols(64), 100, 64)
+    rep = fsck_home(str(home))
+    assert (rep["checked"], rep["clean"], rep["corrupt"]) == (2, 2, 0)
+    assert {os.path.basename(a["path"]): a["status"]
+            for a in rep["artifacts"]} == {"snap_fp.cols": "ok",
+                                           "snap_other.cols": "ok"}
+    cols = d / "snap_fp.cols"
+    raw = bytearray(cols.read_bytes())
+    raw[-1] ^= 0x01                       # the last table's last byte
+    cols.write_bytes(bytes(raw))
+    rep = fsck_home(str(home))
+    assert (rep["clean"], rep["corrupt"]) == (1, 1)
+    (bad,) = [a for a in rep["artifacts"] if a["status"] == "corrupt"]
+    assert bad["artifact"] == "snapshot" and bad["path"] == str(cols)
+
+
+@pytest.mark.parametrize("rebuilt", [False, True])
+def test_fsck_reports_a_schema2_npz_as_stale_not_damage(
+        tmp_path, monkeypatch, rebuilt):
+    """An ``.npz`` an older tree left is no train's input: ``stale``,
+    exit clean; ``--repair`` removes it — and its manifest only while
+    that is still the older schema's."""
+    from predictionio_tpu.data.snapshot import save_snapshot
+
+    monkeypatch.delenv("PIO_SCAN_CACHE_DIR", raising=False)
+    home = tmp_path / "home"
+    d = home / "scan_cache"
+    d.mkdir(parents=True)
+    if rebuilt:     # a v3 pair of the same key, and the npz not yet gone
+        assert save_snapshot(str(d), "fp", _make_cols(), 100, 32)
+    else:
+        (d / "snap_fp.json").write_text(json.dumps(
+            {"schema": 2, "filter": "fp", "digests": {}}))
+    (d / "snap_fp.npz").write_bytes(b"PK\x03\x04 whatever it held")
+    rep = fsck_home(str(home))
+    assert rep["stale"] == 1 and rep["corrupt"] == 0
+    assert rep["clean"] == (1 if rebuilt else 0)
+    rep = fsck_home(str(home), repair=True)
+    assert rep["stale"] == 1 and rep["repaired"] == 0
+    assert not (d / "snap_fp.npz").exists()
+    assert (d / "snap_fp.json").exists() == rebuilt
+    rep = fsck_home(str(home))
+    assert rep["stale"] == 0 and rep["checked"] == (1 if rebuilt else 0)
+
+
 def test_fsck_repairs_corrupt_snapshot_by_deletion(tmp_path, monkeypatch):
     from predictionio_tpu.data.snapshot import save_snapshot
 
@@ -447,7 +501,7 @@ def test_fsck_repairs_corrupt_snapshot_by_deletion(tmp_path, monkeypatch):
     d = home / "scan_cache"
     d.mkdir(parents=True)
     assert save_snapshot(str(d), "fp", _make_cols(), 100, 32)
-    npz = d / "snap_fp.npz"
+    npz = d / "snap_fp.cols"
     raw = bytearray(npz.read_bytes())
     raw[len(raw) // 2] ^= 0xFF
     npz.write_bytes(bytes(raw))

@@ -205,6 +205,202 @@ class TestDiskFormat:
             assert variant != base
 
 
+# -- the column file (schema 3): one buffer, verified where it lies ------------
+
+
+def _counter(c):
+    return c._values.get(("snapshot",), 0.0)
+
+
+def _wide_cols(n=1000):
+    """Every dtype with more than a line of bytes a column."""
+    rng = np.random.default_rng(42)
+    return ColumnarEvents(
+        entity_idx=(np.arange(n) % 37).astype(np.uint32),
+        target_idx=(np.arange(n) % 11).astype(np.uint32),
+        name_idx=(np.arange(n) % 2).astype(np.uint16),
+        values=rng.integers(1, 11, n) / 2.0,
+        times_us=np.arange(n, dtype=np.int64) + 1_700_000_000_000_000,
+        entity_ids=[f"user-{k}" for k in range(37)],
+        target_ids=[f"ü∞{k}" for k in range(11)], names=["rate", "buy"])
+
+
+def _load_traced(d, key):
+    """``load_snapshot`` under a span: what it returned, and the
+    counters it left on the span."""
+    from predictionio_tpu.utils import tracing
+
+    with tracing.verb("snapshot.test"):
+        with tracing.span("storage.scan.load") as sp:
+            got = snap.load_snapshot(d, key)
+        return got, dict(sp.attrs)
+
+
+class TestColumnFile:
+    KEY = "c" * 64
+    SECTIONS = (snap._HEADER, *snap._ARRAY_FIELDS, *snap._TABLE_FIELDS)
+
+    def _saved(self, tmp_path, cols=None):
+        d = str(tmp_path)
+        assert snap.save_snapshot(d, self.KEY, cols or _wide_cols(), 5, 1000)
+        path, _man = snap._paths(d, self.KEY)
+        with open(path, "rb") as f:
+            raw = f.read()
+        return d, path, raw
+
+    def test_columns_are_views_of_one_verified_buffer(self, tmp_path):
+        d, path, raw = self._saved(tmp_path)
+        got, attrs = _load_traced(d, self.KEY)
+        cols, man = got
+        _assert_cols_equal(cols, _wide_cols())
+        assert man.schema == snap.SCHEMA_VERSION == 3
+        buffers = set()
+        for k in snap._ARRAY_FIELDS:
+            a = getattr(cols, k)
+            assert a.dtype == np.dtype(snap._DTYPES[k])
+            assert not a.flags.writeable and not a.flags.owndata
+            assert a.flags.aligned and a.flags.c_contiguous
+            assert a.ctypes.data % 64 == 0
+            buffers.add(id(a.base.obj))
+            with pytest.raises(ValueError):
+                a[0] = 1
+        assert len(buffers) == 1                    # ONE buffer, mapped
+        assert attrs == {"schema": 3, "mapped": 1, "copied_bytes": 0,
+                         "bytes": attrs["bytes"]}
+        # every byte of the file is digested or a gap of zeros
+        n_rows, where = snap._sections(raw)
+        assert n_rows == 1000 and list(where) == list(self.SECTIONS)
+        assert attrs["bytes"] == sum(c * t.itemsize
+                                     for _at, c, t in where.values())
+        assert 0 <= len(raw) - attrs["bytes"] < 64 * len(where)
+        assert all(at % 64 == 0 for at, _c, _t in where.values())
+
+    def test_digests_are_those_of_the_arrays_bytes(self, tmp_path):
+        import hashlib
+
+        d, _path, _raw = self._saved(tmp_path)
+        _cols, man = snap.load_snapshot(d, self.KEY)
+        want = _wide_cols()
+        for k in snap._ARRAY_FIELDS:
+            assert man.digests[k] == hashlib.sha256(
+                getattr(want, k).tobytes()).hexdigest()
+        for k in snap._TABLE_FIELDS:
+            assert man.digests[k] == hashlib.sha256(
+                np.asarray(getattr(want, k), np.str_).tobytes()).hexdigest()
+        assert set(man.digests) == set(self.SECTIONS)
+
+    @pytest.mark.parametrize("section", SECTIONS)
+    def test_flipped_byte_is_a_counted_miss(self, tmp_path, section):
+        from predictionio_tpu.utils.integrity import (INTEGRITY_FAILED,
+                                                      INTEGRITY_VERIFIED)
+
+        d, path, raw = self._saved(tmp_path)
+        at, count, dtype = snap._sections(raw)[1][section]
+        damaged = bytearray(raw)
+        damaged[at + count * dtype.itemsize // 2] ^= 0x01
+        with open(path, "wb") as f:
+            f.write(damaged)
+        failed, verified = (_counter(INTEGRITY_FAILED),
+                            _counter(INTEGRITY_VERIFIED))
+        assert snap.load_snapshot(d, self.KEY) is None
+        assert _counter(INTEGRITY_FAILED) == failed + 1
+        assert _counter(INTEGRITY_VERIFIED) == verified
+        with open(path, "wb") as f:                 # and healed: a hit
+            f.write(raw)
+        assert snap.load_snapshot(d, self.KEY) is not None
+        assert _counter(INTEGRITY_VERIFIED) == verified + 1
+
+    @pytest.mark.parametrize("damage", [
+        "gap", "tail", "in_header", "in_column", "empty", "foreign"])
+    def test_file_not_laid_out_as_saved_is_a_counted_miss(self, tmp_path,
+                                                          damage):
+        from predictionio_tpu.utils.integrity import INTEGRITY_FAILED
+
+        d, path, raw = self._saved(tmp_path)
+        _n, where = snap._sections(raw)
+        head_end = where[snap._HEADER][1]
+        assert head_end % 64, "the header ends on a line: no gap to test"
+        at_values = where["values"][0]
+        bad = {
+            "gap": raw[:head_end] + b"\x01" + raw[head_end + 1:],
+            "tail": raw + b"\x00",
+            "in_header": raw[:head_end // 2],
+            "in_column": raw[:at_values + 100],
+            "empty": b"",
+            "foreign": b"PK\x03\x04" + raw[4:],
+        }[damage]
+        with open(path, "wb") as f:
+            f.write(bad)
+        failed = _counter(INTEGRITY_FAILED)
+        assert snap.load_snapshot(d, self.KEY) is None
+        assert _counter(INTEGRITY_FAILED) == failed + 1
+
+    def test_armed_fault_site_copies_and_trips(self, tmp_path):
+        from predictionio_tpu.utils import faults
+        from predictionio_tpu.utils.integrity import INTEGRITY_FAILED
+
+        d, _path, _raw = self._saved(tmp_path)
+        failed = _counter(INTEGRITY_FAILED)
+        faults.FAULTS.arm("data.corrupt.snapshot", count=1)
+        try:
+            assert snap.load_snapshot(d, self.KEY) is None
+            assert _counter(INTEGRITY_FAILED) == failed + 1
+            assert faults.FAULTS.fired("data.corrupt.snapshot") == 1
+            # armed but spent: the buffer is the mapping again
+            got, attrs = _load_traced(d, self.KEY)
+            assert got is not None and attrs["mapped"] == 1
+        finally:
+            faults.FAULTS.disarm()
+
+    def test_unmappable_file_is_read_once_into_one_buffer(self, tmp_path,
+                                                          monkeypatch):
+        import mmap
+
+        d, _path, raw = self._saved(tmp_path)
+
+        def refused(*a, **kw):
+            raise OSError("no map on this file system")
+
+        monkeypatch.setattr(mmap, "mmap", refused)
+        got, attrs = _load_traced(d, self.KEY)
+        cols, _man = got
+        _assert_cols_equal(cols, _wide_cols())
+        assert attrs["mapped"] == 0 and attrs["copied_bytes"] == len(raw)
+        assert not cols.values.flags.writeable
+        assert {id(getattr(cols, k).base)
+                for k in snap._ARRAY_FIELDS} == {id(cols.values.base)}
+        assert isinstance(cols.values.base, bytes)  # the ONE read
+
+    def test_empty_snapshot_roundtrip(self, tmp_path):
+        empty = ColumnarEvents(
+            entity_idx=np.empty(0, np.uint32),
+            target_idx=np.empty(0, np.uint32),
+            name_idx=np.empty(0, np.uint16),
+            values=np.empty(0, np.float64),
+            times_us=np.empty(0, np.int64),
+            entity_ids=[], target_ids=[], names=[])
+        d = str(tmp_path)
+        assert snap.save_snapshot(d, self.KEY, empty, 1, 0)
+        cols, man = snap.load_snapshot(d, self.KEY)
+        assert cols.n == 0 and man.n_rows == 0
+        assert cols.entity_ids == cols.target_ids == cols.names == []
+
+    def test_columns_of_another_dtype_are_not_saved(self, tmp_path):
+        cols = _wide_cols()
+        cols.entity_idx = cols.entity_idx.astype(np.int64)
+        assert not snap.save_snapshot(str(tmp_path), self.KEY, cols, 1, 1000)
+        assert os.listdir(tmp_path) == []
+
+    def test_strided_columns_are_saved_contiguous(self, tmp_path):
+        cols = _wide_cols(2000)
+        for k in snap._ARRAY_FIELDS:
+            setattr(cols, k, getattr(cols, k)[::2])
+        d, _path, _raw = self._saved(tmp_path, cols)
+        got, _man = snap.load_snapshot(d, self.KEY)
+        _assert_cols_equal(got, cols)
+        assert got.values.flags.c_contiguous
+
+
 # -- concat_columnar ----------------------------------------------------------
 
 
@@ -264,7 +460,7 @@ class TestCachedScan:
         cold = _cached(store)
         assert _misses("cold") == m0 + 1
         _assert_cols_equal(cold, _plain(store))
-        assert any(f.endswith(".npz") for f in os.listdir(cache))
+        assert any(f.endswith(".cols") for f in os.listdir(cache))
         warm = _cached(store)
         assert _hits() == h0 + 1
         _assert_cols_equal(warm, cold)
@@ -289,7 +485,7 @@ class TestCachedScan:
         _assert_cols_equal(a, _plain(store, event_names=["rate"]))
         _assert_cols_equal(b, _plain(store, event_names=["buy"]))
         # two distinct snapshots on disk, and each warm-load stays true
-        assert sum(f.endswith(".npz") for f in os.listdir(cache)) == 2
+        assert sum(f.endswith(".cols") for f in os.listdir(cache)) == 2
         _assert_cols_equal(_cached(store, event_names=["rate"]), a)
         _assert_cols_equal(_cached(store, event_names=["buy"]), b)
 
@@ -311,7 +507,7 @@ class TestCachedScan:
         store.insert_batch([_ev(i) for i in range(12)], APP)
         _cached(store)
         npz = next(str(cache / f) for f in os.listdir(cache)
-                   if f.endswith(".npz"))
+                   if f.endswith(".cols"))
         open(npz, "wb").write(b"not a zipfile")
         m0 = _misses("cold")
         again = _cached(store)
@@ -321,6 +517,61 @@ class TestCachedScan:
         h0 = _hits()
         _cached(store)
         assert _hits() == h0 + 1
+
+    def test_schema2_pair_is_a_cold_miss_rebuilt_as_schema3(self, store,
+                                                             cache):
+        """What an older tree left — ``snap_<key>.npz`` under a schema-2
+        manifest — is never read: cold miss, rebuilt, then a hit."""
+        import hashlib
+
+        store.insert_batch([_ev(i) for i in range(12)], APP)
+        truth = _plain(store)
+        key = snap.filter_fingerprint(
+            store.cache_identity, APP, None, None, None, None, "rating")
+        os.makedirs(cache)
+        arrays = {k: getattr(truth, k) for k in snap._ARRAY_FIELDS}
+        arrays.update((k, np.asarray(getattr(truth, k), np.str_))
+                      for k in snap._TABLE_FIELDS)
+        old = snap.legacy_path(str(cache), key)
+        with open(old, "wb") as f:
+            np.savez(f, **arrays)
+        count, max_c = store.creation_stats(APP, None)
+        with open(cache / f"snap_{key}.json", "w") as f:
+            json.dump({"schema": 2, "filter": key, "watermark_us": max_c,
+                       "pre_count": count, "n_rows": truth.n,
+                       "created_at": 0.0,
+                       "digests": {k: hashlib.sha256(a.tobytes()).hexdigest()
+                                   for k, a in arrays.items()}}, f)
+        assert snap.load_snapshot(str(cache), key) is None
+        m0, h0 = _misses("cold"), _hits()
+        _assert_cols_equal(_cached(store), truth)
+        assert _misses("cold") == m0 + 1 and _hits() == h0
+        assert sorted(os.listdir(cache)) == [f"snap_{key}.cols",
+                                             f"snap_{key}.json"]
+        assert json.load(open(cache / f"snap_{key}.json"))["schema"] == 3
+        _assert_cols_equal(_cached(store), truth)
+        assert _hits() == h0 + 1
+
+    def test_delta_over_mapped_columns_equals_a_full_rescan(self, store,
+                                                            cache):
+        from predictionio_tpu.utils import tracing
+
+        store.insert_batch([_ev(i) for i in range(40)], APP)
+        _cached(store)
+        key = snap.filter_fingerprint(
+            store.cache_identity, APP, None, None, None, None, "rating")
+        path, _man = snap._paths(str(cache), key)
+        on_disk = open(path, "rb").read()
+        store.insert_batch([_ev(i) for i in range(40, 43)], APP)
+        with tracing.verb("snapshot.test"):
+            with tracing.span("storage.scan") as sp:
+                merged = _cached(store)
+        assert sp.attrs["scan_cache"] == "hit:delta"
+        _assert_cols_equal(merged, _plain(store))
+        assert merged.entity_idx.flags.writeable    # a fresh array
+        # 3 rows of 40: no compaction, and the mapped base was not
+        # written through (it could not be: its pages are read-only)
+        assert open(path, "rb").read() == on_disk
 
     def test_delete_invalidates(self, store, cache):
         ids = store.insert_batch([_ev(i) for i in range(15)], APP)

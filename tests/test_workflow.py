@@ -155,6 +155,8 @@ SPAN_PARENTS = {
     "train.init": "train.run",
     "train.read": "train.run",
     "storage.scan": "train.read",
+    "storage.scan.probe": "storage.scan",
+    "storage.scan.load": "storage.scan",
     "train.read.index": "train.read",
     "train.read.arrays": "train.read",
     "train.prepare": "train.run",
@@ -355,6 +357,41 @@ class TestTrainSpans:
         assert not {s["spanId"] for s in tree} & {s["spanId"] for s in first}
         (scan,) = _by_name(tree, "storage.scan")
         assert scan["attrs"]["scan_cache"] == "hit"
+        (load,) = _by_name(tree, "storage.scan.load")
+        assert {"mapped", "bytes", "copied_bytes", "schema"} <= set(
+            load["attrs"])
+
+    def test_benchmark_reads_the_load_span(self, traced_train,
+                                           monkeypatch):
+        """``benchmark/layers/snapshot_load_s.py`` (ISSUE 42) against a
+        real tree; None — never an error — on a program without the
+        span (the parent), and declared for the two ALS cells."""
+        import json
+
+        bench = os.path.join(REPO, "benchmark")
+        monkeypatch.syspath_prepend(bench)
+        spec = importlib.util.spec_from_file_location(
+            "bench_layers_snapshot_load_s",
+            os.path.join(bench, "layers", "snapshot_load_s.py"))
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+        tree = traced_train["tree"]
+        (load,) = _by_name(tree, "storage.scan.load")
+        (scan,) = _by_name(tree, "storage.scan")
+        got = reader.read({"spans": tree})
+        assert got == (load["endNs"] - load["startNs"]) / 1e9
+        assert 0 < got <= (scan["endNs"] - scan["startNs"]) / 1e9
+        older = [s for s in tree if not s["name"].startswith("storage.scan.")]
+        assert reader.read({"spans": older}) is None
+        assert reader.read({"spans": []}) is None
+        with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+            (entry,) = [m for m in json.load(f)["per_layer"]
+                        if m["name"] == "snapshot_load_s"]
+        assert entry == {
+            "name": "snapshot_load_s", "unit": "s", "better": "lower",
+            "source": "program_span", "layer": "training read",
+            "moves": "train_updates_per_s",
+            "workloads": ["als-ml20m-train", "ials-lastfm360k-train"]}
 
     def test_bare_als_train_leaves_the_tree_alone(self, traced_train):
         from predictionio_tpu.models.als import (ALSParams, RatingsCOO,
@@ -379,6 +416,58 @@ class TestTrainSpans:
         assert tree[0]["attrs"]["status"] == "FAILED"
         assert tree[0]["status"] == "error"
         assert not tracing.TRACER.active
+
+
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+def test_train_from_a_snapshot_hit_is_the_cold_trains_bit_for_bit(
+        tmp_path, implicit):
+    """The second train reads its columns as views of the mapped
+    snapshot (ISSUE 42) — verified, not copied — and everything behind
+    the read stays on the path it took from a cold scan: the same
+    index passes, the same layout path, the same factors to the bit."""
+    from predictionio_tpu import native
+
+    variant = dict(VARIANT, algorithms=[{"name": "als", "params": {
+        "rank": 8, "numIterations": 3, "lambda": 0.05,
+        "implicitPrefs": implicit, "alpha": 1.0, "seed": 7}}])
+    trees, models = [], []
+    with scan_storage(tmp_path) as st:
+        seed_ratings(st)
+        for _ in range(2):
+            iid = run_train(FACTORY, variant=variant, storage=st,
+                            use_mesh=False)
+            trees.append(tracing.last_verb("train.run"))
+            models.append(prepare_deploy(instance_id=iid,
+                                         storage=st).models[0])
+    cold, hit = trees
+    assert _by_name(cold, "storage.scan")[0]["attrs"]["scan_cache"] \
+        == "miss:cold"
+    (scan,) = _by_name(hit, "storage.scan")
+    assert scan["attrs"]["scan_cache"] == "hit"
+    (load,) = _by_name(hit, "storage.scan.load")
+    probes = _by_name(hit, "storage.scan.probe")
+    assert len(probes) == 2         # the store's count; the old
+    #                                 watermark's count + the delta scan
+    assert {s["parentId"] for s in (load, *probes)} == {scan["spanId"]}
+    assert probes[0]["endNs"] <= load["startNs"] <= load["endNs"] \
+        <= probes[1]["startNs"]
+    attrs = load["attrs"]
+    assert attrs["mapped"] == 1 and attrs["copied_bytes"] == 0
+    assert attrs["schema"] == 3 and attrs["bytes"] > 0
+    assert not _by_name(cold, "storage.scan.load")[0].get("attrs")
+    order = "native" if native.als_layout_library() is not None else "radix"
+    for tree in trees:
+        (index,) = _by_name(tree, "train.read.index")
+        assert (index["attrs"]["densify_e"], index["attrs"]["densify_t"],
+                index["attrs"]["masked"]) == ("identity", "identity", 0)
+        (prep,) = _by_name(tree, "als.prepare")
+        assert prep["attrs"]["order_path_u"] == order
+        assert prep["attrs"]["order_path_i"] == order
+    a, b = models
+    assert a.U.tobytes() == b.U.tobytes() and a.V.tobytes() == b.V.tobytes()
+    assert list(a.user_ids) == list(b.user_ids)
+    assert list(a.item_ids) == list(b.item_ids)
 
 
 def _ordered(tree):
