@@ -41,14 +41,14 @@ Histories are PACKED into ``seq_len``-slot sequences with segment ids
 that any backbone has (``_mm``, ``_rms``, ``_rope``, ``_swiglu``,
 ``_moe``, ``_chunked_ce``, ``_cast_in_loop``), the train step and the
 verb's spans are :mod:`predictionio_tpu.models.seq_backbone`'s; what is
-THIS block's is here.
+THIS block's is here, and the file ends in its declaration
+(:func:`seq_backbone.build` makes the rest of it).
 """
 
 from __future__ import annotations
 
-import functools
-from dataclasses import dataclass, fields
-from typing import Any, ClassVar, Dict, Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import Any, ClassVar, Dict
 
 import numpy as np
 
@@ -59,18 +59,19 @@ from predictionio_tpu.models.seq_backbone import (  # noqa: F401 — the
     _moe, _path_name, _rms, _rope, _stacked, _swiglu, _swiglu_shapes,
     pack_histories, scope)
 
-#: what the published config may say and this file can honour
-_REQUIRED = {"hidden_act": "silu", "attention_bias": False, "n_group": 1,
-             "topk_group": 1, "topk_method": "noaux_tc",
-             "rope_scaling": None, "tie_word_embeddings": False,
-             "partial_rotary_factor": 1, "model_type": "glm4_moe_lite"}
-#: published keys that size nothing here (a limit, not a shape)
-_UNUSED = ("max_position_embeddings",)
-
 
 @dataclass(frozen=True)
-class GlmConfig:
+class GlmConfig(seq_backbone.ArchitectureConfig):
     model_type: ClassVar[str] = "glm4_moe_lite"
+    #: what the published config may say and this file can honour
+    _REQUIRED: ClassVar[Dict[str, Any]] = {
+        "hidden_act": "silu", "attention_bias": False, "n_group": 1,
+        "topk_group": 1, "topk_method": "noaux_tc", "rope_scaling": None,
+        "tie_word_embeddings": False, "partial_rotary_factor": 1,
+        "model_type": "glm4_moe_lite"}
+    #: published keys that size nothing here (a limit, not a shape)
+    _UNUSED: ClassVar[tuple] = ("max_position_embeddings",)
+    _HELD: ClassVar[str] = "n_routed_experts"
     hidden_size: int = 2048
     intermediate_size: int = 10240
     moe_intermediate_size: int = 1536
@@ -109,38 +110,19 @@ class GlmConfig:
 
     @classmethod
     def from_architecture(cls, arch: Dict[str, Any]) -> "GlmConfig":
-        """The ``architecture`` object of the algorithm's parameters:
-        the published config's keys (and this class's own)."""
-        for key, want in _REQUIRED.items():
-            if key in arch and arch[key] != want:
-                raise ValueError(f"architecture.{key} = {arch[key]!r}: "
-                                 f"only {want!r} is implemented")
+        c = super().from_architecture(arch)
         if arch.get("num_key_value_heads",
                     arch.get("num_attention_heads")) != arch.get(
                         "num_attention_heads"):
             raise ValueError("latent attention has one key per head")
         if arch.get("num_nextn_predict_layers", 1) != 1:
             raise ValueError("exactly one MTP module is implemented")
-        names = {f.name for f in fields(cls)}
-        unknown = set(arch) - cls.known_keys()
-        if unknown:
-            raise ValueError(f"unknown architecture keys {sorted(unknown)}")
-        return cls(**{k: v for k, v in arch.items() if k in names})
+        return c
 
     @classmethod
     def known_keys(cls) -> frozenset:
-        """Every key an ``architecture`` object may hold."""
-        return frozenset({f.name for f in fields(cls)} | set(_REQUIRED)
-                         | set(_UNUSED) | {"num_key_value_heads"})
-
-    @property
-    def router_experts(self) -> int:
-        return self.n_routed_experts * self.ep_size
-
-    @property
-    def held(self) -> Tuple[int, ...]:
-        lo = self.ep_rank * self.n_routed_experts
-        return tuple(range(lo, lo + self.n_routed_experts))
+        # latent attention has no key-value heads; the key may say so
+        return super().known_keys() | {"num_key_value_heads"}
 
     @property
     def n_moe_layers(self) -> int:
@@ -195,10 +177,6 @@ def param_shapes(c: GlmConfig) -> Dict[str, Any]:
     }
 
 
-def n_params(c: GlmConfig) -> int:
-    return seq_backbone.count_params(param_shapes(c))
-
-
 def group_of(name: str) -> str:
     """The parameter group a leaf's gradient norm is recorded under
     (the ``moe`` stack's last slice, the MTP module's block, goes
@@ -233,21 +211,6 @@ def group_squares(grads) -> Dict[str, Any]:
         else:
             add(group, jnp.sum(g))
     return out
-
-
-def init_state(c: GlmConfig, seed: int, with_optimizer: bool = False):
-    """(params, router bias) made ON the device from the seed, by one
-    jitted program: normal(0, init_std) matrices, unit norm gains, zero
-    bias, the PAD row of the embedding zero. ``with_optimizer``: Adam's
-    zeroed state too — (params, opt_state, bias), still one program."""
-    return _init_compiled(c, with_optimizer)(np.uint32(seed % (1 << 32)))
-
-
-@functools.lru_cache(maxsize=4)
-def _init_compiled(c: GlmConfig, with_optimizer: bool):
-    return seq_backbone.init_program(
-        c, param_shapes(c), (c.n_moe_layers + 1, c.router_experts),
-        with_optimizer)
 
 
 # -- the block ----------------------------------------------------------------
@@ -395,78 +358,32 @@ def logits_both(params, bias, batch, c: GlmConfig):
             _head_logits(params, params["mtp"]["final_norm"], xm, c))
 
 
-# -- the train program --------------------------------------------------------
+def _next_logits(params, bias, batch, n, c: GlmConfig):
+    x, _, _ = _stack(params, bias, batch, c, mtp=False)
+    return _head_logits(params, params["final_norm"], x[0, n - 1], c)
+
+
+# -- the declaration ----------------------------------------------------------
 
 
 BATCH_KEYS = ("tokens", "seg", "pos", "tgt1", "tgt2")
 
+BACKBONE = seq_backbone.build(
+    GlmConfig, param_shapes=param_shapes,
+    # the expert layers, then the MTP module's block
+    bias_shape=lambda c: (c.n_moe_layers + 1, c.router_experts),
+    group_squares=group_squares, loss_fn=loss_fn,
+    # a lambda, as the program it makes always was: ``jit__lambda`` is
+    # its name in the persistent compile cache (seq_backbone.build)
+    logits=lambda params, bias, batch, c: logits_both(params, bias, batch, c),
+    next_logits=_next_logits, heads=("loss", "mtp_loss"),
+    batch_keys=BATCH_KEYS)
 
-@functools.lru_cache(maxsize=8)
-def grad_groups(c: GlmConfig) -> Tuple[str, ...]:
-    """The parameter groups, in the order ``group_norms`` records."""
-    return seq_backbone.grad_groups(group_squares, param_shapes(c))
-
-
-@functools.lru_cache(maxsize=8)
-def train_program(c: GlmConfig, epochs: int):
-    """``train(state, data) -> (state, records)``, ``epochs`` passes as
-    ONE compiled program (:func:`seq_backbone.train_program`)."""
-    return seq_backbone.train_program(c, epochs, loss_fn, group_squares,
-                                      grad_groups(c))
-
-
-def glm_train(histories: Sequence[Sequence[int]], c: GlmConfig,
-              epochs: int, lr: float, seed: int,
-              checkpoint_dir: Optional[str] = None,
-              checkpoint_every: int = 1) -> Tuple[Dict, np.ndarray]:
-    """Train on per-user item-id histories
-    (:func:`seq_backbone.train_histories`): the model's arrays on the
-    HOST (``{"params", "bias"}``) and the loss of every step run in
-    this process."""
-    return seq_backbone.train_histories(
-        histories, c, epochs, lr, seed, model_type=c.model_type,
-        init_state=init_state, program=train_program, n_params=n_params(c),
-        groups=grad_groups(c), batch_keys=BATCH_KEYS,
-        checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every)
-
-
-# -- serving ------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=16)
-def _logits_compiled(c: GlmConfig):
-    import jax
-
-    return jax.jit(lambda params, bias, batch: logits_both(
-        params, bias, batch, c))
-
-
-def sequence_logits(model: Dict, batch: Dict[str, np.ndarray],
-                    c: GlmConfig):
-    """Both heads' logits of whole packed sequences, by the program."""
-    return _logits_compiled(c)(
-        model["params"], model["bias"], batch)
-
-
-@functools.lru_cache(maxsize=16)
-def _next_compiled(c: GlmConfig):
-    def last_logits(params, bias, batch, n):
-        x, _, _ = _stack(params, bias, batch, c, mtp=False)
-        return _head_logits(params, params["final_norm"], x[0, n - 1], c)
-
-    return seq_backbone.next_program(last_logits)
-
-
-def next_item_scores(model: Dict, history: Sequence[int],
-                     c: GlmConfig) -> np.ndarray:
-    """Scores over the vocabulary for the item after ``history``
-    (:func:`seq_backbone.next_item_scores`); PAD = -inf."""
-    return seq_backbone.next_item_scores(_next_compiled(c), model, history,
-                                         c)
-
-
-BACKBONE = seq_backbone.Backbone(
-    model_type=GlmConfig.model_type, config=GlmConfig, train=glm_train,
-    sequence_logits=sequence_logits, next_item_scores=next_item_scores,
-    heads=("loss", "mtp_loss"), batch_keys=BATCH_KEYS, init_state=init_state, n_params=n_params,
-    group_squares=group_squares)
+# Built functions under the names this module had, for the benchmark's
+# GLM-only files (not this repo's to edit; ROADMAP D9 points them at the
+# table, and these four go then). Nothing else may read them: tests and
+# the template go through ``BACKBONE``.
+n_params = BACKBONE.n_params        # benchmark/generators/seq_train_jobs.py
+init_state = BACKBONE.init_state    # benchmark/generators/seq_train_jobs.py
+sequence_logits = BACKBONE.sequence_logits  # the same, seq_precision_probe.py
+glm_train = BACKBONE.train          # benchmark/seq_precision_probe.py

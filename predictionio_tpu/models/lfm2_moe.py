@@ -42,14 +42,14 @@ the benchmark's five layers three (conv + dense, attention + experts,
 Precision, packing, the pieces any backbone has, the train step and
 the verb's spans are :mod:`predictionio_tpu.models.seq_backbone`'s.
 Here besides: the convolution's taps and both gate products are
-float32.
+float32. The file ends in the backbone's declaration
+(:func:`seq_backbone.build` makes the rest of it).
 """
 
 from __future__ import annotations
 
-import functools
-from dataclasses import dataclass, fields
-from typing import Any, ClassVar, Dict, List, Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import Any, ClassVar, Dict, List, Tuple
 
 import numpy as np
 
@@ -58,17 +58,19 @@ from predictionio_tpu.models.seq_backbone import (
     _cast_in_loop, _chunked_ce, _dt, _mm, _moe, _path_name, _rms, _rope,
     _stacked, _swiglu, _swiglu_shapes, scope)
 
-#: what the published config may say and this file can honour
-_REQUIRED = {"model_type": "lfm2_moe", "conv_bias": False,
-             "use_expert_bias": True, "tie_word_embeddings": True}
-#: published keys that size nothing here (a limit, not a shape)
-_UNUSED = ("max_position_embeddings",)
 _OPS = ("conv", "full_attention")
 
 
 @dataclass(frozen=True)
-class Lfm2Config:
+class Lfm2Config(seq_backbone.ArchitectureConfig):
     model_type: ClassVar[str] = "lfm2_moe"
+    #: what the published config may say and this file can honour
+    _REQUIRED: ClassVar[Dict[str, Any]] = {
+        "model_type": "lfm2_moe", "conv_bias": False,
+        "use_expert_bias": True, "tie_word_embeddings": True}
+    #: published keys that size nothing here (a limit, not a shape)
+    _UNUSED: ClassVar[tuple] = ("max_position_embeddings",)
+    _HELD: ClassVar[str] = "num_experts"
     hidden_size: int = 2048
     intermediate_size: int = 7168
     moe_intermediate_size: int = 1792
@@ -103,20 +105,7 @@ class Lfm2Config:
 
     @classmethod
     def from_architecture(cls, arch: Dict[str, Any]) -> "Lfm2Config":
-        """The ``architecture`` object of the algorithm's parameters:
-        the published config's keys (and this class's own)."""
-        for key, want in _REQUIRED.items():
-            if key in arch and arch[key] != want:
-                raise ValueError(f"architecture.{key} = {arch[key]!r}: "
-                                 f"only {want!r} is implemented")
-        unknown = set(arch) - cls.known_keys()
-        if unknown:
-            raise ValueError(f"unknown architecture keys {sorted(unknown)}")
-        names = {f.name for f in fields(cls)}
-        kw = {k: v for k, v in arch.items() if k in names}
-        if "layer_types" in kw:
-            kw["layer_types"] = tuple(kw["layer_types"])
-        c = cls(**kw)
+        c = super().from_architecture(arch)
         if len(c.layer_types) != c.num_hidden_layers:
             raise ValueError(f"{len(c.layer_types)} layer_types for "
                              f"{c.num_hidden_layers} layers")
@@ -135,21 +124,6 @@ class Lfm2Config:
                              f"{c.num_hidden_layers} layers leaves no "
                              "expert layer")
         return c
-
-    @classmethod
-    def known_keys(cls) -> frozenset:
-        """Every key an ``architecture`` object may hold."""
-        return frozenset({f.name for f in fields(cls)} | set(_REQUIRED)
-                         | set(_UNUSED))
-
-    @property
-    def router_experts(self) -> int:
-        return self.num_experts * self.ep_size
-
-    @property
-    def held(self) -> Tuple[int, ...]:
-        lo = self.ep_rank * self.num_experts
-        return tuple(range(lo, lo + self.num_experts))
 
     @property
     def head_dim(self) -> int:
@@ -205,10 +179,6 @@ def param_shapes(c: Lfm2Config) -> Dict[str, Any]:
             "final_norm": (c.hidden_size,)}
 
 
-def n_params(c: Lfm2Config) -> int:
-    return seq_backbone.count_params(param_shapes(c))
-
-
 def group_of(name: str) -> str:
     """The parameter group a leaf's gradient norm is recorded under:
     by part, over all the layers that have it."""
@@ -221,20 +191,6 @@ def group_of(name: str) -> str:
 def group_squares(grads) -> Dict[str, Any]:
     """Σ g² per parameter group of a gradient tree."""
     return seq_backbone.squares_by_group(grads, group_of)
-
-
-def init_state(c: Lfm2Config, seed: int, with_optimizer: bool = False):
-    """(params, router bias) made ON the device from the seed, by one
-    jitted program (:func:`seq_backbone.init_program`);
-    ``with_optimizer``: Adam's zeroed state too."""
-    return _init_compiled(c, with_optimizer)(np.uint32(seed % (1 << 32)))
-
-
-@functools.lru_cache(maxsize=4)
-def _init_compiled(c: Lfm2Config, with_optimizer: bool):
-    return seq_backbone.init_program(
-        c, param_shapes(c), (c.n_moe_layers, c.router_experts),
-        with_optimizer)
 
 
 # -- the block ----------------------------------------------------------------
@@ -366,85 +322,31 @@ def loss_fn(params, bias, batch, c: Lfm2Config):
     return ce, {"loss": ce, "moe": stats}
 
 
-# -- the train program --------------------------------------------------------
+def _next_logits(params, bias, batch, n, c: Lfm2Config):
+    x, _ = _stack(params, bias, batch, c)
+    return _head_logits(params, x[0, n - 1], c)
+
+
+# -- the declaration ----------------------------------------------------------
+
+
+def _conv_layers(c: Lfm2Config) -> int:
+    return sum(op == "conv" for op in c.layer_types)
 
 
 BATCH_KEYS = ("tokens", "seg", "pos", "tgt1")
 
+BACKBONE = seq_backbone.build(
+    Lfm2Config, param_shapes=param_shapes,
+    bias_shape=lambda c: (c.n_moe_layers, c.router_experts),
+    group_squares=group_squares, loss_fn=loss_fn,
+    logits=lambda params, bias, batch, c: (_head_logits(
+        params, _stack(params, bias, batch, c)[0], c),),
+    next_logits=_next_logits, heads=("loss",), batch_keys=BATCH_KEYS,
+    pack_attrs=lambda packed, c: {"conv_masked_taps": conv_masked_taps(
+        packed.pos, packed.seg, c.conv_L_cache)},
+    fit_attrs=lambda c: {
+        "conv_layers": _conv_layers(c),
+        "attn_layers": c.num_hidden_layers - _conv_layers(c)})
 
-@functools.lru_cache(maxsize=8)
-def grad_groups(c: Lfm2Config) -> Tuple[str, ...]:
-    """The parameter groups, in the order ``group_norms`` records."""
-    return seq_backbone.grad_groups(group_squares, param_shapes(c))
-
-
-@functools.lru_cache(maxsize=8)
-def train_program(c: Lfm2Config, epochs: int):
-    """``train(state, data) -> (state, records)``, ``epochs`` passes as
-    ONE compiled program (:func:`seq_backbone.train_program`)."""
-    return seq_backbone.train_program(c, epochs, loss_fn, group_squares,
-                                      grad_groups(c))
-
-
-def lfm2_train(histories: Sequence[Sequence[int]], c: Lfm2Config,
-               epochs: int, lr: float, seed: int,
-               checkpoint_dir: Optional[str] = None,
-               checkpoint_every: int = 1) -> Tuple[Dict, np.ndarray]:
-    """Train on per-user item-id histories
-    (:func:`seq_backbone.train_histories`): the model's arrays on the
-    HOST (``{"params", "bias"}``) and the loss of every step run in
-    this process."""
-    conv_layers = sum(op == "conv" for op in c.layer_types)
-    return seq_backbone.train_histories(
-        histories, c, epochs, lr, seed, model_type=c.model_type,
-        init_state=init_state, program=train_program, n_params=n_params(c),
-        groups=grad_groups(c), batch_keys=BATCH_KEYS,
-        pack_attrs=lambda packed: {"conv_masked_taps": conv_masked_taps(
-            packed.pos, packed.seg, c.conv_L_cache)},
-        fit_attrs={"conv_layers": conv_layers,
-                   "attn_layers": c.num_hidden_layers - conv_layers},
-        checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every)
-
-
-# -- serving ------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=16)
-def _logits_compiled(c: Lfm2Config):
-    import jax
-
-    return jax.jit(lambda params, bias, batch: (_head_logits(
-        params, _stack(params, bias, batch, c)[0], c),))
-
-
-def sequence_logits(model: Dict, batch: Dict[str, np.ndarray],
-                    c: Lfm2Config):
-    """The head's float32 logits [B, S, V] of whole packed sequences
-    (a tuple of one: a backbone gives each of its heads'), by the
-    program."""
-    return _logits_compiled(c)(model["params"], model["bias"], batch)
-
-
-@functools.lru_cache(maxsize=16)
-def _next_compiled(c: Lfm2Config):
-    def last_logits(params, bias, batch, n):
-        x, _ = _stack(params, bias, batch, c)
-        return _head_logits(params, x[0, n - 1], c)
-
-    return seq_backbone.next_program(last_logits)
-
-
-def next_item_scores(model: Dict, history: Sequence[int],
-                     c: Lfm2Config) -> np.ndarray:
-    """Scores over the vocabulary for the item after ``history``
-    (:func:`seq_backbone.next_item_scores`: the whole history, one
-    segment, through the same stack); PAD = -inf."""
-    return seq_backbone.next_item_scores(_next_compiled(c), model, history,
-                                         c)
-
-
-BACKBONE = seq_backbone.Backbone(
-    model_type=Lfm2Config.model_type, config=Lfm2Config, train=lfm2_train,
-    sequence_logits=sequence_logits, next_item_scores=next_item_scores,
-    heads=("loss",), batch_keys=BATCH_KEYS, init_state=init_state,
-    n_params=n_params, group_squares=group_squares)
+n_params = BACKBONE.n_params    # benchmark/tests/test_lfm2_layers.py

@@ -51,14 +51,14 @@ attention in the same layer). Precision, packing, the pieces any
 backbone has, the train step and the verb's spans are
 :mod:`predictionio_tpu.models.seq_backbone`'s. The train step's router
 bias is carried as zeros and never moves (``bias_update_rate`` 0): this
-router has none.
+router has none. The file ends in the backbone's declaration
+(:func:`seq_backbone.build` makes the rest of it).
 """
 
 from __future__ import annotations
 
-import functools
-from dataclasses import dataclass, fields
-from typing import Any, ClassVar, Dict, Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import Any, ClassVar, Dict
 
 import numpy as np
 
@@ -67,21 +67,22 @@ from predictionio_tpu.models.seq_backbone import (
     _cast_in_loop, _chunked_ce, _dt, _experts, _mm, _rms, _rope, _route,
     _stacked, _swiglu_shapes, scope)
 
-#: what the published config may say and this file can honour
-_REQUIRED = {"model_type": "sdar_moe", "attention_bias": False,
-             "decoder_sparse_step": 1, "hidden_act": "silu",
-             "mlp_only_layers": [], "norm_topk_prob": True,
-             "rope_scaling": None, "tie_word_embeddings": False,
-             "use_sliding_window": False}
-#: published keys that size nothing here: the dense width no layer has,
-#: a limit, and the window no layer uses
-_UNUSED = ("intermediate_size", "max_position_embeddings",
-           "max_window_layers", "sliding_window")
-
 
 @dataclass(frozen=True)
-class SdarConfig:
+class SdarConfig(seq_backbone.ArchitectureConfig):
     model_type: ClassVar[str] = "sdar_moe"
+    #: what the published config may say and this file can honour
+    _REQUIRED: ClassVar[Dict[str, Any]] = {
+        "model_type": "sdar_moe", "attention_bias": False,
+        "decoder_sparse_step": 1, "hidden_act": "silu",
+        "mlp_only_layers": [], "norm_topk_prob": True, "rope_scaling": None,
+        "tie_word_embeddings": False, "use_sliding_window": False}
+    #: published keys that size nothing here: the dense width no layer
+    #: has, a limit, and the window no layer uses
+    _UNUSED: ClassVar[tuple] = ("intermediate_size",
+                                "max_position_embeddings",
+                                "max_window_layers", "sliding_window")
+    _HELD: ClassVar[str] = "num_experts"
     #: this router has no bias: the step's rule moves it by nothing
     bias_update_rate: ClassVar[float] = 0.0
     hidden_size: int = 2048
@@ -114,17 +115,7 @@ class SdarConfig:
 
     @classmethod
     def from_architecture(cls, arch: Dict[str, Any]) -> "SdarConfig":
-        """The ``architecture`` object of the algorithm's parameters:
-        the published config's keys (and this class's own)."""
-        for key, want in _REQUIRED.items():
-            if key in arch and arch[key] != want:
-                raise ValueError(f"architecture.{key} = {arch[key]!r}: "
-                                 f"only {want!r} is implemented")
-        unknown = set(arch) - cls.known_keys()
-        if unknown:
-            raise ValueError(f"unknown architecture keys {sorted(unknown)}")
-        names = {f.name for f in fields(cls)}
-        c = cls(**{k: v for k, v in arch.items() if k in names})
+        c = super().from_architecture(arch)
         if c.num_attention_heads % c.num_key_value_heads:
             raise ValueError(f"{c.num_attention_heads} query heads over "
                              f"{c.num_key_value_heads} key-value heads")
@@ -137,21 +128,6 @@ class SdarConfig:
         if not 0.0 < c.noise_eps < 1.0:
             raise ValueError(f"noise_eps {c.noise_eps} outside (0, 1)")
         return c
-
-    @classmethod
-    def known_keys(cls) -> frozenset:
-        """Every key an ``architecture`` object may hold."""
-        return frozenset({f.name for f in fields(cls)} | set(_REQUIRED)
-                         | set(_UNUSED))
-
-    @property
-    def router_experts(self) -> int:
-        return self.num_experts * self.ep_size
-
-    @property
-    def held(self) -> Tuple[int, ...]:
-        lo = self.ep_rank * self.num_experts
-        return tuple(range(lo, lo + self.num_experts))
 
     @property
     def mask_id(self) -> int:
@@ -182,10 +158,6 @@ def param_shapes(c: SdarConfig) -> Dict[str, Any]:
             "head": (c.hidden_size, c.vocab_size)}
 
 
-def n_params(c: SdarConfig) -> int:
-    return seq_backbone.count_params(param_shapes(c))
-
-
 def group_of(name: str) -> str:
     """The parameter group a leaf's gradient norm is recorded under:
     by part, over all the layers."""
@@ -198,20 +170,6 @@ def group_of(name: str) -> str:
 def group_squares(grads) -> Dict[str, Any]:
     """Σ g² per parameter group of a gradient tree."""
     return seq_backbone.squares_by_group(grads, group_of)
-
-
-def init_state(c: SdarConfig, seed: int, with_optimizer: bool = False):
-    """(params, the zero router bias) made ON the device from the seed,
-    by one jitted program (:func:`seq_backbone.init_program`);
-    ``with_optimizer``: Adam's zeroed state too."""
-    return _init_compiled(c, with_optimizer)(np.uint32(seed % (1 << 32)))
-
-
-@functools.lru_cache(maxsize=4)
-def _init_compiled(c: SdarConfig, with_optimizer: bool):
-    return seq_backbone.init_program(
-        c, param_shapes(c), (c.num_hidden_layers, c.router_experts),
-        with_optimizer)
 
 
 # -- the block ----------------------------------------------------------------
@@ -327,30 +285,6 @@ def loss_fn(params, bias, batch, c: SdarConfig):
                 "bd_real": real}
 
 
-# -- the train program --------------------------------------------------------
-
-#: what ``sequence_logits`` reads of a batch: both streams' tokens (the
-#: noise is DATA there); ``weight`` is the loss's, for the comparison
-BATCH_KEYS = ("tokens", "seg", "pos", "noised", "weight")
-#: what a train's batches hold beside ``draw``
-TRAIN_KEYS = ("tokens", "seg", "pos")
-
-
-@functools.lru_cache(maxsize=8)
-def grad_groups(c: SdarConfig) -> Tuple[str, ...]:
-    """The parameter groups, in the order ``group_norms`` records."""
-    return seq_backbone.grad_groups(group_squares, param_shapes(c))
-
-
-@functools.lru_cache(maxsize=8)
-def train_program(c: SdarConfig, epochs: int):
-    """``train(state, data) -> (state, records)``, ``epochs`` passes as
-    ONE compiled program (:func:`seq_backbone.train_program`), its loss
-    told the steps taken."""
-    return seq_backbone.train_program(c, epochs, loss_fn, group_squares,
-                                      grad_groups(c), counted=True)
-
-
 def draws(n_sequences: int, seed: int) -> np.ndarray:
     """[sequences, 2] uint32: what keys each packed sequence's noise
     (the seed, its number)."""
@@ -363,7 +297,7 @@ def first_noise(packed, c: SdarConfig, seed: int, step: int = 0):
     sequence of ``packed`` as if it were in that step's batch (``noised``
     [N, S] int32, ``weight`` [N, S] float32), made by the program's own
     noise function — for the comparison with the plain reference, whose
-    noise is its input."""
+    noise is its input (``benchmark/generators/sdar_train_jobs.py``)."""
     import jax
     import jax.numpy as jnp
 
@@ -374,84 +308,52 @@ def first_noise(packed, c: SdarConfig, seed: int, step: int = 0):
                      packed.tokens).astype(np.int32), np.asarray(weight))
 
 
-def sdar_train(histories: Sequence[Sequence[int]], c: SdarConfig,
-               epochs: int, lr: float, seed: int,
-               checkpoint_dir: Optional[str] = None,
-               checkpoint_every: int = 1) -> Tuple[Dict, np.ndarray]:
-    """Train on per-user item-id histories
-    (:func:`seq_backbone.train_histories`): the model's arrays on the
-    HOST (``{"params", "bias"}``) and the loss of every step run in
-    this process."""
-    def pack_attrs(packed):
-        top = int(packed.tokens.max())
-        if top >= c.mask_id:
-            raise ValueError(f"item id {top} is the MASK row {c.mask_id} "
-                             "of the vocabulary or beyond it")
-        return {}
-
-    return seq_backbone.train_histories(
-        histories, c, epochs, lr, seed, model_type=c.model_type,
-        init_state=init_state, program=train_program, n_params=n_params(c),
-        groups=grad_groups(c), batch_keys=TRAIN_KEYS, pack_attrs=pack_attrs,
-        block=c.block_length,
-        draws=lambda packed: {"draw": draws(packed.tokens.shape[0], seed)},
-        checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every)
+def logits(params, bias, batch, c: SdarConfig):
+    """The head's logits at the NOISED stream's rows of whole packed
+    sequences (``batch["noised"]``: that stream's tokens, given)."""
+    S = batch["tokens"].shape[1]
+    x, _ = _stack(params, bias, batch, c)
+    return (_head_logits(params, x[:, S:], c),)
 
 
-# -- serving ------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=16)
-def _logits_compiled(c: SdarConfig):
-    import jax
-
-    def logits(params, bias, batch):
-        S = batch["tokens"].shape[1]
-        x, _ = _stack(params, bias, batch, c)
-        return (_head_logits(params, x[:, S:], c),)
-
-    return jax.jit(logits)
-
-
-def sequence_logits(model: Dict, batch: Dict[str, np.ndarray],
-                    c: SdarConfig):
-    """The head's float32 logits [B, S, V] at the NOISED stream's rows
-    of whole packed sequences (``batch["noised"]``: that stream's
-    tokens, given; a tuple of one: a backbone gives each of its
-    heads'), by the program."""
-    return _logits_compiled(c)(
-        model["params"], model["bias"],
-        {k: batch[k] for k in ("tokens", "seg", "pos", "noised")})
-
-
-@functools.lru_cache(maxsize=16)
-def _next_compiled(c: SdarConfig):
+def _next_logits(params, bias, batch, n, c: SdarConfig):
+    """The history with MASK rows appended to the end of the block
+    after it (:func:`seq_backbone.next_item_scores`), one segment and
+    ONE stream under the clean stream's rule (a row sees the keys up to
+    the end of its block): the logits at the first MASK row."""
     import jax.numpy as jnp
 
-    def mask_logits(params, bias, batch, n):
-        del n
-        x, _ = _stack(params, bias, batch, c)
-        at = jnp.argmax(batch["tokens"][0] == c.mask_id)
-        return _head_logits(params, x[0, at], c)
-
-    return seq_backbone.next_program(mask_logits)
+    del n
+    x, _ = _stack(params, bias, batch, c)
+    at = jnp.argmax(batch["tokens"][0] == c.mask_id)
+    return _head_logits(params, x[0, at], c)
 
 
-def next_item_scores(model: Dict, history: Sequence[int],
-                     c: SdarConfig) -> np.ndarray:
-    """Scores over the vocabulary for the item after ``history``
-    (:func:`seq_backbone.next_item_scores`): its newest ``seq_len −
-    block_length`` items with MASK rows appended to the end of the
-    block after them, one segment and ONE stream under the clean
-    stream's rule (a row sees the keys up to the end of its block), the
-    logits read at the first MASK row; PAD and MASK = -inf."""
-    return seq_backbone.next_item_scores(
-        _next_compiled(c), model, history, c, mask_id=c.mask_id,
-        block=c.block_length)
+def _refuse_mask_items(packed, c: SdarConfig) -> Dict[str, Any]:
+    top = int(packed.tokens.max())
+    if top >= c.mask_id:
+        raise ValueError(f"item id {top} is the MASK row {c.mask_id} "
+                         "of the vocabulary or beyond it")
+    return {}
 
 
-BACKBONE = seq_backbone.Backbone(
-    model_type=SdarConfig.model_type, config=SdarConfig, train=sdar_train,
-    sequence_logits=sequence_logits, next_item_scores=next_item_scores,
-    heads=("loss",), batch_keys=BATCH_KEYS, init_state=init_state,
-    n_params=n_params, group_squares=group_squares)
+# -- the declaration ----------------------------------------------------------
+
+#: what ``sequence_logits`` reads of a batch: both streams' tokens (the
+#: noise is DATA there); ``weight`` is the loss's, for the comparison
+BATCH_KEYS = ("tokens", "seg", "pos", "noised", "weight")
+#: what a train's batches hold beside ``draw``
+TRAIN_KEYS = ("tokens", "seg", "pos")
+
+BACKBONE = seq_backbone.build(
+    SdarConfig, param_shapes=param_shapes,
+    bias_shape=lambda c: (c.num_hidden_layers, c.router_experts),
+    group_squares=group_squares, loss_fn=loss_fn,
+    counted=True,       # the loss folds the steps taken into its noise
+    logits=logits, next_logits=_next_logits, heads=("loss",),
+    batch_keys=BATCH_KEYS, train_keys=TRAIN_KEYS,
+    pack_attrs=_refuse_mask_items,
+    draws=lambda packed, seed: {
+        "draw": draws(packed.tokens.shape[0], seed)})
+
+n_params = BACKBONE.n_params    # benchmark/tests/test_sdar_layers.py

@@ -5,10 +5,16 @@ A backbone is a module of this package that trains a published block
 on PACKED item histories (the item catalog in place of the token
 vocabulary; id 0 is PAD) as one chip's share of an expert-parallel
 job. Each keeps only what is its block's: its config, parameter
-shapes, operators and stack. Everything else is here, once:
+shapes, operators, stack and loss, and ends in ONE declaration of them
+handed to :func:`build`. Everything else is here, once:
 
 - the table: ``architecture["model_type"]`` → :class:`Backbone`
-  (:func:`backbone`);
+  (:func:`backbone`), what the config classes share
+  (:class:`ArchitectureConfig`), and :func:`build`, which makes of a
+  declaration every function the table hands out — the parameter
+  count, the state and its init program, the gradient groups, the
+  train program, the train verb, the logits and the next-item program
+  — each compiled program kept ONCE per (frozen, hashable) config;
 - :func:`pack_histories`: histories → ``seq_len``-slot sequences with
   segment ids, positions that restart with each segment, targets that
   never cross a segment's end; with a ``window``, the pairs it leaves;
@@ -46,7 +52,10 @@ shapes, operators and stack. Everything else is here, once:
   a scan over epochs of a scan over steps; ``counted``: the loss is
   also told how many steps were taken) and :func:`train_histories`
   (the verb's ``seqrec.pack`` / ``.init`` / ``.fit`` / ``.fetch`` spans
-  with their counters, through ``seq_rec.run_epoch_blocks``);
+  with their counters, through ``seq_rec.run_epoch_blocks``; which
+  counters beside the shared ones, which arrays beside ``Packed``'s,
+  are the backbone's declaration, a window, a block length and a MASK
+  id its config's);
 - :func:`next_item_scores`: one history, one segment, through the
   same stack — the item after it read at its last row, or (a backbone
   that fills blocks) at the first of the MASK rows appended to it.
@@ -59,11 +68,13 @@ norms' statistics and the loss in float32.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import importlib
 import math
-from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
-                    Sequence, Tuple)
+from dataclasses import fields
+from typing import (Any, Callable, ClassVar, Dict, List, NamedTuple,
+                    Optional, Sequence, Tuple)
 
 import numpy as np
 
@@ -73,7 +84,8 @@ from predictionio_tpu.ops import moe_dispatch, seq_attention
 
 
 class Backbone(NamedTuple):
-    """What the template and the benchmark ask of a backbone. Which
+    """What the template and the benchmark ask of a backbone, as
+    :func:`build` makes it of the backbone's declaration. Which
     layers have a window or rotary positions, where the router reads,
     what its experts' activation is, which mask it trains under
     (causal, or the block rule over two streams) and which objective
@@ -83,14 +95,16 @@ class Backbone(NamedTuple):
     ``_chunked_ce``."""
     model_type: str
     config: type                   # .from_architecture(arch) -> config
-    #: (histories, config, epochs, lr, seed, checkpoint_dir=) ->
-    #: ({"params", "bias"} on the host, the losses of the steps run)
+    #: (histories, config, epochs, lr, seed, checkpoint_dir=,
+    #: checkpoint_every=) -> ({"params", "bias"} on the host, the losses
+    #: of the steps run): :func:`train_histories`
     train: Callable
     #: (model, batch, config) -> each head's logits [B, S, V] (a
     #: next-item head's at every row; a block-diffusion head's at the
     #: rows of the NOISED stream the batch carries)
     sequence_logits: Callable
-    #: (model, history, config) -> scores over the vocabulary
+    #: (model, history, config) -> scores over the vocabulary:
+    #: :func:`next_item_scores`
     next_item_scores: Callable
     #: per head, the name of its loss in the step's record
     heads: Tuple[str, ...]
@@ -100,6 +114,30 @@ class Backbone(NamedTuple):
     init_state: Callable           # (config, seed, with_optimizer=)
     n_params: Callable             # (config) -> int
     group_squares: Callable        # (gradient tree) -> {group: Σ g²}
+    # -- beside the ten names the benchmark reads: what the tests, the
+    # -- verb and a later serving path reach --------------------------
+    param_shapes: Callable         # (config) -> the parameter tree as shapes
+    #: (params, bias, batch, config) -> (loss, the step's records)
+    loss_fn: Callable
+    #: (config) -> the groups, in the order ``group_norms`` records
+    grad_groups: Callable
+    #: (config, epochs) -> the compiled ``train(state, data)``
+    train_program: Callable
+    #: of ``Packed``, what a TRAIN's batches hold
+    train_keys: Tuple[str, ...]
+    #: (config) -> the compiled programs behind ``sequence_logits`` and
+    #: ``next_item_scores``
+    logits_program: Callable
+    next_program: Callable
+    #: the backbone's own part of the verb's spans: ``fit_attrs(config)``
+    #: on ``seqrec.fit``; ``pack_attrs(packed, config)`` on
+    #: ``seqrec.pack`` (it may refuse the histories); ``draws(packed,
+    #: seed)`` the per-sequence arrays [sequences, …] a train's batches
+    #: hold beside ``train_keys`` (what keys a backbone's noise). Each
+    #: gives a dict, empty where a backbone has none.
+    fit_attrs: Callable
+    pack_attrs: Callable
+    draws: Callable
 
 
 #: ``model_type`` → the module that defines ``BACKBONE``
@@ -118,6 +156,161 @@ def backbone(model_type: Optional[str] = None) -> Backbone:
         raise ValueError(f"architecture.model_type = {model_type!r}: "
                          f"implemented are {sorted(_MODULES)}")
     return importlib.import_module(_MODULES[model_type]).BACKBONE
+
+
+class ArchitectureConfig:
+    """What the backbones' config classes share: METHODS only. Every
+    field is the frozen dataclass's own that derives from this — the
+    config object is pickled, field by field, into a saved model's
+    head. A class names ``_REQUIRED`` (what the published config may
+    say and its file can honour), ``_UNUSED`` (published keys that size
+    nothing there) and ``_HELD`` (its field for the routed experts HELD
+    on this chip; the router is ``ep_size`` times as wide)."""
+    _REQUIRED: ClassVar[Dict[str, Any]]
+    _UNUSED: ClassVar[Tuple[str, ...]]
+    _HELD: ClassVar[str]
+
+    @classmethod
+    def from_architecture(cls, arch: Dict[str, Any]):
+        """The ``architecture`` object of the algorithm's parameters:
+        the published config's keys (and the class's own). A key of
+        ``_REQUIRED`` that says otherwise and a key nobody knows are
+        refused; a list becomes a tuple (the config is hashed). A class
+        adds its OWN checks after."""
+        for key, want in cls._REQUIRED.items():
+            if key in arch and arch[key] != want:
+                raise ValueError(f"architecture.{key} = {arch[key]!r}: "
+                                 f"only {want!r} is implemented")
+        unknown = set(arch) - cls.known_keys()
+        if unknown:
+            raise ValueError(f"unknown architecture keys {sorted(unknown)}")
+        names = {f.name for f in fields(cls)}
+        return cls(**{k: tuple(v) if isinstance(v, list) else v
+                      for k, v in arch.items() if k in names})
+
+    @classmethod
+    def known_keys(cls) -> frozenset:
+        """Every key an ``architecture`` object may hold."""
+        return frozenset({f.name for f in fields(cls)} | set(cls._REQUIRED)
+                         | set(cls._UNUSED))
+
+    @property
+    def router_experts(self) -> int:
+        return getattr(self, self._HELD) * self.ep_size
+
+    @property
+    def held(self) -> Tuple[int, ...]:
+        n = getattr(self, self._HELD)
+        return tuple(range(self.ep_rank * n, (self.ep_rank + 1) * n))
+
+    # what :func:`train_histories` and :func:`next_item_scores` read of
+    # ANY config; the backbone that has one overrides it
+    @property
+    def window(self) -> Optional[int]:
+        """The key window of the window layers; None: no layer has one."""
+        return None
+
+    @property
+    def block_length(self) -> Optional[int]:
+        """Rows of a block of the block rule; None: causal training."""
+        return None
+
+    @property
+    def mask_id(self) -> Optional[int]:
+        """The MASK row of the vocabulary; None: it has none."""
+        return None
+
+
+def build(config: type, *, param_shapes: Callable, bias_shape: Callable,
+          group_squares: Callable, loss_fn: Callable, logits: Callable,
+          next_logits: Callable, heads: Tuple[str, ...],
+          batch_keys: Tuple[str, ...],
+          train_keys: Optional[Tuple[str, ...]] = None,
+          counted: bool = False, fit_attrs: Optional[Callable] = None,
+          pack_attrs: Optional[Callable] = None,
+          draws: Optional[Callable] = None) -> Backbone:
+    """A backbone's DECLARATION → the :class:`Backbone` the table hands
+    out. The declaration is what is the backbone's own: its ``config``
+    class (an :class:`ArchitectureConfig`), ``param_shapes(c)``,
+    ``bias_shape(c)`` (layers with a router, ``c.router_experts``),
+    ``group_squares(grads)``, ``loss_fn(params, bias, batch, c)`` and
+    whether it is ``counted`` (:func:`train_program`), ``logits(params,
+    bias, batch, c)`` → a tuple, one array [B, S, V] a head of ``heads``,
+    ``next_logits(params, bias, batch, n, c)`` → [V] of the item after
+    the ``n`` tokens of a one-segment batch, ``batch_keys`` (and
+    ``train_keys`` where a train's batches hold others), and its part of
+    the verb's spans (``fit_attrs``, ``pack_attrs``, ``draws``:
+    :class:`Backbone`). Everything a caller runs is made HERE, the same
+    for every backbone, and each compiled program is kept once per
+    config — the configs are frozen dataclasses, hashed by value."""
+    def n_params(c) -> int:
+        return count_params(param_shapes(c))
+
+    @functools.lru_cache(maxsize=4)
+    def init_compiled(c, with_optimizer: bool):
+        return init_program(c, param_shapes(c), bias_shape(c),
+                            with_optimizer)
+
+    def init_state(c, seed: int, with_optimizer: bool = False):
+        """(params, router bias) made ON the device from the seed, by
+        one jitted program (:func:`init_program`); ``with_optimizer``:
+        Adam's zeroed state too — (params, opt_state, bias)."""
+        return init_compiled(c, with_optimizer)(np.uint32(seed % (1 << 32)))
+
+    @functools.lru_cache(maxsize=8)
+    def groups_of(c) -> Tuple[str, ...]:
+        return grad_groups(group_squares, param_shapes(c))
+
+    @functools.lru_cache(maxsize=8)
+    def train_compiled(c, epochs: int):
+        return train_program(c, epochs, loss_fn, group_squares,
+                             groups_of(c), counted)
+
+    def train(histories, c, epochs: int, lr: float, seed: int,
+              checkpoint_dir: Optional[str] = None,
+              checkpoint_every: int = 1) -> Tuple[Dict, np.ndarray]:
+        return train_histories(built, histories, c, epochs, lr, seed,
+                               checkpoint_dir, checkpoint_every)
+
+    @functools.lru_cache(maxsize=16)
+    def logits_compiled(c):
+        # named after the DECLARED function (a lambda: ``jit__lambda``):
+        # the name is part of a program's key in the persistent
+        # compilation cache (:func:`program_name`), so renaming one
+        # compiles it once more on every machine that had it
+        import jax
+
+        def program(params, bias, batch):
+            return logits(params, bias, batch, c)
+
+        program.__name__ = logits.__name__
+        return jax.jit(program)
+
+    def sequence_logits(model: Dict, batch: Dict[str, np.ndarray], c):
+        """Each head's float32 logits of whole packed sequences (a
+        tuple, one array a head), by the program."""
+        return logits_compiled(c)(model["params"], model["bias"], batch)
+
+    @functools.lru_cache(maxsize=16)
+    def next_compiled(c):
+        return next_program(lambda params, bias, batch, n: next_logits(
+            params, bias, batch, n, c))
+
+    def scores(model: Dict, history: Sequence[int], c) -> np.ndarray:
+        return next_item_scores(built, model, history, c)
+
+    built = Backbone(
+        model_type=config.model_type, config=config, train=train,
+        sequence_logits=sequence_logits, next_item_scores=scores,
+        heads=heads, batch_keys=batch_keys, init_state=init_state,
+        n_params=n_params, group_squares=group_squares,
+        param_shapes=param_shapes, loss_fn=loss_fn, grad_groups=groups_of,
+        train_program=train_compiled, train_keys=train_keys or batch_keys,
+        logits_program=logits_compiled, next_program=next_compiled,
+        fit_attrs=fit_attrs or (lambda c: {}),
+        pack_attrs=pack_attrs or (lambda packed, c: {}),
+        draws=draws or (lambda packed, seed: {}))
+    return built
 
 
 # -- packing ------------------------------------------------------------------
@@ -672,30 +865,24 @@ def train_program(c, epochs: int, loss_fn, group_squares,
     return jax.jit(train, donate_argnums=(0,))
 
 
-def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
-                    lr: float, seed: int, *, model_type: str, init_state,
-                    program, n_params: int, groups: Tuple[str, ...],
-                    batch_keys: Tuple[str, ...],
-                    pack_attrs: Optional[Callable] = None,
-                    fit_attrs: Optional[Dict[str, Any]] = None,
-                    window: Optional[int] = None,
-                    block: Optional[int] = None,
-                    draws: Optional[Callable] = None,
+def train_histories(backbone: Backbone, histories: Sequence[Sequence[int]],
+                    c, epochs: int, lr: float, seed: int,
                     checkpoint_dir: Optional[str] = None,
                     checkpoint_every: int = 1) -> Tuple[Dict, np.ndarray]:
-    """Train on per-user item-id histories; returns the model's arrays
-    on the HOST (``{"params", "bias"}``) and the loss of every step run
-    in this process. Spans ``seqrec.pack`` / ``.init`` / ``.fit`` /
-    ``.fetch`` land in the verb record (docs/observability.md).
-    ``program(c, n)`` is the backbone's compiled train of ``n`` epochs;
-    ``pack_attrs(packed)`` and ``fit_attrs`` add the backbone's own
-    counters to the two spans; ``window``: the key window of the
-    backbone's window layers, counted on ``seqrec.pack``
-    (``attn_pairs_window``, ``window_bound_tokens``,
-    ``attn_tile_pairs_window``); ``block``: the block length of a
-    backbone that trains under the block rule, counted there too
-    (``bd_*``, ``attn_pairs_bd``, ``attn_tile_pairs_bd``,
-    ``stream_rows``); ``draws(packed)``: further per-sequence arrays
+    """Train ``backbone`` on per-user item-id histories; returns the
+    model's arrays on the HOST (``{"params", "bias"}``) and the loss of
+    every step run in this process. Spans ``seqrec.pack`` / ``.init`` /
+    ``.fit`` / ``.fetch`` land in the verb record
+    (docs/observability.md). ``backbone.train_program(c, n)`` is the
+    compiled train of ``n`` epochs; ``backbone.pack_attrs(packed, c)``
+    and ``backbone.fit_attrs(c)`` add the backbone's own counters to
+    the two spans; ``c.window``: the key window of the backbone's
+    window layers, counted on ``seqrec.pack`` (``attn_pairs_window``,
+    ``window_bound_tokens``, ``attn_tile_pairs_window``);
+    ``c.block_length``: the block length of a backbone that trains
+    under the block rule, counted there too (``bd_*``,
+    ``attn_pairs_bd``, ``attn_tile_pairs_bd``, ``stream_rows``);
+    ``backbone.draws(packed, seed)``: further per-sequence arrays
     [sequences, …] of the batches (what the backbone's noise is keyed
     by). Every ``bd_*`` number of the steps' records is summed onto
     ``seqrec.fit``."""
@@ -707,6 +894,8 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
 
     if c.seq_len % min(c.attn_block, c.seq_len):
         raise ValueError("seq_len must be a multiple of attn_block")
+    n_params, groups = backbone.n_params(c), backbone.grad_groups(c)
+    window, block = c.window, c.block_length
     with tracing.span("seqrec.pack") as sp:
         packed = pack_histories(histories, c.seq_len, c.seqs_per_step, seed,
                                 window, block)
@@ -730,15 +919,16 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
         if block is not None:
             sp.set_attr("attn_tile_pairs_bd", seq_attention.block_tile_pairs(
                 packed.seg, bq, bk, block))
-        for k, v in (pack_attrs(packed) if pack_attrs else {}).items():
+        for k, v in backbone.pack_attrs(packed, c).items():
             sp.set_attr(k, v)
     with tracing.span("seqrec.init") as sp:
         B = c.seqs_per_step
         data = {k: jnp.asarray(getattr(packed, k).reshape(
-            -1, B, packed.tokens.shape[1])) for k in batch_keys}
-        for k, v in (draws(packed) if draws else {}).items():
+            -1, B, packed.tokens.shape[1])) for k in backbone.train_keys}
+        for k, v in backbone.draws(packed, seed).items():
             data[k] = jnp.asarray(v.reshape((-1, B) + v.shape[1:]))
-        params, opt_state, bias = init_state(c, seed, with_optimizer=True)
+        params, opt_state, bias = backbone.init_state(
+            c, seed, with_optimizer=True)
         opt_state.hyperparams["learning_rate"] = jnp.float32(lr)
         state = jax.block_until_ready(
             {"params": params, "opt_state": opt_state, "bias": bias})
@@ -747,9 +937,10 @@ def train_histories(histories: Sequence[Sequence[int]], c, epochs: int,
     steps = packed.tokens.shape[0] // c.seqs_per_step
     with tracing.span("seqrec.fit", steps=steps * epochs,
                       tokens_per_step=c.seqs_per_step * c.seq_len,
-                      backbone=model_type, **(fit_attrs or {})) as sp:
+                      backbone=backbone.model_type,
+                      **backbone.fit_attrs(c)) as sp:
         def run_block(state, n):
-            out, rec = program(c, int(n))(
+            out, rec = backbone.train_program(c, int(n))(
                 (state["params"], state["opt_state"], state["bias"]), data)
             return (dict(zip(("params", "opt_state", "bias"), out)),
                     jax.device_get(rec))
@@ -806,17 +997,17 @@ def next_program(last_logits):
     return jax.jit(score)
 
 
-def next_item_scores(program, model: Dict, history: Sequence[int],
-                     c, mask_id: Optional[int] = None,
-                     block: int = 1) -> np.ndarray:
+def next_item_scores(backbone: Backbone, model: Dict,
+                     history: Sequence[int], c) -> np.ndarray:
     """Scores over the vocabulary for the item after ``history`` (its
     last ``seq_len`` items, right-padded to a power-of-two bucket so
-    that a handful of programs serve every length); PAD = -inf.
-    ``program``: the backbone's :func:`next_program`. With a
-    ``mask_id`` (a backbone that fills blocks of ``block`` items): the
-    newest ``seq_len − block`` items with MASK rows appended up to the
-    end of the block after them — the program reads the FIRST of those
-    rows —, and MASK = -inf too."""
+    that a handful of programs serve every length), by
+    ``backbone.next_program(c)``; PAD = -inf. A config with a
+    ``mask_id`` (a backbone that fills blocks of ``block_length``
+    items): the newest ``seq_len − block_length`` items with MASK rows
+    appended up to the end of the block after them — the program reads
+    the FIRST of those rows —, and MASK = -inf too."""
+    mask_id, block = c.mask_id, c.block_length
     seq = [i for i in history if i > 0][-c.seq_len:]
     if mask_id is not None:
         seq = [i for i in seq if i != mask_id][-(c.seq_len - block):]
@@ -824,7 +1015,7 @@ def next_item_scores(program, model: Dict, history: Sequence[int],
     bucket = min(c.seq_len, max(16, 1 << max(len(seq) - 1, 0).bit_length()))
     tokens = np.zeros(bucket, np.int32)
     tokens[:len(seq)] = seq
-    logits = np.array(program(
+    logits = np.array(backbone.next_program(c)(
         model["params"], model["bias"], tokens, np.int32(max(len(seq), 1))))
     logits[0] = -np.inf
     if mask_id is not None:

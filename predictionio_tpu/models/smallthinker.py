@@ -39,14 +39,14 @@ two (1 × global, 3 × window).
 Precision, packing, the pieces any backbone has, the train step and
 the verb's spans are :mod:`predictionio_tpu.models.seq_backbone`'s.
 The train step's router bias is carried as zeros and never moves
-(``bias_update_rate`` 0): this router has none.
+(``bias_update_rate`` 0): this router has none. The file ends in the backbone's declaration
+(:func:`seq_backbone.build` makes the rest of it).
 """
 
 from __future__ import annotations
 
-import functools
-from dataclasses import dataclass, fields
-from typing import Any, ClassVar, Dict, List, Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import Any, ClassVar, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -55,19 +55,23 @@ from predictionio_tpu.models.seq_backbone import (
     _cast_in_loop, _chunked_ce, _dt, _experts, _mm, _rms, _rope, _route,
     _stacked, _swiglu_shapes, scope)
 
-#: what the published config may say and this file can honour
-_REQUIRED = {"model_type": "smallthinker",
-             "moe_primary_router_apply_softmax": True,
-             "tie_word_embeddings": False, "rope_scaling": None}
-#: published keys that size nothing here: a name, a limit, and a switch
-#: that changes nothing (the softmax over the selected sums to 1)
-_UNUSED = ("model_name", "max_position_embeddings", "norm_topk_prob")
 KINDS = ("global", "window")
 
 
 @dataclass(frozen=True)
-class SmallThinkerConfig:
+class SmallThinkerConfig(seq_backbone.ArchitectureConfig):
     model_type: ClassVar[str] = "smallthinker"
+    #: what the published config may say and this file can honour
+    _REQUIRED: ClassVar[Dict[str, Any]] = {
+        "model_type": "smallthinker",
+        "moe_primary_router_apply_softmax": True,
+        "tie_word_embeddings": False, "rope_scaling": None}
+    #: published keys that size nothing here: a name, a limit, and a
+    #: switch that changes nothing (the softmax over the selected sums
+    #: to 1)
+    _UNUSED: ClassVar[tuple] = ("model_name", "max_position_embeddings",
+                                "norm_topk_prob")
+    _HELD: ClassVar[str] = "moe_num_primary_experts"
     #: this router has no bias: the step's rule moves it by nothing
     bias_update_rate: ClassVar[float] = 0.0
     hidden_size: int = 2560
@@ -103,21 +107,10 @@ class SmallThinkerConfig:
 
     @classmethod
     def from_architecture(cls, arch: Dict[str, Any]) -> "SmallThinkerConfig":
-        """The ``architecture`` object of the algorithm's parameters:
-        the published config's keys (and this class's own)."""
-        for key, want in _REQUIRED.items():
-            if key in arch and arch[key] != want:
-                raise ValueError(f"architecture.{key} = {arch[key]!r}: "
-                                 f"only {want!r} is implemented")
-        unknown = set(arch) - cls.known_keys()
-        if unknown:
-            raise ValueError(f"unknown architecture keys {sorted(unknown)}")
-        names = {f.name for f in fields(cls)}
-        kw = {k: v for k, v in arch.items() if k in names}
-        for key in ("sliding_window_layout", "rope_layout"):
-            if key in kw:
-                kw[key] = tuple(int(v) for v in kw[key])
-        c = cls(**kw)
+        layouts = {key: [int(v) for v in arch[key]]
+                   for key in ("sliding_window_layout", "rope_layout")
+                   if key in arch}
+        c = super().from_architecture(dict(arch, **layouts))
         if len(c.sliding_window_layout) != c.num_hidden_layers:
             raise ValueError(f"{len(c.sliding_window_layout)} entries of "
                              f"sliding_window_layout for "
@@ -135,21 +128,6 @@ class SmallThinkerConfig:
             raise ValueError(f"top-{c.moe_num_active_primary_experts} of a "
                              f"router of {c.router_experts}")
         return c
-
-    @classmethod
-    def known_keys(cls) -> frozenset:
-        """Every key an ``architecture`` object may hold."""
-        return frozenset({f.name for f in fields(cls)} | set(_REQUIRED)
-                         | set(_UNUSED))
-
-    @property
-    def router_experts(self) -> int:
-        return self.moe_num_primary_experts * self.ep_size
-
-    @property
-    def held(self) -> Tuple[int, ...]:
-        lo = self.ep_rank * self.moe_num_primary_experts
-        return tuple(range(lo, lo + self.moe_num_primary_experts))
 
     @property
     def num_experts_per_tok(self) -> int:
@@ -199,10 +177,6 @@ def param_shapes(c: SmallThinkerConfig) -> Dict[str, Any]:
             "head": (c.hidden_size, c.vocab_size)}
 
 
-def n_params(c: SmallThinkerConfig) -> int:
-    return seq_backbone.count_params(param_shapes(c))
-
-
 def group_of(name: str) -> str:
     """The parameter group a leaf's gradient norm is recorded under:
     by part, over all the layers that have it."""
@@ -215,21 +189,6 @@ def group_of(name: str) -> str:
 def group_squares(grads) -> Dict[str, Any]:
     """Σ g² per parameter group of a gradient tree."""
     return seq_backbone.squares_by_group(grads, group_of)
-
-
-def init_state(c: SmallThinkerConfig, seed: int,
-               with_optimizer: bool = False):
-    """(params, the zero router bias) made ON the device from the seed,
-    by one jitted program (:func:`seq_backbone.init_program`);
-    ``with_optimizer``: Adam's zeroed state too."""
-    return _init_compiled(c, with_optimizer)(np.uint32(seed % (1 << 32)))
-
-
-@functools.lru_cache(maxsize=4)
-def _init_compiled(c: SmallThinkerConfig, with_optimizer: bool):
-    return seq_backbone.init_program(
-        c, param_shapes(c), (c.num_hidden_layers, c.router_experts),
-        with_optimizer)
 
 
 # -- the block ----------------------------------------------------------------
@@ -327,86 +286,27 @@ def loss_fn(params, bias, batch, c: SmallThinkerConfig):
     return ce, {"loss": ce, "moe": stats}
 
 
-# -- the train program --------------------------------------------------------
+def _next_logits(params, bias, batch, n, c: SmallThinkerConfig):
+    """The newest ``seq_len`` items, one segment, through the same
+    stack: the window layers see their window there too."""
+    x, _ = _stack(params, bias, batch, c)
+    return _head_logits(params, x[0, n - 1], c)
+
+
+# -- the declaration ----------------------------------------------------------
 
 
 BATCH_KEYS = ("tokens", "seg", "pos", "tgt1")
 
+BACKBONE = seq_backbone.build(
+    SmallThinkerConfig, param_shapes=param_shapes,
+    bias_shape=lambda c: (c.num_hidden_layers, c.router_experts),
+    group_squares=group_squares, loss_fn=loss_fn,
+    logits=lambda params, bias, batch, c: (_head_logits(
+        params, _stack(params, bias, batch, c)[0], c),),
+    next_logits=_next_logits, heads=("loss",), batch_keys=BATCH_KEYS,
+    fit_attrs=lambda c: {
+        "window_layers": sum(c.sliding_window_layout),
+        "global_layers": c.num_hidden_layers - sum(c.sliding_window_layout)})
 
-@functools.lru_cache(maxsize=8)
-def grad_groups(c: SmallThinkerConfig) -> Tuple[str, ...]:
-    """The parameter groups, in the order ``group_norms`` records."""
-    return seq_backbone.grad_groups(group_squares, param_shapes(c))
-
-
-@functools.lru_cache(maxsize=8)
-def train_program(c: SmallThinkerConfig, epochs: int):
-    """``train(state, data) -> (state, records)``, ``epochs`` passes as
-    ONE compiled program (:func:`seq_backbone.train_program`)."""
-    return seq_backbone.train_program(c, epochs, loss_fn, group_squares,
-                                      grad_groups(c))
-
-
-def smallthinker_train(histories: Sequence[Sequence[int]],
-                       c: SmallThinkerConfig, epochs: int, lr: float,
-                       seed: int, checkpoint_dir: Optional[str] = None,
-                       checkpoint_every: int = 1) -> Tuple[Dict, np.ndarray]:
-    """Train on per-user item-id histories
-    (:func:`seq_backbone.train_histories`): the model's arrays on the
-    HOST (``{"params", "bias"}``) and the loss of every step run in
-    this process."""
-    window_layers = sum(c.sliding_window_layout)
-    return seq_backbone.train_histories(
-        histories, c, epochs, lr, seed, model_type=c.model_type,
-        init_state=init_state, program=train_program, n_params=n_params(c),
-        groups=grad_groups(c), batch_keys=BATCH_KEYS,
-        fit_attrs={"window_layers": window_layers,
-                   "global_layers": c.num_hidden_layers - window_layers},
-        window=c.window, checkpoint_dir=checkpoint_dir,
-        checkpoint_every=checkpoint_every)
-
-
-# -- serving ------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=16)
-def _logits_compiled(c: SmallThinkerConfig):
-    import jax
-
-    return jax.jit(lambda params, bias, batch: (_head_logits(
-        params, _stack(params, bias, batch, c)[0], c),))
-
-
-def sequence_logits(model: Dict, batch: Dict[str, np.ndarray],
-                    c: SmallThinkerConfig):
-    """The head's float32 logits [B, S, V] of whole packed sequences
-    (a tuple of one: a backbone gives each of its heads'), by the
-    program."""
-    return _logits_compiled(c)(model["params"], model["bias"], batch)
-
-
-@functools.lru_cache(maxsize=16)
-def _next_compiled(c: SmallThinkerConfig):
-    def last_logits(params, bias, batch, n):
-        x, _ = _stack(params, bias, batch, c)
-        return _head_logits(params, x[0, n - 1], c)
-
-    return seq_backbone.next_program(last_logits)
-
-
-def next_item_scores(model: Dict, history: Sequence[int],
-                     c: SmallThinkerConfig) -> np.ndarray:
-    """Scores over the vocabulary for the item after ``history``
-    (:func:`seq_backbone.next_item_scores`: its newest ``seq_len``
-    items, one segment, through the same stack — the window layers see
-    their window there too); PAD = -inf."""
-    return seq_backbone.next_item_scores(_next_compiled(c), model, history,
-                                         c)
-
-
-BACKBONE = seq_backbone.Backbone(
-    model_type=SmallThinkerConfig.model_type, config=SmallThinkerConfig,
-    train=smallthinker_train, sequence_logits=sequence_logits,
-    next_item_scores=next_item_scores, heads=("loss",),
-    batch_keys=BATCH_KEYS, init_state=init_state, n_params=n_params,
-    group_squares=group_squares)
+n_params = BACKBONE.n_params    # benchmark/tests/test_smallthinker_layers.py

@@ -350,38 +350,6 @@ def test_grouped_query_attention_at_published_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
-def test_lfm2_train_program_at_published_widths(one_chip):
-    """The cell's whole train program — 507.8 M parameters with Adam's
-    state, 16 steps of 8 × 4,096 slots, three scanned bodies (conv +
-    dense, attention + experts, 3 × conv + experts), the tied head —
-    for the described chip: the attention kernels and the grouped
-    products are in it, and it fits the chip's 16 GB."""
-    from predictionio_tpu.models import lfm2_moe as lfm
-    from predictionio_tpu.models import seq_backbone
-    from predictionio_tpu.models.seq_rec import _make_tx
-
-    c = _lfm2_cell_config()
-    assert lfm.n_params(c) == 507_820_160
-    params = jax.tree.map(lambda s: _sds(s, jnp.float32, one_chip),
-                          lfm.param_shapes(c),
-                          is_leaf=seq_backbone._is_shape)
-    opt = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
-                       jax.eval_shape(_make_tx().init, params))
-    bias = _sds((c.n_moe_layers, c.router_experts), jnp.float32, one_chip)
-    data = {k: _sds((16, c.seqs_per_step, c.seq_len), jnp.int32, one_chip)
-            for k in lfm.BATCH_KEYS}
-    compiled = lfm.train_program(c, 1).lower((params, opt, bias),
-                                             data).compile()
-    # forward, recomputation and backward of one attention layer's
-    # three kernels, and of four expert layers' three grouped products
-    assert _ragged_calls(compiled) >= 30
-    mem = compiled.memory_analysis()
-    # the donated state is counted in the arguments AND (updated) in
-    # the temporaries: what the program holds at once is the latter
-    assert mem.argument_size_in_bytes < 6.2e9
-    assert mem.temp_size_in_bytes < 12.5e9
-
-
 # -- the smallthinker backbone at published widths -----------------------------
 
 
@@ -433,39 +401,6 @@ def test_long_sequence_attention_at_published_widths(one_chip, windowed):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
 
 
-def test_smallthinker_train_program_at_published_widths(one_chip):
-    """The cell's whole train program — 370.5 M parameters with Adam's
-    state, 16 steps of 2 × 16,384 slots, two scanned bodies (1 × global,
-    3 × window), the untied head — for the described chip: both kinds
-    of attention layer and the grouped products are in it, and it fits
-    the chip's 16 GB (by 1 GB: the pair buffer is 196,608 rows)."""
-    from predictionio_tpu.models import seq_backbone
-    from predictionio_tpu.models import smallthinker as st
-    from predictionio_tpu.models.seq_rec import _make_tx
-
-    c = _smallthinker_cell_config()
-    assert st.n_params(c) == 370_547_200
-    params = jax.tree.map(lambda s: _sds(s, jnp.float32, one_chip),
-                          st.param_shapes(c),
-                          is_leaf=seq_backbone._is_shape)
-    opt = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
-                       jax.eval_shape(_make_tx().init, params))
-    bias = _sds((c.num_hidden_layers, c.router_experts), jnp.float32,
-                one_chip)
-    data = {k: _sds((16, c.seqs_per_step, c.seq_len), jnp.int32, one_chip)
-            for k in st.BATCH_KEYS}
-    compiled = st.train_program(c, 1).lower((params, opt, bias),
-                                            data).compile()
-    # forward, recomputation and backward of two scanned bodies' three
-    # attention kernels and three grouped products
-    assert _ragged_calls(compiled) >= 30
-    mem = compiled.memory_analysis()
-    # the donated state is counted in the arguments AND (updated) in
-    # the temporaries: what the program holds at once is the latter
-    assert mem.argument_size_in_bytes < 4.6e9
-    assert mem.temp_size_in_bytes < 15.3e9
-
-
 def _sdar_cell_config():
     """The benchmark's ``seqrec-sdar-30b-a3b-ep8`` as the template
     builds it: the configuration's published keys and its job."""
@@ -512,36 +447,63 @@ def test_block_rule_attention_at_published_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
-def test_sdar_train_program_at_published_widths(one_chip):
-    """The cell's whole train program — 456.3 M parameters with Adam's
-    state, 32 steps of one 8,192-slot sequence as two streams, ONE
-    scanned body of four layers, the noise drawn in the step, the
-    untied head on the noised rows — for the described chip, and it
-    fits the chip's 16 GB."""
-    from predictionio_tpu.models import sdar_moe as sd
+# -- the backbones' whole train programs at published widths -------------------
+
+#: cell → (its config, the parameters it pins, steps of a train, the
+#: least grouped products, the most bytes of arguments and temporaries)
+TRAIN_PROGRAMS = {
+    # 507.8 M parameters, 16 steps of 8 × 4,096 slots, three scanned
+    # bodies (conv + dense, attention + experts, 3 × conv + experts),
+    # the tied head: forward, recomputation and backward of one
+    # attention layer's three kernels, and of four expert layers' three
+    # grouped products
+    "lfm2": (_lfm2_cell_config, 507_820_160, 16, 30, 6.2e9, 12.5e9),
+    # 370.5 M parameters, 16 steps of 2 × 16,384 slots, two scanned
+    # bodies (1 × global, 3 × window), the untied head; it fits by 1 GB:
+    # the pair buffer is 196,608 rows
+    "smallthinker": (_smallthinker_cell_config, 370_547_200, 16, 30,
+                     4.6e9, 15.3e9),
+    # 456.3 M parameters, 32 steps of one 8,192-slot sequence as two
+    # streams, ONE scanned body of four layers, the noise drawn in the
+    # step, the untied head on the noised rows
+    "sdar": (_sdar_cell_config, 456_346_624, 32, 15, 5.6e9, 9.6e9),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(TRAIN_PROGRAMS))
+def test_train_program_at_published_widths(one_chip, cell):
+    """A cell's whole train program, as ``seq_backbone.build`` makes it
+    of the backbone's declaration — parameters with Adam's state, the
+    router bias and the batches a train holds, all read from the
+    backbone — for the described chip: the attention kernels and the
+    grouped products are in it, and it fits the chip's 16 GB."""
+    import types
+
     from predictionio_tpu.models import seq_backbone
     from predictionio_tpu.models.seq_rec import _make_tx
 
-    c = _sdar_cell_config()
-    assert sd.n_params(c) == 456_346_624
+    config, n_params, steps, ragged, arguments, temporaries = (
+        TRAIN_PROGRAMS[cell])
+    c = config()
+    b = seq_backbone.backbone(c.model_type)
+    assert b.n_params(c) == n_params
     params = jax.tree.map(lambda s: _sds(s, jnp.float32, one_chip),
-                          sd.param_shapes(c),
-                          is_leaf=seq_backbone._is_shape)
+                          b.param_shapes(c), is_leaf=seq_backbone._is_shape)
     opt = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
                        jax.eval_shape(_make_tx().init, params))
-    bias = _sds((c.num_hidden_layers, c.router_experts), jnp.float32,
-                one_chip)
-    data = {k: _sds((32, c.seqs_per_step, c.seq_len), jnp.int32, one_chip)
-            for k in sd.TRAIN_KEYS}
-    data["draw"] = _sds((32, c.seqs_per_step, 2), jnp.uint32, one_chip)
-    compiled = sd.train_program(c, 1).lower((params, opt, bias),
-                                            data).compile()
-    # forward, recomputation and backward of one scanned body's three
-    # attention kernels and three grouped products
-    assert _ragged_calls(compiled) >= 15
+    bias = _sds(jax.eval_shape(lambda: b.init_state(c, 0))[1].shape,
+                jnp.float32, one_chip)
+    B = c.seqs_per_step
+    data = {k: _sds((steps, B, c.seq_len), jnp.int32, one_chip)
+            for k in b.train_keys}
+    packed = types.SimpleNamespace(tokens=np.zeros((steps * B, 1), np.int32))
+    for k, v in b.draws(packed, 0).items():     # what keys a noise
+        data[k] = _sds((steps, B) + v.shape[1:], v.dtype, one_chip)
+    compiled = b.train_program(c, 1).lower((params, opt, bias),
+                                           data).compile()
+    assert _ragged_calls(compiled) >= ragged
     mem = compiled.memory_analysis()
     # the donated state is counted in the arguments AND (updated) in
     # the temporaries: what the program holds at once is the latter
-    assert mem.argument_size_in_bytes < 5.6e9
-    assert mem.temp_size_in_bytes < 9.6e9
-
+    assert mem.argument_size_in_bytes < arguments
+    assert mem.temp_size_in_bytes < temporaries

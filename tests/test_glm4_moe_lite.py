@@ -52,7 +52,7 @@ def _histories(seed=0, n=12, top=50):
 def _setup(c, seed=3):
     packed = glm.pack_histories(_histories(), c.seq_len, c.seqs_per_step,
                                 seed=1)
-    params, bias = glm.init_state(c, seed)
+    params, bias = glm.BACKBONE.init_state(c, seed)
     # a bias that matters: selection differs from the plain top-k
     bias = jax.tree.map(
         lambda b: 0.05 * jax.random.normal(jax.random.PRNGKey(1), b.shape),
@@ -68,7 +68,7 @@ def _rel(a, b):
 
 def _logits(params, bias, batch, c):
     """The program's two heads, through its own jitted entry point."""
-    return glm.sequence_logits({"params": params, "bias": bias}, batch, c)
+    return glm.BACKBONE.sequence_logits({"params": params, "bias": bias}, batch, c)
 
 
 def _ref_logits(params, bias, batch, c, **kw):
@@ -172,7 +172,7 @@ def test_expert_shares_add_up_to_the_whole_layer():
     of the experts, the shared expert counted once: the sum is the
     uncut reference's output for the whole layer."""
     whole = _config(matmul_dtype="float32")
-    params, bias = glm.init_state(whole, 5)
+    params, bias = glm.BACKBONE.init_state(whole, 5)
     w = jax.tree.map(lambda a: a[0], params["moe"])
     x = jax.random.normal(jax.random.PRNGKey(2), (128, whole.hidden_size))
     valid = jnp.ones(128, bool)
@@ -323,7 +323,7 @@ def test_router_bias_takes_no_gradient_and_moves_by_the_rule(exact):
     # one train step: b moves by γ·sign(mean load − load), every expert
     from predictionio_tpu.models.seq_rec import _make_tx
 
-    program = glm.train_program(c, 1)
+    program = glm.BACKBONE.train_program(c, 1)
     opt = _make_tx().init(exact["params"])
     copy = jax.tree.map(jnp.array, (exact["params"], opt, exact["bias"]))
     data = {k: v[None] for k, v in exact["batch"].items()}
@@ -389,7 +389,7 @@ def test_a_history_reads_the_same_packed_or_alone(head):
     """Segments never attend across users: the logits of a history
     inside a packed sequence are those of the history alone."""
     c = _config(matmul_dtype="float32", seqs_per_step=1)
-    params, bias = glm.init_state(c, 7)
+    params, bias = glm.BACKBONE.init_state(c, 7)
     a, b = _histories(3, n=2)[:2]
     a, b = a[:30], b[:25]
     both = glm.pack_histories([a, b], 64, 1, seed=0)
@@ -492,7 +492,7 @@ def test_a_train_killed_after_an_epoch_resumes_to_the_same_parameters(
 
     c = _config(matmul_dtype="float32", vocab_size=16, init_std=0.02)
     hist = [list((np.arange(14) + u) % 8 + 1) for u in range(20)]
-    straight, losses = glm.glm_train(hist, c, 2, 0.003, 5)
+    straight, losses = glm.BACKBONE.train(hist, c, 2, 0.003, 5)
     steps = len(losses) // 2
 
     saves = []
@@ -507,10 +507,10 @@ def test_a_train_killed_after_an_epoch_resumes_to_the_same_parameters(
     ckdir = str(tmp_path / "ck")
     monkeypatch.setattr(TrainCheckpointer, "save", save_then_die)
     with pytest.raises(KeyboardInterrupt):
-        glm.glm_train(hist, c, 2, 0.003, 5, checkpoint_dir=ckdir)
+        glm.BACKBONE.train(hist, c, 2, 0.003, 5, checkpoint_dir=ckdir)
     monkeypatch.setattr(TrainCheckpointer, "save", real_save)
     assert saves == [1]           # between the blocks, never after the last
-    resumed, rest = glm.glm_train(hist, c, 2, 0.003, 5, checkpoint_dir=ckdir)
+    resumed, rest = glm.BACKBONE.train(hist, c, 2, 0.003, 5, checkpoint_dir=ckdir)
     assert len(rest) == steps     # only the second epoch ran
     assert TrainCheckpointer(ckdir).latest_step() == 1
     for a, b in zip(jax.tree.leaves(straight), jax.tree.leaves(resumed)):

@@ -57,7 +57,7 @@ def _histories(seed=0, n=12, top=50):
 def _setup(c, seed=3):
     packed = seq_backbone.pack_histories(_histories(), c.seq_len,
                                          c.seqs_per_step, seed=1)
-    params, bias = lfm.init_state(c, seed)
+    params, bias = lfm.BACKBONE.init_state(c, seed)
     # a bias that matters: selection differs from the plain top-k
     bias = 0.05 * jax.random.normal(jax.random.PRNGKey(1), bias.shape)
     batch = {k: jnp.asarray(getattr(packed, k)[:c.seqs_per_step])
@@ -71,7 +71,7 @@ def _rel(a, b):
 
 def _logits(params, bias, batch, c):
     """The program's head, through its own jitted entry point."""
-    return lfm.sequence_logits({"params": params, "bias": bias}, batch, c)[0]
+    return lfm.BACKBONE.sequence_logits({"params": params, "bias": bias}, batch, c)[0]
 
 
 def _ref_logits(params, bias, batch, c, **kw):
@@ -128,7 +128,7 @@ def test_parameter_count_of_the_benchmarks_share():
         layer_types=["conv", "full_attention", "conv", "conv", "conv"],
         num_hidden_layers=5, num_dense_layers=1, num_experts=8, ep_size=4,
         vocab_size=16384))
-    assert lfm.n_params(c) == 507_820_160
+    assert lfm.BACKBONE.n_params(c) == 507_820_160
     assert c.held == tuple(range(8)) and c.router_experts == 32
 
 
@@ -160,10 +160,10 @@ def test_every_gradient_leaf_matches_reference(exact, leaf):
 
 
 def test_every_leaf_has_a_group_and_the_groups_are_the_parts():
-    assert lfm.grad_groups(_config()) == (
+    assert lfm.BACKBONE.grad_groups(_config()) == (
         "attn", "conv", "embed", "experts", "ffn", "norms", "router")
     assert {lfm.group_of(leaf) for leaf in _LEAVES} == set(
-        lfm.grad_groups(_config()))
+        lfm.BACKBONE.grad_groups(_config()))
 
 
 def test_the_tied_tables_gradient_is_the_sum_of_its_two_uses(exact):
@@ -236,7 +236,7 @@ def test_four_shares_of_eight_experts_add_up_to_the_whole_layer():
     the whole layer (no shared expert to count once)."""
     whole = _config(matmul_dtype="float32", num_experts=32,
                     num_experts_per_tok=4)
-    params, bias = lfm.init_state(whole, 5)
+    params, bias = lfm.BACKBONE.init_state(whole, 5)
     w = jax.tree.map(lambda a: a[0], params["runs"][1])
     x = jax.random.normal(jax.random.PRNGKey(2), (128, whole.hidden_size))
     valid = jnp.ones(128, bool)
@@ -266,7 +266,7 @@ def test_no_pair_dropped_under_a_skewed_router():
     """A selection bias that sends every token to experts 0 and 1: the
     layer keeps every pair and reports the skew."""
     c = _config(matmul_dtype="float32")
-    params, _ = lfm.init_state(c, 5)
+    params, _ = lfm.BACKBONE.init_state(c, 5)
     w = jax.tree.map(lambda a: a[0], params["runs"][1])
     x = jax.random.normal(jax.random.PRNGKey(3), (128, c.hidden_size))
     bias = jnp.zeros(c.router_experts).at[:2].set(10.0)
@@ -290,7 +290,7 @@ def test_router_bias_takes_no_gradient_and_moves_by_the_rule(exact):
     assert float(jnp.abs(g).max()) == 0.0
     from predictionio_tpu.models.seq_rec import _make_tx
 
-    program = lfm.train_program(c, 1)
+    program = lfm.BACKBONE.train_program(c, 1)
     opt = _make_tx().init(exact["params"])
     copy = jax.tree.map(jnp.array, (exact["params"], opt, exact["bias"]))
     data = {k: v[None] for k, v in exact["batch"].items()}
@@ -336,7 +336,7 @@ def test_a_history_reads_the_same_packed_or_alone(layer_types):
     of the history alone — through both layer kinds, and each alone."""
     c = _config(matmul_dtype="float32", seqs_per_step=1,
                 layer_types=layer_types)
-    params, bias = lfm.init_state(c, 7)
+    params, bias = lfm.BACKBONE.init_state(c, 7)
     both, alone, inside, n = _packed_and_alone(c)
     packed, single = _both_logits(params, bias, c, both, alone)
     np.testing.assert_allclose(packed[inside], single[:n], atol=2e-5)
@@ -347,16 +347,16 @@ def test_a_tap_that_crossed_a_segments_start_would_show(monkeypatch):
     is told it lies deep inside its segment): the first rows of the
     second history now read the first one's last rows, and differ."""
     c = _config(matmul_dtype="float32", seqs_per_step=1)
-    params, bias = lfm.init_state(c, 7)
+    params, bias = lfm.BACKBONE.init_state(c, 7)
     both, alone, inside, n = _packed_and_alone(c)
     mix = lfm._conv_mix
     monkeypatch.setattr(lfm, "_conv_mix", lambda b, cc, u, taps, pos: mix(
         b, cc, u, taps, pos + taps.shape[0]))
-    lfm._logits_compiled.cache_clear()
+    lfm.BACKBONE.logits_program.cache_clear()
     try:
         packed, single = _both_logits(params, bias, c, both, alone)
     finally:
-        lfm._logits_compiled.cache_clear()
+        lfm.BACKBONE.logits_program.cache_clear()
     assert np.abs(packed[inside][:2] - single[:2]).max() > 1e-3
 
 
@@ -455,7 +455,7 @@ def test_train_deploy_predict_returns_the_references_top_items(storage,
     assert (fit["backbone"], fit["conv_layers"], fit["attn_layers"]) == (
         "lfm2_moe", 3, 1)
     assert fit["moe_dropped_pairs"] == 0 and fit["losses_finite"]
-    assert set(fit["grad_norms_first"]) == set(lfm.grad_groups(_config()))
+    assert set(fit["grad_norms_first"]) == set(lfm.BACKBONE.grad_groups(_config()))
     assert not any(k.startswith("mtp") for k in fit)
     deployed = prepare_deploy(engine_factory=FACTORY, storage=storage,
                               instance_id=iid)
@@ -507,7 +507,7 @@ def test_a_saved_model_names_its_backbone_and_an_older_one_is_glm(
                                 n_routed_experts=2, num_experts_per_tok=2,
                                 num_hidden_layers=2,
                                 vocab_size=16), "glm4_moe_lite")):
-        params, bias = module.init_state(config, 1)
+        params, bias = module.BACKBONE.init_state(config, 1)
         model = eng.SeqRecModel(
             jax.device_get({"params": params, "bias": bias}),
             BiMap.string_int(f"i{i}" for i in range(8)), "LfmApp", config,
@@ -531,7 +531,7 @@ def test_a_train_killed_after_an_epoch_resumes_to_the_same_parameters(
 
     c = _config(matmul_dtype="float32", vocab_size=16, init_std=0.02)
     hist = [list((np.arange(14) + u) % 8 + 1) for u in range(20)]
-    straight, losses = lfm.lfm2_train(hist, c, 2, 0.003, 5)
+    straight, losses = lfm.BACKBONE.train(hist, c, 2, 0.003, 5)
     steps = len(losses) // 2
 
     saves = []
@@ -546,10 +546,10 @@ def test_a_train_killed_after_an_epoch_resumes_to_the_same_parameters(
     ckdir = str(tmp_path / "ck")
     monkeypatch.setattr(TrainCheckpointer, "save", save_then_die)
     with pytest.raises(KeyboardInterrupt):
-        lfm.lfm2_train(hist, c, 2, 0.003, 5, checkpoint_dir=ckdir)
+        lfm.BACKBONE.train(hist, c, 2, 0.003, 5, checkpoint_dir=ckdir)
     monkeypatch.setattr(TrainCheckpointer, "save", real_save)
     assert saves == [1]           # between the blocks, never after the last
-    resumed, rest = lfm.lfm2_train(hist, c, 2, 0.003, 5,
+    resumed, rest = lfm.BACKBONE.train(hist, c, 2, 0.003, 5,
                                    checkpoint_dir=ckdir)
     assert len(rest) == steps     # only the second epoch ran
     assert TrainCheckpointer(ckdir).latest_step() == 1

@@ -67,7 +67,7 @@ def _setup(c, seed=3, step=0):
     packed = seq_backbone.pack_histories(_histories(), c.seq_len,
                                          c.seqs_per_step, seed=4,
                                          block=c.block_length)
-    params, bias = sd.init_state(c, seed)
+    params, bias = sd.BACKBONE.init_state(c, seed)
     B = c.seqs_per_step
     noised, weight = sd.first_noise(packed, c, SEED, step)
     train = {k: jnp.asarray(getattr(packed, k)[:B]) for k in sd.TRAIN_KEYS}
@@ -86,7 +86,7 @@ def _rel(a, b):
 def _logits(params, bias, batch, c):
     """The program's head on the noised stream, through its own jitted
     entry point."""
-    return sd.sequence_logits({"params": params, "bias": bias}, batch, c)[0]
+    return sd.BACKBONE.sequence_logits({"params": params, "bias": bias}, batch, c)[0]
 
 
 def _ref_logits(params, bias, batch, c, **kw):
@@ -147,7 +147,7 @@ def test_parameter_count_of_the_benchmarks_share():
     layer = (2 * d * 4096 + 2 * d * 512) + (2 * d + 2 * 128) + d * 128 \
         + 16 * 3 * d * f
     assert layer == 94_638_336
-    assert sd.n_params(c) == 4 * layer + 2 * 18992 * d + d == 456_346_624
+    assert sd.BACKBONE.n_params(c) == 4 * layer + 2 * 18992 * d + d == 456_346_624
     assert (c.router_experts, c.held, c.mask_id) == (
         128, tuple(range(16)), 18991)
 
@@ -174,7 +174,7 @@ def test_loss_matches_reference_on_the_steps_own_masks(exact):
 def test_group_gradient_norms_match_reference(exact):
     got = jax.jit(sd.group_squares)(exact["grads"])
     want = jax.jit(sd.group_squares)(exact["rgrads"])
-    assert set(got) == set(sd.grad_groups(exact["c"]))
+    assert set(got) == set(sd.BACKBONE.grad_groups(exact["c"]))
     for g in got:
         assert abs(float(got[g]) ** 0.5 / float(want[g]) ** 0.5 - 1) < 2e-5, g
 
@@ -195,7 +195,7 @@ def test_every_gradient_leaf_matches_reference(exact, leaf):
 def test_every_leaf_has_a_group_and_the_groups_are_the_parts(exact):
     groups = {sd.group_of(n) for n in _LEAVES}
     assert groups == {"attn", "embed", "experts", "head", "norms", "router"}
-    assert sd.grad_groups(exact["c"]) == tuple(sorted(groups))
+    assert sd.BACKBONE.grad_groups(exact["c"]) == tuple(sorted(groups))
 
 
 def test_the_loss_is_the_weighted_ce_of_masked_rows_at_their_own_items(
@@ -394,7 +394,7 @@ def test_gates_are_the_softmax_over_all_renormalised_over_the_top_8():
 
 
 def _one_layer(c, seed=5):
-    params, _ = sd.init_state(c, seed)
+    params, _ = sd.BACKBONE.init_state(c, seed)
     w = jax.tree.map(lambda a: a[0], params["layers"])
     packed = seq_backbone.pack_histories(_histories(), c.seq_len, 1, seed=1)
     rng = np.random.default_rng(seed)
@@ -497,7 +497,7 @@ def test_a_history_reads_the_same_packed_or_alone():
     stream: the noised stream's logits of a history packed between
     others equal those of the history alone in a sequence."""
     c = _config(matmul_dtype="float32", seqs_per_step=1)
-    params, bias = sd.init_state(c, 3)
+    params, bias = sd.BACKBONE.init_state(c, 3)
     rng = np.random.default_rng(2)
     hist = [rng.integers(1, 49, n) for n in (9, 22, 14)]
     packed = seq_backbone.pack_histories(hist, c.seq_len, seed=0)
@@ -559,7 +559,7 @@ def test_the_table_names_the_backbone():
 def test_an_item_on_the_mask_row_is_refused():
     c = _config()
     with pytest.raises(ValueError, match="MASK row 49"):
-        sd.sdar_train([[1, 2, 49, 3]], c, 1, 1e-3, 0)
+        sd.BACKBONE.train([[1, 2, 49, 3]], c, 1, 1e-3, 0)
 
 
 # -- 6. through the template -------------------------------------------------
@@ -626,7 +626,7 @@ def test_train_deploy_predict_returns_the_references_top_items(storage,
     assert 0 < fit["bd_masked"] < fit["bd_real"]
     # both streams' rows, top-3, in each of the two layers
     assert fit["moe_pairs"] == 2 * fit["bd_real"] * 3 * 2
-    assert set(fit["grad_norms_first"]) == set(sd.grad_groups(_config()))
+    assert set(fit["grad_norms_first"]) == set(sd.BACKBONE.grad_groups(_config()))
     deployed = prepare_deploy(engine_factory=FACTORY, storage=storage,
                               instance_id=iid)
     model = deployed.models[0]
@@ -659,7 +659,7 @@ def test_train_deploy_predict_returns_the_references_top_items(storage,
         assert [s["item"] for s in got] == [inv[int(i)] for i in top]
         np.testing.assert_allclose([s["score"] for s in got], scores[top],
                                    rtol=1e-4, atol=1e-5)
-    raw = sd.next_item_scores(model.device_params(), [1, 2, 3, 4, 5],
+    raw = sd.BACKBONE.next_item_scores(model.device_params(), [1, 2, 3, 4, 5],
                               model.hp)
     assert raw[0] == -np.inf and raw[mask] == -np.inf
     assert np.isfinite(raw[1:mask]).all()
@@ -671,7 +671,7 @@ def test_a_train_killed_after_an_epoch_resumes_with_the_noise_it_would_have_had(
 
     c = _config(matmul_dtype="float32", vocab_size=16, init_std=0.02)
     hist = [list((np.arange(30) + u) % 8 + 1) for u in range(20)]
-    straight, losses = sd.sdar_train(hist, c, 2, 0.003, 5)
+    straight, losses = sd.BACKBONE.train(hist, c, 2, 0.003, 5)
     steps = len(losses) // 2
     # the second epoch drew other masks than the first: same data, same
     # weights would else give losses that only the updates separate
@@ -689,10 +689,10 @@ def test_a_train_killed_after_an_epoch_resumes_with_the_noise_it_would_have_had(
     ckdir = str(tmp_path / "ck")
     monkeypatch.setattr(TrainCheckpointer, "save", save_then_die)
     with pytest.raises(KeyboardInterrupt):
-        sd.sdar_train(hist, c, 2, 0.003, 5, checkpoint_dir=ckdir)
+        sd.BACKBONE.train(hist, c, 2, 0.003, 5, checkpoint_dir=ckdir)
     monkeypatch.setattr(TrainCheckpointer, "save", real_save)
     assert saves == [1]           # between the blocks, never after the last
-    resumed, rest = sd.sdar_train(hist, c, 2, 0.003, 5,
+    resumed, rest = sd.BACKBONE.train(hist, c, 2, 0.003, 5,
                                   checkpoint_dir=ckdir)
     assert len(rest) == steps     # only the second epoch ran
     np.testing.assert_allclose(rest, losses[steps:], rtol=1e-5, atol=1e-6)

@@ -230,7 +230,7 @@ def test_tiles_must_divide_the_sequence():
 
 
 def test_the_model_counts_its_tiles():
-    """``glm_train`` records the pairs inside the tiles its attention
+    """The GLM backbone's train records the pairs inside the tiles its attention
     visits beside the real ones, by the tiles ``_attention`` uses."""
     from predictionio_tpu.utils import tracing
 
@@ -244,7 +244,7 @@ def test_the_model_counts_its_tiles():
     rng = np.random.default_rng(2)
     hist = [rng.integers(1, 40, n) for n in (30, 20, 9, 5)]
     with tracing.verb("train.run"):
-        glm.glm_train(hist, c, 1, 1e-3, 0)
+        glm.BACKBONE.train(hist, c, 1, 1e-3, 0)
     attrs = next(s["attrs"] for s in tracing.last_verb("train.run")
                  if s["name"] == "seqrec.pack")
     assert glm._attn_tiles(c, 64) == (16, 64)

@@ -2,10 +2,13 @@
 which names a lowered program carries and where, and that the
 program's identity in JAX's persistent compilation cache changes with
 them — the cache strips debug info before it hashes a module, and a
-scope lives only there.
+scope lives only there. And the seam the backbones share (PR 43): what
+``seq_backbone.build`` makes of a declaration, once, for each entry of
+the table.
 """
 
 import contextlib
+import dataclasses
 import os
 import re
 
@@ -71,20 +74,21 @@ def _abstract_args(module, c):
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype)
 
-    params = jax.tree.map(sds, module.param_shapes(c),
+    backbone = module.BACKBONE
+    params = jax.tree.map(sds, backbone.param_shapes(c),
                           is_leaf=seq_backbone._is_shape)
-    _, bias = jax.eval_shape(lambda: module.init_state(c, 0))
+    _, bias = jax.eval_shape(lambda: backbone.init_state(c, 0))
     data = {k: sds((2, c.seqs_per_step, c.seq_len), jnp.int32)
-            for k in getattr(module, "TRAIN_KEYS", module.BATCH_KEYS)}
+            for k in backbone.train_keys}
     if hasattr(module, "draws"):       # what keys the backbone's noise
         data["draw"] = sds((2, c.seqs_per_step, 2), jnp.uint32)
     return (params, jax.eval_shape(_make_tx().init, params), bias), data
 
 
 def _program(module, c):
-    """The backbone's train program, traced anew (``module.train_program``
-    keeps one per config)."""
-    return module.train_program.__wrapped__(c, 1)
+    """The backbone's train program, traced anew (the built
+    ``train_program`` keeps one per config)."""
+    return module.BACKBONE.train_program.__wrapped__(c, 1)
 
 
 @contextlib.contextmanager
@@ -260,3 +264,51 @@ def test_under_one_name_the_cache_answers_with_the_old_scopes(tmp_path,
         assert "seqrec.stack" not in parent
         assert "seqrec.stack" not in stale
         assert _train_entries(tmp_path) == ["jit_train"]
+
+
+# -- (c) the seam: a backbone declares, ``build`` makes the rest --------------
+
+#: what the benchmark reads of a backbone, by name
+TEN_FIELDS = ("model_type", "config", "train", "sequence_logits",
+              "next_item_scores", "heads", "batch_keys", "init_state",
+              "n_params", "group_squares")
+#: what ``build`` makes; a backbone module that defines one of them has
+#: started the fifth copy
+BUILT = ("n_params", "init_state", "_init_compiled", "grad_groups",
+         "train_program", "_logits_compiled", "sequence_logits",
+         "_next_compiled", "next_item_scores", "glm_train", "lfm2_train",
+         "smallthinker_train", "sdar_train")
+#: the bindings ``benchmark/`` reads by these names (Tentpole 4 of
+#: ISSUE 43; they go with ROADMAP D9): each IS the built function
+BINDINGS = {"glm4_moe_lite": {"n_params": "n_params",
+                              "init_state": "init_state",
+                              "sequence_logits": "sequence_logits",
+                              "glm_train": "train"},
+            "lfm2_moe": {"n_params": "n_params"},
+            "smallthinker": {"n_params": "n_params"},
+            "sdar_moe": {"n_params": "n_params"}}
+
+
+@pytest.mark.parametrize("model_type", sorted(seq_backbone._MODULES))
+def test_a_backbone_is_its_declaration_built_once(model_type):
+    assert set(BACKBONES) == set(seq_backbone._MODULES)
+    module, c = BACKBONES[model_type]
+    b = seq_backbone.backbone(model_type)
+    assert b is module.BACKBONE
+    assert b._fields[:10] == TEN_FIELDS
+    assert (b.model_type, b.config) == (model_type, type(c))
+    assert isinstance(c, seq_backbone.ArchitectureConfig)
+    # an EQUAL config (another object) is answered by the same programs
+    again = dataclasses.replace(c)
+    assert again == c and again is not c
+    assert b.train_program(c, 1) is b.train_program(again, 1)
+    assert b.train_program(c, 1) is not b.train_program(c, 2)
+    assert b.logits_program(c) is b.logits_program(again)
+    assert b.next_program(c) is b.next_program(again)
+    assert b.grad_groups(c) is b.grad_groups(again)
+    assert b.n_params(c) == seq_backbone.count_params(b.param_shapes(c))
+    # the module wrote none of it itself
+    own = {name for name in BUILT if name in vars(module)}
+    assert own == set(BINDINGS[model_type])
+    for name, field in BINDINGS[model_type].items():
+        assert getattr(module, name) is getattr(b, field)

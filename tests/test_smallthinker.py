@@ -64,7 +64,7 @@ def _setup(c, seed=3):
     packed = seq_backbone.pack_histories(_histories(), c.seq_len,
                                          c.seqs_per_step, seed=1,
                                          window=c.window)
-    params, bias = st.init_state(c, seed)
+    params, bias = st.BACKBONE.init_state(c, seed)
     batch = {k: jnp.asarray(getattr(packed, k)[:c.seqs_per_step])
              for k in st.BATCH_KEYS}
     return packed, params, bias, batch
@@ -76,7 +76,7 @@ def _rel(a, b):
 
 def _logits(params, bias, batch, c):
     """The program's head, through its own jitted entry point."""
-    return st.sequence_logits({"params": params, "bias": bias}, batch, c)[0]
+    return st.BACKBONE.sequence_logits({"params": params, "bias": bias}, batch, c)[0]
 
 
 def _ref_logits(params, bias, batch, c, **kw):
@@ -132,7 +132,7 @@ def test_parameter_count_of_the_benchmarks_share():
     """ISSUE 38's arithmetic: 370,547,200 parameters."""
     c = st.SmallThinkerConfig.from_architecture(dict(
         moe_num_primary_experts=8, ep_size=8, vocab_size=18992))
-    assert st.n_params(c) == 370_547_200
+    assert st.BACKBONE.n_params(c) == 370_547_200
     assert c.held == tuple(range(8)) and c.router_experts == 64
     assert (c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
             c.head_dim, c.moe_ffn_hidden_size, c.num_experts_per_tok,
@@ -168,7 +168,7 @@ def test_every_gradient_leaf_matches_reference(exact, leaf):
 
 
 def test_every_leaf_has_a_group_and_the_groups_are_the_parts(exact):
-    groups = st.grad_groups(_config())
+    groups = st.BACKBONE.grad_groups(_config())
     assert groups == ("attn", "embed", "experts", "head", "norms", "router")
     assert {st.group_of(leaf) for leaf in _LEAVES} == set(groups)
     got = jax.jit(st.group_squares)(exact["grads"])
@@ -223,7 +223,7 @@ def test_lower_precision_fails(stated):
 
 
 def _one_layer(c, seed=5):
-    params, _ = st.init_state(c, seed)
+    params, _ = st.BACKBONE.init_state(c, seed)
     w = jax.tree.map(lambda a: a[0], params["runs"][1])     # a window layer
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.normal(size=(1, 64, c.hidden_size)), jnp.float32)
@@ -400,7 +400,7 @@ def test_the_step_has_no_router_bias_to_move(exact):
     c = exact["c"]
     assert c.bias_update_rate == 0.0
     assert not np.asarray(exact["bias"]).any()
-    program = st.train_program(c, 1)
+    program = st.BACKBONE.train_program(c, 1)
     opt = _make_tx().init(exact["params"])
     copy = jax.tree.map(jnp.array, (exact["params"], opt, exact["bias"]))
     data = {k: v[None] for k, v in exact["batch"].items()}
@@ -457,7 +457,7 @@ def test_a_history_reads_the_same_packed_or_alone():
     """Neither kind of layer crosses a segment's start: the logits of a
     history inside a packed sequence are those of the history alone."""
     c = _config(matmul_dtype="float32", seqs_per_step=1)
-    params, bias = st.init_state(c, 7)
+    params, bias = st.BACKBONE.init_state(c, 7)
     a, b = _histories(3, n=2)[:2]
     a, b = a[:30], b[:29]
     both = seq_backbone.pack_histories([a, b], 64, 1, seed=0)
@@ -564,7 +564,7 @@ def test_train_deploy_predict_returns_the_references_top_items(storage,
         "smallthinker", 3, 1)
     assert fit["moe_dropped_pairs"] == 0 and fit["losses_finite"]
     assert fit["router_bias_absmax"] == 0.0
-    assert set(fit["grad_norms_first"]) == set(st.grad_groups(_config()))
+    assert set(fit["grad_norms_first"]) == set(st.BACKBONE.grad_groups(_config()))
     deployed = prepare_deploy(engine_factory=FACTORY, storage=storage,
                               instance_id=iid)
     model = deployed.models[0]
@@ -597,7 +597,7 @@ def test_a_train_killed_after_an_epoch_resumes_to_the_same_loss(
 
     c = _config(matmul_dtype="float32", vocab_size=16, init_std=0.02)
     hist = [list((np.arange(30) + u) % 8 + 1) for u in range(20)]
-    straight, losses = st.smallthinker_train(hist, c, 2, 0.003, 5)
+    straight, losses = st.BACKBONE.train(hist, c, 2, 0.003, 5)
     steps = len(losses) // 2
 
     saves = []
@@ -612,10 +612,10 @@ def test_a_train_killed_after_an_epoch_resumes_to_the_same_loss(
     ckdir = str(tmp_path / "ck")
     monkeypatch.setattr(TrainCheckpointer, "save", save_then_die)
     with pytest.raises(KeyboardInterrupt):
-        st.smallthinker_train(hist, c, 2, 0.003, 5, checkpoint_dir=ckdir)
+        st.BACKBONE.train(hist, c, 2, 0.003, 5, checkpoint_dir=ckdir)
     monkeypatch.setattr(TrainCheckpointer, "save", real_save)
     assert saves == [1]           # between the blocks, never after the last
-    resumed, rest = st.smallthinker_train(hist, c, 2, 0.003, 5,
+    resumed, rest = st.BACKBONE.train(hist, c, 2, 0.003, 5,
                                           checkpoint_dir=ckdir)
     assert len(rest) == steps     # only the second epoch ran
     np.testing.assert_allclose(rest, losses[steps:], rtol=1e-5, atol=1e-6)
