@@ -19,12 +19,18 @@ handed to :func:`build`. Everything else is here, once:
   segment ids, positions that restart with each segment, targets that
   never cross a segment's end; with a ``window``, the pairs it leaves;
   with a ``block``, the blocks and the pairs the block rule leaves;
+  with a ``chunk``, the chunks a recurrence's scan walks and those a
+  segment starts inside;
 - the two MASKS a backbone may train under: causal inside a segment
   (:func:`attention`: a row sees its segment's keys up to itself,
   under a ``window`` its newest keys only), and the block rule of
   block diffusion (:func:`block_attention`: a clean and a noised copy
   of every sequence in one pass, a row's keys decided by BLOCKS of its
-  segment);
+  segment) — and beside them a RECURRENCE: a layer that carries a
+  state along the segment instead of reading keys
+  (:mod:`predictionio_tpu.ops.gated_delta`, the backbone's own call:
+  the state is zero at a segment's first row, as a mask lets no key of
+  another segment through);
 - the two OBJECTIVES: the next item (``_chunked_ce`` over shifted
   targets, every real row once) and the block-diffusion one — a
   noised row predicts its OWN item, weighted by 1/p of its block's
@@ -39,7 +45,8 @@ handed to :func:`build`. Everything else is here, once:
   from ONE tensor: sigmoid scores with a selection bias, or a softmax
   over the selected logits) and ``_experts`` (dispatch → grouped gated
   units → combine on ANOTHER, later; the unit's activation is the
-  backbone's; a shared expert where the layer's weights hold one) —
+  backbone's; a shared expert where the layer's weights hold one,
+  behind its sigmoid gate where they hold that too) —
   and ``_moe``, both halves on the same rows
   (:mod:`predictionio_tpu.ops.moe_dispatch`), ``_cast_in_loop``,
   ``_chunked_ce``;
@@ -54,8 +61,8 @@ handed to :func:`build`. Everything else is here, once:
   (the verb's ``seqrec.pack`` / ``.init`` / ``.fit`` / ``.fetch`` spans
   with their counters, through ``seq_rec.run_epoch_blocks``; which
   counters beside the shared ones, which arrays beside ``Packed``'s,
-  are the backbone's declaration, a window, a block length and a MASK
-  id its config's);
+  are the backbone's declaration, a window, a block length, a chunk
+  and a MASK id its config's);
 - :func:`next_item_scores`: one history, one segment, through the
   same stack — the item after it read at its last row, or (a backbone
   that fills blocks) at the first of the MASK rows appended to it.
@@ -86,9 +93,10 @@ from predictionio_tpu.ops import moe_dispatch, seq_attention
 class Backbone(NamedTuple):
     """What the template and the benchmark ask of a backbone, as
     :func:`build` makes it of the backbone's declaration. Which
-    layers have a window or rotary positions, where the router reads,
-    what its experts' activation is, which mask it trains under
-    (causal, or the block rule over two streams) and which objective
+    layers have a window or rotary positions or carry a recurrent
+    state in place of attention, where the router reads, what its
+    experts' activation is, which mask it trains under (causal, or the
+    block rule over two streams) and which objective
     (the next item, or the items a noised copy hides) are the
     backbone's own affair: it passes them to :func:`attention` or
     :func:`block_attention`, ``_route``, ``_experts`` and
@@ -144,7 +152,8 @@ class Backbone(NamedTuple):
 _MODULES = {"glm4_moe_lite": "predictionio_tpu.models.glm4_moe_lite",
             "lfm2_moe": "predictionio_tpu.models.lfm2_moe",
             "smallthinker": "predictionio_tpu.models.smallthinker",
-            "sdar_moe": "predictionio_tpu.models.sdar_moe"}
+            "sdar_moe": "predictionio_tpu.models.sdar_moe",
+            "qwen3_next": "predictionio_tpu.models.qwen3_next"}
 #: an ``architecture`` without ``model_type``, and a model saved before
 #: the table existed
 DEFAULT = "glm4_moe_lite"
@@ -216,6 +225,12 @@ class ArchitectureConfig:
         return None
 
     @property
+    def chunk(self) -> Optional[int]:
+        """Rows of a chunk of a recurrence's scan; None: no layer
+        carries a state."""
+        return None
+
+    @property
     def mask_id(self) -> Optional[int]:
         """The MASK row of the vocabulary; None: it has none."""
         return None
@@ -228,7 +243,8 @@ def build(config: type, *, param_shapes: Callable, bias_shape: Callable,
           train_keys: Optional[Tuple[str, ...]] = None,
           counted: bool = False, fit_attrs: Optional[Callable] = None,
           pack_attrs: Optional[Callable] = None,
-          draws: Optional[Callable] = None) -> Backbone:
+          draws: Optional[Callable] = None,
+          init_leaf: Optional[Callable] = None) -> Backbone:
     """A backbone's DECLARATION → the :class:`Backbone` the table hands
     out. The declaration is what is the backbone's own: its ``config``
     class (an :class:`ArchitectureConfig`), ``param_shapes(c)``,
@@ -240,7 +256,9 @@ def build(config: type, *, param_shapes: Callable, bias_shape: Callable,
     the ``n`` tokens of a one-segment batch, ``batch_keys`` (and
     ``train_keys`` where a train's batches hold others), and its part of
     the verb's spans (``fit_attrs``, ``pack_attrs``, ``draws``:
-    :class:`Backbone`). Everything a caller runs is made HERE, the same
+    :class:`Backbone`), and ``init_leaf`` where some leaf starts as
+    neither a normal matrix nor a unit gain (:func:`init_program`).
+    Everything a caller runs is made HERE, the same
     for every backbone, and each compiled program is kept once per
     config — the configs are frozen dataclasses, hashed by value."""
     def n_params(c) -> int:
@@ -249,7 +267,7 @@ def build(config: type, *, param_shapes: Callable, bias_shape: Callable,
     @functools.lru_cache(maxsize=4)
     def init_compiled(c, with_optimizer: bool):
         return init_program(c, param_shapes(c), bias_shape(c),
-                            with_optimizer)
+                            with_optimizer, init_leaf)
 
     def init_state(c, seed: int, with_optimizer: bool = False):
         """(params, router bias) made ON the device from the seed, by
@@ -348,7 +366,8 @@ def window_pairs(sizes: np.ndarray, window: int) -> Tuple[int, int]:
 def pack_histories(histories: Sequence[Sequence[int]], seq_len: int,
                    seqs_per_step: int = 1, seed: int = 0,
                    window: Optional[int] = None,
-                   block: Optional[int] = None) -> Packed:
+                   block: Optional[int] = None,
+                   chunk: Optional[int] = None) -> Packed:
     """Histories (item ids ≥ 1, oldest first) → ``seq_len``-slot
     sequences with segment ids. A history longer than a sequence is
     cut into ``seq_len`` pieces; pieces go whole, longest first, into
@@ -364,7 +383,11 @@ def pack_histories(histories: Sequence[Sequence[int]], seq_len: int,
     (``bd_blocks``; ``bd_partial_blocks`` of them shorter than
     ``block``), the pairs the block rule leaves of both streams
     (``attn_pairs_bd``, by :func:`seq_attention.block_pairs`) and the
-    rows the two streams make (``stream_rows``)."""
+    rows the two streams make (``stream_rows``); with a ``chunk``, the
+    chunks of ``chunk`` rows a recurrence's scan walks over every
+    sequence (``gdn_chunks``) and those that hold a segment's first row
+    after their own (``gdn_boundary_chunks``: the state is reset INSIDE
+    them)."""
     S = int(seq_len)
     pieces: List[np.ndarray] = []
     n_hist = n_split = 0
@@ -429,6 +452,13 @@ def pack_histories(histories: Sequence[Sequence[int]], seq_len: int,
             bd_partial_blocks=int((sizes % block > 0).sum()),
             attn_pairs_bd=seq_attention.block_pairs(sizes, block),
             stream_rows=2 * n_all * S)
+    if chunk is not None:
+        first = np.zeros((n_all, S), bool)
+        first[:, 1:] = (seg[:, 1:] != seg[:, :-1]) & (seg[:, 1:] > 0)
+        first[:, ::chunk] = False   # at a chunk's first row: nothing inside
+        inside = np.add.reduceat(first, np.arange(0, S, chunk), axis=1) > 0
+        counters.update(gdn_chunk=int(chunk), gdn_chunks=int(inside.size),
+                        gdn_boundary_chunks=int(inside.sum()))
     return Packed(tokens, seg, pos, tgt1, tgt2, counters)
 
 
@@ -477,11 +507,14 @@ def squares_by_group(grads, group_of: Callable[[str], str]) -> Dict[str, Any]:
     return out
 
 
-def init_program(c, shapes, bias_shape: tuple, with_optimizer: bool):
+def init_program(c, shapes, bias_shape: tuple, with_optimizer: bool,
+                 init_leaf: Optional[Callable] = None):
     """``init(seed) -> (params, [Adam's zeroed state,] router bias)``,
     made ON the device by one jitted program: normal(0, init_std)
     matrices, unit gains for every leaf named ``*norm``, zero bias, the
-    PAD row of the embedding zero."""
+    PAD row of the embedding zero. ``init_leaf(name, key, shape)``: a
+    backbone's own start of a leaf (its dotted path), or None for the
+    rule here."""
     import jax
     import jax.numpy as jnp
 
@@ -497,7 +530,10 @@ def init_program(c, shapes, bias_shape: tuple, with_optimizer: bool):
                                 len(leaves))
         out = []
         for key, (path, shape) in zip(keys, leaves):
-            if _path_name(path).endswith("norm"):
+            own = init_leaf and init_leaf(_path_name(path), key, shape)
+            if own is not None:
+                out.append(own)
+            elif _path_name(path).endswith("norm"):
                 out.append(jnp.ones(shape, jnp.float32))
             else:
                 out.append(c.init_std * jax.random.normal(
@@ -534,7 +570,10 @@ SCOPES = frozenset({
     "seqrec.conv", "seqrec.conv.mix",                     # lfm2_moe
     "seqrec.gqa", "seqrec.gqa.attention",   # lfm2_moe; smallthinker: global
     "seqrec.swa", "seqrec.swa.attention",   # smallthinker: window layers
-    "seqrec.bd", "seqrec.bd.attention", "seqrec.bd.noise"})     # sdar_moe
+    "seqrec.bd", "seqrec.bd.attention", "seqrec.bd.noise",      # sdar_moe
+    # qwen3_next: the linear layers (the full one: seqrec.gqa*) — the
+    # projections, gates and gated norm; the convolution; the scan
+    "seqrec.gdn", "seqrec.gdn.conv", "seqrec.gdn.scan"})
 
 
 def scope(name: str):
@@ -716,7 +755,11 @@ def _experts(w, x, gates, p, c, act=None):
     :func:`_route`: this chip's part of the layer's result [T, d] —
     dispatch, the held experts' gated units (``act``: SiLU where none
     is given), combine. The shared expert is added where the layer has
-    one (``w["shared"]``)."""
+    one (``w["shared"]``), times σ(x · w_s) where it has a gate
+    (``w["shared_gate"]`` [d]; the gate's product float32)."""
+    import jax
+    import jax.numpy as jnp
+
     with scope("seqrec.norm"):      # the normed rows as the products take them
         rows = x.astype(_dt(c))
     out = moe_dispatch.experts_swiglu(
@@ -725,7 +768,12 @@ def _experts(w, x, gates, p, c, act=None):
         gates, p, act)
     if "shared" in w:
         with scope("seqrec.ffn"):
-            out = out + _swiglu(w["shared"], x, c)
+            shared = _swiglu(w["shared"], x, c)
+            if "shared_gate" in w:
+                shared = shared * jax.nn.sigmoid(jnp.dot(
+                    x, w["shared_gate"],
+                    precision=jax.lax.Precision.HIGHEST))[:, None]
+            out = out + shared
     return out
 
 
@@ -882,6 +930,8 @@ def train_histories(backbone: Backbone, histories: Sequence[Sequence[int]],
     ``c.block_length``: the block length of a backbone that trains
     under the block rule, counted there too (``bd_*``,
     ``attn_pairs_bd``, ``attn_tile_pairs_bd``, ``stream_rows``);
+    ``c.chunk``: the chunk of a backbone whose layers carry a recurrent
+    state (``gdn_chunk``, ``gdn_chunks``, ``gdn_boundary_chunks``);
     ``backbone.draws(packed, seed)``: further per-sequence arrays
     [sequences, …] of the batches (what the backbone's noise is keyed
     by). Every ``bd_*`` number of the steps' records is summed onto
@@ -898,7 +948,7 @@ def train_histories(backbone: Backbone, histories: Sequence[Sequence[int]],
     window, block = c.window, c.block_length
     with tracing.span("seqrec.pack") as sp:
         packed = pack_histories(histories, c.seq_len, c.seqs_per_step, seed,
-                                window, block)
+                                window, block, c.chunk)
         top = max(int(packed.tokens.max()), 0)
         if top >= c.vocab_size:
             raise ValueError(f"item id {top} outside the vocabulary of "

@@ -9,6 +9,9 @@ Pallas TPU kernels for the ops where fusion/streaming matters:
 - :mod:`.segment` — segment reductions (Naive Bayes, CCO counts).
 - :mod:`.seq_attention` — causal attention inside the segments of a
   packed sequence, only the tiles a segment reaches (sequence backbone).
+- :mod:`.gated_delta` — the gated delta rule (a linear-attention
+  layer's recurrent state) along those segments, as a chunked scan in
+  plain ``jax.numpy`` (sequence backbone).
 
 The kernels above the last have an XLA twin; ``use_pallas()`` decides
 by platform (compiled on TPU, XLA elsewhere, interpret-mode in tests)

@@ -447,6 +447,24 @@ def test_block_rule_attention_at_published_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
+def _qwen3next_cell_config():
+    """The benchmark's ``seqrec-qwen3next-80b-a3b-ep16`` as the template
+    builds it: the configuration's published keys and its job."""
+    import json
+    import os
+
+    from predictionio_tpu.models import qwen3_next as qn
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "seqrec-qwen3next-80b-a3b-ep16.json")
+    with open(path) as f:
+        conf = json.load(f)
+    arch = {k: v for k, v in conf.items()
+            if k in qn.Qwen3NextConfig.known_keys()}
+    return qn.Qwen3NextConfig.from_architecture(dict(arch, **conf["job"]))
+
+
 # -- the backbones' whole train programs at published widths -------------------
 
 #: cell → (its config, the parameters it pins, steps of a train, the
@@ -467,6 +485,13 @@ TRAIN_PROGRAMS = {
     # streams, ONE scanned body of four layers, the noise drawn in the
     # step, the untied head on the noised rows
     "sdar": (_sdar_cell_config, 456_346_624, 32, 15, 5.6e9, 9.6e9),
+    # 625.7 M parameters, 16 steps of one 16,384-slot sequence, two
+    # scanned bodies (3 × linear, 1 × full), the expert half in four
+    # chunks of 4,096 rows, the untied head; the recurrence's scan keeps
+    # a state a 2,048-row block: 11.14 GB of temporaries (the updated
+    # state 7.5 and the gradients 2.5 among them), 4.8 GB under the chip
+    "qwen3next": (_qwen3next_cell_config, 625_667_136, 16, 15, 7.6e9,
+                  11.6e9),
 }
 
 

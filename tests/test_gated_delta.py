@@ -1,0 +1,281 @@
+"""The gated delta rule as a chunked scan (``ops/gated_delta.py``)
+against the row-by-row recurrence of the plain reference
+(``benchmark/reference/qwen3_next_jnp.delta_rule``): chunk length and
+packing change nothing beyond float32 rounding."""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from reference import qwen3_next_jnp as ref  # noqa: E402
+
+from predictionio_tpu.ops import gated_delta  # noqa: E402
+
+S, HK, H, DK, DV = 128, 2, 4, 16, 8
+NAMES = ("q", "k", "v", "g", "beta")
+
+
+def _segments(*lengths):
+    seg, at = np.zeros(S, np.int32), 0
+    for j, n in enumerate(lengths):
+        seg[at:at + n] = j + 1
+        at += n
+    assert at <= S
+    return seg
+
+
+#: starts inside chunks of 16 and of 64, at a chunk's first row (64)
+#: and at its last (63, 127), a one-row segment (row 0; rows 63, 127),
+#: padding behind (none in the first)
+PACKINGS = {
+    "starts_everywhere": _segments(1, 16, 46, 1, 36, 27, 1),
+    "one_segment": _segments(128),
+    "padding_tail": _segments(40, 23, 30),
+    "many_short": _segments(*([5, 9, 2, 17, 3, 11, 7] * 2)),
+}
+
+
+def _operands(seed=0, g_scale=3.0):
+    rng = np.random.default_rng(seed)
+
+    def unit(shape):
+        x = rng.normal(size=shape)
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    return tuple(jnp.asarray(a, jnp.float32) for a in (
+        unit((S, HK, DK)), unit((S, HK, DK)), rng.normal(size=(S, H, DV)),
+        -rng.uniform(0, g_scale, (S, H)), rng.uniform(0, 1, (S, H))))
+
+
+def _chunked(seg, chunk):
+    seg = jnp.asarray(seg)
+    return lambda q, k, v, g, beta: gated_delta.gated_delta_rule(
+        q[None], k[None], v[None], g[None], beta[None], seg[None],
+        chunk)[0]
+
+
+def _rows(seg):
+    seg = jnp.asarray(seg)
+
+    def run(q, k, v, g, beta):
+        with jax.default_matmul_precision("highest"):
+            return ref.delta_rule(jnp.repeat(q, H // HK, axis=1),
+                                  jnp.repeat(k, H // HK, axis=1), v, g, beta,
+                                  seg)
+
+    return run
+
+
+def _value_and_grads(rule, ops, weight):
+    return jax.jit(jax.value_and_grad(
+        lambda *ops: (rule(*ops) * weight).sum(), range(5)))(*ops)
+
+
+def _weight(seg, seed=9):
+    return jnp.asarray(np.random.default_rng(seed).normal(size=(S, H, DV))
+                       * (seg > 0)[:, None, None], jnp.float32)
+
+
+# -- 1. chunked = row by row ---------------------------------------------------
+
+
+@pytest.mark.parametrize("packing, chunk", [
+    ("starts_everywhere", 16), ("starts_everywhere", 64),
+    ("starts_everywhere", S), ("padding_tail", 16), ("one_segment", 64)])
+def test_chunked_equals_the_recurrence_output_and_every_gradient(packing,
+                                                                 chunk):
+    seg, ops = PACKINGS[packing], _operands()
+    out = jax.jit(_chunked(seg, chunk))(*ops)
+    want = jax.jit(_rows(seg))(*ops)
+    assert out.shape == (S, H, DV) and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    w = _weight(seg)
+    got = _value_and_grads(_chunked(seg, chunk), ops, w)
+    exp = _value_and_grads(_rows(seg), ops, w)
+    np.testing.assert_allclose(float(got[0]), float(exp[0]), rtol=1e-4,
+                               atol=1e-4)
+    for name, a, b in zip(NAMES, got[1], exp[1]):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape and np.isfinite(a).all(), name
+        assert np.abs(a - b).max() <= 5e-5 * max(np.abs(b).max(), 1.0), name
+
+
+def test_the_chunk_taken_divides_the_sequence():
+    """A chunk that does not divide S: the most that does is taken (a
+    serving bucket of 16 or 32 rows under the configuration's 64)."""
+    seg, ops = PACKINGS["starts_everywhere"], _operands()
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(_chunked(seg, 48))(*ops)),       # gcd 16
+        np.asarray(jax.jit(_chunked(seg, 16))(*ops)), atol=1e-6)
+
+
+def test_blocks_of_rows_are_checkpoints_not_another_result(monkeypatch):
+    """The walk's checkpointed blocks (2,048 rows at the cell's size)
+    cut at 32 rows here: four blocks, the state and the run handed from
+    one to the next — the same output and gradients."""
+    seg, ops, w = PACKINGS["starts_everywhere"], _operands(), None
+    w = _weight(seg)
+    whole = _value_and_grads(_chunked(seg, 16), ops, w)
+    monkeypatch.setattr(gated_delta, "BLOCK_ROWS", 32)
+    cut = _value_and_grads(_chunked(seg, 16), ops, w)
+    np.testing.assert_allclose(float(cut[0]), float(whole[0]), rtol=1e-5)
+    for a, b in zip(cut[1], whole[1]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   rtol=2e-5)
+
+
+# -- 2. packed = each segment alone --------------------------------------------
+
+
+@pytest.mark.parametrize("chunk", [64])
+def test_a_packed_segment_gets_what_it_gets_alone(chunk):
+    """Each segment of the packing moved to the FRONT of a sequence of
+    its own: the same rows, whatever stood before it in the packing and
+    wherever in a chunk it started."""
+    seg, ops = PACKINGS["starts_everywhere"], _operands()
+    packed = np.asarray(jax.jit(_chunked(seg, chunk))(*ops))
+    for j in range(1, seg.max() + 1):
+        rows = np.flatnonzero(seg == j)
+        alone_seg = _segments(rows.size)
+        moved = tuple(jnp.zeros_like(a).at[:rows.size].set(a[rows])
+                      for a in ops)
+        alone = np.asarray(jax.jit(_chunked(alone_seg, chunk))(*moved))
+        np.testing.assert_allclose(packed[rows], alone[:rows.size],
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_no_row_reads_across_a_segments_start():
+    """Everything before a segment changed: its rows read as before
+    (to the rounding of a cumulative sum that starts at the chunk's
+    first row), and no gradient of them reaches the rows before — also
+    where the segment starts in the middle of a chunk."""
+    seg, ops = PACKINGS["starts_everywhere"], _operands()
+    start = int(np.flatnonzero(seg == 6)[0])
+    assert start % 64 and start % 16                        # mid-chunk
+    before = np.arange(S) < start
+    bumped = tuple(jnp.where(before.reshape((S,) + (1,) * (a.ndim - 1)),
+                             a * 0.5 - 0.25, a) for a in ops)
+    for chunk in (16, 64):
+        a = np.asarray(jax.jit(_chunked(seg, chunk))(*ops))
+        b = np.asarray(jax.jit(_chunked(seg, chunk))(*bumped))
+        np.testing.assert_allclose(a[start:], b[start:], atol=1e-5)
+        assert np.abs(a[:start] - b[:start]).max() > 1e-3
+        w = _weight(seg) * jnp.asarray(~before, jnp.float32)[:, None, None]
+        for name, g in zip(NAMES, _value_and_grads(_chunked(seg, chunk),
+                                                   ops, w)[1]):
+            g = np.asarray(g)
+            # (a decay's cotangent comes back through the chunk's
+            # cumulative sum: equal terms of both signs, summed in two
+            # orders)
+            assert np.abs(g[:start]).max() <= (
+                1e-6 * np.abs(g).max() if name == "g" else 0.0), name
+            assert g[start:].any(), name
+
+
+def test_a_state_that_is_not_reset_would_show():
+    """The packing as ONE segment — what a scan without resets
+    computes: the rows behind a start differ."""
+    seg, ops = PACKINGS["starts_everywhere"], _operands(g_scale=0.1)
+    reset = np.asarray(jax.jit(_chunked(seg, 16))(*ops))
+    carried = np.asarray(jax.jit(_chunked(_segments(S), 16))(*ops))
+    first = np.flatnonzero(seg == 1)
+    np.testing.assert_allclose(reset[first], carried[first], atol=1e-6)
+    assert np.abs(reset - carried).max() > 1e-2
+
+
+def test_padding_rows_give_and_take_no_gradient():
+    seg, ops = PACKINGS["padding_tail"], _operands()
+    pad = seg == 0
+    assert pad.sum() == 35
+    grads = _value_and_grads(_chunked(seg, 16), ops, _weight(seg))[1]
+    for name, g in zip(NAMES, grads):
+        g = np.asarray(g)
+        assert np.isfinite(g).all() and not g[pad].any(), name
+        assert g[~pad].any(), name
+
+
+# -- 3. decays -----------------------------------------------------------------
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_a_decay_of_minus_21_a_row_stays_finite(chunk):
+    """A = 16 and softplus ≈ 1.3: g = −21 a row, −1,344 over a chunk of
+    64: e^{−γ} is no float32, the differences' exponentials are."""
+    seg = PACKINGS["starts_everywhere"]
+    q, k, v, _, beta = _operands()
+    g = jnp.full((S, H), -21.0).at[:, 1].set(-1e-3).at[:, 2].set(-5.0)
+    ops = (q, k, v, g, beta)
+    out = np.asarray(jax.jit(_chunked(seg, chunk))(*ops))
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, np.asarray(jax.jit(_rows(seg))(*ops)),
+                               atol=2e-5, rtol=2e-5)
+    got = _value_and_grads(_chunked(seg, chunk), ops, _weight(seg))[1]
+    exp = _value_and_grads(_rows(seg), ops, _weight(seg))[1]
+    for name, a, b in zip(NAMES, got, exp):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.isfinite(a).all(), name
+        assert np.abs(a - b).max() <= 5e-5 * max(np.abs(b).max(), 1.0), name
+
+
+# -- 4. operands ---------------------------------------------------------------
+
+
+def test_operands_of_any_dtype_give_a_float32_result():
+    """The layer hands over float32; bfloat16 operands (a caller that
+    has them) are taken up into float32 before any product."""
+    seg, ops = PACKINGS["one_segment"], _operands(g_scale=0.01)
+    low = tuple(a.astype(jnp.bfloat16) for a in ops)
+    got = jax.jit(_chunked(seg, 16))(*low)
+    assert got.dtype == jnp.float32
+    want = jax.jit(_chunked(seg, 16))(*(a.astype(jnp.float32) for a in low))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_value_heads_read_the_key_head_of_their_group():
+    """4 value heads over 2 key heads = the same with every key head
+    handed over twice."""
+    seg, (q, k, v, g, beta) = PACKINGS["many_short"], _operands()
+    seg1 = jnp.asarray(seg)[None]
+    grouped = gated_delta.gated_delta_rule(
+        q[None], k[None], v[None], g[None], beta[None], seg1, 16)
+    alone = gated_delta.gated_delta_rule(
+        jnp.repeat(q, 2, axis=1)[None], jnp.repeat(k, 2, axis=1)[None],
+        v[None], g[None], beta[None], seg1, 16)
+    np.testing.assert_allclose(np.asarray(grouped), np.asarray(alone),
+                               atol=1e-6)
+
+
+def test_runs_of_counts_maximal_runs_of_one_id():
+    seg = np.asarray([3, 3, 4, 4, 4, 1, 0, 0], np.int32)
+    np.testing.assert_array_equal(gated_delta.runs_of(seg, np),
+                                  [1, 1, 2, 2, 2, 3, 4, 4])
+
+
+@pytest.mark.parametrize("C", [8, 16, 64])
+def test_the_unit_lower_solve_is_the_triangular_solve(C):
+    """Diagonal blocks of 16 rows inverted by doubling, the block rows
+    by substitution: (I + L)⁻¹ rhs to float32 rounding — also where
+    every entry of L is ½ (one item played over and over: equal keys),
+    whose powers over a whole chunk of 64 would leave float32."""
+    from jax.scipy.linalg import solve_triangular
+
+    rng = np.random.default_rng(C)
+    for lower in (np.tril(rng.normal(size=(3, 2, C, C)) * 0.4, -1),
+                  np.tril(np.full((3, 2, C, C), 0.5), -1)):
+        rhs = rng.normal(size=(3, 2, C, 5))
+        lower, rhs = (jnp.asarray(a, jnp.float32) for a in (lower, rhs))
+        want = np.asarray(solve_triangular(lower + jnp.eye(C), rhs,
+                                           lower=True), np.float64)
+        got = np.asarray(jax.jit(gated_delta.solve_unit_lower)(lower, rhs))
+        assert np.abs(got - want).max() <= 2e-5 * max(np.abs(want).max(), 1)

@@ -112,6 +112,35 @@ def test_tiled_equals_dense_forward_and_gradients(packing, dtype):
         assert not a[~real].any(), name
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_groups_of_eight_over_a_head_of_256(dtype):
+    """``qwen3_next``'s full layer: 16 query heads over 2 key-value
+    heads of width 256, through the same kernels — output and all three
+    gradients against the dense form. In bfloat16 a key-value head's
+    cotangent sums eight query heads' parts: compared to one unit in
+    the last place of the largest."""
+    seg, bq, bk = _segments(20, 70, 38, S=128), 32, 32
+    q, k, v = _operands(128, dtype, 256, 256, 16, 2)
+    real = seg > 0
+    dense = lambda q, k, v: _dense(  # noqa: E731
+        q, k, v, jnp.asarray(seg), 256 ** -0.5)
+    out = _tiled(seg, bq, bk)(q, k, v)
+    assert out.shape == (128, 16, 256) and out.dtype == v.dtype
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32)[real],
+                               np.asarray(dense(q, k, v))[real], atol=tol,
+                               rtol=tol)
+    got = _value_and_grads(_tiled(seg, bq, bk), q, k, v, seg)
+    ref = _value_and_grads(dense, q, k, v, seg)
+    ulp = 2e-5 if dtype == jnp.float32 else 2.0 ** -7
+    for name, a, b in zip("qkv", got[1], ref[1]):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert a.shape == b.shape and np.isfinite(a).all()
+        assert np.abs(a - b).max() <= ulp * max(np.abs(b).max(), 1.0), name
+        assert not a[~real].any(), name
+
+
 def test_one_key_value_head_a_query_head_is_the_ungrouped_kernel():
     """Grouped against ungrouped, bit for bit: 8 query heads over 2
     key-value heads, and the same with each key-value head handed over
@@ -427,13 +456,18 @@ def test_the_window_skips_the_tiles_it_hides():
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("heads", [(16, 8, 8, 2), (256, 256, 16, 2)],
+                         ids=["8_over_2_of_16", "16_over_2_of_256"])
 @pytest.mark.parametrize("window", [None, 40], ids=["global", "window"])
-def test_dkv_by_query_head_equals_dkv_by_group(monkeypatch, window):
+def test_dkv_by_query_head_equals_dkv_by_group(monkeypatch, window, heads):
     """Where a key-value head's query rows do not fit VMEM the dk/dv
     grid walks the query heads and the group's parts are summed outside
-    the kernel: the same gradients as the grouped walk, to rounding."""
+    the kernel: the same gradients as the grouped walk, to rounding —
+    the walk ``qwen3_next``'s full layer takes at 16,384 rows (8 query
+    heads × 16,384 × (256 + 256) × 2 B = 134 MB a key-value head)."""
+    assert 8 * 16384 * (256 + 256) * 2 > sa._WHOLE_HEAD_BYTES
     seg, bq, bk = _segments(100, 40, 20, 60, S=256), 32, 32
-    q, k, v = _operands(256, jnp.float32, H=8, Hkv=2)
+    q, k, v = _operands(256, jnp.float32, *heads)
     grouped = _value_and_grads(_windowed(seg, bq, bk, window), q, k, v, seg)
     monkeypatch.setattr(sa, "_WHOLE_HEAD_BYTES", 0)
     split = _value_and_grads(_windowed(seg, bq, bk, window), q, k, v, seg)

@@ -9,6 +9,7 @@ the table.
 
 import contextlib
 import dataclasses
+import hashlib
 import os
 import re
 
@@ -18,6 +19,7 @@ import pytest
 
 from predictionio_tpu.models import glm4_moe_lite as glm
 from predictionio_tpu.models import lfm2_moe as lfm
+from predictionio_tpu.models import qwen3_next as qn
 from predictionio_tpu.models import sdar_moe as sd
 from predictionio_tpu.models import seq_backbone
 from predictionio_tpu.models import smallthinker as st
@@ -52,6 +54,13 @@ BACKBONES = {
         model_type="sdar_moe", head_dim=16, num_attention_heads=4,
         num_key_value_heads=2, num_experts=8, num_hidden_layers=2,
         block_length=4))),
+    "qwen3_next": (qn, qn.Qwen3NextConfig.from_architecture(dict(
+        {k: v for k, v in TINY.items() if k != "intermediate_size"},
+        model_type="qwen3_next", head_dim=32, num_attention_heads=4,
+        num_key_value_heads=2, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=16,
+        linear_value_head_dim=16, shared_expert_intermediate_size=32,
+        num_experts=8, num_hidden_layers=4, gdn_chunk=16))),
 }
 #: what this PR added: around and beside the operators' scopes
 NEW = {"seqrec.step", "seqrec.stack", "seqrec.stack.cast", "seqrec.norm",
@@ -63,7 +72,9 @@ OWN = {"glm4_moe_lite": {"seqrec.mla", "seqrec.mla.attention", "seqrec.mtp"},
                     "seqrec.gqa.attention"},
        "smallthinker": {"seqrec.swa", "seqrec.swa.attention", "seqrec.gqa",
                         "seqrec.gqa.attention"},
-       "sdar_moe": {"seqrec.bd", "seqrec.bd.attention", "seqrec.bd.noise"}}
+       "sdar_moe": {"seqrec.bd", "seqrec.bd.attention", "seqrec.bd.noise"},
+       "qwen3_next": {"seqrec.gdn", "seqrec.gdn.conv", "seqrec.gdn.scan",
+                      "seqrec.gqa", "seqrec.gqa.attention"}}
 #: a scope every other backbone opens and this one has nothing for: no
 #: dense feed-forward layer and no shared expert
 LACKS = {"smallthinker": {"seqrec.ffn"}, "sdar_moe": {"seqrec.ffn"}}
@@ -266,6 +277,30 @@ def test_under_one_name_the_cache_answers_with_the_old_scopes(tmp_path,
         assert _train_entries(tmp_path) == ["jit_train"]
 
 
+#: the accepted backbones' train programs at the sizes above, lowered:
+#: SHA-256 of the text with the program's name (a digest of the scope
+#: table) taken out, as the parent of PR 45 lowers them — a change to
+#: shared code for a new backbone leaves them text for text
+LOWERED = {"glm4_moe_lite": "29a798e81f5899aa",
+           "lfm2_moe": "b2087560cb293449",
+           "smallthinker": "e21c877aefa353db",
+           "sdar_moe": "5d08acd6ea8c5038"}
+
+
+@pytest.mark.parametrize("model_type", sorted(LOWERED))
+def test_an_accepted_backbones_program_lowers_as_before(model_type):
+    """The rule of PRs 38, 40 and 45: what a new backbone passes to
+    shared code (a recurrence's chunk, a shared expert's gate, a leaf's
+    own start) leaves the accepted programs apart from their NAME as
+    they were. (A JAX that prints another text moves all four.)"""
+    module, c = BACKBONES[model_type]
+    text = _program(module, c).lower(*_abstract_args(module, c)).as_text()
+    name = seq_backbone.program_name()
+    assert name in text
+    assert hashlib.sha256(text.replace(name, "train").encode(
+        )).hexdigest()[:16] == LOWERED[model_type]
+
+
 # -- (c) the seam: a backbone declares, ``build`` makes the rest --------------
 
 #: what the benchmark reads of a backbone, by name
@@ -286,7 +321,8 @@ BINDINGS = {"glm4_moe_lite": {"n_params": "n_params",
                               "glm_train": "train"},
             "lfm2_moe": {"n_params": "n_params"},
             "smallthinker": {"n_params": "n_params"},
-            "sdar_moe": {"n_params": "n_params"}}
+            "sdar_moe": {"n_params": "n_params"},
+            "qwen3_next": {"n_params": "n_params"}}
 
 
 @pytest.mark.parametrize("model_type", sorted(seq_backbone._MODULES))
