@@ -50,6 +50,15 @@ family's zero-centred norm):
 A run of consecutive layers of one kind is ONE scanned body over its
 stacked weights (``params["runs"][r]``): the published 48 layers are
 24 runs, the benchmark's period of four two (3 × linear, 1 × full).
+A layer turn of that scan is one ``jax.checkpoint``: the backward pass
+recomputes the turn — projections, convolution, attention, the expert
+half — from the residual stream that entered it, EXCEPT the
+recurrence: the turn's policy keeps the rule's output and the states
+that enter the blocks of its walk (``gated_delta.KEPT``;
+``gdn_kept_bytes`` on the ``seqrec.fit`` span), so a step walks a
+linear layer forward twice (the forward pass, then block by block
+inside the rule's backward), not three times. In a full-attention run
+the names do not occur and nothing is kept.
 
 Precision, packing, the pieces any backbone has, the train step and
 the verb's spans are :mod:`predictionio_tpu.models.seq_backbone`'s.
@@ -391,12 +400,15 @@ def _layer(w, x, seg, pos, c: Qwen3NextConfig, kind: str):
 def _stack(params, bias, batch, c: Qwen3NextConfig):
     """Embedding and the stack: x_L [B, S, d] and the layers' routing
     records (leading axis: layer, in stack order). ``bias`` is the
-    step's zero router bias: nothing reads it."""
+    step's zero router bias: nothing reads it. A layer turn is one
+    ``jax.checkpoint`` that keeps what the recurrence names
+    (:data:`gated_delta.KEPT`) and recomputes the rest."""
     import jax
     import jax.numpy as jnp
 
     del bias
     seg, pos = batch["seg"], batch["pos"]
+    kept = jax.checkpoint_policies.save_only_these_names(*gated_delta.KEPT)
     with scope("seqrec.embed"):
         x = params["embed"][batch["tokens"]]
     stats = []
@@ -408,7 +420,7 @@ def _stack(params, bias, batch, c: Qwen3NextConfig):
 
         with scope("seqrec.stack"):
             x, s = jax.lax.scan(
-                lambda x, iw: jax.checkpoint(turn)(x, iw), x,
+                lambda x, iw: jax.checkpoint(turn, policy=kept)(x, iw), x,
                 (jnp.arange(n), w))
         stats.append(s)
     return x, jax.tree.map(lambda *a: jnp.concatenate(a), *stats)
@@ -438,6 +450,17 @@ def _next_logits(params, bias, batch, n, c: Qwen3NextConfig):
     return _head_logits(params, x[0, n - 1], c)
 
 
+def gdn_kept_bytes(c: Qwen3NextConfig) -> int:
+    """Bytes a step keeps for the backward pass on account of the
+    turn's policy: per linear layer and sequence the recurrence's
+    output and the state that enters each block of its walk, float32."""
+    H, dk, dv = (c.linear_num_value_heads, c.linear_key_head_dim,
+                 c.linear_value_head_dim)
+    blocks = c.seq_len // gated_delta.block_rows(c.gdn_chunk, c.seq_len)[1]
+    return (c.kinds.count("linear") * c.seqs_per_step * 4
+            * (c.seq_len * H * dv + blocks * H * dk * dv))
+
+
 # -- the declaration ----------------------------------------------------------
 
 
@@ -455,6 +478,7 @@ BACKBONE = seq_backbone.build(
         packed.pos, packed.seg, c.linear_conv_kernel_dim)},
     fit_attrs=lambda c: {
         "linear_layers": c.kinds.count("linear"),
-        "full_layers": c.kinds.count("full")})
+        "full_layers": c.kinds.count("full"),
+        "gdn_kept_bytes": gdn_kept_bytes(c)})
 
 n_params = BACKBONE.n_params    # benchmark/tests/test_qwen3next_layers.py

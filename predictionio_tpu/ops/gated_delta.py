@@ -38,12 +38,18 @@ a chunk (the two that read S₀ are one), batched over the heads.
 - **Decays** enter as ``exp`` of DIFFERENCES of γ inside a chunk, each
   ≤ 0, in float32 — never as a quotient of exponentials: γ reaches
   −1,300 in a chunk of 64 and e^{−γ} is not a float32.
-- **Memory.** The walk is cut into blocks of :data:`BLOCK_ROWS` rows;
-  a block is one ``jax.checkpoint``: what is kept for the backward
-  pass is the state that enters each block, and inside the block being
-  differentiated the state that enters each chunk (64 KB a head at
-  128 × 128) — never a state a row, and nothing of a chunk's
-  triangular matrices outside the block at work.
+- **Memory.** The walk is cut into blocks of :data:`BLOCK_ROWS` rows.
+  The rule is a ``jax.custom_vjp``: its forward hands the backward
+  pass the operands and the state that ENTERS each block (64 KB a head
+  at 128 × 128), and names the output ``"gdn_out"`` and those states
+  ``"gdn_states"`` (``jax.ad_checkpoint.checkpoint_name``). A CALLER
+  that runs the rule under ``jax.checkpoint`` with the policy
+  ``save_only_these_names("gdn_out", "gdn_states")`` keeps the two and
+  its backward pass does not walk forward again; without a policy the
+  checkpoint recomputes the forward, as it does everything else. Inside
+  the block being differentiated what is kept is the state that enters
+  each chunk — never a state a row, and nothing of a chunk's triangular
+  matrices outside the block at work.
 - **Precision.** float32 throughout, the products at
   ``Precision.HIGHEST`` (six bfloat16 passes of the chip's multiplier):
   inside a chunk the state is never written down — P Δ and (e^{γ_C − γ}
@@ -55,7 +61,9 @@ a chunk (the two that read S₀ are one), batched over the heads.
 
 Keys and queries may have FEWER heads than the values: with ``Hk`` key
 heads, ``r = H ÷ Hk`` adjacent value heads read key head ``h ÷ r``.
-Plain ``jax.numpy`` under ``lax.scan``; the backward pass is JAX's.
+Plain ``jax.numpy`` under ``lax.scan``; the backward pass is ``jax.vjp``
+of a block, walked over the blocks in reverse by the rule's own
+backward (:func:`_backward`).
 """
 
 from __future__ import annotations
@@ -66,6 +74,9 @@ import math
 BLOCK_ROWS = 2048
 #: rows of a diagonal block of a chunk's triangular system
 SOLVE_BLOCK = 16
+#: the names the rule's forward gives its output and the states that
+#: enter its blocks: what a caller's checkpoint policy may keep
+KEPT = ("gdn_out", "gdn_states")
 
 
 def runs_of(seg, xp):
@@ -174,25 +185,115 @@ def _block(carry, xs, chunk: int):
     return (state, run[-1, -1]), jnp.moveaxis(out, 1, 2).reshape(R, H, dv)
 
 
+def block_rows(chunk: int, S: int):
+    """(rows of a chunk, rows of a block) of a sequence of ``S`` rows:
+    the most of ``chunk`` that divides S, and the most chunks of
+    :data:`BLOCK_ROWS` rows that divide its chunks."""
+    C = math.gcd(chunk, S)
+    return C, C * math.gcd(max(BLOCK_ROWS // C, 1), S // C)
+
+
+def _blocks(x, R: int):
+    """[S, …] → [S ÷ R, R, …]: the rows of each block."""
+    return x.reshape((x.shape[0] // R, R) + x.shape[1:])
+
+
+def _forward(q, k, v, g, beta, seg, chunk: int):
+    """The walk over the blocks, one sequence at a time: the output
+    [B, S, H, dv] and the state [B, blocks, H, dk, dv] that ENTERS each
+    block."""
+    import jax
+    import jax.numpy as jnp
+
+    S, H, dv = v.shape[1:]
+    dk = k.shape[-1]
+    C, R = block_rows(chunk, S)
+
+    def block(carry, xs):
+        # the scan hands out the state that LEAVES a block (= enters
+        # the next): handing out the carry that came in keeps the
+        # chip's compiler from holding the walk's state in fast memory
+        # (measured: the forward walk 1.30 s a train of the cell
+        # against 1.20 s so)
+        left, out = _block(carry, xs, C)
+        return left, (out, left[0])
+
+    def one(args):
+        *rows, seg = args
+        xs = tuple(_blocks(x, R) for x in (*rows, runs_of(seg, jnp)))
+        zero = jnp.zeros((H, dk, dv), jnp.float32)
+        out, left = jax.lax.scan(block, (zero, jnp.int32(0)), xs)[1]
+        return (out.reshape(S, H, dv),
+                jnp.concatenate([zero[None], left[:-1]]))
+
+    return jax.lax.map(one, (q, k, v, g, beta, seg))
+
+
+def _rule(q, k, v, g, beta, seg, chunk: int):
+    return _forward(q, k, v, g, beta, seg, chunk)[0]
+
+
+def _rule_fwd(q, k, v, g, beta, seg, chunk: int):
+    from jax.ad_checkpoint import checkpoint_name
+
+    out, states = (checkpoint_name(x, name)
+                   for x, name in zip(_forward(q, k, v, g, beta, seg, chunk),
+                                      KEPT))
+    return out, (q, k, v, g, beta, seg, states)
+
+
+def _backward(chunk: int, kept, d_out):
+    """The blocks in REVERSE: a block's forward again from the state
+    that entered it, kept chunk by chunk, then its backward walk
+    (``jax.vjp`` of :func:`_block`), which hands the state's cotangent
+    to the block before. ``seg`` gets none, nor does an operand of
+    integers."""
+    import jax
+    import jax.numpy as jnp
+
+    *rows, seg, states = kept
+    S = seg.shape[1]
+    C, R = block_rows(chunk, S)
+    floats = [i for i, x in enumerate(rows)
+              if jnp.issubdtype(x.dtype, jnp.floating)]
+
+    def one(args):
+        rows, seg, states, d_out = args
+        run = _blocks(runs_of(seg, jnp), R)
+        before = jnp.concatenate([jnp.zeros(1, jnp.int32), run[:-1, -1]])
+
+        def back(d_state, xs):
+            state, before, rows, run, d_out = xs
+
+            def leaves(state, *some):
+                at = dict(zip(floats, some))
+                (state, _), out = _block(
+                    (state, before),
+                    (*(at.get(i, x) for i, x in enumerate(rows)), run), C)
+                return state, out
+
+            pull = jax.vjp(leaves, state, *(rows[i] for i in floats))[1]
+            d_state, *d_rows = pull((d_state, d_out))
+            return d_state, tuple(d_rows)
+
+        d_rows = jax.lax.scan(
+            back, jnp.zeros(states.shape[1:], jnp.float32),
+            (states, before, tuple(_blocks(x, R) for x in rows), run,
+             _blocks(d_out, R)), reverse=True)[1]
+        return tuple(d.reshape((S,) + d.shape[2:]) for d in d_rows)
+
+    d_rows = dict(zip(floats, jax.lax.map(
+        one, (tuple(rows), seg, states, d_out))))
+    return tuple(d_rows.get(i) for i in range(len(rows))) + (None,)
+
+
 def gated_delta_rule(q, k, v, g, beta, seg, chunk: int):
     """``q``, ``k`` [B, S, Hk, dk] (normalised), ``v`` [B, S, H, dv],
     ``g``, ``beta`` [B, S, H], ``seg`` [B, S] int32 → the rule's output
     [B, S, H, dv] float32, one sequence at a time. ``chunk``: rows of a
     chunk (the most that divides S is taken)."""
     import jax
-    import jax.numpy as jnp
 
-    S, H, dv = v.shape[1:]
-    dk = k.shape[-1]
-    C = math.gcd(chunk, S)
-    R = C * math.gcd(max(BLOCK_ROWS // C, 1), S // C)
-    block = jax.checkpoint(lambda carry, xs: _block(carry, xs, C))
-
-    def one(args):
-        *rows, seg = args
-        xs = tuple(x.reshape((S // R, R) + x.shape[1:])
-                   for x in (*rows, runs_of(seg, jnp)))
-        first = (jnp.zeros((H, dk, dv), jnp.float32), jnp.int32(0))
-        return jax.lax.scan(block, first, xs)[1].reshape(S, H, dv)
-
-    return jax.lax.map(one, (q, k, v, g, beta, seg))
+    rule = jax.custom_vjp(_rule, nondiff_argnums=(6,))
+    rule.defvjp(_rule_fwd, _backward)
+    return rule(q, k, v, g, beta, seg, chunk)

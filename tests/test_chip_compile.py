@@ -487,11 +487,13 @@ TRAIN_PROGRAMS = {
     "sdar": (_sdar_cell_config, 456_346_624, 32, 15, 5.6e9, 9.6e9),
     # 625.7 M parameters, 16 steps of one 16,384-slot sequence, two
     # scanned bodies (3 × linear, 1 × full), the expert half in four
-    # chunks of 4,096 rows, the untied head; the recurrence's scan keeps
-    # a state a 2,048-row block: 11.14 GB of temporaries (the updated
-    # state 7.5 and the gradients 2.5 among them), 4.8 GB under the chip
+    # chunks of 4,096 rows, the untied head; a linear layer's turn keeps
+    # the recurrence's output and a state a 2,048-row block (0.86 GB
+    # over the three: ``gdn_kept_bytes``): 12.18 GB of temporaries (the
+    # updated state 7.5 and the gradients 2.5 among them), 3.8 GB under
+    # the chip
     "qwen3next": (_qwen3next_cell_config, 625_667_136, 16, 15, 7.6e9,
-                  11.6e9),
+                  12.3e9),
 }
 
 

@@ -4,6 +4,7 @@ against the row-by-row recurrence of the plain reference
 packing change nothing beyond float32 rounding."""
 
 import os
+import re
 import sys
 
 import numpy as np
@@ -81,6 +82,21 @@ def _value_and_grads(rule, ops, weight):
         lambda *ops: (rule(*ops) * weight).sum(), range(5)))(*ops)
 
 
+def _keeping(rule):
+    """The rule as a layer turn runs it: inside a ``jax.checkpoint``
+    whose policy keeps what the rule's forward names."""
+    return jax.checkpoint(
+        rule, policy=jax.checkpoint_policies.save_only_these_names(
+            *gated_delta.KEPT))
+
+
+def _assert_same(got, want):
+    """A value and its gradients equal to another's, bit for bit."""
+    assert float(got[0]) == float(want[0])
+    for name, a, b in zip(NAMES, got[1], want[1]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+
+
 def _weight(seg, seed=9):
     return jnp.asarray(np.random.default_rng(seed).normal(size=(S, H, DV))
                        * (seg > 0)[:, None, None], jnp.float32)
@@ -111,6 +127,22 @@ def test_chunked_equals_the_recurrence_output_and_every_gradient(packing,
         assert np.abs(a - b).max() <= 5e-5 * max(np.abs(b).max(), 1.0), name
 
 
+@pytest.mark.parametrize("packing, chunk", [("starts_everywhere", 16),
+                                            ("padding_tail", 64)])
+def test_chunked_under_a_checkpoint_that_keeps_its_names(packing, chunk):
+    """The rule inside ``jax.checkpoint(…, policy=save_only_these_names(
+    "gdn_out", "gdn_states"))``: the backward pass reads the kept
+    output and entering states in place of a second forward walk —
+    the same output and the same gradients, bit for bit."""
+    seg, ops, w = PACKINGS[packing], _operands(), _weight(PACKINGS[packing])
+    rule = _chunked(seg, chunk)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(_keeping(rule))(*ops)),
+        np.asarray(jax.jit(rule)(*ops)))
+    _assert_same(_value_and_grads(_keeping(rule), ops, w),
+                 _value_and_grads(rule, ops, w))
+
+
 def test_the_chunk_taken_divides_the_sequence():
     """A chunk that does not divide S: the most that does is taken (a
     serving bucket of 16 or 32 rows under the configuration's 64)."""
@@ -121,10 +153,11 @@ def test_the_chunk_taken_divides_the_sequence():
 
 
 def test_blocks_of_rows_are_checkpoints_not_another_result(monkeypatch):
-    """The walk's checkpointed blocks (2,048 rows at the cell's size)
-    cut at 32 rows here: four blocks, the state and the run handed from
-    one to the next — the same output and gradients."""
-    seg, ops, w = PACKINGS["starts_everywhere"], _operands(), None
+    """The walk's blocks (2,048 rows at the cell's size: what the
+    backward pass recomputes at a time) cut at 32 rows here: four
+    blocks, the state and the run handed from one to the next — the
+    same output and gradients."""
+    seg, ops = PACKINGS["starts_everywhere"], _operands()
     w = _weight(seg)
     whole = _value_and_grads(_chunked(seg, 16), ops, w)
     monkeypatch.setattr(gated_delta, "BLOCK_ROWS", 32)
@@ -133,6 +166,89 @@ def test_blocks_of_rows_are_checkpoints_not_another_result(monkeypatch):
     for a, b in zip(cut[1], whole[1]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
                                    rtol=2e-5)
+
+
+def test_blocks_under_a_checkpoint_that_keeps_their_entering_states(
+        monkeypatch):
+    """Four blocks of 32 rows inside a checkpoint that keeps the rule's
+    names: the four entering states are what its backward walk starts
+    each block from — the cut rule's own output and gradients, bit for
+    bit."""
+    seg, ops = PACKINGS["starts_everywhere"], _operands()
+    w = _weight(seg)
+    monkeypatch.setattr(gated_delta, "BLOCK_ROWS", 32)
+    assert gated_delta.block_rows(16, S) == (16, 32)
+    _assert_same(_value_and_grads(_keeping(_chunked(seg, 16)), ops, w),
+                 _value_and_grads(_chunked(seg, 16), ops, w))
+
+
+# -- 1b. what a layer turn keeps of the rule -----------------------------------
+
+
+def _layer_gradient(policy):
+    """The gradient program of two scanned layer turns (a projection a
+    side of the rule), each turn one ``jax.checkpoint`` under
+    ``policy``, and its operands."""
+    seg = jnp.asarray(PACKINGS["starts_everywhere"])[None]
+    rng = np.random.default_rng(2)
+    d = H * DV
+    weights = {name: jnp.asarray(rng.normal(size=(2, d, n)) * 0.2,
+                                 jnp.float32)
+               for name, n in (("q", HK * DK), ("k", HK * DK), ("v", d),
+                               ("g", H), ("beta", H), ("out", d))}
+    x = jnp.asarray(rng.normal(size=(1, S, d)), jnp.float32)
+
+    def turn(x, w):
+        def unit(a):
+            a = a.reshape(1, S, HK, DK)
+            return a * jax.lax.rsqrt((a * a).sum(-1, keepdims=True) + 1e-6)
+
+        o = gated_delta.gated_delta_rule(
+            unit(x @ w["q"]), unit(x @ w["k"]),
+            (x @ w["v"]).reshape(1, S, H, DV), -jax.nn.softplus(x @ w["g"]),
+            jax.nn.sigmoid(x @ w["beta"]), seg, 16)
+        return x + jnp.tanh(o.reshape(1, S, d)) @ w["out"], None
+
+    def loss(weights, x):
+        return (jax.lax.scan(jax.checkpoint(turn, policy=policy), x,
+                             weights)[0] ** 2).mean()
+
+    return jax.jit(jax.value_and_grad(loss, (0, 1))), (weights, x)
+
+
+def _scans(jaxpr):
+    """The ``scan`` equations of a jaxpr, those inside its equations'
+    own jaxprs too."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "scan"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _scans(sub)
+    return n
+
+
+def test_a_turn_that_keeps_the_names_walks_forward_once_less(monkeypatch):
+    """A checkpointed layer turn's gradient program with and without
+    the policy: with it the backward pass holds no second forward of
+    the rule — one scan over blocks and one over a block's chunks fewer
+    (and the map over sequences around them), in the jaxpr and as loops
+    of the compiled program — and reads the same numbers."""
+    monkeypatch.setattr(gated_delta, "BLOCK_ROWS", 32)
+    names = jax.checkpoint_policies.save_only_these_names(*gated_delta.KEPT)
+    counts, values = {}, {}
+    for key, policy in (("plain", None), ("kept", names)):
+        program, operands = _layer_gradient(policy)
+        text = program.lower(*operands).compile().as_text()
+        counts[key] = (_scans(jax.make_jaxpr(program)(*operands).jaxpr),
+                       len(re.findall(r"(?m)^\s*(?:ROOT )?\S+ = .* while\(",
+                                      text)))
+        values[key] = program(*operands)
+    # sequences, blocks, chunks: a forward walk of the rule is 3 scans
+    assert counts["plain"][0] - counts["kept"][0] == 3
+    # … and two loops once the one-sequence map is unrolled
+    assert counts["plain"][1] - counts["kept"][1] == 2
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b)), values["kept"], values["plain"])
 
 
 # -- 2. packed = each segment alone --------------------------------------------
@@ -240,6 +356,39 @@ def test_operands_of_any_dtype_give_a_float32_result():
     assert got.dtype == jnp.float32
     want = jax.jit(_chunked(seg, 16))(*(a.astype(jnp.float32) for a in low))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_integers_get_no_cotangent_and_low_floats_their_own():
+    """``seg`` gets no cotangent, nor does an operand handed over as
+    integers (a write strength of 0 / 1); bfloat16 operands get
+    bfloat16 cotangents of a float32 result — the values a float32
+    copy of them gets, rounded."""
+    seg = jnp.asarray(PACKINGS["starts_everywhere"])
+    q, k, v, g, beta = _operands(g_scale=0.1)
+    w = _weight(np.asarray(seg))
+
+    def loss(q, k, v, g, beta, seg):
+        out = gated_delta.gated_delta_rule(
+            q[None], k[None], v[None], g[None], beta[None], seg[None], 16)[0]
+        assert out.dtype == jnp.float32
+        return (out * w).sum()
+
+    grad = jax.jit(jax.grad(loss, range(6), allow_int=True))
+    ones = jnp.ones((S, H), jnp.int32)
+    whole = grad(q, k, v, g, ones, seg)
+    assert whole[4].dtype == whole[5].dtype == jax.dtypes.float0
+    assert whole[4].shape == (S, H) and whole[5].shape == (S,)
+    want = grad(q, k, v, g, ones.astype(jnp.float32), seg)
+    for name, a, b in zip(NAMES[:4], whole, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+    low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v, g, beta))
+    got = grad(*low, seg)
+    want = grad(*(a.astype(jnp.float32) for a in low), seg)
+    for name, a, b in zip(NAMES, got, want):
+        assert a.dtype == jnp.bfloat16 and b.dtype == jnp.float32, name
+        np.testing.assert_array_equal(
+            np.asarray(a.astype(jnp.float32)),
+            np.asarray(b.astype(jnp.bfloat16).astype(jnp.float32)), name)
 
 
 def test_value_heads_read_the_key_head_of_their_group():

@@ -24,6 +24,7 @@ from reference import qwen3_next_jnp as ref  # noqa: E402
 
 from predictionio_tpu.models import qwen3_next as qn  # noqa: E402
 from predictionio_tpu.models import seq_backbone  # noqa: E402
+from predictionio_tpu.ops import gated_delta  # noqa: E402
 
 ARCH = dict(
     model_type="qwen3_next", hidden_size=64, num_hidden_layers=4,
@@ -504,6 +505,38 @@ def test_next_item_scores_is_the_last_row_of_sequence_logits():
 # -- 5. the architecture object ----------------------------------------------
 
 
+def test_what_the_turn_keeps_changes_no_number_of_a_step(monkeypatch):
+    """A layer turn's checkpoint keeps the recurrence's output and the
+    states that enter its blocks (``gated_delta.KEPT``); under a plain
+    ``jax.checkpoint(turn)`` — the policy taken away here — the step
+    walks the recurrence forward once more and reads the same loss and
+    routing records bit for bit, and the same gradients to the last
+    places of a float32 (the recomputed forward is another fusion:
+    4e-7 of a leaf's largest entry here)."""
+    c = _config(matmul_dtype="float32")
+    _, params, bias, batch = _setup(c)
+
+    def step():
+        return jax.jit(lambda p, b, bt: jax.value_and_grad(
+            qn.loss_fn, has_aux=True)(p, b, bt, c))(params, bias, batch)
+
+    (loss, rec), grads = step()
+    asked = []
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: asked.append(names))
+    (plain_loss, plain_rec), plain_grads = step()
+    # the stack asked for its policy and got None: a plain turn
+    assert asked == [gated_delta.KEPT]
+    assert float(loss) == float(plain_loss)
+    assert {"load", "pairs", "pairs_here", "dropped"} <= set(rec["moe"])
+    jax.tree.map(np.testing.assert_array_equal, rec, plain_rec)
+    plain_grads = _named(plain_grads)
+    for name, g in _named(grads).items():
+        g, want = np.asarray(g), np.asarray(plain_grads[name])
+        assert want.any(), name
+        assert np.abs(g - want).max() <= 5e-6 * np.abs(want).max(), name
+
+
 @pytest.mark.parametrize("over, match", [
     (dict(decoder_sparse_step=2), "decoder_sparse_step"),
     (dict(tie_word_embeddings=True), "tie_word_embeddings"),
@@ -578,6 +611,10 @@ def test_the_benchmarks_configuration_is_the_published_one():
             c.token_chunk) == (16, 16384, 1, 64, 4096)
     assert f"{qn.BACKBONE.n_params(c):,} parameters" in conf["bytes"]
     assert qn.BACKBONE.n_params(c) * 16 == 10_010_674_176
+    # three linear layers keep the rule's output [16,384 × 32 × 128]
+    # and the 8 states [32 × 128 × 128] that enter its blocks, float32
+    assert qn.BACKBONE.fit_attrs(c)["gdn_kept_bytes"] == 3 * 4 * (
+        67_108_864 + 4_194_304) == 855_638_016
 
 
 # -- 6. through the template -------------------------------------------------
@@ -638,6 +675,9 @@ def test_train_deploy_predict_returns_the_references_top_items(storage,
     fit = spans["seqrec.fit"]
     assert (fit["backbone"], fit["linear_layers"], fit["full_layers"]) == (
         "qwen3_next", 3, 1)
+    # 3 linear layers × 2 sequences a step × float32 × (32 rows × 4
+    # heads × 16 + one block's entering state 4 × 16 × 16)
+    assert fit["gdn_kept_bytes"] == 3 * 2 * 4 * (32 * 4 * 16 + 4 * 16 * 16)
     assert fit["moe_dropped_pairs"] == 0 and fit["losses_finite"]
     assert fit["router_bias_absmax"] == 0.0
     assert set(fit["grad_norms_first"]) == set(
