@@ -47,7 +47,13 @@ whole block over several denoising steps is not here.
 
 The four equal layers are ONE scanned body, run on both streams at
 once (the clean stream's keys and values feed the noised stream's
-attention in the same layer). Precision, packing, the pieces any
+attention in the same layer). A layer turn of that scan is one
+``jax.checkpoint``: the backward pass recomputes the turn — norms,
+projections, RoPE, the expert half — from the residual stream that
+entered it, EXCEPT the attention kernel's forward: the turn's policy
+keeps its output and the rows' log-sum-exp (``seq_attention.KEPT``;
+``attn_kept_bytes`` on the ``seqrec.fit`` span), so a step runs that
+kernel once a layer, not twice. Precision, packing, the pieces any
 backbone has, the train step and the verb's spans are
 :mod:`predictionio_tpu.models.seq_backbone`'s. The train step's router
 bias is carried as zeros and never moves (``bias_update_rate`` 0): this
@@ -66,6 +72,7 @@ from predictionio_tpu.models import seq_backbone
 from predictionio_tpu.models.seq_backbone import (
     _cast_in_loop, _chunked_ce, _dt, _experts, _mm, _rms, _rope, _route,
     _stacked, _swiglu_shapes, scope)
+from predictionio_tpu.ops import seq_attention
 
 
 @dataclass(frozen=True)
@@ -227,12 +234,15 @@ def _stack(params, bias, batch, c: SdarConfig):
     records (leading axis: layer). With ``batch["noised"]`` (the
     noised stream's tokens) R = 2·S, the clean rows first; without,
     ONE stream of ``batch["tokens"]`` under the clean stream's rule.
-    ``bias`` is the step's zero router bias: nothing reads it."""
+    ``bias`` is the step's zero router bias: nothing reads it. A layer
+    turn is one ``jax.checkpoint`` that keeps what attention names
+    (:data:`seq_attention.KEPT`) and recomputes the rest."""
     import jax
     import jax.numpy as jnp
 
     del bias
     seg, pos, tokens = batch["seg"], batch["pos"], batch["tokens"]
+    kept = jax.checkpoint_policies.save_only_these_names(*seq_attention.KEPT)
     if "noised" in batch:
         tokens = jnp.concatenate([tokens, batch["noised"]], axis=1)
     with scope("seqrec.embed"):
@@ -244,7 +254,7 @@ def _stack(params, bias, batch, c: SdarConfig):
 
     with scope("seqrec.stack"):
         return jax.lax.scan(
-            lambda x, iw: jax.checkpoint(turn)(x, iw), x,
+            lambda x, iw: jax.checkpoint(turn, policy=kept)(x, iw), x,
             (jnp.arange(c.num_hidden_layers), params["layers"]))
 
 
@@ -329,6 +339,15 @@ def _next_logits(params, bias, batch, n, c: SdarConfig):
     return _head_logits(params, x[0, at], c)
 
 
+def attn_kept_bytes(c: SdarConfig) -> int:
+    """Bytes a step keeps for the backward pass on account of the
+    turn's policy: per layer and sequence the kernel's output over both
+    streams' rows in the products' dtype and a float32 a row and head."""
+    rows = c.num_hidden_layers * c.seqs_per_step * 2 * c.seq_len
+    return rows * c.num_attention_heads * (
+        c.head_dim * _dt(c).itemsize + 4)
+
+
 def _refuse_mask_items(packed, c: SdarConfig) -> Dict[str, Any]:
     top = int(packed.tokens.max())
     if top >= c.mask_id:
@@ -354,6 +373,7 @@ BACKBONE = seq_backbone.build(
     batch_keys=BATCH_KEYS, train_keys=TRAIN_KEYS,
     pack_attrs=_refuse_mask_items,
     draws=lambda packed, seed: {
-        "draw": draws(packed.tokens.shape[0], seed)})
+        "draw": draws(packed.tokens.shape[0], seed)},
+    fit_attrs=lambda c: {"attn_kept_bytes": attn_kept_bytes(c)})
 
 n_params = BACKBONE.n_params    # benchmark/tests/test_sdar_layers.py

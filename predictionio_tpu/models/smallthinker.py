@@ -34,7 +34,15 @@ A run of consecutive layers of one kind is ONE scanned body over its
 stacked weights (``params["runs"][r]``), so the global and the window
 layers carry scopes of their own (``seqrec.gqa*`` / ``seqrec.swa*``):
 the published 52 layers are 26 runs, the benchmark's period of four
-two (1 × global, 3 × window).
+two (1 × global, 3 × window). A layer turn of a scan is one
+``jax.checkpoint``: the backward pass recomputes the turn from the
+residual stream that entered it. A GLOBAL layer's turn keeps the
+attention kernel's output and the rows' log-sum-exp
+(``seq_attention.KEPT``; ``attn_kept_bytes`` on the ``seqrec.fit``
+span) and so runs that kernel once a step, not twice; a WINDOW layer's
+turn keeps nothing: what a layer keeps costs the same bytes whatever
+its kind, and a window layer's forward is only the window's share of a
+global one's.
 
 Precision, packing, the pieces any backbone has, the train step and
 the verb's spans are :mod:`predictionio_tpu.models.seq_backbone`'s.
@@ -54,6 +62,7 @@ from predictionio_tpu.models import seq_backbone
 from predictionio_tpu.models.seq_backbone import (
     _cast_in_loop, _chunked_ce, _dt, _experts, _mm, _rms, _rope, _route,
     _stacked, _swiglu_shapes, scope)
+from predictionio_tpu.ops import seq_attention
 
 KINDS = ("global", "window")
 
@@ -246,12 +255,16 @@ def _layer(w, x, seg, pos, c: SmallThinkerConfig, kind: str):
 def _stack(params, bias, batch, c: SmallThinkerConfig):
     """Embedding and the stack: h_L [B, S, d] and the layers' routing
     records (leading axis: layer, in stack order). ``bias`` is the
-    step's zero router bias: nothing reads it."""
+    step's zero router bias: nothing reads it. A layer turn is one
+    ``jax.checkpoint``; a global layer's keeps what attention names
+    (:data:`seq_attention.KEPT`), a window layer's nothing: the bytes
+    are the same, the kernel's forward the window's share."""
     import jax
     import jax.numpy as jnp
 
     del bias
     seg, pos = batch["seg"], batch["pos"]
+    kept = jax.checkpoint_policies.save_only_these_names(*seq_attention.KEPT)
     with scope("seqrec.embed"):
         x = params["embed"][batch["tokens"]]
     stats = []
@@ -260,9 +273,10 @@ def _stack(params, bias, batch, c: SmallThinkerConfig):
             i, w = iw
             return _layer(_cast_in_loop(w, c, i), x, seg, pos, c, kind)
 
+        keep = kept if kind == "global" else None
         with scope("seqrec.stack"):
             x, s = jax.lax.scan(
-                lambda x, iw: jax.checkpoint(turn)(x, iw), x,
+                lambda x, iw: jax.checkpoint(turn, policy=keep)(x, iw), x,
                 (jnp.arange(n), w))
         stats.append(s)
     return x, jax.tree.map(lambda *a: jnp.concatenate(a), *stats)
@@ -293,6 +307,16 @@ def _next_logits(params, bias, batch, n, c: SmallThinkerConfig):
     return _head_logits(params, x[0, n - 1], c)
 
 
+def attn_kept_bytes(c: SmallThinkerConfig) -> int:
+    """Bytes a step keeps for the backward pass on account of the
+    global turns' policy: per global layer and sequence the kernel's
+    output in the products' dtype and a float32 a row and head."""
+    rows = (sum(n for kind, n in c.runs if kind == "global")
+            * c.seqs_per_step * c.seq_len)
+    return rows * c.num_attention_heads * (
+        c.head_dim * _dt(c).itemsize + 4)
+
+
 # -- the declaration ----------------------------------------------------------
 
 
@@ -307,6 +331,7 @@ BACKBONE = seq_backbone.build(
     next_logits=_next_logits, heads=("loss",), batch_keys=BATCH_KEYS,
     fit_attrs=lambda c: {
         "window_layers": sum(c.sliding_window_layout),
-        "global_layers": c.num_hidden_layers - sum(c.sliding_window_layout)})
+        "global_layers": c.num_hidden_layers - sum(c.sliding_window_layout),
+        "attn_kept_bytes": attn_kept_bytes(c)})
 
 n_params = BACKBONE.n_params    # benchmark/tests/test_smallthinker_layers.py

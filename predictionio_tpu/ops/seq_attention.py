@@ -601,9 +601,9 @@ def _attend(q, k, v, seg, bq, bk, scale, window=None):
     out, lse = _by_platform(
         functools.partial(_forward, bq=bq, bk=bk, scale=scale),
         *map(_heads_first, (q, k, v)), first, lo)
-    out = _heads_first(out)
+    out, lse = _named(_heads_first(out), lse[..., 0])
     # one number a row is kept for the backward pass, not its 128 lanes
-    return out, (q, k, v, out, lse[..., 0], seg)
+    return out, (q, k, v, out, lse, seg)
 
 
 def _attend_bwd(bq, bk, scale, window, res, do):
@@ -669,8 +669,8 @@ def _attend_blocks(q, k, v, seg, bq, bk, scale, block):
     out, lse = _by_platform(
         functools.partial(_forward, bq=bq, bk=bk, scale=scale, two=True),
         *map(_heads_first, (q, k, v)), spans, fwd)
-    out = _heads_first(out)
-    return out, (q, k, v, out, lse[..., 0], seg)
+    out, lse = _named(_heads_first(out), lse[..., 0])
+    return out, (q, k, v, out, lse, seg)
 
 
 def _attend_blocks_bwd(bq, bk, scale, block, res, do):
@@ -685,3 +685,25 @@ def _attend_blocks_bwd(bq, bk, scale, block, res, do):
 
 
 block_attention.defvjp(_attend_blocks, _attend_blocks_bwd)
+
+
+# -- what a caller's checkpoint may keep ---------------------------------------
+# (below the kernels' call sites: a line that moves above them is a new
+# cache key for every program that holds a kernel, PERF.md §6, PR 43)
+
+#: the names either forward gives its output ([S, H, Dv] in v's dtype,
+#: as it is handed back) and the rows' log-sum-exp ([H, S] float32) —
+#: of the backward pass's residuals (q, k, v, out, lse, seg) the two
+#: that only the forward KERNEL can make again. A caller that runs
+#: attention under ``jax.checkpoint`` with the policy
+#: ``save_only_these_names(*KEPT)`` keeps the two, and its backward
+#: pass recomputes q, k and v but does not run the forward kernel a
+#: second time; without a policy the names do nothing and the
+#: checkpoint recomputes the kernel, as it does everything else.
+KEPT = ("attn_out", "attn_lse")
+
+
+def _named(out, lse):
+    from jax.ad_checkpoint import checkpoint_name
+
+    return tuple(checkpoint_name(x, name) for x, name in zip((out, lse), KEPT))

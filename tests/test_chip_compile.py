@@ -468,23 +468,40 @@ def _qwen3next_cell_config():
 # -- the backbones' whole train programs at published widths -------------------
 
 #: cell → (its config, the parameters it pins, steps of a train, the
-#: least grouped products, the most bytes of arguments and temporaries)
+#: least grouped products, the most bytes of arguments and temporaries,
+#: the attention kernels' calls: forward, dq, dk/dv)
 TRAIN_PROGRAMS = {
     # 507.8 M parameters, 16 steps of 8 × 4,096 slots, three scanned
     # bodies (conv + dense, attention + experts, 3 × conv + experts),
     # the tied head: forward, recomputation and backward of one
     # attention layer's three kernels, and of four expert layers' three
     # grouped products
-    "lfm2": (_lfm2_cell_config, 507_820_160, 16, 30, 6.2e9, 12.5e9),
+    "lfm2": (_lfm2_cell_config, 507_820_160, 16, 30, 6.2e9, 12.5e9,
+             {"seq_attention_fwd": 2, "seq_attention_dq": 1,
+              "seq_attention_dkv": 1}),
     # 370.5 M parameters, 16 steps of 2 × 16,384 slots, two scanned
     # bodies (1 × global, 3 × window), the untied head; it fits by 1 GB:
-    # the pair buffer is 196,608 rows
+    # the pair buffer is 196,608 rows. The GLOBAL turn keeps the
+    # kernel's output and log-sum-exp (0.239 GB, ``attn_kept_bytes``:
+    # 15.07 GB of temporaries where a plain turn reads 14.93) and runs
+    # its forward once; the window run's three layers would keep
+    # 0.716 GB more, which does not fit, and run theirs twice: three
+    # forward calls, not four
     "smallthinker": (_smallthinker_cell_config, 370_547_200, 16, 30,
-                     4.6e9, 15.3e9),
+                     4.6e9, 15.3e9,
+                     {"seq_attention_fwd": 3, "seq_attention_dq": 2,
+                      "seq_attention_dkv": 2}),
     # 456.3 M parameters, 32 steps of one 8,192-slot sequence as two
     # streams, ONE scanned body of four layers, the noise drawn in the
-    # step, the untied head on the noised rows
-    "sdar": (_sdar_cell_config, 456_346_624, 32, 15, 5.6e9, 9.6e9),
+    # step, the untied head on the noised rows. Every turn keeps the
+    # kernel's output and log-sum-exp (0.545 GB over the four layers,
+    # ``attn_kept_bytes``) and the body holds ONE forward call: 8.82 GB
+    # of temporaries where a plain turn reads 9.08 (the second
+    # forward's output and its rows' statistics in their 128 lanes,
+    # 0.40 GB a layer, are no longer made)
+    "sdar": (_sdar_cell_config, 456_346_624, 32, 15, 5.6e9, 9.6e9,
+             {"seq_attention_bd_fwd": 1, "seq_attention_bd_dq": 1,
+              "seq_attention_bd_dkv": 1}),
     # 625.7 M parameters, 16 steps of one 16,384-slot sequence, two
     # scanned bodies (3 × linear, 1 × full), the expert half in four
     # chunks of 4,096 rows, the untied head; a linear layer's turn keeps
@@ -493,7 +510,9 @@ TRAIN_PROGRAMS = {
     # updated state 7.5 and the gradients 2.5 among them), 3.8 GB under
     # the chip
     "qwen3next": (_qwen3next_cell_config, 625_667_136, 16, 15, 7.6e9,
-                  12.3e9),
+                  12.3e9,
+                  {"seq_attention_fwd": 2, "seq_attention_dq": 1,
+                   "seq_attention_dkv": 1}),
 }
 
 
@@ -503,13 +522,17 @@ def test_train_program_at_published_widths(one_chip, cell):
     of the backbone's declaration — parameters with Adam's state, the
     router bias and the batches a train holds, all read from the
     backbone — for the described chip: the attention kernels and the
-    grouped products are in it, and it fits the chip's 16 GB."""
+    grouped products are in it — the forward kernel twice a scanned
+    body (the pass and the turn's recomputation) unless the body's
+    turn keeps its output —, and it fits the chip's 16 GB."""
+    import collections
+    import re
     import types
 
     from predictionio_tpu.models import seq_backbone
     from predictionio_tpu.models.seq_rec import _make_tx
 
-    config, n_params, steps, ragged, arguments, temporaries = (
+    config, n_params, steps, ragged, arguments, temporaries, attention = (
         TRAIN_PROGRAMS[cell])
     c = config()
     b = seq_backbone.backbone(c.model_type)
@@ -529,6 +552,10 @@ def test_train_program_at_published_widths(one_chip, cell):
     compiled = b.train_program(c, 1).lower((params, opt, bias),
                                            data).compile()
     assert _ragged_calls(compiled) >= ragged
+    # a kernel's custom call is named after it: ``%seq_attention_fwd.3 =``
+    assert collections.Counter(re.findall(
+        r"(?m)^\s*%?(seq_attention_\w+?)(?:\.\d+)? = ",
+        compiled.as_text())) == attention
     mem = compiled.memory_analysis()
     # the donated state is counted in the arguments AND (updated) in
     # the temporaries: what the program holds at once is the latter

@@ -23,7 +23,9 @@ from reference import sdar_moe_jnp as ref  # noqa: E402
 
 from predictionio_tpu.models import sdar_moe as sd  # noqa: E402
 from predictionio_tpu.models import seq_backbone  # noqa: E402
-from predictionio_tpu.ops import moe_dispatch  # noqa: E402
+from predictionio_tpu.ops import moe_dispatch, seq_attention  # noqa: E402
+from tests.kernel_calls import kernel_calls  # noqa: E402
+from tests.test_chip_compile import _sdar_cell_config  # noqa: E402
 
 ARCH = dict(
     model_type="sdar_moe", hidden_size=64, head_dim=16,
@@ -519,6 +521,53 @@ def test_a_history_reads_the_same_packed_or_alone():
         np.testing.assert_allclose(got[rows], want, rtol=2e-4, atol=2e-5)
 
 
+def test_what_the_turn_keeps_changes_no_number_of_a_step(exact, monkeypatch):
+    """A layer turn's checkpoint keeps the attention kernel's output
+    and log-sum-exp (``seq_attention.KEPT``); under a plain
+    ``jax.checkpoint(turn)`` — the policy taken away here — the step
+    runs the forward kernel a second time and reads the same loss and
+    records bit for bit, and the same gradients to the last places of a
+    float32 (what is recomputed beside the kernel is another fusion)."""
+    c, args = exact["c"], (exact["params"], exact["bias"], exact["train"])
+    step = lambda: jax.jit(lambda p, b, bt: jax.value_and_grad(  # noqa: E731
+        sd.loss_fn, has_aux=True)(p, b, bt, c))
+    calls = kernel_calls(jax.make_jaxpr(step())(*args).jaxpr)
+    asked = []
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: asked.append(names))
+    plain_calls = kernel_calls(jax.make_jaxpr(step())(*args).jaxpr)
+    (plain_loss, plain_rec), plain_grads = step()(*args)
+    # the stack asked for its policy (twice: two traces) and got None
+    assert asked == [seq_attention.KEPT] * 2
+    # one call site a scanned body (both branches of the platform's
+    # choice) where the plain turn has two
+    assert plain_calls - calls == {"seq_attention_bd_fwd": 2}
+    assert calls == {"seq_attention_bd_fwd": 2, "seq_attention_bd_dq": 2,
+                     "seq_attention_bd_dkv": 2}
+    assert float(exact["loss"]) == float(plain_loss)
+    jax.tree.map(np.testing.assert_array_equal, exact["rec"], plain_rec)
+    plain_grads = _named(plain_grads)
+    for name, g in _named(exact["grads"]).items():
+        g, want = np.asarray(g), np.asarray(plain_grads[name])
+        assert want.any(), name
+        assert np.abs(g - want).max() <= 1e-6 * np.abs(want).max(), name
+
+
+def test_the_bytes_the_turns_keep():
+    """Per layer and sequence: both streams' rows × heads × (the head
+    width in the products' dtype + one float32)."""
+    # 2 layers × 2 sequences × 2 × 64 rows × 4 heads × (16 × 2 B + 4 B)
+    assert sd.BACKBONE.fit_attrs(_config()) == {
+        "attn_kept_bytes": 2 * 2 * 128 * 4 * (32 + 4)}
+    assert sd.attn_kept_bytes(_config(matmul_dtype="float32")) == (
+        2 * 2 * 128 * 4 * (64 + 4))
+    # the cell: 4 layers × 16,384 stream rows × 32 heads of 128
+    c = _sdar_cell_config()
+    assert (c.num_hidden_layers, c.seqs_per_step * 2 * c.seq_len,
+            c.num_attention_heads, c.head_dim) == (4, 16384, 32, 128)
+    assert sd.attn_kept_bytes(c) == 536_870_912 + 8_388_608 == 545_259_520
+
+
 @pytest.mark.parametrize("over, match", [
     ({"tie_word_embeddings": True}, "tie_word_embeddings"),
     ({"mlp_only_layers": [0]}, "mlp_only_layers"),
@@ -620,6 +669,9 @@ def test_train_deploy_predict_returns_the_references_top_items(storage,
     assert pack["attn_pairs_bd"] <= pack["attn_tile_pairs_bd"]
     fit = spans["seqrec.fit"]
     assert fit["backbone"] == "sdar_moe"
+    # 2 layers × 2 sequences a step × 2 × 28 rows × 4 heads × (16
+    # float32 + the log-sum-exp)
+    assert fit["attn_kept_bytes"] == 2 * 2 * 56 * 4 * (64 + 4)
     assert fit["moe_dropped_pairs"] == 0 and fit["losses_finite"]
     assert fit["router_bias_absmax"] == 0.0
     assert fit["bd_real"] == 2 * 20 * 14         # two epochs

@@ -9,6 +9,7 @@ import pytest
 
 from predictionio_tpu.models import glm4_moe_lite as glm
 from predictionio_tpu.ops import seq_attention as sa
+from tests.kernel_calls import kernel_calls
 
 H, D, DV = 2, 16, 8
 
@@ -771,3 +772,98 @@ def test_block_pairs_against_brute_force_and_the_closed_form(block):
     for n in (block, 8 * block, 8192):
         assert sa.block_pairs([n], block) == n * (n + block)
     assert sa.block_pairs([8192], 4) == 67_141_632
+
+
+# -- what a caller's checkpoint keeps ------------------------------------------
+
+
+def _kept_case(name):
+    """(attend(q, k, v), operands of TWO sequences, the forward
+    kernel's name): the global and the window form, grouped heads, and
+    the block rule on both streams."""
+    if name == "block_rule":
+        seg, _, q, k, v, tiled, _ = _bd_case("grouped_8_over_2")
+        fwd = "seq_attention_bd_fwd"
+    else:
+        seg, bq, bk, window, H_, Hkv_ = {
+            "global": (_segments(20, 70, 30, S=128), 32, 64, None, 4, 4),
+            "window": WINDOWS["longer_equal_shorter"],
+            "grouped_8_over_2": (PACKINGS["grouped_8_over_2"][0], 32, 128,
+                                 None, 8, 2),
+            "grouped_window": WINDOWS["one_long_segment"],
+        }[name]
+        q, k, v = _operands(len(seg), jnp.float32, 64, 64, H_, Hkv_)
+        tiled, fwd = _windowed(seg, bq, bk, window), "seq_attention_fwd"
+    rng = np.random.default_rng(4)
+    return tiled, tuple(jnp.stack([a, jnp.asarray(
+        rng.normal(size=a.shape), a.dtype)]) for a in (q, k, v)), fwd
+
+
+@pytest.mark.parametrize("mapped", [False, True],
+                         ids=["one_sequence", "under_lax_map"])
+@pytest.mark.parametrize("name", ["global", "window", "grouped_8_over_2",
+                                  "grouped_window", "block_rule"])
+def test_a_checkpoint_that_keeps_the_names_runs_the_forward_once(name,
+                                                                 mapped):
+    """Attention between two products — a layer turn — under
+    ``jax.checkpoint(turn, policy=save_only_these_names(*KEPT))``
+    against a plain ``jax.checkpoint(turn)``: the gradient program
+    holds HALF the forward kernel's calls (one call site where the
+    plain turn's recomputation adds a second; ``platform_dependent``
+    shows a site once a branch) and as many of dq and dk/dv, and its
+    value and gradients are the plain turn's bit for bit — alone, and
+    under ``jax.lax.map`` over sequences, how the backbones call it."""
+    attend, (q, k, v), fwd = _kept_case(name)
+    if not mapped:
+        q, k, v = q[0], k[0], v[0]
+
+    def turn(q, k, v):
+        one = lambda a: jnp.tanh(attend(a[0] * 0.5, a[1], a[2] * 2.0))  # noqa: E731,E501
+        out = jax.lax.map(one, (q, k, v)) if mapped else one((q, k, v))
+        return (out ** 2).sum()
+
+    plain = jax.value_and_grad(jax.checkpoint(turn), (0, 1, 2))
+    kept = jax.value_and_grad(jax.checkpoint(
+        turn, policy=jax.checkpoint_policies.save_only_these_names(
+            *sa.KEPT)), (0, 1, 2))
+    calls = {key: kernel_calls(jax.make_jaxpr(f)(q, k, v).jaxpr)
+             for key, f in (("plain", plain), ("kept", kept))}
+    assert calls["plain"][fwd] == 4 and calls["kept"][fwd] == 2
+    assert calls["plain"] - calls["kept"] == {fwd: 2}
+    assert set(calls["kept"].values()) == {2} and len(calls["kept"]) == 3
+    got, want = jax.jit(kept)(q, k, v), jax.jit(plain)(q, k, v)
+    assert float(got[0]) == float(want[0])
+    for which, a, b in zip("qkv", got[1], want[1]):
+        assert np.asarray(b).any(), which
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), which)
+
+
+@pytest.mark.parametrize("name", ["global", "block_rule"])
+def test_the_names_alone_keep_nothing(name):
+    """Without a policy the names do nothing: a plain checkpoint's
+    gradient program runs the forward kernel twice, no checkpoint at
+    all once — and what is named is the output as it is handed back
+    and one float32 a row and head."""
+    attend, (q, k, v), fwd = _kept_case(name)
+    q, k, v = q[0], k[0], v[0]
+    loss = lambda q, k, v: (attend(q, k, v) ** 2).sum()  # noqa: E731
+    assert kernel_calls(jax.make_jaxpr(jax.grad(loss))(
+        q, k, v).jaxpr)[fwd] == 2
+    jaxpr = jax.make_jaxpr(jax.grad(jax.checkpoint(loss)))(q, k, v).jaxpr
+    assert kernel_calls(jaxpr)[fwd] == 4
+
+    def named(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "name":
+                out[eqn.params["name"]] = eqn.outvars[0].aval
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                named(sub, out)
+        return out
+
+    avals = named(jaxpr, {})
+    assert tuple(avals) == sa.KEPT == ("attn_out", "attn_lse")
+    S, H_ = q.shape[:2]
+    assert (avals["attn_out"].shape, avals["attn_out"].dtype) == (
+        (S, H_, v.shape[-1]), v.dtype)
+    assert (avals["attn_lse"].shape, avals["attn_lse"].dtype) == (
+        (H_, S), jnp.float32)

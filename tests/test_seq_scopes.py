@@ -24,6 +24,7 @@ from predictionio_tpu.models import sdar_moe as sd
 from predictionio_tpu.models import seq_backbone
 from predictionio_tpu.models import smallthinker as st
 from predictionio_tpu.models.seq_rec import _make_tx
+from tests.kernel_calls import kernel_calls
 
 TINY = dict(hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
             ep_size=1, num_experts_per_tok=2, vocab_size=50, seq_len=64,
@@ -279,26 +280,34 @@ def test_under_one_name_the_cache_answers_with_the_old_scopes(tmp_path,
 
 #: the accepted backbones' train programs at the sizes above, lowered:
 #: SHA-256 of the text with the program's name (a digest of the scope
-#: table) taken out, as the parent of PR 45 lowers them — a change to
-#: shared code for a new backbone leaves them text for text
-LOWERED = {"glm4_moe_lite": "29a798e81f5899aa",
-           "lfm2_moe": "b2087560cb293449",
-           "smallthinker": "e21c877aefa353db",
-           "sdar_moe": "5d08acd6ea8c5038"}
+#: table) and the counters behind its private functions' names
+#: (``@closed_call_757``: what ELSE the process lowered moves them, and
+#: a ``checkpoint_name`` does) taken out. ``glm4_moe_lite``,
+#: ``lfm2_moe`` and ``qwen3_next`` as the parent of PR 48 lowers them —
+#: a change to shared code for another backbone leaves them text for
+#: text; ``smallthinker`` and ``sdar_moe`` as PR 48 left them (their
+#: turns keep attention's output: one forward kernel fewer)
+LOWERED = {"glm4_moe_lite": "9e2bc99c28368c02",
+           "lfm2_moe": "d71df69e79a477b8",
+           "qwen3_next": "b64991842aba6bc7",
+           "smallthinker": "e74f85d73d036491",
+           "sdar_moe": "ecd08ba9dce950d7"}
 
 
 @pytest.mark.parametrize("model_type", sorted(LOWERED))
 def test_an_accepted_backbones_program_lowers_as_before(model_type):
-    """The rule of PRs 38, 40 and 45: what a new backbone passes to
+    """The rule of PRs 38, 40, 45 and 48: what a new backbone passes to
     shared code (a recurrence's chunk, a shared expert's gate, a leaf's
-    own start) leaves the accepted programs apart from their NAME as
-    they were. (A JAX that prints another text moves all four.)"""
+    own start), and what another backbone's turn keeps of a shared
+    operator, leaves the accepted programs apart from their NAME as
+    they were. (A JAX that prints another text moves all five.)"""
     module, c = BACKBONES[model_type]
     text = _program(module, c).lower(*_abstract_args(module, c)).as_text()
     name = seq_backbone.program_name()
     assert name in text
-    assert hashlib.sha256(text.replace(name, "train").encode(
-        )).hexdigest()[:16] == LOWERED[model_type]
+    text = re.sub(r"@(\w+?)_\d+\b", r"@\1", text.replace(name, "train"))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
+        LOWERED[model_type])
 
 
 # -- (c) the seam: a backbone declares, ``build`` makes the rest --------------
@@ -348,3 +357,39 @@ def test_a_backbone_is_its_declaration_built_once(model_type):
     assert own == set(BINDINGS[model_type])
     for name, field in BINDINGS[model_type].items():
         assert getattr(module, name) is getattr(b, field)
+
+
+# -- (c) which layer turns keep attention's output (PR 48) --------------------
+
+#: backbone → (the forward kernel, its calls in the train program's
+#: jaxpr — two a call site, one a branch of ``platform_dependent`` —
+#: and of those the ones a turn's RECOMPUTATION makes): every scanned
+#: body that holds attention calls it in the forward pass; its turn's
+#: recomputation calls it again unless the backbone's checkpoint line
+#: keeps ``seq_attention.KEPT`` (``sdar_moe``: all layers;
+#: ``smallthinker``: the global run, not the window run)
+FORWARD_CALLS = {
+    "glm4_moe_lite": ("seq_attention_fwd", 8, 4),    # dense + expert body
+    "lfm2_moe": ("seq_attention_fwd", 4, 2),
+    "qwen3_next": ("seq_attention_fwd", 4, 2),       # the full run
+    "smallthinker": ("seq_attention_fwd", 6, 2),     # the window run's
+    "sdar_moe": ("seq_attention_bd_fwd", 2, 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BACKBONES))
+def test_which_turns_run_attentions_forward_twice(name):
+    """The train step's jaxpr, backbone by backbone: ``glm4_moe_lite``,
+    ``lfm2_moe`` and ``qwen3_next`` hold the forward kernel calls they
+    held before any turn kept anything (their ``_stack`` carries no
+    such policy), ``smallthinker`` one call site fewer, ``sdar_moe``
+    half — and all of them as many dq and dk/dv calls as bodies."""
+    module, c = BACKBONES[name]
+    calls = kernel_calls(jax.make_jaxpr(_program(module, c))(
+        *_abstract_args(module, c)).jaxpr)
+    fwd, total, recomputed = FORWARD_CALLS[name]
+    assert calls[fwd] == total
+    bodies = (total - recomputed) // 2
+    assert calls[fwd.replace("fwd", "dq")] == 2 * bodies
+    assert calls[fwd.replace("fwd", "dkv")] == 2 * bodies
+    assert len(calls) == 3

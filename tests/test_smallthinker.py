@@ -23,7 +23,9 @@ from reference import smallthinker_jnp as ref  # noqa: E402
 
 from predictionio_tpu.models import seq_backbone  # noqa: E402
 from predictionio_tpu.models import smallthinker as st  # noqa: E402
-from predictionio_tpu.ops import moe_dispatch  # noqa: E402
+from predictionio_tpu.ops import moe_dispatch, seq_attention  # noqa: E402
+from tests.kernel_calls import kernel_calls  # noqa: E402
+from tests.test_chip_compile import _smallthinker_cell_config  # noqa: E402
 
 ARCH = dict(
     model_type="smallthinker", hidden_size=64, head_dim=16,
@@ -474,6 +476,68 @@ def test_a_history_reads_the_same_packed_or_alone():
                                atol=2e-5)
 
 
+def test_what_the_global_turn_keeps_changes_no_number_of_a_step(
+        exact, monkeypatch):
+    """The GLOBAL run's turn keeps the attention kernel's output and
+    log-sum-exp (``seq_attention.KEPT``), the WINDOW run's turn is a
+    plain ``jax.checkpoint``; with the policy taken away here the step
+    runs the global layer's forward kernel a second time and reads the
+    same loss and records bit for bit, and the same gradients to the
+    last places of a float32."""
+    c, args = exact["c"], (exact["params"], exact["bias"], exact["batch"])
+    step = lambda: jax.jit(lambda p, b, bt: jax.value_and_grad(  # noqa: E731
+        st.loss_fn, has_aux=True)(p, b, bt, c))
+    calls = kernel_calls(jax.make_jaxpr(step())(*args).jaxpr)
+    # which run keeps: a stack of global layers alone holds ONE forward
+    # call site (both branches of the platform's choice), a stack of
+    # window layers alone two
+    for layout, sites in (([0] * 4, 1), ([1] * 4, 2)):
+        one = _config(matmul_dtype="float32", sliding_window_layout=layout,
+                      rope_layout=layout)
+        params, bias = jax.eval_shape(lambda: st.BACKBONE.init_state(one, 0))
+        assert kernel_calls(jax.make_jaxpr(jax.grad(
+            lambda p, b, bt: st.loss_fn(p, b, bt, one)[0]))(
+                params, bias, args[2]).jaxpr)["seq_attention_fwd"] == (
+                    2 * sites)
+    asked = []
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: asked.append(names))
+    plain_calls = kernel_calls(jax.make_jaxpr(step())(*args).jaxpr)
+    (plain_loss, plain_rec), plain_grads = step()(*args)
+    assert asked == [seq_attention.KEPT] * 2
+    # two scanned bodies: forward, dq and dk/dv once each (both
+    # branches of the platform's choice), and the WINDOW body's
+    # recomputed forward; the plain step has the global body's too
+    assert calls == {"seq_attention_fwd": 6, "seq_attention_dq": 4,
+                     "seq_attention_dkv": 4}
+    assert plain_calls - calls == {"seq_attention_fwd": 2}
+    assert float(exact["loss"]) == float(plain_loss)
+    jax.tree.map(np.testing.assert_array_equal, exact["rec"], plain_rec)
+    plain_grads = _named(plain_grads)
+    for name, g in _named(exact["grads"]).items():
+        g, want = np.asarray(g), np.asarray(plain_grads[name])
+        assert want.any(), name
+        assert np.abs(g - want).max() <= 1e-6 * np.abs(want).max(), name
+
+
+def test_the_bytes_the_global_turns_keep():
+    """Per GLOBAL layer and sequence: rows × heads × (the head width
+    in the products' dtype + one float32); a window layer keeps none."""
+    # 1 global layer × 2 sequences × 64 rows × 4 heads × (16 × 2 B + 4 B)
+    assert st.BACKBONE.fit_attrs(_config())["attn_kept_bytes"] == (
+        2 * 64 * 4 * (32 + 4))
+    assert st.attn_kept_bytes(_config(
+        num_hidden_layers=8, sliding_window_layout=[0, 1, 1, 1] * 2,
+        rope_layout=[0, 1, 1, 1] * 2)) == 2 * 2 * 64 * 4 * (32 + 4)
+    assert st.attn_kept_bytes(_config(
+        sliding_window_layout=[1] * 4, rope_layout=[1] * 4)) == 0
+    # the cell: 1 global layer × 2 × 16,384 rows × 28 heads of 128
+    c = _smallthinker_cell_config()
+    assert (c.runs, c.seqs_per_step * c.seq_len, c.num_attention_heads,
+            c.head_dim) == ((("global", 1), ("window", 3)), 32768, 28, 128)
+    assert st.attn_kept_bytes(c) == 234_881_024 + 3_670_016 == 238_551_040
+
+
 # -- 5. the architecture object ----------------------------------------------
 
 
@@ -562,6 +626,9 @@ def test_train_deploy_predict_returns_the_references_top_items(storage,
     fit = spans["seqrec.fit"]
     assert (fit["backbone"], fit["window_layers"], fit["global_layers"]) == (
         "smallthinker", 3, 1)
+    # 1 global layer × 2 sequences a step × 32 rows × 4 heads × (16
+    # float32 + the log-sum-exp)
+    assert fit["attn_kept_bytes"] == 2 * 32 * 4 * (64 + 4)
     assert fit["moe_dropped_pairs"] == 0 and fit["losses_finite"]
     assert fit["router_bias_absmax"] == 0.0
     assert set(fit["grad_norms_first"]) == set(st.BACKBONE.grad_groups(_config()))
