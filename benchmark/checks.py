@@ -9,6 +9,12 @@ import numpy as np
 from harness import say
 
 
+#: every check this process made, in order, each with the number it
+#: compared beside its limit: ``run.py`` prints them as the run's last
+#: lines on stderr and puts them last in the result's line
+RECORD: list = []
+
+
 class Verdict:
     """Collects the checks of one run; all must hold."""
 
@@ -16,7 +22,9 @@ class Verdict:
         self.ok = True
 
     def check(self, cond: bool, what: str) -> bool:
-        say(("check ok: " if cond else "check FAILED: ") + what)
+        told = ("check ok: " if cond else "check FAILED: ") + what
+        say(told)
+        RECORD.append(told)
         self.ok = self.ok and bool(cond)
         return bool(cond)
 

@@ -212,6 +212,7 @@ def run(cell) -> dict:
                     config["iterations"])
             flops, moved = roofline.train_flops(*size), roofline.train_bytes(
                 *size)
+            obs["als_train_flops"] = flops
             say(f"the ALS mathematics of one train needs {flops:.3e} "
                 f"operations and {moved:.3e} bytes: over the verb's "
                 f"{trains[0]['wall']:.1f} s that is "

@@ -46,6 +46,31 @@ def metric_applies(spec: dict, cell_name: str) -> bool:
     return "workloads" not in spec or cell_name in spec["workloads"]
 
 
+def check_table(bench: dict) -> None:
+    """A per-layer metric is declared ONCE, under its reader's name
+    (``benchmark/layers/<name>.py``), with its cells in ``workloads``:
+    a name with a dot (a copy under a suffix, as before PR 49), a name
+    that comes twice or a ``workloads`` entry that names no cell is
+    refused before anything runs."""
+    cells = {w["name"] for w in bench["workloads"]}
+    seen = set()
+    for spec in bench["per_layer"]:
+        name = spec["name"]
+        if "." in name:
+            raise BenchFailure(
+                f"per-layer metric {name!r}: a name is its reader's, "
+                f"with no dot; list the cell in {name.split('.')[0]!r}'s "
+                "workloads instead of copying it under a suffix")
+        if name in seen:
+            raise BenchFailure(f"per-layer metric {name!r} is declared "
+                               "twice in BENCHMARK.json")
+        seen.add(name)
+        orphans = [w for w in spec.get("workloads", ()) if w not in cells]
+        if orphans:
+            raise BenchFailure(f"per-layer metric {name!r} lists "
+                               f"{orphans}: no such workload")
+
+
 class Cell:
     """One entry of BENCHMARK.json's ``workloads`` with its files."""
 
@@ -53,6 +78,7 @@ class Cell:
                  tiny: bool, t_start: float) -> None:
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             bench = json.load(f)
+        check_table(bench)
         entry = next((w for w in bench["workloads"] if w["name"] == name),
                      None)
         if entry is None:
@@ -238,11 +264,11 @@ def profiler_options():
 
 def read_layers(cell: Cell, obs: Dict[str, Any]) -> Dict[str, dict]:
     """Each per-layer metric of this cell through its own reader:
-    ``benchmark/layers/<name up to the first dot>.py``. A reader that
-    finds nothing to read returns None and the metric is left out."""
+    ``benchmark/layers/<name>.py``. A reader that finds nothing to read
+    returns None and the metric is left out."""
     out = {}
     for spec in cell.per_layer:
-        reader = load_module("layers", spec["name"].split(".")[0])
+        reader = load_module("layers", spec["name"])
         value = reader.read(obs)
         if value is None:
             say(f"layer metric {spec['name']}: nothing to read, left out")
@@ -267,9 +293,15 @@ def metrics_line(cell: Cell, out: Dict[str, Any]) -> Dict[str, dict]:
 
 def last_line(correct: bool, attempted: int, failed: int,
               metrics: Dict[str, dict], device: dict,
-              breakdown: Optional[dict] = None) -> str:
+              breakdown: Optional[dict] = None,
+              compared: Optional[List[str]] = None) -> str:
+    """``compared``: every check of the run, its number beside its
+    limit, under a key of its own that comes LAST (what the driver keeps
+    of a run that is not correct is the line's end)."""
     line = {"correct": bool(correct), "attempted": int(attempted),
             "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    if compared is not None:
+        line["checks"] = list(compared)
     return json.dumps(line)

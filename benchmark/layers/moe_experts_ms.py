@@ -1,6 +1,13 @@
-"""Layer "kernels": device milliseconds of ONE traced train under the scope ``seqrec.moe.experts``: the grouped products of the held experts and their SwiGLU
-(``scope_reduce``: the operations' ``tf_op`` paths), forward, recomputation
-and backward. Absent where the trace names no such scope."""
+"""Layer "kernels": device milliseconds of ONE traced train that the held
+experts cost, WHOLE: XLA's ``ragged-dot`` kernels (the three grouped
+products of ``moe_dispatch.grouped_matmul`` and their group metadata,
+found by the operations' own names — the compiler writes their path as
+``ragged-dot-none``, under no scope; ``moe_ragged_dot_ms`` alone) plus
+what runs BETWEEN them under the scope ``seqrec.moe.experts`` (the
+activation, the gate's product, copies; ``scope_reduce``: the
+operations' ``tf_op`` paths) — forward, recomputation and backward
+(``seq_layers.seconds``). Before PR 49 it read the scope alone. Absent
+where the trace names no such scope."""
 
 import seq_layers
 

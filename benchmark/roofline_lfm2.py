@@ -13,7 +13,8 @@ Also which ``seqrec.*`` scopes the cell's own device metrics sum
 
 from __future__ import annotations
 
-import roofline
+import scope_layers
+import seq_layers
 
 #: metric → the scopes (innermost wins) whose device seconds it sums
 SCOPES = {
@@ -30,21 +31,11 @@ MIX_FLOPS = 8
 
 
 def seconds(obs, metric: str):
-    scopes = obs.get("scopes")
-    if not scopes:
-        return None
-    hit = [scopes[s] for s in SCOPES[metric] if s in scopes]
-    return sum(hit) if hit else None
+    return scope_layers.seconds(obs, *SCOPES[metric])
 
 
 def roofline_pct(obs, metric: str, part: str):
-    """The least time the chip could take for what ``part`` of
-    ``obs["need"]`` needs over the metric's device time, in percent."""
-    secs, need = seconds(obs, metric), obs.get("need")
-    if not secs or need is None or part not in need or "peaks" not in obs:
-        return None
-    least, _bound = roofline.least_seconds(need[part], obs["peaks"])
-    return 100.0 * least / secs
+    return seq_layers.share_pct(obs, seconds(obs, metric), part)
 
 
 def layers(c) -> dict:

@@ -19,8 +19,8 @@ Also which ``seqrec.*`` scopes the cell's own device metrics sum
 
 from __future__ import annotations
 
-import roofline
 import scope_layers
+import seq_layers
 
 #: metric → the scopes (innermost wins) whose device seconds it sums
 SCOPES = {
@@ -33,18 +33,11 @@ OPERAND = 2
 
 
 def seconds(obs, metric: str):
-    ms = scope_layers.milliseconds(obs, *SCOPES[metric])
-    return None if ms is None else ms / 1e3
+    return scope_layers.seconds(obs, *SCOPES[metric])
 
 
 def roofline_pct(obs, metric: str, part: str):
-    """The least time the chip could take for what ``part`` of
-    ``obs["need"]`` needs over the metric's device time, in percent."""
-    secs, need = seconds(obs, metric), obs.get("need")
-    if not secs or need is None or part not in need or "peaks" not in obs:
-        return None
-    least, _bound = roofline.least_seconds(need[part], obs["peaks"])
-    return 100.0 * least / secs
+    return seq_layers.share_pct(obs, seconds(obs, metric), part)
 
 
 def per_row_macs(c) -> dict:

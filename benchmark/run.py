@@ -5,8 +5,10 @@
 
 set-up (data from the seed, compile or cache load, warm-up) → the
 measured window → the checks → ONE last line of JSON on stdout
-(``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and with
-``--trace 1`` ``breakdown``). Everything else goes on earlier lines.
+(``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, with
+``--trace 1`` ``breakdown``, and last ``checks``: every comparison that
+decided ``correct``, its number beside its limit — also the last lines
+on stderr). Everything else goes on earlier lines.
 
 It sets no platform and no ``PIO_*`` flag that chooses a code path. On
 anything but a TPU whose ``device_kind`` is in ``benchmark/peaks.json``
@@ -31,6 +33,7 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, ROOT)
 
+import checks        # noqa: E402
 import harness       # noqa: E402
 from harness import BenchFailure, say    # noqa: E402
 
@@ -83,7 +86,8 @@ def main(argv=None) -> int:
             device["window_s"] = out["obs"]["trace"].window_s
             breakdown = out.get("breakdown")
         line = harness.last_line(out["correct"], out["attempted"],
-                                 out["failed"], metrics, device, breakdown)
+                                 out["failed"], metrics, device, breakdown,
+                                 checks.RECORD)
     except BenchFailure as e:
         say(f"FAILED: {e}")
         return 1
@@ -91,6 +95,8 @@ def main(argv=None) -> int:
         traceback.print_exc()
         say(f"FAILED: {type(e).__name__}: {e}")
         return 1
+    for told in checks.RECORD:     # each number beside its limit, last
+        print(f"[bench] {told}", file=sys.stderr)
     sys.stderr.flush()
     if not on_chip:
         say(f"rehearsal line: {line}")

@@ -11,14 +11,19 @@ norm and the residual add on the expert side."""
 from __future__ import annotations
 
 
-def milliseconds(obs, *scopes: str):
-    """Device milliseconds under ``scopes``; None where the trace names
-    none of them (a program without the scope) or gave no scopes."""
+def seconds(obs, *scopes: str):
+    """Device seconds under ``scopes``; None where the trace names none
+    of them (a program without the scope) or gave no scopes."""
     found = obs.get("scopes")
     if not found:
         return None
     hit = [found[s] for s in scopes if s in found]
-    return sum(hit) * 1e3 if hit else None
+    return sum(hit) if hit else None
+
+
+def milliseconds(obs, *scopes: str):
+    secs = seconds(obs, *scopes)
+    return None if secs is None else secs * 1e3
 
 
 def ragged_dot_seconds(obs) -> float:

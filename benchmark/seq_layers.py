@@ -1,10 +1,22 @@
-"""What the sequence cell's device-trace readers share: the scopes a
+"""What the sequence cells' device-trace readers share: the scopes a
 metric sums (``scope_reduce.scope_seconds`` of the traced train) and a
-kernel's share of its roofline."""
+kernel's share of its roofline (``share_pct``, which the cells' own
+``roofline_*.roofline_pct`` call too).
+
+The experts are the one metric a scope alone does not hold: the compiler
+writes the ``ragged-dot`` kernels' path as ``ragged-dot-none``, under no
+scope, so ``seqrec.moe.experts`` holds only what runs BETWEEN the
+grouped products. ``seconds(obs, "moe_experts")`` is the scope PLUS the
+kernels by their operations' own names
+(``scope_layers.ragged_dot_seconds``, what ``moe_ragged_dot_ms`` reads):
+the experts' whole cost, for the time and for the share alike (PR 49;
+before it the share divided the kernels' need by seconds that left the
+kernels out)."""
 
 from __future__ import annotations
 
 import roofline
+import scope_layers
 
 #: metric → the scopes (innermost wins) whose device seconds it sums
 SCOPES = {
@@ -20,18 +32,25 @@ SCOPES = {
 
 
 def seconds(obs, metric: str):
-    scopes = obs.get("scopes")
-    if not scopes:
-        return None
-    hit = [scopes[s] for s in SCOPES[metric] if s in scopes]
-    return sum(hit) if hit else None
+    """Device seconds of ONE traced train under the metric's scopes —
+    for ``moe_experts`` with the ``ragged-dot`` kernels, which lie under
+    no scope; None where the trace names none of the scopes."""
+    secs = scope_layers.seconds(obs, *SCOPES[metric])
+    if secs is not None and metric == "moe_experts":
+        secs += scope_layers.ragged_dot_seconds(obs)
+    return secs
 
 
-def roofline_pct(obs, metric: str, part: str):
+def share_pct(obs, secs, part: str):
     """The least time the chip could take for what ``part`` of
-    ``obs["need"]`` needs over the metric's device time, in percent."""
-    secs, need = seconds(obs, metric), obs.get("need")
-    if not secs or need is None or "peaks" not in obs:
+    ``obs["need"]`` needs over ``secs`` of device time, in percent; None
+    (never 0) where either is missing."""
+    need = obs.get("need")
+    if not secs or need is None or part not in need or "peaks" not in obs:
         return None
     least, _bound = roofline.least_seconds(need[part], obs["peaks"])
     return 100.0 * least / secs
+
+
+def roofline_pct(obs, metric: str, part: str):
+    return share_pct(obs, seconds(obs, metric), part)

@@ -23,7 +23,7 @@ def test_cell_finds_its_files_and_metrics_by_name():
         assert cell.per_layer
         for m in cell.per_layer:
             assert m["moves"] in names
-            harness.load_module("layers", m["name"].split(".")[0]).read({})
+            harness.load_module("layers", m["name"]).read({})
         # --tiny runs the sample's sizes and thresholds
         assert cell.shape()["n_users"] == cell.config["sample"]["n_users"]
         assert cell.settings()["correct"] == cell.config["sample"]["correct"]

@@ -35,8 +35,16 @@ def test_tiny_run_prints_the_line_on_an_earlier_line_and_fails():
         assert "no result" in last and not last.startswith("{")
         line = rehearsal_line(p.stdout)
         assert set(line) - {"breakdown"} == {
-            "correct", "attempted", "failed", "metrics", "device"}
+            "correct", "attempted", "failed", "metrics", "device", "checks"}
         assert line["correct"] is True and line["failed"] == 0
+        # every comparison, its number beside its limit: the line's LAST
+        # key, and the last lines on stderr
+        assert list(line)[-1] == "checks" and line["checks"]
+        assert all(c.startswith("check ok: ") for c in line["checks"])
+        assert [ln[len("[bench] "):] for ln in p.stderr.splitlines()
+                if ln.startswith("[bench] check ")] == line["checks"]
+        assert p.stderr.strip().splitlines()[-1] == (
+            "[bench] " + line["checks"][-1])
         assert {"platform", "kind", "count",
                 "memory_peak_bytes"} <= set(line["device"])
         kind = "per_layer" if trace else "end_to_end"

@@ -143,21 +143,20 @@ def test_every_new_metric_is_declared_for_its_cell_and_has_a_reader():
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    cells = {"": "seqrec-glm47flash-train",
-             ".lfm2": "seqrec-lfm2-8b-a1b-train"}
-    for suffix, cell in cells.items():
-        for name, layer, source in (
-                ("seqrec_unscoped_ms", "seqrec step", "device_trace"),
-                ("seqrec_stack_ms", "seqrec step", "device_trace"),
-                ("seqrec_cast_ms", "kernels", "device_trace"),
-                ("seqrec_norm_residual_ms", "kernels", "device_trace"),
-                ("moe_ragged_dot_ms", "kernels", "device_trace"),
-                ("save_write_s", "save", "program_span"),
-                ("save_sync_s", "save", "program_span")):
-            spec = by_name[name + (suffix or (
-                ".seqrec" if name.startswith("save_") else ""))]
-            assert spec["workloads"] == [cell]
-            assert (spec["layer"], spec["source"], spec["better"],
-                    spec["moves"]) == (layer, source, "lower",
-                                       "train_updates_per_s")
-            assert callable(harness.load_module("layers", name).read)
+    sequence_cells = [w["name"] for w in bench["workloads"]
+                      if w["name"].startswith("seqrec-")]
+    assert len(sequence_cells) == 5
+    for name, layer, source in (
+            ("seqrec_unscoped_ms", "seqrec step", "device_trace"),
+            ("seqrec_stack_ms", "seqrec step", "device_trace"),
+            ("seqrec_cast_ms", "kernels", "device_trace"),
+            ("seqrec_norm_residual_ms", "kernels", "device_trace"),
+            ("moe_ragged_dot_ms", "kernels", "device_trace"),
+            ("save_write_s", "save", "program_span"),
+            ("save_sync_s", "save", "program_span")):
+        spec = by_name[name]        # ONE entry, every sequence cell listed
+        assert spec["workloads"] == sequence_cells
+        assert (spec["layer"], spec["source"], spec["better"],
+                spec["moves"]) == (layer, source, "lower",
+                                   "train_updates_per_s")
+        assert callable(harness.load_module("layers", name).read)

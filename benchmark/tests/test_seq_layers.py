@@ -105,12 +105,15 @@ def test_a_trace_without_the_scopes_reads_other_only():
 # -- the layer readers ----------------------------------------------------------
 
 
-class _Trace:
-    n_devices, busy_s, window_s = 1, 10.0, 20.0
+def _Trace(**op_seconds):
+    """A traced window of 20 s, the device busy for 10 of them."""
+    return trace_reduce.TraceSummary(20.0, 10.0, 1, op_seconds=op_seconds)
 
 
 OBS = {
-    "trace": _Trace(),
+    # the grouped products lie under NO scope: found by their names
+    "trace": _Trace(**{"ragged-dot-none.9": 0.2, "ragged-dot-none.12": 0.05,
+                       "fusion.7": 3.05}),
     "scopes": {"seqrec.mla.attention": 2.0, "seqrec.mla": 1.0,
                "seqrec.moe.route": 0.1, "seqrec.moe.dispatch": 0.2,
                "seqrec.moe.combine": 0.3, "seqrec.moe.experts": 0.5,
@@ -127,14 +130,48 @@ OBS = {
     ("seqrec_device_s", 10.0),
     ("seqrec_step_mfu_pct", 100 * 200e12 / (10.0 * 100e12)),
     ("mla_attention_ms", 2000.0), ("mla_proj_ms", 1000.0),
-    ("moe_route_dispatch_ms", 600.0), ("moe_experts_ms", 500.0),
+    ("moe_route_dispatch_ms", 600.0),
+    ("moe_experts_ms", 500.0 + 250.0),      # the scope + the kernels
+    ("moe_ragged_dot_ms", 250.0),
     ("seqrec_ffn_ms", 1500.0), ("seqrec_head_loss_ms", 700.0),
     ("seqrec_optimizer_ms", 400.0),
     ("mla_attention_roofline", 100 * 1.0 / 2.0),     # bound by flops
-    ("moe_experts_roofline", 100 * 0.1 / 0.5),       # bound by bytes
+    ("moe_experts_roofline", 100 * 0.1 / 0.75),      # bound by bytes
 ])
 def test_device_readers(name, want):
     assert _reader(name).read(OBS) == pytest.approx(want)
+
+
+def test_the_experts_seconds_are_the_scope_plus_the_kernels():
+    """PR 49: ``moe_experts_ms`` and the share's divisor are ALL the
+    seconds the experts cost; the scopes, ``other`` less the kernels
+    (``seqrec_unscoped_ms``) and the experts still add up to what the
+    scopes sum to; None, never the kernels alone, where the trace names
+    no ``seqrec.moe.experts`` — and never a share of 0."""
+    import seq_layers
+
+    assert seq_layers.seconds(OBS, "moe_experts") == pytest.approx(0.75)
+    named = sum(v for k, v in OBS["scopes"].items()
+                if k not in ("other", "seqrec.moe.experts"))
+    assert (named + _reader("seqrec_unscoped_ms").read(OBS) / 1e3
+            + _reader("moe_experts_ms").read(OBS) / 1e3) == pytest.approx(
+        sum(OBS["scopes"].values()))
+    # a trace with no such kernel (another grouped product): the scope
+    quiet = dict(OBS, trace=_Trace(**{"fusion.7": 3.3}))
+    assert _reader("moe_experts_ms").read(quiet) == pytest.approx(500.0)
+    assert _reader("moe_experts_roofline").read(quiet) == pytest.approx(20.0)
+    # the kernels alone, under no scope of that name: nothing to read
+    bare = dict(OBS, scopes={"other": 3.3})
+    assert seq_layers.seconds(bare, "moe_experts") is None
+    assert _reader("moe_experts_ms").read(bare) is None
+    assert _reader("moe_experts_roofline").read(bare) is None
+    assert _reader("moe_ragged_dot_ms").read(bare) == pytest.approx(250.0)
+    # no need, no peak, no such part: no share
+    for without in ("need", "peaks"):
+        assert _reader("moe_experts_roofline").read(
+            {k: v for k, v in OBS.items() if k != without}) is None
+    assert _reader("moe_experts_roofline").read(
+        dict(OBS, need={"train_flops": 1.0})) is None
 
 
 @pytest.mark.parametrize("name", [

@@ -133,4 +133,4 @@ def test_every_new_metric_has_its_reader_file():
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     for spec in bench["per_layer"]:
-        harness.load_module("layers", spec["name"].split(".")[0])
+        harness.load_module("layers", spec["name"])
