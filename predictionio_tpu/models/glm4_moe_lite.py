@@ -132,6 +132,17 @@ class GlmConfig(seq_backbone.ArchitectureConfig):
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+    # what :func:`_mla` reads of a config beside its sizes; a family
+    # with scaled positions (``xing4_0``: YaRN) overrides both
+    @property
+    def rope_freqs(self):
+        """The rope half's frequencies; None: θ^(−i/half)."""
+        return None
+
+    @property
+    def softmax_scale(self) -> float:
+        return 1.0 / np.sqrt(self.qk_head_dim)
+
 
 # -- parameters ---------------------------------------------------------------
 
@@ -221,8 +232,7 @@ def _attention(q, k, v, seg, c: GlmConfig):
     each → [S, H, Dv] in v's dtype. Tiles of at most ``attn_block``
     query rows, and only those between a block's earliest segment and
     the diagonal (:mod:`predictionio_tpu.ops.seq_attention`)."""
-    return seq_backbone.attention(q, k, v, seg, c,
-                                  1.0 / np.sqrt(c.qk_head_dim))
+    return seq_backbone.attention(q, k, v, seg, c, c.softmax_scale)
 
 
 def _mla(w, x, seg, pos, c: GlmConfig):
@@ -241,12 +251,12 @@ def _mla(w, x, seg, pos, c: GlmConfig):
         cq = _rms(_mm(x, w["wqa"], c), w["q_norm"], eps)
         q = _mm(cq, w["wqb"], c).reshape(S, H, dn + dr)
         ckv = _mm(x, w["wkva"], c)
-        k_r = _rope(ckv[:, c.kv_lora_rank:], pos, c.rope_theta)
+        k_r = _rope(ckv[:, c.kv_lora_rank:], pos, c.rope_theta, c.rope_freqs)
         kv = _mm(_rms(ckv[:, :c.kv_lora_rank], w["kv_norm"], eps),
                  w["wkvb"], c).reshape(S, H, dn + dv)
         q = jnp.concatenate(
-            [q[..., :dn], _rope(q[..., dn:], pos[:, None], c.rope_theta)],
-            -1)
+            [q[..., :dn], _rope(q[..., dn:], pos[:, None], c.rope_theta,
+                                c.rope_freqs)], -1)
         k = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(k_r[:, None, :], (S, H, dr))],
             -1)
@@ -278,6 +288,17 @@ def _block(w, x, seg, pos, bias, c: GlmConfig):
         return x + y.reshape(B, S, d), stats
 
 
+def _mtp_entry(w, nxt, x, c: GlmConfig):
+    """h' = W_eh [RMSNorm(Emb(t_{i+1})) ; RMSNorm(x_L)]: what the MTP
+    module's block is given for a stream (``w``: ``params["mtp"]``)."""
+    import jax.numpy as jnp
+
+    with scope("seqrec.mtp"):
+        return _mm(jnp.concatenate(
+            [_rms(nxt, w["enorm"], c.rms_norm_eps),
+             _rms(x, w["hnorm"], c.rms_norm_eps)], -1), w["eh_proj"], c)
+
+
 def _stack(params, bias, batch, c: GlmConfig, mtp: bool = True):
     """Embedding, the main stack and (``mtp``) the MTP module: x_L and
     the module's output [B, S, d], and the expert layers' routing
@@ -294,17 +315,14 @@ def _stack(params, bias, batch, c: GlmConfig, mtp: bool = True):
     with scope("seqrec.embed"):
         x = params["embed"][tokens]
         nxt = params["embed"][batch["tgt1"]] if mtp else None
-    n, w_mtp, eps = c.n_moe_layers, params["mtp"], c.rms_norm_eps
+    n = c.n_moe_layers
 
     def dense(x, w):
         return jax.checkpoint(
             lambda w, x: _block(w, x, seg, pos, None, c)[0])(w, x), None
 
     def enter_mtp(x):
-        with scope("seqrec.mtp"):
-            return _mm(jnp.concatenate(
-                [_rms(nxt, w_mtp["enorm"], eps),
-                 _rms(x, w_mtp["hnorm"], eps)], -1), w_mtp["eh_proj"], c), x
+        return _mtp_entry(params["mtp"], nxt, x, c), x
 
     def turn(i, w, b, x, x_last):
         w = _cast_in_loop(w, c, i)

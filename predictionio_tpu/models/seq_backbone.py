@@ -38,7 +38,8 @@ handed to :func:`build`. Everything else is here, once:
   draws the masks, a pure function of seed, step, sequence and slot);
 - the pieces of a block: ``_mm`` (operands in the matmul dtype,
   float32 accumulation), ``_rms``, ``_rope`` (the backbone applies it
-  where its layer has positions: a layer without applies none),
+  where its layer has positions: a layer without applies none; with
+  the config's own frequencies where it scales them),
   :func:`attention` and :func:`block_attention`
   (:mod:`predictionio_tpu.ops.seq_attention`), ``_swiglu``, the
   expert layer in two halves — ``_route`` (ids, gates, plan and load
@@ -114,7 +115,9 @@ class Backbone(NamedTuple):
     #: (model, history, config) -> scores over the vocabulary:
     #: :func:`next_item_scores`
     next_item_scores: Callable
-    #: per head, the name of its loss in the step's record
+    #: per head, the name of its loss in the step's record (``xing4_0``,
+    #: whose config decides whether the MTP head exists: those it MAY
+    #: train; its config's ``heads`` names those it does)
     heads: Tuple[str, ...]
     #: what ``sequence_logits`` reads of a packed batch (of ``Packed``;
     #: a block-diffusion backbone besides: ``noised``, ``weight``)
@@ -153,7 +156,8 @@ _MODULES = {"glm4_moe_lite": "predictionio_tpu.models.glm4_moe_lite",
             "lfm2_moe": "predictionio_tpu.models.lfm2_moe",
             "smallthinker": "predictionio_tpu.models.smallthinker",
             "sdar_moe": "predictionio_tpu.models.sdar_moe",
-            "qwen3_next": "predictionio_tpu.models.qwen3_next"}
+            "qwen3_next": "predictionio_tpu.models.qwen3_next",
+            "xing4_0": "predictionio_tpu.models.xing4_0"}
 #: an ``architecture`` without ``model_type``, and a model saved before
 #: the table existed
 DEFAULT = "glm4_moe_lite"
@@ -563,7 +567,8 @@ SCOPES = frozenset({
     "seqrec.stack.cast",    # _cast_in_loop
     "seqrec.norm",          # the norm between operator and FFN / experts,
                             # and the experts' copy in the matmul dtype
-    "seqrec.residual",      # the expert branch's residual add
+    "seqrec.residual",      # the expert branch's residual add (xing4_0:
+                            # the write-back, under seqrec.mhc.mix)
     "seqrec.ffn", "seqrec.moe.route", "seqrec.moe.dispatch",
     "seqrec.moe.experts", "seqrec.moe.combine",
     "seqrec.mla", "seqrec.mla.attention", "seqrec.mtp",   # glm4_moe_lite
@@ -573,7 +578,11 @@ SCOPES = frozenset({
     "seqrec.bd", "seqrec.bd.attention", "seqrec.bd.noise",      # sdar_moe
     # qwen3_next: the linear layers (the full one: seqrec.gqa*) — the
     # projections, gates and gated norm; the convolution; the scan
-    "seqrec.gdn", "seqrec.gdn.conv", "seqrec.gdn.scan"})
+    "seqrec.gdn", "seqrec.gdn.conv", "seqrec.gdn.scan",
+    # xing4_0 (its attention: seqrec.mla*): the n-copy residual stream —
+    # self: the copies and the fold; the coefficients (norm, projection,
+    # sigmoids, Sinkhorn); the read u and the write-back
+    "seqrec.mhc", "seqrec.mhc.coef", "seqrec.mhc.mix"})
 
 
 def scope(name: str):
@@ -635,13 +644,18 @@ def _rms(x, g, eps: float):
                              + eps) * g
 
 
-def _rope(x, pos, theta: float):
+def _rope(x, pos, theta: float, freqs: Optional[Sequence[float]] = None):
     """Rotate-half RoPE over the last axis, in float32; ``pos``
-    broadcasts against x's leading axes."""
+    broadcasts against x's leading axes. ``freqs``: the half's
+    frequencies where the config scales them (YaRN), else
+    θ^(−i/half)."""
     import jax.numpy as jnp
 
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freq = jnp.asarray(freqs, jnp.float32)
     ang = pos[..., None].astype(jnp.float32) * freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     a, b = x[..., :half], x[..., half:]
@@ -935,7 +949,9 @@ def train_histories(backbone: Backbone, histories: Sequence[Sequence[int]],
     ``backbone.draws(packed, seed)``: further per-sequence arrays
     [sequences, …] of the batches (what the backbone's noise is keyed
     by). Every ``bd_*`` number of the steps' records is summed onto
-    ``seqrec.fit``."""
+    ``seqrec.fit``; ``mhc_ds_err`` (a backbone whose residual stream is
+    mixed by a Sinkhorn-made matrix: how far from doubly stochastic) is
+    the steps' largest."""
     import jax
     import jax.numpy as jnp
 
@@ -1019,6 +1035,8 @@ def train_histories(backbone: Backbone, histories: Sequence[Sequence[int]],
                         float(rec["moe_load_max_over_mean"].mean()))
             sp.set_attr("router_bias_absmax",
                         float(rec["router_bias_absmax"][-1]))
+            if "mhc_ds_err" in rec:     # a backbone with an n-copy stream
+                sp.set_attr("mhc_ds_err", float(rec["mhc_ds_err"].max()))
     with tracing.span("seqrec.fetch") as sp:
         host = jax.device_get({"params": state["params"],
                                "bias": state["bias"]})

@@ -12,6 +12,10 @@ Pallas TPU kernels for the ops where fusion/streaming matters:
 - :mod:`.gated_delta` — the gated delta rule (a linear-attention
   layer's recurrent state) along those segments, as a chunked scan in
   plain ``jax.numpy`` (sequence backbone).
+- :mod:`.hyper_connections` — a residual stream of n mixed copies: a
+  sublayer's coefficients (one projection, two sigmoids, a Sinkhorn
+  chain a token) and the stream's read and write-back as unrolled
+  multiply-adds, in plain ``jax.numpy`` (sequence backbone).
 
 The kernels above the last have an XLA twin; ``use_pallas()`` decides
 by platform (compiled on TPU, XLA elsewhere, interpret-mode in tests)

@@ -23,6 +23,7 @@ from predictionio_tpu.models import qwen3_next as qn
 from predictionio_tpu.models import sdar_moe as sd
 from predictionio_tpu.models import seq_backbone
 from predictionio_tpu.models import smallthinker as st
+from predictionio_tpu.models import xing4_0 as xg
 from predictionio_tpu.models.seq_rec import _make_tx
 from tests.kernel_calls import kernel_calls
 
@@ -62,6 +63,11 @@ BACKBONES = {
         linear_num_value_heads=4, linear_key_head_dim=16,
         linear_value_head_dim=16, shared_expert_intermediate_size=32,
         num_experts=8, num_hidden_layers=4, gdn_chunk=16))),
+    "xing4_0": (xg, xg.XingConfig.from_architecture(dict(
+        TINY, model_type="xing4_0", num_attention_heads=2, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+        v_head_dim=16, n_routed_experts=8, num_hidden_layers=3,
+        first_k_dense_replace=1, num_nextn_predict_layers=1))),
 }
 #: what this PR added: around and beside the operators' scopes
 NEW = {"seqrec.step", "seqrec.stack", "seqrec.stack.cast", "seqrec.norm",
@@ -75,10 +81,15 @@ OWN = {"glm4_moe_lite": {"seqrec.mla", "seqrec.mla.attention", "seqrec.mtp"},
                         "seqrec.gqa.attention"},
        "sdar_moe": {"seqrec.bd", "seqrec.bd.attention", "seqrec.bd.noise"},
        "qwen3_next": {"seqrec.gdn", "seqrec.gdn.conv", "seqrec.gdn.scan",
-                      "seqrec.gqa", "seqrec.gqa.attention"}}
+                      "seqrec.gqa", "seqrec.gqa.attention"},
+       # GLM's operators under GLM's names, and the stream's own (PR 50)
+       "xing4_0": {"seqrec.mla", "seqrec.mla.attention", "seqrec.mtp",
+                   "seqrec.mhc", "seqrec.mhc.coef", "seqrec.mhc.mix"}}
 #: a scope every other backbone opens and this one has nothing for: no
 #: dense feed-forward layer and no shared expert
-LACKS = {"smallthinker": {"seqrec.ffn"}, "sdar_moe": {"seqrec.ffn"}}
+#: — or whose expert branch's residual add IS the mixer's write-back
+LACKS = {"smallthinker": {"seqrec.ffn"}, "sdar_moe": {"seqrec.ffn"},
+         "xing4_0": {"seqrec.residual"}}
 SCOPE = re.compile(r"seqrec\.[a-z_.]+[a-z_]")    # scope_reduce's pattern
 
 
@@ -158,7 +169,8 @@ def test_the_table_holds_every_name_the_program_opens(compiled):
     check: the table that the program's name is made from lists them
     too."""
     assert compiled["found"] <= seq_backbone.SCOPES
-    assert NEW | OWN[compiled["backbone"]] <= compiled["found"]
+    assert (NEW - LACKS.get(compiled["backbone"], set())
+            | OWN[compiled["backbone"]]) <= compiled["found"]
 
 
 def test_a_new_scope_lies_around_or_beside_the_old_ones(compiled):
@@ -182,7 +194,9 @@ def test_a_new_scope_lies_around_or_beside_the_old_ones(compiled):
                 assert "seqrec.stack" in scopes[:-1], path
         innermost.add(scopes[-1])
     # each name is some operation's innermost: a metric has seconds to read
-    assert NEW <= innermost
+    assert NEW - LACKS.get(compiled["backbone"], set()) <= innermost
+    if compiled["backbone"] == "xing4_0":     # and so has the mixer's each
+        assert {"seqrec.mhc", "seqrec.mhc.coef", "seqrec.mhc.mix"} <= innermost
 
 
 def test_the_backward_pass_lands_under_the_same_names(compiled):
@@ -331,7 +345,8 @@ BINDINGS = {"glm4_moe_lite": {"n_params": "n_params",
             "lfm2_moe": {"n_params": "n_params"},
             "smallthinker": {"n_params": "n_params"},
             "sdar_moe": {"n_params": "n_params"},
-            "qwen3_next": {"n_params": "n_params"}}
+            "qwen3_next": {"n_params": "n_params"},
+            "xing4_0": {}}
 
 
 @pytest.mark.parametrize("model_type", sorted(seq_backbone._MODULES))
@@ -374,6 +389,7 @@ FORWARD_CALLS = {
     "qwen3_next": ("seq_attention_fwd", 4, 2),       # the full run
     "smallthinker": ("seq_attention_fwd", 6, 2),     # the window run's
     "sdar_moe": ("seq_attention_bd_fwd", 2, 0),
+    "xing4_0": ("seq_attention_fwd", 8, 4),          # GLM's two bodies
 }
 
 
