@@ -761,6 +761,8 @@ def _route(router, x, valid, bias, c, softmax: bool = False):
     return gates, p, {
         "load": load, "pairs": valid.sum() * k, "pairs_here": p.pairs_here,
         "dropped": p.pairs_here - p.rows,
+        "blocks_run": moe_dispatch.blocks_run(p),
+        "blocks": jnp.int32(moe_dispatch.row_blocks(p.order.shape[0])[0]),
         "load_max_over_mean": held.max() / jnp.maximum(held.mean(), 1e-9)}
 
 
@@ -909,6 +911,8 @@ def train_program(c, epochs: int, loss_fn, group_squares,
             moe_pairs=moe["pairs"].sum(),
             moe_pairs_here=moe["pairs_here"].sum(),
             moe_dropped_pairs=moe["dropped"].sum(),
+            moe_blocks_run=moe["blocks_run"].sum(),
+            moe_blocks=moe["blocks"].sum(),
             moe_load_max_over_mean=moe["load_max_over_mean"].max(),
             router_bias_absmax=jnp.abs(bias).max())
         return (params, opt_state, bias), record
@@ -1029,6 +1033,7 @@ def train_histories(backbone: Backbone, histories: Sequence[Sequence[int]],
             sp.set_attr("grad_norms_first", {
                 g: float(v) for g, v in zip(groups, rec["group_norms"][0])})
             for k in ("moe_pairs", "moe_pairs_here", "moe_dropped_pairs",
+                      "moe_blocks_run", "moe_blocks",
                       *(k for k in rec if k.startswith("bd_"))):
                 sp.set_attr(k, int(rec[k].sum()))
             sp.set_attr("moe_load_max_over_mean",

@@ -521,17 +521,23 @@ TRAIN_PROGRAMS = {
     # bodies (conv + dense, attention + experts, 3 × conv + experts),
     # the tied head: forward, recomputation and backward of one
     # attention layer's three kernels, and of four expert layers' three
-    # grouped products
+    # grouped products (since PR 51 the turn's recomputation holds none:
+    # the experts' backward rule recomputes its blocks itself — 34
+    # custom calls where the parent had 40, 10.27 GB of temporaries
+    # where it had 11.82)
     "lfm2": (_lfm2_cell_config, 507_820_160, 16, 30, 6.2e9, 12.5e9,
              {"seq_attention_fwd": 2, "seq_attention_dq": 1,
               "seq_attention_dkv": 1}),
     # 370.5 M parameters, 16 steps of 2 × 16,384 slots, two scanned
-    # bodies (1 × global, 3 × window), the untied head; it fits by 1 GB:
-    # the pair buffer is 196,608 rows. The GLOBAL turn keeps the
-    # kernel's output and log-sum-exp (0.239 GB, ``attn_kept_bytes``:
-    # 15.07 GB of temporaries where a plain turn reads 14.93) and runs
-    # its forward once; the window run's three layers would keep
-    # 0.716 GB more, which does not fit, and run theirs twice: three
+    # bodies (1 × global, 3 × window), the untied head. The pair buffer
+    # is 196,608 rows; since PR 51 its sorted side is walked a block of
+    # 8,192 rows at a time as far as the held experts' rows reach, and
+    # the program's temporaries are 11.07 GB where they were 15.07 (the
+    # limit is the parent's line: it fitted by 1 GB). The GLOBAL turn
+    # keeps the kernel's output and log-sum-exp (0.239 GB,
+    # ``attn_kept_bytes``) and runs its forward once; the window run's
+    # three layers would keep 0.716 GB more — which did not fit before
+    # PR 51 and has not been tried since — and run theirs twice: three
     # forward calls, not four
     "smallthinker": (_smallthinker_cell_config, 370_547_200, 16, 30,
                      4.6e9, 15.3e9,
@@ -541,10 +547,10 @@ TRAIN_PROGRAMS = {
     # streams, ONE scanned body of four layers, the noise drawn in the
     # step, the untied head on the noised rows. Every turn keeps the
     # kernel's output and log-sum-exp (0.545 GB over the four layers,
-    # ``attn_kept_bytes``) and the body holds ONE forward call: 8.82 GB
-    # of temporaries where a plain turn reads 9.08 (the second
-    # forward's output and its rows' statistics in their 128 lanes,
-    # 0.40 GB a layer, are no longer made)
+    # ``attn_kept_bytes``) and the body holds ONE forward call: 8.62 GB
+    # of temporaries (8.82 before PR 51) where a plain turn read 9.08
+    # (the second forward's output and its rows' statistics in their
+    # 128 lanes, 0.40 GB a layer, are no longer made)
     "sdar": (_sdar_cell_config, 456_346_624, 32, 15, 5.6e9, 9.6e9,
              {"seq_attention_bd_fwd": 1, "seq_attention_bd_dq": 1,
               "seq_attention_bd_dkv": 1}),
@@ -552,9 +558,9 @@ TRAIN_PROGRAMS = {
     # scanned bodies (3 × linear, 1 × full), the expert half in four
     # chunks of 4,096 rows, the untied head; a linear layer's turn keeps
     # the recurrence's output and a state a 2,048-row block (0.86 GB
-    # over the three: ``gdn_kept_bytes``): 12.18 GB of temporaries (the
-    # updated state 7.5 and the gradients 2.5 among them), 3.8 GB under
-    # the chip
+    # over the three: ``gdn_kept_bytes``): 12.10 GB of temporaries (the
+    # updated state 7.5 and the gradients 2.5 among them; 12.18 before
+    # PR 51), 3.8 GB under the chip
     "qwen3next": (_qwen3next_cell_config, 625_667_136, 16, 15, 7.6e9,
                   12.3e9,
                   {"seq_attention_fwd": 2, "seq_attention_dq": 1,
@@ -564,9 +570,9 @@ TRAIN_PROGRAMS = {
     # stream [4, 1, 4096, 3584] float32 (235 MB a kept boundary, 1.17 GB
     # over the five turns); a turn recomputes coefficients and mixes.
     # The compiler updates the donated state IN PLACE here (the
-    # temporaries, 9.28 GB, hold the gradients 3.04 and the activations,
+    # temporaries, 9.27 GB, hold the gradients 3.04 and the activations,
     # not the state again): what the program holds at once is the
-    # compiler's own ``peak_memory_in_bytes``, 15.01 GB (``PEAK``)
+    # compiler's own ``peak_memory_in_bytes``, 15.005 GB (``PEAK``)
     "xing4": (_xing4_cell_config, 759_346_190, 32, 12, 9.2e9, 9.5e9,
               {"seq_attention_fwd": 4, "seq_attention_dq": 2,
                "seq_attention_dkv": 2}),

@@ -21,6 +21,7 @@ from reference import glm4_moe_lite_jnp as ref  # noqa: E402
 
 from predictionio_tpu.models import glm4_moe_lite as glm  # noqa: E402
 from predictionio_tpu.ops import moe_dispatch  # noqa: E402
+from tests.test_moe_dispatch import poisoned  # noqa: E402
 
 ARCH = dict(
     model_type="glm4_moe_lite", hidden_size=64, intermediate_size=128,
@@ -250,33 +251,6 @@ def test_grouped_product_equals_masked_dense(held):
         assert _rel(g, w) < 1e-5
 
 
-def _poisoned(real):
-    """``grouped_matmul`` as the chip runs it: rows behind the last
-    group are never read and hold NaN afterwards — in the product and
-    in ``lhs``'s cotangent alike."""
-    def behind(a, sizes):
-        return (jnp.arange(a.shape[0]) >= sizes.sum())[:, None]
-
-    @jax.custom_vjp
-    def product(lhs, rhs, sizes):
-        return jnp.where(behind(lhs, sizes), jnp.nan,
-                         real(jnp.where(behind(lhs, sizes), 0, lhs), rhs,
-                              sizes))
-
-    def fwd(lhs, rhs, sizes):
-        return product(lhs, rhs, sizes), (lhs, rhs, sizes)
-
-    def bwd(res, g):
-        lhs, rhs, sizes = res
-        clean = lambda a: jnp.where(behind(a, sizes), 0, a)  # noqa: E731
-        d_lhs, d_rhs = jax.vjp(lambda a, b: real(a, b, sizes), clean(lhs),
-                               rhs)[1](clean(g))
-        return jnp.where(behind(lhs, sizes), jnp.nan, d_lhs), d_rhs, None
-
-    product.defvjp(fwd, bwd)
-    return product
-
-
 def test_rows_behind_the_groups_may_hold_anything(monkeypatch):
     """What the buffer held behind the groups (NaN here) reaches no
     result and no gradient — the router's included, through the gates."""
@@ -300,7 +274,7 @@ def test_rows_behind_the_groups_may_hold_anything(monkeypatch):
 
     want = run()
     monkeypatch.setattr(moe_dispatch, "grouped_matmul",
-                        _poisoned(moe_dispatch.grouped_matmul))
+                        poisoned(moe_dispatch.grouped_matmul))
     got = run()
     for g, wnt in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert bool(jnp.isfinite(g).all())

@@ -425,7 +425,8 @@ def test_four_shares_of_four_experts_add_up_to_the_whole_layer(kind):
 
 def test_the_expert_half_in_chunks_is_the_expert_half_whole():
     """``token_chunk`` rows at a time (the cell: four chunks of 4,096)
-    or all 64 at once: the same result, the loads and pairs summed."""
+    or all 64 at once: the same result, the loads and pairs summed —
+    and a pair buffer a chunk, each of them one block here."""
     c = _config(matmul_dtype="float32")
     w, x, seg, _ = _one_layer(c, "full")
     m, valid = x[0], seg[0] > 0
@@ -434,6 +435,9 @@ def test_the_expert_half_in_chunks_is_the_expert_half_whole():
     y1, s1 = jax.jit(lambda w, m: qn._moe(w, m, valid, _config(
         matmul_dtype="float32", token_chunk=64)))(w, m)
     np.testing.assert_allclose(np.asarray(y4), np.asarray(y1), atol=1e-5)
+    blocks = {k: (int(s4.pop(k)), int(s1.pop(k)))
+              for k in ("blocks", "blocks_run")}
+    assert blocks == {"blocks": (4, 1), "blocks_run": (4, 1)}
     for k in s1:
         np.testing.assert_allclose(np.asarray(s4[k]), np.asarray(s1[k]),
                                    rtol=1e-6)

@@ -296,16 +296,15 @@ def test_under_one_name_the_cache_answers_with_the_old_scopes(tmp_path,
 #: SHA-256 of the text with the program's name (a digest of the scope
 #: table) and the counters behind its private functions' names
 #: (``@closed_call_757``: what ELSE the process lowered moves them, and
-#: a ``checkpoint_name`` does) taken out. ``glm4_moe_lite``,
-#: ``lfm2_moe`` and ``qwen3_next`` as the parent of PR 48 lowers them —
-#: a change to shared code for another backbone leaves them text for
-#: text; ``smallthinker`` and ``sdar_moe`` as PR 48 left them (their
-#: turns keep attention's output: one forward kernel fewer)
-LOWERED = {"glm4_moe_lite": "9e2bc99c28368c02",
-           "lfm2_moe": "d71df69e79a477b8",
-           "qwen3_next": "b64991842aba6bc7",
-           "smallthinker": "e74f85d73d036491",
-           "sdar_moe": "ecd08ba9dce950d7"}
+#: a ``checkpoint_name`` does) taken out. All five as PR 51 left them:
+#: it changed what they SHARE (``moe_dispatch.experts_swiglu`` walks
+#: the pair buffer block by block) — a change to shared code for
+#: ANOTHER backbone leaves them text for text
+LOWERED = {"glm4_moe_lite": "22e46aadf4df5982",
+           "lfm2_moe": "d79e90675dc9e8f7",
+           "qwen3_next": "f00ad8e9db4606d3",
+           "smallthinker": "01e9af5f3b3604c1",
+           "sdar_moe": "a477ea53ed464ee7"}
 
 
 @pytest.mark.parametrize("model_type", sorted(LOWERED))
@@ -374,7 +373,45 @@ def test_a_backbone_is_its_declaration_built_once(model_type):
         assert getattr(module, name) is getattr(b, field)
 
 
-# -- (c) which layer turns keep attention's output (PR 48) --------------------
+# -- (c) the blocks of the pair buffer the experts ran (PR 51) -----------------
+
+
+@pytest.mark.parametrize("model_type, ep_size", [
+    *((m, 1) for m in sorted(BACKBONES)), ("glm4_moe_lite", 4)])
+def test_the_fit_span_counts_the_blocks_the_experts_ran(model_type, ep_size,
+                                                        monkeypatch):
+    """A toy train of four steps on sequences without padding, a pair
+    buffer of 256 rows in blocks of 64: with every expert held every
+    block of every layer and step is run; with two of eight held, fewer
+    — and no pair is dropped either way."""
+    import numpy as np
+
+    from predictionio_tpu.ops import moe_dispatch
+    from predictionio_tpu.utils import tracing
+
+    monkeypatch.setattr(moe_dispatch, "BLOCK_ROWS", 64)
+    module, c = BACKBONES[model_type]
+    # a config no other test trains: the built program is kept by config
+    c = dataclasses.replace(c, ep_size=ep_size, init_std=0.21)
+    histories = np.random.default_rng(0).integers(
+        1, c.vocab_size - 1, (8, c.seq_len))    # sdar_moe: the last is MASK
+    with tracing.verb("unit.train"):
+        seq_backbone.train_histories(module.BACKBONE, histories, c, epochs=1,
+                                     lr=1e-3, seed=0)
+    fit = next(s for s in tracing.last_verb("unit.train")
+               if s["name"] == "seqrec.fit")["attrs"]
+    assert fit["moe_dropped_pairs"] == 0 and fit["losses_finite"]
+    # no padding: every row of every layer's buffer holds a pair
+    assert fit["moe_blocks"] * 64 == fit["moe_pairs"]
+    if ep_size == 1:
+        assert fit["moe_pairs_here"] == fit["moe_pairs"]
+        assert fit["moe_blocks_run"] == fit["moe_blocks"]
+    else:
+        assert 0 < fit["moe_blocks_run"] < fit["moe_blocks"]
+        assert fit["moe_blocks_run"] * 64 >= fit["moe_pairs_here"]
+
+
+# -- (d) which layer turns keep attention's output (PR 48) --------------------
 
 #: backbone → (the forward kernel, its calls in the train program's
 #: jaxpr — two a call site, one a branch of ``platform_dependent`` —
