@@ -140,8 +140,10 @@ def _train_run(engine_factory: str, verbose: int):
     Perfetto/TensorBoard; SURVEY.md §5). The trace starts BEFORE the
     root: an annotation made while no session runs is not recorded, and
     every verb span is one (``pio:train.run`` … in the host plane,
-    utils/tracing.py). ``verbose`` prints the finished tree."""
-    from predictionio_tpu.utils import tracing
+    utils/tracing.py). A verb that compiled says so in one ``compile:``
+    line, verbose or not (a first ``pio train`` is mostly that);
+    ``verbose`` prints the finished tree."""
+    from predictionio_tpu.utils import compilecache, tracing
 
     try:
         with contextlib.ExitStack() as stack:
@@ -154,10 +156,15 @@ def _train_run(engine_factory: str, verbose: int):
                 tracing.verb("train.run", engine_factory=engine_factory))
     finally:
         tree = tracing.last_verb("train.run")
-        if verbose and tree:
-            iid = (tree[0].get("attrs") or {}).get("instance_id") or "-"
-            print(f"[workflow {iid}] train spans:\n"
-                  + tracing.render_trace_tree(tree), flush=True)
+        if tree:
+            attrs = tree[0].get("attrs") or {}
+            iid = attrs.get("instance_id") or "-"
+            compiled = compilecache.compile_line(attrs)
+            if compiled:
+                print(f"[workflow {iid}] {compiled}", flush=True)
+            if verbose:
+                print(f"[workflow {iid}] train spans:\n"
+                      + tracing.render_trace_tree(tree), flush=True)
 
 
 def run_train(

@@ -39,7 +39,10 @@ recorded whether or not tracing is enabled — a verb runs for seconds
 to minutes and opens a few dozen spans, so the record costs nothing
 that matters and is what every timing of the verb is read from. The
 finished tree of the newest verb of each root name stays in memory
-(:func:`last_verb`), and every verb span enters a
+(:func:`last_verb`), beside that of the FIRST one of the process
+(:func:`first_verb`: the cold verb, the only one that holds the
+``compile.*`` spans of :mod:`compilecache` and the full scan), and
+every verb span enters a
 ``jax.profiler.TraceAnnotation("pio:<name>")``: under a profiler
 session (``PIO_PROFILE_DIR``) the span lands in the trace's host plane,
 on the clock of the device operations; with no session that is a flag
@@ -385,6 +388,9 @@ class Tracer:
         #: root name → the finished tree of the newest verb of that
         #: name (:func:`last_verb`)
         self.last_verbs: Dict[str, List[Dict[str, Any]]] = {}
+        #: root name → the finished tree of the FIRST verb of that name
+        #: in this process, written once (:func:`first_verb`)
+        self.first_verbs: Dict[str, List[Dict[str, Any]]] = {}
         #: probability a NEW trace is file-exported (errors and slow
         #: spans always are — tail sampling)
         self.sample_rate = 1.0
@@ -501,7 +507,7 @@ class _Verb:
     ``time.perf_counter_ns`` (what lengths and the order of siblings
     are read from; ``startUs`` is wall-clock and may step)."""
 
-    __slots__ = ("name", "spans", "annotation")
+    __slots__ = ("name", "spans", "annotation", "root")
 
     def __init__(self, name: str) -> None:
         # the train path has imported JAX long before it opens its
@@ -511,6 +517,9 @@ class _Verb:
         self.name = name
         self.spans: List[Dict[str, Any]] = []
         self.annotation = TraceAnnotation
+        #: the verb's root span (set by :func:`verb`): where a child
+        #: leaves what it sums over the whole verb (``compilecache``)
+        self.root: Optional[Span] = None
 
     def record(self, span: Span) -> None:
         d = span.to_dict()
@@ -520,6 +529,7 @@ class _Verb:
         if span.parent_id is None:
             self.spans.sort(key=lambda s: s["startNs"])
             TRACER.last_verbs[self.name] = self.spans
+            TRACER.first_verbs.setdefault(self.name, self.spans)
 
 
 def verb(name: str, **attrs: Any):
@@ -529,8 +539,10 @@ def verb(name: str, **attrs: Any):
     (module docstring). When tracing IS enabled the spans also go to
     the ring and the exporters, like any other."""
     tr = TRACER
-    s = Span(name, new_trace_id(), None,
-             tr.enabled and tr._decide_sampled(), attrs, _Verb(name))
+    record = _Verb(name)
+    s = record.root = Span(name, new_trace_id(), None,
+                           tr.enabled and tr._decide_sampled(), attrs,
+                           record)
     return _SpanHandle(tr, s)
 
 
@@ -540,6 +552,15 @@ def last_verb(name: str) -> Optional[List[Dict[str, Any]]]:
     process. One tree is kept per root name; the next verb replaces
     it."""
     spans = TRACER.last_verbs.get(name)
+    return None if spans is None else list(spans)
+
+
+def first_verb(name: str) -> Optional[List[Dict[str, Any]]]:
+    """As :func:`last_verb`, of the FIRST finished verb rooted at
+    ``name`` in this process: kept once and never replaced. In a ``pio
+    train`` process it is the same verb; in a long-lived one (the
+    continuous trainer, the benchmark) it is the cold one."""
+    spans = TRACER.first_verbs.get(name)
     return None if spans is None else list(spans)
 
 

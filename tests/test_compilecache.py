@@ -14,6 +14,8 @@ import textwrap
 import uuid
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from predictionio_tpu.utils import compilecache
@@ -153,3 +155,192 @@ def test_aot_warmup_smoke_with_persistent_cache(monkeypatch):
     sc.warm_buckets(ladder, ks=(5,))
     [(iv, vv)] = sc.recommend_batch(np.asarray([3], np.int32), 5)
     assert iv.shape == (5,) and vv.shape == (5,)
+
+
+# -- compile as a layer of the verb record -------------------------------------
+
+
+@pytest.fixture
+def record():
+    """The listeners on, the tracer clean, and the counters' readings
+    before the test."""
+    from predictionio_tpu.utils import tracing
+
+    tracing.TRACER.reset()
+    compilecache.enable()
+    yield {"seconds": dict(compilecache._M_SECONDS.items()),
+           "programs": dict(compilecache._M_PROGRAMS.items())}
+    tracing.TRACER.reset()
+
+
+def _moved(counter, before):
+    return {k: v - before.get(k, 0.0) for k, v in counter.items()
+            if v != before.get(k, 0.0)}
+
+
+def _compile_spans(tree):
+    return [s for s in tree if s["name"].startswith("compile.")]
+
+
+def _fresh_jit(salt=1.5):
+    """A program no test has traced, with a jit INSIDE it."""
+    @jax.jit
+    def inner(x):
+        return jnp.sin(x) * salt
+
+    @jax.jit
+    def outer(x):
+        return inner(x).sum() + jnp.tanh(x).sum()
+
+    return outer
+
+
+X = np.arange(8, dtype=np.float32)
+
+
+def test_first_call_under_a_verb_leaves_three_stages_a_second_none(record):
+    from predictionio_tpu.utils import tracing
+
+    f = _fresh_jit()
+    with tracing.verb("unit.verb"):
+        with tracing.span("unit.cold") as cold:
+            f(X).block_until_ready()
+        with tracing.span("unit.warm") as warm:
+            f(X).block_until_ready()
+    tree = tracing.last_verb("unit.verb")
+    stages = _compile_spans(tree)
+    # a jit inside a jit is part of the outer one's trace: one span a
+    # stage, the program's
+    assert [s["name"] for s in stages] == [
+        "compile.trace", "compile.lower", "compile.backend"]
+    assert {s["parentId"] for s in stages} == {cold.span_id}
+    assert warm.span_id not in {s["parentId"] for s in tree}
+    assert all("outer" in s["attrs"]["program"] for s in stages)
+    assert "cache" not in stages[0]["attrs"]
+    assert stages[2]["attrs"]["cache"] in ("miss", "off")
+    assert stages[2]["attrs"]["cache_read_s"] == 0.0
+    for a, b in zip(stages, stages[1:]):      # they add: none overlaps
+        assert a["endNs"] <= b["startNs"]
+    root = tree[0]["attrs"]
+    assert set(compilecache.ROOT_SUMS) <= set(root)
+    assert (root["programs_traced"], root["programs_lowered"],
+            root["programs_compiled"], root["cache_hits"]) == (1, 1, 1, 0)
+    for key, s in zip(("trace_s", "lower_s", "compile_s"), stages):
+        assert root[key] == pytest.approx(
+            (s["endNs"] - s["startNs"]) / 1e9, abs=1e-9)
+    assert root["cache_load_s"] == 0.0
+    line = compilecache.compile_line(root)
+    assert line.startswith("compile: traced 1 (") and \
+        "compiled 1 (" in line and line.endswith("cache answered 0 (0.0 s)")
+    # the registry counted the same three, once each
+    assert sorted(k[0] for k in _moved(compilecache._M_PROGRAMS,
+                                       record["programs"])) == [
+        "compile", "lower", "trace"]
+
+
+def test_a_warm_verb_has_no_compile_span_and_no_sums(record):
+    from predictionio_tpu.utils import tracing
+
+    f = _fresh_jit(2.5)
+    f(X).block_until_ready()
+    with tracing.verb("unit.verb", who="warm"):
+        with tracing.span("unit.step"):
+            f(X).block_until_ready()
+    tree = tracing.last_verb("unit.verb")
+    assert [s["name"] for s in tree] == ["unit.verb", "unit.step"]
+    assert tree[0]["attrs"] == {"who": "warm"}
+    assert compilecache.compile_line(tree[0]["attrs"]) is None
+
+
+def test_outside_a_verb_no_span_is_made_and_the_registry_counts(record):
+    from predictionio_tpu.utils import tracing
+
+    _fresh_jit(3.5)(X).block_until_ready()
+    assert tracing.TRACER.last_verbs == {} and len(tracing.TRACER.ring) == 0
+    seconds = _moved(compilecache._M_SECONDS, record["seconds"])
+    assert set(seconds) == {("trace",), ("lower",), ("compile",)}
+    assert all(v > 0 for v in seconds.values())
+    programs = _moved(compilecache._M_PROGRAMS, record["programs"])
+    assert {k[0]: v for k, v in programs.items()} == {
+        "trace": 1, "lower": 1, "compile": 1}
+    from predictionio_tpu.utils.metrics import REGISTRY
+
+    text = REGISTRY.render()
+    assert 'pio_compile_seconds_total{stage="trace"}' in text
+    assert 'pio_compile_programs_total{stage="compile",cache="' in text
+
+
+def test_stages_on_two_threads_are_kept_apart(record):
+    """The depth is the thread's: a program compiled on a bound thread
+    while this one is inside a trace is recorded, under ITS span."""
+    import threading
+
+    from predictionio_tpu.utils import tracing
+
+    other = _fresh_jit(4.5)
+    seen = {}
+
+    def elsewhere():
+        with tracing.span("unit.pooled") as sp:
+            seen["id"] = sp.span_id
+            other(X).block_until_ready()
+
+    @jax.jit
+    def slow_to_trace(x):
+        t = threading.Thread(target=tracing.bind_current(elsewhere))
+        t.start()
+        t.join(timeout=120)
+        assert not t.is_alive()
+        return jnp.cos(x).sum()
+
+    with tracing.verb("unit.verb"):
+        slow_to_trace(X).block_until_ready()
+    tree = tracing.last_verb("unit.verb")
+    pooled = [s for s in _compile_spans(tree)
+              if s["parentId"] == seen["id"]]
+    assert [s["name"] for s in pooled] == [
+        "compile.trace", "compile.lower", "compile.backend"]
+    assert tree[0]["attrs"]["programs_traced"] == 2
+    assert tree[0]["attrs"]["programs_compiled"] == 2
+
+
+def test_cleared_caches_over_a_cache_directory_read_a_hit(
+        record, tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from predictionio_tpu.utils import tracing
+
+    f = _fresh_jit(5.5)
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "xla"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    cc.reset_cache()
+    try:
+        with tracing.verb("unit.verb"):
+            f(X).block_until_ready()
+        cold = tracing.last_verb("unit.verb")
+        jax.clear_caches()
+        with tracing.verb("unit.verb"):
+            f(X).block_until_ready()
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          min_secs)
+        cc.reset_cache()       # the fixture above puts the directory back
+    assert cold[0]["attrs"]["cache_misses"] == 1
+    assert [s["attrs"]["cache"] for s in _compile_spans(cold)
+            if s["name"] == "compile.backend"] == ["miss"]
+    assert tracing.first_verb("unit.verb") == cold
+    tree = tracing.last_verb("unit.verb")
+    (backend,) = [s for s in tree if s["name"] == "compile.backend"]
+    assert backend["attrs"]["cache"] == "hit"
+    assert 0 < backend["attrs"]["cache_read_s"] <= \
+        (backend["endNs"] - backend["startNs"]) / 1e9
+    root = tree[0]["attrs"]
+    assert (root["cache_hits"], root["cache_misses"],
+            root["programs_compiled"]) == (1, 0, 0)
+    assert root["programs_traced"] == root["programs_lowered"] == 1
+    assert root["compile_s"] == 0.0 < root["cache_load_s"]
+    assert "compiled 0 (0.0 s), cache answered 1 (" in \
+        compilecache.compile_line(root)
+    assert _moved(compilecache._M_PROGRAMS, record["programs"])[
+        ("cache_load", "hit")] == 1

@@ -3,6 +3,7 @@ and the native $set/$unset/$delete fold vs the Python reference fold."""
 
 import datetime as dt
 import json
+import time
 import numpy as np
 
 import pytest
@@ -692,6 +693,37 @@ class TestNativeJsonlImport:
         assert n == 1
         evs = list(store.find(APP))
         assert evs[0].properties == {"rating": 1, "rating2": 2, "ratin": 3}
+
+    @pytest.mark.parametrize("durable", [False, True])
+    def test_append_jsonl_counts_its_own_seconds(self, store, durable):
+        """The import's clock is the program's (``REGISTRY``): the C++
+        call, its fsync (durable stores only) and the Python around
+        them, and the lines that landed."""
+        from predictionio_tpu.utils.metrics import REGISTRY
+
+        events = REGISTRY.counter("pio_ingest_events_total", "", ("path",))
+        seconds = REGISTRY.counter("pio_ingest_seconds_total", "",
+                                   ("stage",))
+        store.set_durable(durable)
+        n_lines = 300
+        blob = "".join(
+            '{"event":"rate","entityType":"user","entityId":"u%d",'
+            '"targetEntityType":"item","targetEntityId":"i%d",'
+            '"properties":{"rating":3.5}}\n' % (i, i % 7)
+            for i in range(n_lines)).encode()
+        before = {k: seconds.get((k,))
+                  for k in ("native", "sync", "python")}
+        had = events.get(("native",))
+        t0 = time.perf_counter()
+        assert store.append_jsonl(blob, n_lines, APP) == (n_lines, [])
+        wall = time.perf_counter() - t0
+        assert events.get(("native",)) - had == n_lines
+        moved = {k: seconds.get((k,)) - v for k, v in before.items()}
+        assert moved["native"] > 0 and moved["python"] > 0
+        assert (moved["sync"] > 0) == durable
+        assert sum(moved.values()) <= wall
+        assert 'pio_ingest_seconds_total{stage="native"}' in \
+            REGISTRY.render()
 
     def test_batch_creation_times_strictly_increase(self, store):
         """Defaulted creationTimes within one import batch must be

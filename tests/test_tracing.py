@@ -336,6 +336,38 @@ class TestVerb:
             pass
         assert tracing.last_verb("unit.verb")[0]["attrs"] == {"run": 2}
 
+    def test_first_verb_is_written_once(self):
+        """The cold verb's record outlives the warm ones: a second verb
+        of the name replaces ``last_verb`` only."""
+        assert tracing.first_verb("unit.verb") is None
+        with tracing.verb("unit.verb", run=1):
+            with tracing.span("unit.cold.only"):
+                pass
+        first = tracing.first_verb("unit.verb")
+        assert first == tracing.last_verb("unit.verb")
+        assert [s["name"] for s in first] == ["unit.verb", "unit.cold.only"]
+        for run in (2, 3):
+            with tracing.verb("unit.verb", run=run):
+                # an open verb displaces neither record
+                assert tracing.first_verb("unit.verb") == first
+        assert tracing.first_verb("unit.verb") == first
+        assert tracing.last_verb("unit.verb")[0]["attrs"] == {"run": 3}
+        first.clear()      # a copy, as last_verb's
+        assert len(tracing.first_verb("unit.verb")) == 2
+        # kept by root name, and a failed verb is a first verb too
+        with pytest.raises(RuntimeError):
+            with tracing.verb("unit.other"):
+                raise RuntimeError("boom")
+        assert tracing.first_verb("unit.other")[0]["status"] == "error"
+        assert tracing.first_verb("unit.verb")[0]["attrs"] == {"run": 1}
+        tracing.TRACER.reset()
+        assert tracing.first_verb("unit.verb") is None
+
+    def test_verb_knows_its_root(self):
+        with tracing.verb("unit.verb") as root:
+            with tracing.span("unit.child") as child:
+                assert child.verb is root.verb and child.verb.root is root
+
     def test_error_closes_the_verb_and_is_recorded(self):
         with pytest.raises(RuntimeError):
             with tracing.verb("unit.verb"):

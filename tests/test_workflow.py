@@ -176,6 +176,8 @@ SPAN_PARENTS = {
     "train.finish": "train.run",
 }
 
+COMPILE_SPANS = {"compile.trace", "compile.lower", "compile.backend"}
+
 SPAN_ATTRS = {
     "train.run": ("engine_factory", "instance_id", "status"),
     "storage.scan": ("scan_cache", "records"),
@@ -231,10 +233,14 @@ def _generator_phase_regex():
 @pytest.fixture(scope="class")
 def traced_train(tmp_path_factory):
     """ONE verbose train with tracing disabled: its tree, its context
-    and what it printed."""
+    and what it printed. A COLD one: whatever this process has jitted
+    is dropped first, so the verb traces, lowers and compiles."""
+    import jax
+
     import predictionio_tpu.core.workflow as wf
 
     tracing.TRACER.reset()
+    jax.clear_caches()
     seen = {}
     build = wf._build_context
 
@@ -288,7 +294,50 @@ class TestTrainSpans:
 
     def test_no_unknown_span(self, traced_train):
         names = {s["name"] for s in traced_train["tree"]}
-        assert names == set(SPAN_PARENTS) | {"train.run"}
+        assert names == set(SPAN_PARENTS) | {"train.run"} | COMPILE_SPANS
+
+    def test_cold_train_names_what_it_compiled(self, traced_train):
+        """The stages of every program the cold verb jitted lie under
+        the span that called it, a few a program, and add up."""
+        tree = traced_train["tree"]
+        ids = {s["spanId"]: s for s in tree}
+        stages = [s for s in tree if s["name"] in COMPILE_SPANS]
+        root = tree[0]["attrs"]
+        assert len(stages) == (root["programs_traced"]
+                               + root["programs_lowered"]
+                               + root["programs_compiled"]
+                               + root["cache_hits"])
+        assert root["programs_compiled"] + root["cache_hits"] >= 1
+        assert len(stages) <= 3 * root["programs_traced"]
+        for s in stages:
+            assert ids[s["parentId"]]["name"] in SPAN_PARENTS
+            assert s["attrs"]["program"]
+            if s["name"] == "compile.backend":
+                assert s["attrs"]["cache"] in ("hit", "miss", "off")
+        assert {ids[s["parentId"]]["name"] for s in stages
+                if s["name"] == "compile.backend"} >= {"als.iterate"}
+        stages.sort(key=lambda s: s["startNs"])
+        for a, b in zip(stages, stages[1:]):
+            assert a["endNs"] <= b["startNs"], (a, b)
+        summed = sum(root[k] for k in ("trace_s", "lower_s", "compile_s",
+                                       "cache_load_s"))
+        assert summed == pytest.approx(
+            sum(s["endNs"] - s["startNs"] for s in stages) / 1e9, abs=1e-6)
+
+    def test_compile_line_is_printed_once_on_its_own_line(
+            self, traced_train):
+        holding = [ln for ln in traced_train["lines"] if "compile:" in ln]
+        root = traced_train["tree"][0]["attrs"]
+        assert len(holding) == 1
+        assert re.fullmatch(
+            rf"\[workflow {traced_train['iid']}\] compile: "
+            rf"traced {root['programs_traced']} \([\d.]+ s\), "
+            rf"lowered {root['programs_lowered']} \([\d.]+ s\), "
+            rf"compiled {root['programs_compiled']} \([\d.]+ s\), "
+            rf"cache answered {root['cache_hits']} \([\d.]+ s\)",
+            holding[0])
+        assert "train phases:" not in holding[0]
+        assert not _generator_phase_regex().search(holding[0])
 
     def test_siblings_do_not_overlap(self, traced_train):
         kids = {}
@@ -348,10 +397,18 @@ class TestTrainSpans:
 
     def test_second_train_replaces_the_tree(self, traced_train):
         first = traced_train["tree"]
-        second_id = run_train(FACTORY, variant=SPAN_VARIANT,
-                              storage=traced_train["storage"],
-                              use_mesh=False)
+        out = io.StringIO()
+        with contextlib.redirect_stdout(out):
+            second_id = run_train(FACTORY, variant=SPAN_VARIANT,
+                                  storage=traced_train["storage"],
+                                  use_mesh=False)
         tree = tracing.last_verb("train.run")
+        # the warm verb jits nothing: the parent's span names, the
+        # parent's root, no compile line — and the cold record is kept
+        assert {s["name"] for s in tree} == set(SPAN_PARENTS) | {"train.run"}
+        assert set(tree[0]["attrs"]) == set(SPAN_ATTRS["train.run"])
+        assert "compile:" not in out.getvalue()
+        assert tracing.first_verb("train.run") == first
         assert tree[0]["attrs"]["instance_id"] == second_id != \
             first[0]["attrs"]["instance_id"]
         assert not {s["spanId"] for s in tree} & {s["spanId"] for s in first}
@@ -504,12 +561,13 @@ def _time_limit(seconds: int):
 def test_verb_spans_land_in_the_profilers_trace(tmp_path):
     """Under a profiler session every verb span is an event of the
     trace's host plane, named ``pio:<span>`` — on the clock of the
-    device operations."""
+    device operations; a cold verb's compile stages among them."""
     import glob
 
     import jax
     from jax.profiler import ProfileData
 
+    jax.clear_caches()
     with _time_limit(240):
         with scan_storage(tmp_path / "home") as st:
             seed_ratings(st)
@@ -530,6 +588,7 @@ def test_verb_spans_land_in_the_profilers_trace(tmp_path):
                   if ev.name.startswith("pio:")}
     tree = tracing.last_verb("train.run")
     assert {"pio:" + s["name"] for s in tree} == set(events)
+    assert {"pio:" + name for name in COMPILE_SPANS} <= set(events)
     for name in ("train.run", "als.prepare"):
         (s,) = _by_name(tree, name)
         ev = events["pio:" + name]
