@@ -461,6 +461,21 @@ def gdn_kept_bytes(c: Qwen3NextConfig) -> int:
             * (c.seq_len * H * dv + blocks * H * dk * dv))
 
 
+def gdn_walk(c: Qwen3NextConfig) -> dict:
+    """The ``seqrec.fit`` span's account of the walk over a block's
+    chunks: the form it takes at this configuration's shapes
+    (``gated_delta.walk_form``: ``"kernel"`` or ``"scan"``) and the
+    chunk steps a train step walks — per linear layer and sequence
+    every chunk forward twice (the pass; block by block inside the
+    rule's backward) and in reverse once."""
+    C = gated_delta.block_rows(c.gdn_chunk, c.seq_len)[0]
+    return {"gdn_walk": gated_delta.walk_form(
+                C, c.linear_key_head_dim, c.linear_value_head_dim),
+            "gdn_walk_chunk_steps": (c.kinds.count("linear")
+                                     * c.seqs_per_step * 3
+                                     * (c.seq_len // C))}
+
+
 # -- the declaration ----------------------------------------------------------
 
 
@@ -479,6 +494,6 @@ BACKBONE = seq_backbone.build(
     fit_attrs=lambda c: {
         "linear_layers": c.kinds.count("linear"),
         "full_layers": c.kinds.count("full"),
-        "gdn_kept_bytes": gdn_kept_bytes(c)})
+        "gdn_kept_bytes": gdn_kept_bytes(c), **gdn_walk(c)})
 
 n_params = BACKBONE.n_params    # benchmark/tests/test_qwen3next_layers.py

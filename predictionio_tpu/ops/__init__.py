@@ -10,8 +10,11 @@ Pallas TPU kernels for the ops where fusion/streaming matters:
 - :mod:`.seq_attention` — causal attention inside the segments of a
   packed sequence, only the tiles a segment reaches (sequence backbone).
 - :mod:`.gated_delta` — the gated delta rule (a linear-attention
-  layer's recurrent state) along those segments, as a chunked scan in
-  plain ``jax.numpy`` (sequence backbone).
+  layer's recurrent state) along those segments, chunk by chunk: the
+  chunks' triangular systems by their inverses, the walk over the
+  chunks as a pair of Pallas kernels (forward, reverse) that keep the
+  state in VMEM — plain ``jax.numpy`` and a ``lax.scan`` at shapes the
+  kernels do not take (sequence backbone).
 - :mod:`.hyper_connections` — a residual stream of n mixed copies: a
   sublayer's coefficients (one projection, two sigmoids, a Sinkhorn
   chain a token) and the stream's read and write-back as unrolled

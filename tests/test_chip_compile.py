@@ -465,6 +465,62 @@ def _qwen3next_cell_config():
     return qn.Qwen3NextConfig.from_architecture(dict(arch, **conf["job"]))
 
 
+#: rows of a chunk → the most scoped VMEM (bytes) the walk's forward and
+#: reverse kernel may use: the cell's chunk of 64 rows (7.1 and 14.3 MB
+#: of the chip's 16 MiB as its compiler counts them), and the least and
+#: the next chunk ``gated_delta.walk_form`` sends to the kernels (a
+#: ``gdn_chunk`` is a config field, 8 rows one tile of a float32: 3.0
+#: and 7.7 MB, 3.7 and 8.7 MB — the state and its products' results do
+#: not shrink with the chunk)
+WALK_CHUNKS = {8: (4 << 20, 8 << 20), 16: (4 << 20, 9 << 20),
+               64: (8 << 20, 15 << 20)}
+
+
+@pytest.mark.parametrize("C", sorted(WALK_CHUNKS))
+def test_the_delta_rules_walk_at_published_widths(one_chip, C):
+    """The walk over one 2,048-row block of the Qwen3-Next cell — 32
+    value heads, a 128 × 128 float32 state a head, 32 chunks of 64 rows
+    (the cell's) or as many of 8 and 16 as the block holds (the
+    shortest chunks the shape rule sends to the kernels) — forward
+    (with the entering states kept) and in reverse, as ``jax.vjp`` of
+    ``gated_delta._walk`` meets them: two Mosaic kernels, each INSIDE
+    the default scoped VMEM — neither asks for a limit of its own
+    (``scoped_memory_configs`` empty), which would change how XLA tiles
+    the rest of the train program (PERF.md §6, PR 41)."""
+    import re
+
+    from predictionio_tpu.ops import gated_delta
+
+    H, D = 32, 128
+    M = gated_delta.BLOCK_ROWS // C
+    assert gated_delta.walk_form(C, D, D) == "kernel"
+
+    def f32(*shape):
+        return _sds(shape, jnp.float32, one_chip)
+
+    operands = (f32(H, D, D), f32(M, H, C, D), f32(M, H, 2 * C, D),
+                f32(M, H, C, C), f32(M, H, C, D), f32(M, H))
+    compiled = jax.jit(
+        lambda d, *operands: jax.vjp(gated_delta._walk, *operands)[1](d)
+    ).lower((f32(H, D, D), f32(M, H, C, D)), *operands).compile()
+    text = compiled.as_text()
+    used = {}
+    for line in text.splitlines():
+        name = re.match(r"\s*(?:ROOT )?%?(gdn_walk_\w+?)(?:\.\d+)? = ", line)
+        if name:
+            assert '"scoped_memory_configs":[]' in line, name.group(1)
+            used[name.group(1)] = int(re.search(
+                r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+                r'"offset":"0","size":"(\d+)"', line).group(1))
+    assert sorted(used) == ["gdn_walk_bwd", "gdn_walk_fwd"]
+    forward, reverse = WALK_CHUNKS[C]
+    assert used["gdn_walk_fwd"] < forward and used["gdn_walk_bwd"] < reverse
+    # the kept entering states [M, 32, 128, 128] float32 and nothing
+    # a chunk's products would leave behind
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        M * H * D * D * 4 + 0.04e9)
+
+
 def _xing4_cell_config():
     """The benchmark's ``seqrec-xing4-29b-a4b-ep8`` as the template
     builds it: the configuration's published keys and its job."""
@@ -515,7 +571,8 @@ def test_latent_attention_at_xing4_widths(one_chip):
 
 #: cell → (its config, the parameters it pins, steps of a train, the
 #: least grouped products, the most bytes of arguments and temporaries,
-#: the attention kernels' calls: forward, dq, dk/dv)
+#: the kernels' calls: attention's forward, dq, dk/dv and the delta
+#: rule's walk)
 TRAIN_PROGRAMS = {
     # 507.8 M parameters, 16 steps of 8 × 4,096 slots, three scanned
     # bodies (conv + dense, attention + experts, 3 × conv + experts),
@@ -560,11 +617,19 @@ TRAIN_PROGRAMS = {
     # the recurrence's output and a state a 2,048-row block (0.86 GB
     # over the three: ``gdn_kept_bytes``): 12.10 GB of temporaries (the
     # updated state 7.5 and the gradients 2.5 among them; 12.18 before
-    # PR 51), 3.8 GB under the chip
+    # PR 51), 3.8 GB under the chip. Since PR 53 the walk over a block's
+    # chunks is its own kernels — forward at the rule's two forward
+    # sites, in reverse once — and the chunks' solve the inverse times
+    # the right-hand side; the chip's compiler reads the same bytes as
+    # before them (temporaries 12,101,136,384 against 12,101,942,784,
+    # arguments 7,512,296,960 either side): inside the rule's backward
+    # a block's entering states [32, 32, 128, 128] are what the
+    # differentiated scan kept too, so the bounds stay where they were
     "qwen3next": (_qwen3next_cell_config, 625_667_136, 16, 15, 7.6e9,
                   12.3e9,
                   {"seq_attention_fwd": 2, "seq_attention_dq": 1,
-                   "seq_attention_dkv": 1}),
+                   "seq_attention_dkv": 1, "gdn_walk_fwd": 2,
+                   "gdn_walk_bwd": 1}),
     # 759.3 M parameters, 32 steps of one 4,096-slot sequence, two
     # scanned bodies (1 × dense, 4 × experts) whose carry is the FOUR-copy
     # stream [4, 1, 4096, 3584] float32 (235 MB a kept boundary, 1.17 GB
@@ -622,7 +687,7 @@ def test_train_program_at_published_widths(one_chip, cell):
     assert _ragged_calls(compiled) >= ragged
     # a kernel's custom call is named after it: ``%seq_attention_fwd.3 =``
     assert collections.Counter(re.findall(
-        r"(?m)^\s*%?(seq_attention_\w+?)(?:\.\d+)? = ",
+        r"(?m)^\s*%?((?:seq_attention|gdn_walk)_\w+?)(?:\.\d+)? = ",
         compiled.as_text())) == attention
     mem = compiled.memory_analysis()
     # the donated state is counted in the arguments AND (updated) in

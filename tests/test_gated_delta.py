@@ -428,3 +428,146 @@ def test_the_unit_lower_solve_is_the_triangular_solve(C):
                                            lower=True), np.float64)
         got = np.asarray(jax.jit(gated_delta.solve_unit_lower)(lower, rhs))
         assert np.abs(got - want).max() <= 2e-5 * max(np.abs(want).max(), 1)
+
+
+@pytest.mark.parametrize("C", [8, 16, 64])
+def test_the_unit_lower_inverse_is_the_inverse(C):
+    """What the solve multiplies its right-hand side by: (I + L)⁻¹,
+    unit lower triangular itself, from the 16-row blocks — also where
+    every entry of L is ½."""
+    rng = np.random.default_rng(C + 1)
+    for lower in (np.tril(rng.normal(size=(2, 3, C, C)) * 0.4, -1),
+                  np.tril(np.full((2, 3, C, C), 0.5), -1)):
+        got = np.asarray(jax.jit(gated_delta.unit_lower_inverse)(
+            jnp.asarray(lower, jnp.float32)), np.float64)
+        np.testing.assert_array_equal(np.triu(got, 1), 0)
+        np.testing.assert_array_equal(np.diagonal(got, 0, -2, -1), 1)
+        eye = got @ (np.eye(C) + lower)
+        assert np.abs(eye - np.eye(C)).max() <= 2e-5 * np.abs(got).max()
+
+
+# -- 7. the walk over a block's chunks: kernels and scan, one rule -------------
+
+WALK_NAMES = ("state", "u", "wq", "p", "k_out", "keep")
+
+
+def _walk_operands(H, Hk, M, starts, run_before, monkeypatch, C=64, D=128):
+    """What :func:`gated_delta._block` hands its walk at kernel-sized
+    shapes (chunks of ``C`` rows, a ``D`` × ``D`` state a head): ``M``
+    chunks whose segments start at the rows ``starts``, the block
+    entered in run ``run_before`` by a state that is not zero."""
+    rng = np.random.default_rng(H * 100 + M)
+    R = M * C
+
+    def unit(shape):
+        x = rng.normal(size=shape)
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    run = 1 + np.isin(np.arange(R), starts).cumsum()
+    rows = tuple(jnp.asarray(a, jnp.float32) for a in (
+        unit((R, Hk, D)), unit((R, Hk, D)), rng.normal(size=(R, H, D)),
+        -rng.uniform(0, 0.1, (R, H)), rng.uniform(0, 1, (R, H))))
+    state = jnp.asarray(rng.normal(size=(H, D, D)) * 0.1, jnp.float32)
+    caught = []
+    with monkeypatch.context() as patch:
+        patch.setattr(gated_delta, "_walk", lambda *operands: (
+            caught.append(operands), (operands[0], operands[1]))[1])
+        gated_delta._block((state, jnp.int32(run_before)),
+                           (*rows, jnp.asarray(run, jnp.int32)), C)
+    return caught[0]
+
+
+@pytest.mark.parametrize("H, Hk, M, starts, run_before", [
+    (2, 2, 1, (20,), 1), (2, 1, 4, (64,), 1), (2, 2, 4, (0,), 0),
+    (8, 4, 1, (), 1), (8, 4, 4, (0, 70, 128, 191), 0)],
+    ids=["H2-M1-inside_a_chunk", "H2-M4-a_chunks_first_row",
+         "H2-M4-the_blocks_first_row", "H8k4-M1-one_segment",
+         "H8k4-M4-starts_of_every_kind"])
+def test_the_walks_kernels_are_the_plain_scan(H, Hk, M, starts, run_before,
+                                              monkeypatch):
+    """``_walk`` at kernel-sized shapes — the Pallas kernels,
+    interpreted on the CPU, behind their own ``custom_vjp`` — against
+    the plain scan differentiated by JAX: the chunks' output, the state
+    that leaves, and the cotangent of every operand and of the state
+    that enters."""
+    operands = _walk_operands(H, Hk, M, starts, run_before, monkeypatch)
+    assert gated_delta.walk_form(64, 128, 128) == "kernel"
+    assert operands[1].shape == (M, H, 64, 128)
+    assert np.abs(np.asarray(operands[0])).max() > 0
+    rng = np.random.default_rng(7)
+    weights = [jnp.asarray(rng.normal(size=shape), jnp.float32)
+               for shape in ((H, 128, 128), (M, H, 64, 128))]
+
+    def graded(walk):
+        return jax.jit(lambda *a: jax.vjp(walk, *a)[1](tuple(weights))
+                       + tuple(walk(*a)))(*operands)
+
+    got = graded(gated_delta._walk)
+    want = graded(gated_delta._scan_walk)
+    for name, a, b in zip(WALK_NAMES + ("left", "out"), got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        # a segment that starts at the block's first row reads nothing
+        # of the state that enters: that state's cotangent is zero
+        wiped = name == "state" and 0 in starts
+        assert a.shape == b.shape and (np.abs(b).max() > 0) != wiped, name
+        assert np.abs(a - b).max() <= 1e-5 * np.abs(b).max(), name
+
+
+def _kernel_calls(jaxpr, found=None):
+    """The names of a jaxpr's ``pallas_call`` equations, those inside
+    its equations' own jaxprs too. A site of the walk holds its kernel
+    TWICE: compiled (a TPU) and interpreted (anywhere else), one taken
+    when the program is lowered."""
+    import collections
+
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_calls(sub, found)
+    return found
+
+
+def _rule_gradient(dk, dv, chunk, policy="none"):
+    """The jaxpr of the rule's gradient on one 128-row sequence of two
+    heads — under a ``jax.checkpoint`` with ``policy``, if any."""
+    rows = tuple(jnp.ones(shape, jnp.float32) for shape in (
+        (1, S, 1, dk), (1, S, 1, dk), (1, S, 2, dv), (1, S, 2), (1, S, 2)))
+    seg = jnp.ones((1, S), jnp.int32)
+
+    def rule(*rows):
+        return gated_delta.gated_delta_rule(*rows, seg, chunk)
+
+    if policy != "none":
+        rule = jax.checkpoint(rule, policy=policy)
+    return jax.make_jaxpr(jax.grad(lambda *rows: rule(*rows).sum(),
+                                   range(5)))(*rows).jaxpr
+
+
+def test_the_shapes_alone_choose_the_walks_form():
+    """The toy shapes of this file's other tests (states of 16 × 8,
+    chunks of 8, 16, 64) walk under ``lax.scan`` and hold no kernel; the
+    benchmark cell's (chunks of 64 rows, a 128 × 128 state) walk in the
+    kernels — forward at the rule's two forward sites, in reverse once."""
+    for chunk in (8, 16, 64, S):
+        assert gated_delta.walk_form(chunk, DK, DV) == "scan"
+    assert gated_delta.walk_form(64, 128, 128) == "kernel"
+    for C, dk, dv in ((60, 128, 128), (64, 64, 128), (64, 128, 192)):
+        assert gated_delta.walk_form(C, dk, dv) == "scan"
+    assert not _kernel_calls(_rule_gradient(DK, DV, 16))
+    assert _kernel_calls(_rule_gradient(128, 128, 64)) == {
+        "gdn_walk_fwd": 2 * 2, "gdn_walk_bwd": 2}
+
+
+def test_a_turn_that_keeps_the_names_still_walks_forward_twice():
+    """The rule inside a checkpoint at kernel-sized shapes: a plain
+    turn runs the forward kernel at three sites (the pass, the turn's
+    recomputation, block by block inside the rule's backward), one
+    that keeps ``KEPT`` at two — and the reverse kernel at one either
+    way."""
+    kept = jax.checkpoint_policies.save_only_these_names(*gated_delta.KEPT)
+    assert _kernel_calls(_rule_gradient(128, 128, 64, None)) == {
+        "gdn_walk_fwd": 2 * 3, "gdn_walk_bwd": 2}
+    assert _kernel_calls(_rule_gradient(128, 128, 64, kept)) == {
+        "gdn_walk_fwd": 2 * 2, "gdn_walk_bwd": 2}

@@ -617,8 +617,13 @@ def test_the_benchmarks_configuration_is_the_published_one():
     assert qn.BACKBONE.n_params(c) * 16 == 10_010_674_176
     # three linear layers keep the rule's output [16,384 × 32 × 128]
     # and the 8 states [32 × 128 × 128] that enter its blocks, float32
-    assert qn.BACKBONE.fit_attrs(c)["gdn_kept_bytes"] == 3 * 4 * (
+    fit = qn.BACKBONE.fit_attrs(c)
+    assert fit["gdn_kept_bytes"] == 3 * 4 * (
         67_108_864 + 4_194_304) == 855_638_016
+    # chunks of 64 rows, a 128 × 128 state: the walk runs in its kernels
+    # — per step three layers' 256 chunks forward twice, in reverse once
+    assert (fit["gdn_walk"], fit["gdn_walk_chunk_steps"]) == (
+        "kernel", 3 * 3 * 256)
 
 
 # -- 6. through the template -------------------------------------------------
@@ -682,6 +687,9 @@ def test_train_deploy_predict_returns_the_references_top_items(storage,
     # 3 linear layers × 2 sequences a step × float32 × (32 rows × 4
     # heads × 16 + one block's entering state 4 × 16 × 16)
     assert fit["gdn_kept_bytes"] == 3 * 2 * 4 * (32 * 4 * 16 + 4 * 16 * 16)
+    # a 16 × 16 state is no whole tile: the walk stays a scan — 3 layers
+    # × 2 sequences × 3 walks × 4 chunks of 8 rows
+    assert (fit["gdn_walk"], fit["gdn_walk_chunk_steps"]) == ("scan", 72)
     assert fit["moe_dropped_pairs"] == 0 and fit["losses_finite"]
     assert fit["router_bias_absmax"] == 0.0
     assert set(fit["grad_norms_first"]) == set(

@@ -296,13 +296,16 @@ def test_under_one_name_the_cache_answers_with_the_old_scopes(tmp_path,
 #: SHA-256 of the text with the program's name (a digest of the scope
 #: table) and the counters behind its private functions' names
 #: (``@closed_call_757``: what ELSE the process lowered moves them, and
-#: a ``checkpoint_name`` does) taken out. All five as PR 51 left them:
-#: it changed what they SHARE (``moe_dispatch.experts_swiglu`` walks
-#: the pair buffer block by block) — a change to shared code for
-#: ANOTHER backbone leaves them text for text
+#: a ``checkpoint_name`` does) taken out. Four as PR 51 left them: it
+#: changed what they SHARE (``moe_dispatch.experts_swiglu`` walks the
+#: pair buffer block by block) — a change to shared code for ANOTHER
+#: backbone leaves them text for text; ``qwen3_next`` as PR 53 left it
+#: (its own ``ops/gated_delta.py``: the chunks' solve is the inverse
+#: times the right-hand side; the walk's kernels are for whole tiles,
+#: and this toy's 16 × 16 state walks under the ``lax.scan`` it had)
 LOWERED = {"glm4_moe_lite": "22e46aadf4df5982",
            "lfm2_moe": "d79e90675dc9e8f7",
-           "qwen3_next": "f00ad8e9db4606d3",
+           "qwen3_next": "e2513e529b578f39",
            "smallthinker": "01e9af5f3b3604c1",
            "sdar_moe": "a477ea53ed464ee7"}
 
